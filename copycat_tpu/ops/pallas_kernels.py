@@ -12,8 +12,9 @@ blocked Pallas kernel instead of ``jnp.sort``:
 - the same closed-form selection is also provided as a pure-jnp reference
   (``kth_largest``), the default path and the differential-test oracle.
 
-On CPU the kernel runs in interpreter mode (tests); on TPU it compiles to
-Mosaic. Gate via ``Config.use_pallas`` (``ops.consensus``).
+The kernel compiles to Mosaic for the TPU; ``interpret=True`` runs it in
+Pallas's interpreter (tests on the CPU). The caller says which — gate via
+``Config.use_pallas`` / ``Config.pallas_interpret`` (``ops.consensus``).
 """
 
 from __future__ import annotations
@@ -22,6 +23,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 INT_MIN = jnp.iinfo(jnp.int32).min
 
@@ -86,14 +88,10 @@ def _kth_kernel(x_ref, out_ref, *, k: int):
     out_ref[...] = jnp.sum(jnp.where(sel, m, 0), axis=0, keepdims=True)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "block", "interpret"))
-def kth_largest_pallas(x: jnp.ndarray, k: int, block: int = 512,
-                       interpret: bool | None = None) -> jnp.ndarray:
-    """k-th largest along axis 1 of ``x [G, P]`` via a Pallas TPU kernel."""
+def _kth_blocks(x: jnp.ndarray, k: int, block: int,
+                interpret: bool) -> jnp.ndarray:
     from jax.experimental import pallas as pl
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     G, P = x.shape
     Gp = (G + block - 1) // block * block
     xt = jnp.transpose(x)  # [P, G] — groups on the lane axis in the kernel
@@ -109,3 +107,25 @@ def kth_largest_pallas(x: jnp.ndarray, k: int, block: int = 512,
         interpret=interpret,
     )(xt)
     return out[0, :G]
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("k", "block", "interpret", "mesh"))
+def kth_largest_pallas(x: jnp.ndarray, k: int, block: int = 512,
+                       interpret: bool = False, mesh=None) -> jnp.ndarray:
+    """k-th largest along axis 1 of ``x [G, P]`` via a Pallas TPU kernel.
+
+    ``mesh``: the mesh ``x`` is sharded over, if any. A Mosaic kernel
+    cannot be partitioned automatically (the TPU compiler refuses a
+    sharded operand outright), so under a mesh the kernel runs inside a
+    ``shard_map``: groups are independent, so each device tallies its own
+    block of the group axis — no collective — and the peer axis, which
+    the tally reduces over, is gathered whole first when the mesh shards
+    it."""
+    kernel = functools.partial(_kth_blocks, k=k, block=block,
+                               interpret=interpret)
+    if mesh is None:
+        return kernel(x)
+    g = "groups" if "groups" in mesh.axis_names else None
+    return jax.shard_map(kernel, mesh=mesh, in_specs=P(g, None),
+                         out_specs=P(g), check_vma=False)(x)

@@ -11,5 +11,11 @@ Two scaling axes (SURVEY.md §2.2):
   traffic (``AtomixReplica.java:358-363``).
 """
 
-from .mesh import make_mesh, raft_specs, shard_state, shard_step_inputs  # noqa: F401
+from .mesh import (  # noqa: F401
+    make_mesh,
+    raft_shardings,
+    raft_specs,
+    shard_state,
+    shard_step_inputs,
+)
 from . import multihost  # noqa: F401  (multi-process: one SPMD step over DCN)

@@ -46,10 +46,21 @@ def raft_specs(mesh: Mesh, state: RaftState) -> RaftState:
         lambda x: P(g, p, *([None] * (x.ndim - 2))), state)
 
 
+def raft_shardings(mesh: Mesh, state: RaftState) -> tuple[RaftState, Any]:
+    """``NamedSharding``s for every ``state`` leaf (arrays or shapes) and
+    for the ``[G,P,P]`` deliver mask — what a program that builds them
+    already placed takes as ``out_shardings``."""
+    g = "groups" if "groups" in mesh.axis_names else None
+    p = "peers" if "peers" in mesh.axis_names else None
+    state_sh = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                            raft_specs(mesh, state),
+                            is_leaf=lambda x: isinstance(x, P))
+    return state_sh, NamedSharding(mesh, P(g, p, None))
+
+
 def shard_state(state: RaftState, mesh: Mesh) -> RaftState:
-    specs = raft_specs(mesh, state)
-    return jax.tree.map(
-        lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), state, specs)
+    return jax.tree.map(jax.device_put, state,
+                        raft_shardings(mesh, state)[0])
 
 
 def shard_step_inputs(submits: Submits, deliver: Any, mesh: Mesh

@@ -50,7 +50,7 @@ from ..ops.consensus import (
     query_step,
     step,
 )
-from .mesh import raft_specs
+from .mesh import raft_shardings
 
 
 def initialize(coordinator_address: str, num_processes: int,
@@ -60,18 +60,13 @@ def initialize(coordinator_address: str, num_processes: int,
     coordinator (process 0's address)."""
     if platform:
         jax.config.update("jax_platforms", platform)
-    # CPU multiprocess needs an explicit collectives backend: jaxlib
-    # builds that default jax_cpu_collectives_implementation to "none"
-    # refuse every cross-process program outright ("Multiprocess
-    # computations aren't implemented on the CPU backend" — the round-9
-    # tier-1 drift). Gloo ships in jaxlib; selecting it restores the
-    # CPU-mesh lockstep tests and is inert for TPU meshes (the knob only
-    # picks the CPU backend's collectives transport). Older jax without
-    # the knob already wires CPU collectives — skip quietly there.
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # noqa: BLE001 — knob absent: nothing to select
-        pass
+    # CPU multiprocess needs an explicit collectives backend: the
+    # installed jaxlib defaults jax_cpu_collectives_implementation to
+    # "none" and refuses every cross-process program outright
+    # ("Multiprocess computations aren't implemented on the CPU
+    # backend"). Gloo ships in jaxlib; it is inert for TPU meshes (the
+    # knob only picks the CPU backend's collectives transport).
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address,
                                num_processes=num_processes,
                                process_id=process_id)
@@ -126,10 +121,7 @@ class MultiHostRaftGroups(RaftGroups):
             members = np.arange(num_peers) < voters
         full = init_state(self.global_groups, num_peers, log_slots,
                           init_key, self.config, members=members)
-        specs = raft_specs(self.mesh, full)
-        is_spec = lambda x: isinstance(x, P)
-        state_sh = jax.tree.map(lambda s: NamedSharding(self.mesh, s),
-                                specs, is_leaf=is_spec)
+        state_sh, _ = raft_shardings(self.mesh, full)
         self.state = jax.tree.map(
             lambda x, s: jax.make_array_from_callback(
                 x.shape, s, lambda idx, x=x: np.asarray(x)[idx]),
@@ -225,11 +217,9 @@ class MultiHostRaftGroups(RaftGroups):
             acc2 = NamedSharding(self.mesh, P("groups", None))
             acc1 = NamedSharding(self.mesh, P("groups"))
             # donation mirrors the single-host deep program: state +
-            # accumulators are handed back to XLA for in-place reuse on
-            # accelerators (saves a full sharded-state copy per round);
-            # unimplemented on CPU, where it would only warn
-            donate = ((0, 1, 2, 3, 4)
-                      if jax.default_backend() != "cpu" else ())
+            # accumulators are handed back to XLA for in-place reuse
+            # (saves a full sharded-state copy per round)
+            donate = (0, 1, 2, 3, 4) if self.donate else ()
             self._deep_jit = jax.jit(
                 partial(deep_step, config=self.config, onehot=True),
                 donate_argnums=donate,

@@ -24,12 +24,9 @@ import math
 import sys
 import time
 
-from ..utils import knobs
-from ..utils.platform import honor_jax_platforms_env
-
-honor_jax_platforms_env()
-
 import numpy as np
+
+from ..utils import knobs
 
 from ..models.raft_groups import RaftGroups
 from ..ops import apply as ap
@@ -587,10 +584,10 @@ def _write_artifact(result: dict) -> None:
 
 
 def main() -> None:
-    from ..utils.platform import enable_compilation_cache, require_devices
-    require_devices(env="COPYCAT_VERDICT_DEVICE_TIMEOUT")
+    from ..utils.platform import enable_compilation_cache, require_platform
+    device = require_platform()
     enable_compilation_cache()
-    result = run_verdict()
+    result = {**run_verdict(), **device}
     if DEEP:
         deep = run_deep_verdict()
         result["deep_plane"] = deep

@@ -66,7 +66,6 @@ SECTIONS = (
     ("durability", "Snapshots & durability"),
     ("observability", "Observability & invariants"),
     ("client", "Client"),
-    ("platform", "Platform & device probing"),
     ("bench", "Bench scenarios (`bench.py`)"),
     ("scaling", "Multichip scaling driver"),
     ("verdict", "Linearizability verdict runner"),
@@ -282,27 +281,6 @@ _knob("COPYCAT_EDGE_FLUSH_MS", "float", 10.0,
       "(state-based merge makes coalescing free); `0` flushes every "
       "event-loop turn", section="client")
 
-# --- platform --------------------------------------------------------------
-_knob("COPYCAT_COMPILE_CACHE", "raw", None,
-      default_doc="`~/.cache/copycat_tpu/xla`",
-      doc="XLA compile-cache directory; `0` or empty disables",
-      section="platform")
-_knob("COPYCAT_DEVICE_TIMEOUT", "float", 120.0,
-      "seconds per device-enumeration probe before declaring the "
-      "accelerator unreachable", section="platform")
-_knob("COPYCAT_DEVICE_PROBES", "int", None,
-      default_doc="5 (entry dryrun: 2)",
-      doc="device-enumeration probe attempts before failing",
-      section="platform")
-_knob("COPYCAT_ENTRY_DEVICE_TIMEOUT", "float", 120.0,
-      "probe timeout for the `__graft_entry__` multichip dryrun",
-      section="platform")
-_knob("COPYCAT_BENCH_DEVICE_TIMEOUT", "float", 120.0,
-      "probe timeout for bench runs (failed probes fall back to CPU "
-      "unless `COPYCAT_BENCH_NO_CPU_FALLBACK=1`)", section="platform")
-_knob("COPYCAT_VERDICT_DEVICE_TIMEOUT", "float", 120.0,
-      "probe timeout for the verdict runner", section="platform")
-
 # --- bench -----------------------------------------------------------------
 _knob("COPYCAT_BENCH_SCENARIO", "str", "counter",
       "scenario: `counter`/`election`/`map`/`map_read`/`lock`/`mixed`/"
@@ -507,9 +485,6 @@ _knob("COPYCAT_BENCH_FANOUT_BURSTS", "int", 3,
 _knob("COPYCAT_BENCH_FANOUT_ZIPF", "float", 0.9,
       "zipf skew exponent for the fanout scenario's key draw",
       section="bench")
-_knob("COPYCAT_BENCH_NO_CPU_FALLBACK", "bool", False,
-      "`1` makes an unreachable accelerator FATAL instead of a degraded "
-      "CPU fallback", section="bench")
 
 # --- scaling ---------------------------------------------------------------
 _knob("COPYCAT_SCALING_GROUPS", "int", 4096,
@@ -563,7 +538,7 @@ def _lookup(name: str) -> Knob:
 def get_raw(name: str) -> str | None:
     """The raw env value, or ``None`` when unset. For tri-state knobs
     where *set at all* is meaningful (``COPYCAT_INVARIANTS``,
-    ``COPYCAT_BENCH_PALLAS``, ``COPYCAT_COMPILE_CACHE``)."""
+    ``COPYCAT_BENCH_PALLAS``)."""
     _lookup(name)
     return os.environ.get(name)
 

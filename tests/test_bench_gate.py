@@ -48,45 +48,6 @@ def test_gate_missing_baseline_and_unit_change():
     assert not ok and "unit changed" in line
 
 
-def test_gate_degraded_mismatch_skips_the_floor():
-    """A CPU-fallback ("degraded": true) artifact graded against a
-    non-degraded window is a different experiment: the comparison is
-    marked degraded_mismatch and the device-plane floor is SKIPPED —
-    even a value far below the floor must not read as a regression."""
-    art = _artifact(value=500.0)  # 20x below the 7,500 floor
-    art["degraded"] = True
-    ok, line = bench_gate.gate_artifact(art, _golden())
-    assert ok, line
-    assert "degraded_mismatch" in line and "skipped" in line
-    assert "REGRESSION" not in line
-    # ...and the mirror: a healthy run against a degraded window
-    golden = _golden(value=500.0)
-    golden["scenarios"]["spi"]["degraded"] = True
-    ok, line = bench_gate.gate_artifact(_artifact(value=9000.0), golden)
-    assert ok and "degraded_mismatch" in line
-    assert "stale" not in line  # a lane change is not a perf win
-    # matching degraded lanes still grade normally
-    art2 = _artifact(value=300.0)  # below the 375 floor
-    art2["degraded"] = True
-    ok, line = bench_gate.gate_artifact(art2, golden)
-    assert not ok and "REGRESSION" in line
-
-
-def test_update_golden_records_the_degraded_lane(tmp_path):
-    golden_path = str(tmp_path / "baseline.json")
-    artifact_path = str(tmp_path / "a.json")
-    art = _artifact(value=500.0)
-    art["degraded"] = True
-    with open(artifact_path, "w") as f:
-        json.dump(art, f)
-    assert bench_gate.main([artifact_path, "--golden", golden_path,
-                            "--update-golden"]) == 0
-    golden = json.load(open(golden_path))
-    assert golden["scenarios"]["spi"]["degraded"] is True
-    # the freshly recorded degraded window gates its own artifact green
-    assert bench_gate.main([artifact_path, "--golden", golden_path]) == 0
-
-
 def test_gate_rejects_empty_headline():
     ok, line = bench_gate.gate_artifact(
         {"scenario": "spi", "value": 0, "unit": "ops/sec"}, _golden())

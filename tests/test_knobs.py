@@ -55,14 +55,7 @@ def test_every_used_knob_is_registered_and_vice_versa():
     registered = set(knobs.REGISTRY)
     assert used - registered == set(), (
         f"unregistered knobs in code: {sorted(used - registered)}")
-    # knobs passed by parameter (require_devices(env=...)) reach the
-    # getters as variables, so they can't be collected statically —
-    # they're exactly the platform probe family
-    indirect = {"COPYCAT_DEVICE_TIMEOUT", "COPYCAT_DEVICE_PROBES",
-                "COPYCAT_ENTRY_DEVICE_TIMEOUT",
-                "COPYCAT_BENCH_DEVICE_TIMEOUT",
-                "COPYCAT_VERDICT_DEVICE_TIMEOUT"}
-    zombies = registered - used - indirect
+    zombies = registered - used
     assert zombies == set(), (
         f"registered knobs no code reads: {sorted(zombies)}")
 

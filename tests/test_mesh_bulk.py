@@ -17,7 +17,7 @@ from copycat_tpu.models import BulkDriver, RaftGroups  # noqa: E402
 from copycat_tpu.ops import apply as ap  # noqa: E402
 from copycat_tpu.ops.consensus import Config  # noqa: E402
 from copycat_tpu.parallel.mesh import make_mesh  # noqa: E402
-from copycat_tpu.parallel.scaling import _census_text, _deep_census  # noqa: E402
+from copycat_tpu.parallel.scaling import census_text, _deep_census  # noqa: E402
 
 
 def _mesh_engine(n_groups=48, seed=51):
@@ -91,5 +91,5 @@ def test_census_positive_control():
     x = jax.device_put(np.ones(64, np.float32),
                        NamedSharding(mesh, P("groups")))
     txt = jax.jit(lambda v: v.sum()).lower(x).compile().as_text()
-    census = _census_text(txt)
+    census = census_text(txt)
     assert census, f"cross-shard sum must census >=1 collective: {txt[:200]}"

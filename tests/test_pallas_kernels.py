@@ -1,8 +1,9 @@
 """Pallas quorum-tally kernel tests (ops/pallas_kernels.py).
 
 Differential against the jnp closed-form selection and against numpy
-sort; plus a full consensus run with Config(use_pallas=True) — interpret
-mode on CPU, Mosaic on TPU.
+sort; plus a full consensus run with Config(use_pallas=True), in
+interpret mode (the tests run on the CPU; tests/test_tpu_compile.py
+compiles the kernel for the chip).
 """
 
 import numpy as np
@@ -31,13 +32,14 @@ def test_pallas_kernel_matches_reference(G):
     rng = np.random.default_rng(G)
     x = rng.integers(0, 1 << 20, (G, 3)).astype(np.int32)
     expect = np.asarray(kth_largest(jnp.asarray(x), 2))
-    got = np.asarray(kth_largest_pallas(jnp.asarray(x), 2, block=256))
+    got = np.asarray(kth_largest_pallas(jnp.asarray(x), 2, block=256,
+                                       interpret=True))
     assert (got == expect).all()
 
 
 def test_pallas_with_duplicates():
     x = jnp.asarray([[5, 5, 5], [1, 1, 2], [0, 7, 7]], jnp.int32)
-    got = np.asarray(kth_largest_pallas(x, 2, block=256))
+    got = np.asarray(kth_largest_pallas(x, 2, block=256, interpret=True))
     assert got.tolist() == [5, 1, 7]
 
 
@@ -46,7 +48,8 @@ def test_consensus_with_pallas_quorum():
     from copycat_tpu.ops import apply as ap
     from copycat_tpu.ops.consensus import Config
 
-    rg = RaftGroups(4, 3, log_slots=32, config=Config(use_pallas=True))
+    rg = RaftGroups(4, 3, log_slots=32, config=Config(use_pallas=True,
+                                  pallas_interpret=True))
     rg.wait_for_leaders()
     tags = [rg.submit(g, ap.OP_LONG_ADD, g + 1) for g in range(4)
             for _ in range(3)]

@@ -1,0 +1,229 @@
+"""Compile rehearsals: the main path's programs, built by the TPU's own
+compiler for a v5e that is described and not attached.
+
+Nothing runs here — a compile says nothing of results or time — but what
+the chip's compiler refuses (a misaligned kernel block, a donated buffer
+it cannot reuse, a program that does not fit) fails in tier-1 instead of
+on the chip. ``tests/tpu_compile_rehearsal.py`` runs the same helpers at
+the deployment sizes ``chip_smoke.py`` uses (too slow for tier-1).
+
+The topology is described inside a fixture and never at import: one
+process at a time may load the TPU library, so only the worker that runs
+this file does. The persistent compile cache is off around the compiles
+(an entry written for a described chip cannot be read back without one).
+"""
+
+from functools import partial
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import (  # noqa: E402
+    Mesh,
+    NamedSharding,
+    PartitionSpec as P,
+    SingleDeviceSharding,
+)
+
+from copycat_tpu.ops.apply import ResourceConfig  # noqa: E402
+from copycat_tpu.ops.consensus import (  # noqa: E402
+    Config,
+    Submits,
+    deep_scan,
+    deep_step,
+    init_state,
+    query_step,
+    step,
+)
+from copycat_tpu.ops.pallas_kernels import kth_largest_pallas  # noqa: E402
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    return Mesh(np.asarray(topo.devices), ("groups",))
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_compile_cache():
+    from jax.experimental.compilation_cache import compilation_cache
+
+    saved = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", saved)
+    compilation_cache.reset_cache()
+
+
+# -- shapes: what each program is called with, placed by ``place`` --------
+
+def _struct(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def group_placer(sharding):
+    """``place(ndim)`` for one chip (everything on it) or for a
+    ``('groups',)`` mesh (leading axis sharded, the rest replicated)."""
+    if isinstance(sharding, Mesh):
+        return lambda ndim: NamedSharding(
+            sharding, P("groups", *([None] * (ndim - 1))))
+    return lambda ndim: sharding
+
+
+def replicated(sharding):
+    if isinstance(sharding, Mesh):
+        return NamedSharding(sharding, P())
+    return sharding
+
+
+def state_shapes(G, P_, L, config, sharding):
+    place = group_placer(sharding)
+    shapes = jax.eval_shape(
+        partial(init_state, G, P_, L, config=config),
+        jax.ShapeDtypeStruct((2,), jnp.uint32))
+    return jax.tree.map(
+        lambda x: _struct(x.shape, x.dtype, place(x.ndim)), shapes)
+
+
+def submit_shapes(lead, sharding, tag_width=None):
+    """``Submits`` with leading dims ``lead`` (``(G, S)`` or ``(W, G, S)``);
+    the group axis is the one before last."""
+    if isinstance(sharding, Mesh):
+        spec = [None] * len(lead)
+        spec[-2] = "groups"
+        sh = NamedSharding(sharding, P(*spec))
+    else:
+        sh = sharding
+    i32 = _struct(lead, jnp.int32, sh)
+    tag = i32 if tag_width is None else _struct(
+        (*lead[:-1], tag_width), jnp.int32, sh)
+    return Submits(opcode=i32, a=i32, b=i32, c=i32, tag=tag,
+                   valid=_struct(lead, jnp.bool_, sh))
+
+
+def step_args(G, P_, L, S, config, sharding):
+    place = group_placer(sharding)
+    return (state_shapes(G, P_, L, config, sharding),
+            submit_shapes((G, S), sharding),
+            _struct((G, P_, P_), jnp.bool_, place(3)),
+            _struct((2,), jnp.uint32, replicated(sharding)))
+
+
+def compile_step(G, P_, L, S, config, sharding):
+    return jax.jit(partial(step, config=config)).lower(
+        *step_args(G, P_, L, S, config, sharding)).compile()
+
+
+def deep_args(G, P_, L, S, B, config, sharding, windows=None):
+    """Arguments of ``deep_step`` (``windows=None``) or ``deep_scan``."""
+    place = group_placer(sharding)
+    state, _, deliver, key = step_args(G, P_, L, S, config, sharding)
+    acc = lambda dt: _struct((G, B), dt, place(2))
+    head = (state, acc(jnp.int32), acc(jnp.bool_), acc(jnp.int32),
+            _struct((G,), jnp.bool_, place(1)),
+            _struct((G,), jnp.int32, place(1)))
+    if windows is None:
+        return (*head, _struct((), jnp.int32, replicated(sharding)),
+                submit_shapes((G, S), sharding, tag_width=1), deliver, key)
+    return (*head, submit_shapes((windows, G, S), sharding, tag_width=1),
+            deliver, key)
+
+
+def compile_deep(G, P_, L, S, B, config, sharding, windows=None):
+    """The deep drive's program exactly as ``models/bulk.py`` jits it on
+    the chip: state and accumulators donated."""
+    fn = deep_step if windows is None else deep_scan
+    onehot = isinstance(sharding, Mesh)
+    return jax.jit(partial(fn, config=config, onehot=onehot),
+                   donate_argnums=(0, 1, 2, 3, 4)).lower(
+        *deep_args(G, P_, L, S, B, config, sharding, windows)).compile()
+
+
+def compile_query(G, P_, L, S, config, sharding):
+    place = group_placer(sharding)
+    return jax.jit(partial(query_step, config=config)).lower(
+        state_shapes(G, P_, L, config, sharding),
+        submit_shapes((G, S), sharding),
+        _struct((G, S), jnp.bool_, place(2))).compile()
+
+
+def collectives_in(compiled) -> dict:
+    from copycat_tpu.parallel.scaling import census_text
+
+    return census_text(compiled.as_text())
+
+
+def has_kernel(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+# -- the cases ------------------------------------------------------------
+
+@pytest.mark.parametrize("P_", [3, 5, 7])
+def test_tally_kernel_compiles_for_the_chip(one_chip, P_):
+    x = _struct((10_000, P_), jnp.int32, one_chip)
+    compiled = kth_largest_pallas.lower(
+        x, k=P_ // 2 + 1, interpret=False).compile()
+    assert has_kernel(compiled)
+
+
+@pytest.mark.parametrize("G,P_,L,S,resource", [
+    (1024, 3, 64, 4, ResourceConfig()),                 # engine default
+    (10_000, 3, 64, 16, ResourceConfig.counters_only()),  # bench counter
+], ids=["engine-default", "counter-10kx3"])
+def test_step_compiles_with_the_kernel_inside(one_chip, G, P_, L, S,
+                                              resource):
+    config = Config(use_pallas=True, resource=resource,
+                    append_window=max(4, S), applies_per_round=max(4, S))
+    compiled = compile_step(G, P_, L, S, config, one_chip)
+    # Config alone decides: the program holds the Mosaic kernel although
+    # this process's default backend is the CPU
+    assert has_kernel(compiled)
+
+
+def test_deep_scan_compiles_with_donation(one_chip):
+    config = Config(monotone_tag_accept=True,
+                    resource=ResourceConfig.counters_only(),
+                    append_window=16, applies_per_round=16)
+    shape = (10_000, 3, 64, 16, 64, config, one_chip)
+    compiled = compile_deep(*shape, windows=64 // 16 + 3)
+    # the donated state + accumulators are reused in place (a refused
+    # donation would hold the whole state twice on the chip)
+    donated = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(
+        deep_args(*shape, windows=64 // 16 + 3)[:5]))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 0.95 * donated, (mem, donated)
+
+
+def test_query_step_compiles(one_chip):
+    compile_query(1024, 3, 64, 8, Config(), one_chip)
+
+
+@pytest.mark.parametrize("pallas", [False, True], ids=["jnp", "pallas"])
+def test_group_sharded_step_has_zero_collectives(four_chips, pallas):
+    # RaftGroups(mesh=...) hands the kernel its mesh the same way: left
+    # alone, the TPU compiler refuses a Mosaic kernel on sharded operands
+    config = Config(use_pallas=pallas,
+                    kernel_mesh=four_chips if pallas else None)
+    compiled = compile_step(4096, 3, 64, 4, config, four_chips)
+    assert collectives_in(compiled) == {}
+    assert has_kernel(compiled) == pallas
