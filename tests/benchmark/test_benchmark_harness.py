@@ -1,0 +1,333 @@
+"""The benchmark's harness rehearsed on the CPU at tiny sizes: each plane
+prints the contract's line with ``correct: true``; a fault in the harness's
+inputs, or the timed path broken underneath it, gives ``correct: false``;
+``run.py`` refuses to run without a TPU; ``BENCHMARK.json`` is well formed and
+everything it names resolves. Tiny sizes come from ``tests/benchmark/data``,
+never from the cells' own files. No number from here is a device number.
+"""
+
+import importlib.util
+import json
+import os
+import re
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+BENCH = os.path.join(REPO, "benchmarks")
+DATA = os.path.join(REPO, "tests", "benchmark", "data")
+SERVED_W, SERVED_R, RAW = ("served-tiny.write-tiny",
+                           "served-tiny.read90-tiny", "mixed-tiny.raw-tiny")
+
+pytest.importorskip("jax")
+sys.path.insert(0, REPO)
+
+
+@pytest.fixture(scope="module")
+def harness():
+    spec = importlib.util.spec_from_file_location(
+        "benchmark_run", os.path.join(BENCH, "run.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def drive(harness, cell, trace=False, fault=None, seed=2**31 + 77):
+    rc, line = harness.run_cell(
+        cell, seed, 0.5, trace, fault,
+        bench_file=os.path.join(DATA, "BENCHMARK.json"), data_root=DATA,
+        require_tpu=False)
+    assert rc == 0
+    json.dumps(line)                       # the line is plain JSON
+    return line
+
+
+@pytest.mark.parametrize("cell,trace", [
+    (SERVED_W, False), (SERVED_R, True), (RAW, False), (RAW, True),
+    (RAW + "4", False)],
+    ids=["served-write", "served-read90-traced", "raw", "raw-traced",
+         "raw-over-a-mesh-of-4"])
+def test_cell_prints_the_contracts_line_and_is_correct(harness, cell, trace):
+    line = drive(harness, cell, trace)
+    assert set(line) >= {"correct", "attempted", "failed", "metrics", "device"}
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] > 0
+    assert set(line["device"]) >= {"platform", "kind", "count",
+                                   "memory_peak_bytes"}
+    assert line["device"]["count"] == (4 if cell.endswith("4") else 1)
+    bench = json.load(open(os.path.join(DATA, "BENCHMARK.json")))
+    group = "per_layer" if trace else "end_to_end"
+    wanted = {m["name"] for m in harness.metrics_of(bench, group, cell)}
+    assert set(line["metrics"]) <= wanted and line["metrics"]
+    if not trace:
+        assert set(line["metrics"]) == wanted
+        assert all(m["value"] > 0 for m in line["metrics"].values())
+    else:
+        assert {"busy_s", "window_s"} <= set(line["device"])
+        assert len(line["breakdown"]["device_ops"]) <= 10
+        assert len(line["breakdown"]["idle_gaps"]) <= 10
+    if cell == SERVED_R:
+        # the tail stands per layer where it is too unsteady end to end
+        assert "ack_p99_ms" not in wanted
+        assert {"client.ack_p99_ms", "client.ack_p50_ms"} <= set(
+            line["metrics"])
+    units = {m["name"]: m["unit"] for m in bench[group]}
+    assert all(v["unit"] == units[k] for k, v in line["metrics"].items())
+
+
+@pytest.mark.parametrize("cell", [SERVED_W, RAW], ids=["served", "raw"])
+@pytest.mark.parametrize("fault", ["drop-ack", "flip-result"])
+def test_a_fault_in_the_harness_gives_correct_false(harness, cell, fault):
+    assert drive(harness, cell, fault=fault)["correct"] is False
+
+
+def test_a_reply_altered_where_it_is_produced_gives_correct_false(
+        harness, monkeypatch):
+    """The served path broken underneath the harness: the client's
+    resource hands back one wrong counter value."""
+    from copycat_tpu.atomic import DistributedAtomicLong
+
+    real, state = DistributedAtomicLong.add_and_get, {"left": 1}
+
+    async def broken(self, delta):
+        value = await real(self, delta)
+        if state["left"]:
+            state["left"] -= 1
+            return value + 1
+        return value
+
+    monkeypatch.setattr(DistributedAtomicLong, "add_and_get", broken)
+    line = drive(harness, SERVED_W)
+    assert line["correct"] is False and state["left"] == 0
+
+
+def test_a_step_that_reports_wrong_results_gives_correct_false(
+        harness, monkeypatch):
+    """The raw plane broken underneath the harness: the compiled step
+    reports every result one too high."""
+    from copycat_tpu.ops import consensus
+
+    real = consensus.step
+
+    def broken(state, submits, deliver, key, config):
+        state, out = real(state, submits, deliver, key, config=config)
+        return state, out._replace(out_result=out.out_result + 1)
+
+    raw = harness.load_module("planes", "raw", DATA)
+    raw.scan_program.cache_clear()
+    monkeypatch.setattr(consensus, "step", broken)
+    try:
+        line = drive(harness, RAW)
+    finally:
+        raw.scan_program.cache_clear()
+    assert line["correct"] is False and line["failed"] > 0
+
+
+def test_a_step_that_returns_its_state_unchanged_gives_no_result(
+        harness, monkeypatch):
+    from copycat_tpu.ops import consensus
+
+    real = consensus.step
+
+    def idle(state, submits, deliver, key, config):
+        _, out = real(state, submits, deliver, key, config=config)
+        return state, out
+
+    raw = harness.load_module("planes", "raw", DATA)
+    raw.scan_program.cache_clear()
+    monkeypatch.setattr(consensus, "step", idle)
+    try:
+        with pytest.raises(RuntimeError, match="have a leader"):
+            drive(harness, RAW)
+    finally:
+        raw.scan_program.cache_clear()
+
+
+def run_cli(cwd, *args, env=None):
+    return subprocess.run(
+        [sys.executable, os.path.join("benchmarks", "run.py"), *args],
+        cwd=cwd, env={**os.environ, "JAX_PLATFORMS": "cpu", **(env or {})},
+        capture_output=True, text=True, timeout=180)
+
+
+def test_run_py_exits_2_and_prints_no_line_without_a_tpu():
+    out = run_cli(REPO, "--workload", "served-1k.write", "--seed", "1",
+                  "--seconds", "1", "--trace", "0")
+    assert out.returncode == 2
+    assert '"correct"' not in out.stdout
+    assert "needs 1 TPU chip" in out.stderr
+
+
+def test_run_py_fails_in_a_directory_with_the_benchmark_alone(tmp_path):
+    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), tmp_path)
+    for path in ("benchmarks", os.path.join("tests", "benchmark")):
+        shutil.copytree(os.path.join(REPO, path), tmp_path / path,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    out = run_cli(tmp_path, "--workload", "served-1k.write", "--seed", "1",
+                  "--seconds", "1", "--trace", "0")
+    assert out.returncode != 0 and '"correct"' not in out.stdout
+
+
+# -- BENCHMARK.json ----------------------------------------------------------
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+
+
+@pytest.fixture(scope="module")
+def bench():
+    return json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+
+
+def test_benchmark_json_has_the_contracts_keys_and_limits(bench):
+    assert set(bench) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+    assert bench["command"] == ["python3", "benchmarks/run.py"]
+    assert bench["paths"] == ["benchmarks", "tests/benchmark"]
+    assert isinstance(bench["run_seconds"], int)
+    assert 1 <= bench["run_seconds"] <= 51
+    assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) <= 64 << 10
+    fours = sum(w["chips"] == 4 for w in bench["workloads"])
+    assert fours <= max(1, len(bench["workloads"]) // 2)
+    for m in bench["end_to_end"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "bound",
+                                          "source"}
+        assert 0.01 <= m["bound"] <= 0.25
+        assert m["source"] in ("host_clock", "device_trace")
+    for m in bench["per_layer"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "source",
+                                          "layer", "moves"}
+        assert m["source"] in ("device_trace", "program_span",
+                                "program_counter", "host_clock")
+    assert "setup_s" in {m["name"] for m in bench["end_to_end"]}
+
+
+def test_every_name_and_unit_is_made_of_the_allowed_characters(bench):
+    names = [x["name"] for group in ("configs", "workloads", "end_to_end",
+                                     "per_layer") for x in bench[group]]
+    names += [w[k] for w in bench["workloads"] for k in ("config", "traffic")]
+    names += [k for c in bench["configs"] for k in c["reduced"]]
+    assert all(NAME.match(n) for n in names), names
+    for group in ("configs", "workloads"):
+        assert len({x["name"] for x in bench[group]}) == len(bench[group])
+    metrics = bench["end_to_end"] + bench["per_layer"]
+    assert len({m["name"] for m in metrics}) == len(metrics)
+    assert all(UNIT.match(m["unit"]) for m in metrics)
+    assert all(m["better"] in ("lower", "higher") for m in metrics)
+    for x in bench["configs"] + bench["workloads"]:
+        assert 1 <= len(x["why"]) <= 200 and "\n" not in x["why"]
+    pairs = [(w["config"], w["traffic"]) for w in bench["workloads"]]
+    assert len(set(pairs)) == len(pairs)
+
+
+def test_everything_benchmark_json_names_resolves(bench, harness):
+    configs = {c["name"]: c for c in bench["configs"]}
+    cells = {w["name"] for w in bench["workloads"]}
+    end_to_end = {m["name"]: m for m in bench["end_to_end"]}
+    assert {w["config"] for w in bench["workloads"]} == set(configs)
+    files = [c["file"] for c in configs.values()]
+    assert len(set(files)) == len(files)
+    for c in configs.values():
+        assert c["file"].startswith("benchmarks/configs/")
+        held = json.load(open(os.path.join(REPO, c["file"])))
+        assert all(key in held for key in c["reduced"])
+    for w in bench["workloads"]:
+        assert w["chips"] in (1, 4)
+        mix = json.load(open(os.path.join(
+            BENCH, "traffic", w["traffic"] + ".json")))
+        assert os.path.exists(os.path.join(
+            BENCH, "planes", mix["plane"] + ".py"))
+        reported = {m["name"] for g in ("end_to_end", "per_layer")
+                    for m in harness.metrics_of(bench, g, w["name"])}
+        assert "setup_s" in reported
+        assert len(reported & set(end_to_end)) >= 2
+        assert reported - set(end_to_end)
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        assert set(m.get("workloads", [])) <= cells
+    for m in bench["per_layer"]:
+        spec = json.load(open(os.path.join(
+            BENCH, "layer_metrics", m["name"] + ".json")))
+        assert all(spec[k] == m[k] for k in ("name", "unit", "better",
+                                             "layer", "moves", "source"))
+        assert os.path.exists(os.path.join(
+            BENCH, "reducers", spec["reducer"] + ".py"))
+        moved = end_to_end[m["moves"]]
+        # every cell that reads the metric reports the metric it moves
+        assert set(m["workloads"]) <= set(moved.get("workloads", cells))
+    peaks = json.load(open(os.path.join(BENCH, "peaks.json")))
+    assert peaks["devices"]["TPU v5 lite"]["hbm_bytes_per_s"] == 819e9
+
+
+# -- the yardstick's own arithmetic ------------------------------------------
+
+def test_percentile_of_an_exact_histogram():
+    import numpy as np
+
+    from benchmarks import generators as gen
+
+    hist = np.zeros(10, np.int64)
+    hist[1], hist[5] = 90, 10            # 90 samples of 1 round, 10 of 5
+    assert gen.percentile_rounds(hist, 0.50) == (1, 1 + 50 / 90)
+    bucket, exact = gen.percentile_rounds(hist, 0.99)
+    assert bucket == 5 and exact == pytest.approx(5.9)
+    assert gen.percentile_rounds(np.zeros(4, np.int64), 0.99) == (0, 0.0)
+
+
+def test_isolation_masks_cycle_and_cut_one_peer():
+    import numpy as np
+
+    from benchmarks import generators as gen
+
+    victims = gen.isolation_masks(48, 7, 5, period=20, seed=3)
+    assert victims.shape == (48, 7)
+    isolated = (victims >= 0).all(axis=1)
+    assert isolated.tolist() == [r % 20 < 10 for r in range(48)]
+    deliver = np.asarray(gen.victim_deliver(victims[0], 7, 5))
+    for g in range(7):
+        v = victims[0, g]
+        assert not deliver[g, v].any() and not deliver[g, :, v].any()
+        assert deliver[g].sum() == 16
+    assert np.asarray(gen.victim_deliver(victims[10], 7, 5)).all()
+
+
+def test_the_plain_model_catches_a_flipped_result():
+    import numpy as np
+
+    from benchmarks import generators as gen
+    from benchmarks import reference
+
+    S = 16
+    pattern = gen.mixed_pattern(S)
+    model = reference.PlainGroup()
+    valid = np.ones((1, 1, S), bool)
+    tag = np.arange(1, S + 1).reshape(1, 1, S)
+    index = tag.copy()
+    result = np.asarray([model.apply(int(pattern[0][j]), int(pattern[1][j]),
+                                     int(pattern[2][j]), j + 1)
+                         for j in range(S)]).reshape(1, 1, S)
+    assert reference.replay_reports(
+        (valid, tag, result, index), pattern, S, [0])[:2] == (S, 0)
+    result[0, 0, 3] ^= 1
+    compared, wrong, first = reference.replay_reports(
+        (valid, tag, result, index), pattern, S, [0])
+    assert (compared, wrong) == (S, 1) and "plain model" in first
+
+
+def test_roofline_bytes_come_from_shapes():
+    harness_dir = os.path.join(BENCH, "reducers", "hbm_roofline.py")
+    spec = importlib.util.spec_from_file_location("hbm_roofline", harness_dir)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    assert module.least_seconds_per_round(819_000_000, 819e9) == 0.002
+    sources = {"clock": {"program": "raw_plane_scan", "state_bytes": 819e6,
+                         "rounds_per_dispatch": 10},
+               "trace": {"modules": [["jit_raw_plane_scan(1)", 0.0, 1e9],
+                                     ["jit_other(2)", 0.0, 5e9]]},
+               "peaks": {"hbm_bytes_per_s": 819e9}}
+    assert module.reduce(sources, {}) == pytest.approx(2.0)
+    sources["trace"]["modules"] = []
+    assert module.reduce(sources, {}) is None
