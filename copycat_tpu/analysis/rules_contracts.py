@@ -24,7 +24,8 @@ and ``set_exception`` never acks anything.
 
 ``span-pairing`` — the causal-trace span discipline (docs/
 OBSERVABILITY.md "Span-name vocabulary"): every literal span name at a
-``Tracer.span``-family call site (``TRACER.span``, ``self._trace_span``)
+``Tracer.span``-family call site (``TRACER.span``, ``self._trace_span``,
+and the batch-scope openers ``TRACER.open_span`` / ``<open>.then``)
 must come from the vocabulary table, exactly as metric-registry
 validates metric names — an off-vocabulary span silently falls out of
 the cross-member assembly, the phase→histogram mapping, and the
@@ -241,6 +242,10 @@ _SPAN_ROW_RE = re.compile(r"^\|\s*`([a-z0-9_.]+)`\s*\|")
 SPAN_RECORD_ATTRS = ("span", "_trace_span")
 SPAN_NAME_ARG = 1
 SPAN_MIN_ARGS = 4
+#: the batch-scope openers (``TRACER.open_span(name, ...)`` and an open
+#: span's ``.then(name, ...)``): the name is the FIRST positional
+#: argument and the timestamps are the opener's own
+SPAN_OPEN_ATTRS = ("open_span", "then")
 
 
 def parse_span_catalog(observability_md: str) -> set[str] | None:
@@ -263,7 +268,7 @@ def parse_span_catalog(observability_md: str) -> set[str] | None:
 
 def _span_family_call(node: ast.Call) -> bool:
     return (isinstance(node.func, ast.Attribute)
-            and node.func.attr in SPAN_RECORD_ATTRS)
+            and node.func.attr in SPAN_RECORD_ATTRS + SPAN_OPEN_ATTRS)
 
 
 def _enclosing_params(tree: ast.Module, lineno: int) -> set[str]:
@@ -318,7 +323,10 @@ def check_span_contract(tree: ast.Module, path: str,
         if not isinstance(node, ast.Call) or not _span_family_call(node):
             continue
         symbol = enclosing_symbol(tree, node.lineno)
-        if len(node.args) < SPAN_MIN_ARGS:
+        opener = node.func.attr in SPAN_OPEN_ATTRS
+        if opener and not node.args:
+            continue  # an unrelated zero-argument .then()/.open_span()
+        if not opener and len(node.args) < SPAN_MIN_ARGS:
             # the record family's signature is (trace, name, start,
             # end, ...): a shorter call is missing its timestamps — the
             # span can't represent a completed (start, end) pair
@@ -330,7 +338,7 @@ def check_span_contract(tree: ast.Module, path: str,
                          "timestamps records nothing pairable"),
                 symbol=symbol))
             continue
-        name_arg = node.args[SPAN_NAME_ARG]
+        name_arg = node.args[0 if opener else SPAN_NAME_ARG]
         if isinstance(name_arg, ast.IfExp) \
                 and const_str(name_arg.body) is not None \
                 and const_str(name_arg.orelse) is not None:

@@ -149,11 +149,16 @@ class RaftClient(Managed):
         self.session_timeout = session_timeout
         self.strategy = connection_strategy or AnyConnectionStrategy()
         self.client_id = f"client-{uuid.uuid4().hex[:8]}-{next(_client_counter)}"
-        # Observability: submit->response latency, retry/re-route and
-        # indeterminate-outcome counters (docs/OBSERVABILITY.md). The
-        # hot path pays one counter add and, per flushed batch, one
-        # histogram record.
+        # Observability: submit->response latency and submitted-operation
+        # counters (docs/OBSERVABILITY.md). The hot path pays one counter
+        # add and, per flushed batch, one histogram record.
         self.metrics = MetricsRegistry()
+        TRACER.register(self.metrics, "client.")
+        # batch-scope tracing (utils/tracing.py): the open client.stage
+        # span of the command micro-batch and of the read batch being
+        # staged (first operation staged -> its flush began)
+        self._stage_span: Any = None
+        self._query_stage_span: Any = None
 
         self._client = transport.client()
         self._loop: asyncio.AbstractEventLoop | None = None  # pinned at open
@@ -317,7 +322,6 @@ class RaftClient(Managed):
                 response = await asyncio.wait_for(conn.send(request), tmo)
             except (TransportError, OSError, asyncio.TimeoutError) as e:
                 last = e
-                self.metrics.counter("client_retries").inc()
                 # A hinted leader that failed the attempt gets no second
                 # pin: _connect prefers the hint, so keeping it after a
                 # timeout re-dialed the SAME stuck server every retry —
@@ -333,7 +337,6 @@ class RaftClient(Managed):
                 continue
             error = getattr(response, "error", None)
             if error in (msg.NOT_LEADER, msg.NO_LEADER):
-                self.metrics.counter("client_reroutes").inc()
                 self._leader_hint = getattr(response, "leader", None)
                 members = getattr(response, "members", None)
                 if members:
@@ -381,7 +384,6 @@ class RaftClient(Managed):
                 break
             self.metrics.counter("client_reads_follower_lane").inc()
             return response
-        self.metrics.counter("client_reads_leader_fallback").inc()
         return await self._request(request, leader_required=False)
 
     # -- session protocol --------------------------------------------------
@@ -486,6 +488,8 @@ class RaftClient(Managed):
         self._pending_batch.append((seq, operation, fut))
         if not self._batch_scheduled:
             self._batch_scheduled = True
+            if TRACER.enabled:
+                self._stage_span = TRACER.open_span("client.stage")
             loop.call_soon(self._launch_batch)
         return fut
 
@@ -495,32 +499,35 @@ class RaftClient(Managed):
     def _launch_batch(self) -> None:
         self._batch_scheduled = False
         batch, self._pending_batch = self._pending_batch, []
+        staged, self._stage_span = self._stage_span, None
         if batch:
-            spawn(self._flush_batch(batch), name="command-batch")
+            spawn(self._flush_batch(batch, staged), name="command-batch")
+        elif staged is not None:
+            staged.drop()
 
-    def _submit_done(self, t0: float, n: int, trace: int | None) -> None:
+    def _submit_done(self, t0: float, n: int, trace: int | None) -> Any:
         """Per-request latency bookkeeping: one histogram sample per wire
         request (every command in a batch experienced that latency), one
-        ``client.submit`` span when tracing."""
+        ``client.submit`` span when tracing. Returns the batch's open
+        ``client.resolve`` span (responses correlated -> last future
+        resolved) when traced, else ``None``."""
         end = time.perf_counter()
         self.metrics.histogram("submit_latency_ms").record((end - t0) * 1e3)
-        if trace is not None:
-            TRACER.span(trace, "client.submit", t0, end, n=n)
+        if trace is None:
+            return None
+        TRACER.span(trace, "client.submit", t0, end, n=n)
+        return TRACER.open_span("client.resolve", trace, start=end)
 
-    def _submit_failed(self, e: BaseException, n: int) -> None:
-        """A submit whose outcome is UNKNOWN is INDETERMINATE — the
-        reference's session-loss command failure. That is exactly the
-        routing-exhaustion ProtocolError from ``_request`` (per-attempt
-        timeouts are retried internally and surface as NO_LEADER; the
-        command may have been appended by a leader we lost)."""
-        if isinstance(e, msg.ProtocolError) \
-                and e.code in (msg.NO_LEADER, msg.NOT_LEADER):
-            self.metrics.counter("commands_indeterminate").inc(n)
-
-    async def _flush_batch(self, batch: list) -> None:
+    async def _flush_batch(self, batch: list, staged: Any = None) -> None:
         self.metrics.counter("commands_submitted").inc(len(batch))
-        trace = TRACER.new_trace() if TRACER.enabled else None
-        t0 = time.perf_counter()
+        if staged is not None:
+            # the staging span joins the flush's trace (its id crosses
+            # the wire); the flush begins where the staging ends
+            trace = staged.trace_id
+            t0 = staged.close(n=len(batch))
+        else:
+            trace = TRACER.new_trace() if TRACER.enabled else None
+            t0 = time.perf_counter()
         if len(batch) == 1:
             seq, operation, fut = batch[0]
             try:
@@ -529,13 +536,14 @@ class RaftClient(Managed):
                     operation=operation, trace=trace))
                 result = self._finish(response, seq)
             except BaseException as e:  # noqa: BLE001 — delivered via fut
-                self._submit_failed(e, 1)
                 if not fut.done():
                     fut.set_exception(e)
                 return
-            self._submit_done(t0, 1, trace)
+            resolve = self._submit_done(t0, 1, trace)
             if not fut.done():
                 fut.set_result(result)
+            if resolve is not None:
+                resolve.close(n=1)
             return
         try:
             response = await self._request(msg.CommandBatchRequest(
@@ -546,12 +554,11 @@ class RaftClient(Managed):
             if getattr(response, "error", None):
                 self._finish(response, None)
         except BaseException as e:  # noqa: BLE001
-            self._submit_failed(e, len(batch))
             for _, _, fut in batch:
                 if not fut.done():
                     fut.set_exception(e)
             return
-        self._submit_done(t0, len(batch), trace)
+        resolve = self._submit_done(t0, len(batch), trace)
         resp_entries = response.entries or []
         # positional fast path: the server answers in request order, so
         # the common case correlates by zip — the by-seq dict is built
@@ -593,6 +600,9 @@ class RaftClient(Managed):
                 if not fut.done():
                     fut.set_exception(e)
             raise
+        finally:
+            if resolve is not None:
+                resolve.close(n=len(batch))
 
     def _ack_seq(self, seq: int, index: int | None) -> None:
         """Per-command success bookkeeping (the _finish tail): advance the
@@ -630,19 +640,37 @@ class RaftClient(Managed):
             (operation, fut))
         if not self._query_flush_scheduled:
             self._query_flush_scheduled = True
+            if TRACER.enabled:
+                self._query_stage_span = TRACER.open_span("client.stage")
             loop.call_soon(self._launch_query_batches)
         return await fut
 
     def _launch_query_batches(self) -> None:
         self._query_flush_scheduled = False
         pending, self._pending_queries = self._pending_queries, {}
+        staged, self._query_stage_span = self._query_stage_span, None
+        t_staged = staged.start if staged is not None else None
         for consistency, items in pending.items():
             if items:
-                spawn(self._flush_query_batch(consistency, items),
+                if staged is None and t_staged is not None:
+                    # a further level staged this turn: a batch of its own
+                    staged = TRACER.open_span("client.stage", start=t_staged)
+                spawn(self._flush_query_batch(consistency, items, staged),
                       name="query-batch")
+                staged = None
+        if staged is not None:
+            staged.drop()
 
     async def _flush_query_batch(self, consistency: str,
-                                 items: list) -> None:
+                                 items: list, staged: Any = None) -> None:
+        """One consistency level's read batch. ``staged`` is its open
+        ``client.stage`` span when traced; the batch's ``client.query``
+        (flush -> responses correlated) and ``client.resolve`` spans
+        follow it under the same client-local id (a ``Query*Request``
+        carries no trace id)."""
+        n = len(items)
+        query = (staged.then("client.query", n=n)
+                 if staged is not None else None)
         leader_required = consistency in ("linearizable",
                                           "bounded_linearizable")
         # Edge read tier (docs/EDGE_READS.md): these reads already
@@ -674,13 +702,19 @@ class RaftClient(Managed):
                         request, leader_required=leader_required)
                 result = self._finish(response, None)
             except BaseException as e:  # noqa: BLE001 — delivered via fut
+                if query is not None:
+                    query.close(n=1, error=type(e).__name__)
                 if not fut.done():
                     fut.set_exception(e)
                 return
+            resolve = (query.then("client.resolve", n=1)
+                       if query is not None else None)
             if subscribe is not None and edge is not None:
                 edge.seed_response(items, getattr(response, "edge", None))
             if not fut.done():
                 fut.set_result(result)
+            if resolve is not None:
+                resolve.close(n=1)
             return
         try:
             request = msg.QueryBatchRequest(
@@ -696,10 +730,14 @@ class RaftClient(Managed):
             if getattr(response, "error", None):
                 self._finish(response, None)  # raises the right exception
         except BaseException as e:  # noqa: BLE001
+            if query is not None:
+                query.close(n=n, error=type(e).__name__)
             for _, fut in items:
                 if not fut.done():
                     fut.set_exception(e)
             return
+        resolve = (query.then("client.resolve", n=n)
+                   if query is not None else None)
         if subscribe is not None and edge is not None:
             edge.seed_response(items, getattr(response, "edge", None))
         try:
@@ -725,6 +763,9 @@ class RaftClient(Managed):
                 if not fut.done():
                     fut.set_exception(e)
             raise
+        finally:
+            if resolve is not None:
+                resolve.close(n=n)
 
     def _finish(self, response: Any, seq: int | None) -> Any:
         error = getattr(response, "error", None)

@@ -15,6 +15,7 @@ deterministic apply at batch scale.
 
 from __future__ import annotations
 
+import time
 from collections import deque
 from functools import lru_cache, partial
 from typing import Any
@@ -34,6 +35,7 @@ from ..ops.consensus import (
     query_step,
     step,
 )
+from ..utils.tracing import TRACER
 
 
 @lru_cache(maxsize=None)
@@ -213,6 +215,14 @@ class RaftGroups:
         # first-class ops/sec + latency metrics (SURVEY.md §5.5)
         from ..utils.metrics import MetricsRegistry
         self.metrics = MetricsRegistry()
+        # what the host pays around the compiled step: wall time of the
+        # step call itself, blocking device->host fetches and their bytes
+        # (the whole-window report reads the counters' deltas as engine.*)
+        self._m_step_wall = self.metrics.histogram("step_wall_ms")
+        self._m_fetches = self.metrics.counter("fetches")
+        self._m_fetch_bytes = self.metrics.counter("fetch_bytes")
+        self._m_settle_rounds = self.metrics.counter("query_settle_rounds")
+        TRACER.register(self.metrics, "engine.")
         # device-plane flight recorder: hub folds the step's telemetry
         # deltas into the device.* metric family, the flight ring, and
         # the online invariant monitor (models/telemetry.py)
@@ -447,20 +457,28 @@ class RaftGroups:
     def _stage_deliver(self, deliver: Any) -> Any:
         return deliver
 
+    def _note_fetch(self, host: Any) -> Any:
+        """Count one blocking device->host fetch and its bytes (every
+        fetch hook, the multi-host overrides too, ends in this)."""
+        self._m_fetches.inc()
+        self._m_fetch_bytes.inc(
+            sum(getattr(x, "nbytes", 0) for x in jax.tree.leaves(host)))
+        return host
+
     def _fetch_outputs(self, raw: StepOutputs) -> StepOutputs:
         # ONE overlapped device->host transfer for all output arrays: the
         # lazy per-array np.asarray calls in the harvest each paid a
         # blocking transfer of their own.
         for leaf in jax.tree.leaves(raw):
             leaf.copy_to_host_async()
-        return jax.tree.map(np.asarray, raw)
+        return self._note_fetch(jax.tree.map(np.asarray, raw))
 
     def _stale_any(self, raw: StepOutputs, out: StepOutputs) -> bool:
         return bool(out.stale.any())
 
     def _run_query(self, sub: Submits, atomic) -> tuple[Any, Any]:
         results, served = self._query(self.state, sub, atomic)
-        return np.asarray(results), np.asarray(served)
+        return self._note_fetch((np.asarray(results), np.asarray(served)))
 
     # Deep-plane hooks (models/bulk.py _drive_deep): accumulator staging,
     # fetch, and the jitted deep program. The multihost subclass overrides
@@ -490,7 +508,7 @@ class RaftGroups:
     def _fetch_acc(self, arrays: Any) -> Any:
         """Fetch a pytree of group-leading device arrays to host numpy
         (this process's local block on multihost)."""
-        return jax.device_get(arrays)
+        return self._note_fetch(jax.device_get(arrays))
 
     def _deep_fn(self) -> Any:
         """The jitted ``deep_step`` used by the deep drive. One-hot
@@ -500,18 +518,39 @@ class RaftGroups:
                              donate=self.donate)
 
     def step_round(self, submits: Submits | None = None,
-                   deliver: Any | None = None) -> StepOutputs:
-        """Advance every group one round; harvests results into ``results``."""
+                   deliver: Any | None = None,
+                   correlate: Any | None = None) -> StepOutputs:
+        """Advance every group one round; harvests results into ``results``.
+        ``correlate(out)`` is a caller's own pass over the round's outputs
+        (``drive_vector``'s): it runs at the end of the round, inside its
+        harvest stage. One function, no inner helper: every Python frame
+        under the first call of the compiled step lengthens each of its
+        operations' source locations, and lowering pays for it."""
+        stage = TRACER.open_span("engine.stage") if TRACER.enabled else None
         explicit = submits is not None
         if submits is None:
             submits = self._build_submits()
         self._key, key = jax.random.split(self._key)
         dl = self.deliver if deliver is None else self._stage_deliver(deliver)
-        with self.metrics.timer("step_wall_ms"):
-            self.state, raw = self._step(
-                self.state, self._stage_submits(submits), dl, key)
-            raw = jax.block_until_ready(raw)  # time compute, not dispatch
+        staged = self._stage_submits(submits)
+        if stage is not None:
+            stage = stage.then("engine.wait")
+            t0 = stage.start
+        else:
+            t0 = time.perf_counter()
+        self.state, raw = self._step(self.state, staged, dl, key)
+        raw = jax.block_until_ready(raw)  # time compute, not dispatch
+        if stage is not None:
+            stage = stage.then("engine.fetch")
+            t1 = stage.start
+        else:
+            t1 = time.perf_counter()
+        self._m_step_wall.record((t1 - t0) * 1e3)
+        fetched = self._m_fetch_bytes.value
         out = self._fetch_outputs(raw)
+        if stage is not None:
+            stage = stage.then(
+                "engine.harvest", bytes=self._m_fetch_bytes.value - fetched)
         self.rounds += 1
         self.metrics.counter("rounds").inc()
         if not explicit:
@@ -535,6 +574,10 @@ class RaftGroups:
             self.state = self._install(self.state, raw.stale, raw.leader)
         if self._sessions is not None:
             self._sessions.tick()
+        if correlate is not None:
+            correlate(out)
+        if stage is not None:
+            stage.close()
         return out
 
     def step_rounds(self, n: int) -> None:
@@ -560,19 +603,36 @@ class RaftGroups:
             for _ in range(n):
                 self.step_round()
             return
+        stage = TRACER.open_span("engine.stage") if TRACER.enabled else None
         submits = self._build_submits()
         self._key, key = jax.random.split(self._key)
         fused = _fused_rounds_program(self.config, n)
-        with self.metrics.timer("step_wall_ms"):
-            self.state, raw0, raws = fused(self.state, submits,
-                                           self.deliver, key)
-            raws = jax.block_until_ready(raws)
+        if stage is not None:
+            stage = stage.then("engine.wait", rounds=n)
+            t0 = stage.start
+        else:
+            t0 = time.perf_counter()
+        self.state, raw0, raws = fused(self.state, submits,
+                                       self.deliver, key)
+        raws = jax.block_until_ready(raws)
+        if stage is not None:
+            stage = stage.then("engine.fetch", rounds=n)
+            t1 = stage.start
+        else:
+            t1 = time.perf_counter()
+        self._m_step_wall.record((t1 - t0) * 1e3)
         # overlap BOTH transfers (round 0 + the stacked tail) before the
         # first blocking conversion — one round-trip for the whole fetch
         for leaf in jax.tree.leaves(raws):
             leaf.copy_to_host_async()
+        fetched = self._m_fetch_bytes.value
         out0 = self._fetch_outputs(raw0)
         outs = jax.tree.map(np.asarray, raws)
+        self._m_fetch_bytes.inc(sum(x.nbytes for x in jax.tree.leaves(outs)))
+        if stage is not None:
+            stage = stage.then(
+                "engine.harvest", rounds=n,
+                bytes=self._m_fetch_bytes.value - fetched)
         self.rounds += 1
         self.metrics.counter("rounds").inc()
         self._requeue_rejected(submits, out0)
@@ -594,6 +654,8 @@ class RaftGroups:
         if bool(outs.stale[-1].any()):
             last = jax.tree.map(lambda x: x[-1], raws)
             self.state = self._install(self.state, last.stale, last.leader)
+        if stage is not None:
+            stage.close(rounds=n)
 
     def serve_query(self, group: int, opcode: int, a: int = 0, b: int = 0,
                     c: int = 0, max_attempts: int = 50,
@@ -701,6 +763,7 @@ class RaftGroups:
         out = np.zeros(n, np.int64)
         if n == 0:
             return out
+        span = TRACER.open_span("engine.query") if TRACER.enabled else None
         bc = lambda x: np.broadcast_to(
             np.asarray(x, np.int32).ravel(), (n,))
         op_a, a_a, b_a, c_a = bc(opcode), bc(a), bc(b), bc(c)
@@ -730,7 +793,7 @@ class RaftGroups:
         at[gs, slots] = at_a[order]
         done = np.zeros(n, bool)
         served_ctr = self.metrics.counter("queries_served")
-        for _ in range(max_attempts):
+        for attempt in range(max_attempts):
             results, served = self._run_query(sub, at)
             hit = served[gs, slots] & ~done[order]
             if hit.any():
@@ -741,8 +804,18 @@ class RaftGroups:
                 sub.valid[gs[hit], slots[hit]] = False
             if self._agree(bool(done.all())):
                 self.metrics.counter("query_vector_drives").inc()
+                if span is not None:
+                    span.close(attempts=attempt + 1, width=S, n=n)
                 return out
-            self.step_round()  # no leader yet / applied < commit: settle
+            # no leader yet / applied < commit: settle
+            self._m_settle_rounds.inc()
+            if span is not None:
+                with TRACER.scope(span.trace_id, "engine.query"):
+                    self.step_round()
+            else:
+                self.step_round()
+        if span is not None:
+            span.close(attempts=max_attempts, width=S, n=n, error="timeout")
         raise TimeoutError(
             f"query vector: {int((~done).sum())}/{n} rows unservable "
             f"after {max_attempts} attempts")
@@ -788,7 +861,6 @@ class RaftGroups:
             # permanent rejection (e.g. a config change that would empty
             # the group): fail to the client now — requeueing would block
             # the group's queue forever behind the FIFO suffix-reject
-            failed = self.metrics.counter("ops_refused")
             for g, s in zip(*np.nonzero(refused & valid)):
                 tag = int(submits.tag[g, s])
                 # recorded for UNTRACKED tags too: drive_vector's rows
@@ -796,7 +868,6 @@ class RaftGroups:
                 # refused row would spin the whole run to TimeoutError —
                 # failing rows that DID commit on device
                 self.results[tag] = FAIL
-                failed.inc()
                 if tag in self._inflight:
                     self._inflight.pop(tag)
                     self._inflight_ops.pop(tag, None)
@@ -837,7 +908,6 @@ class RaftGroups:
             idx_l = np.asarray(out.out_index)[gi, ii].tolist()
             term_l = np.asarray(out.out_term)[gi, ii].tolist()
             latency = self.metrics.histogram("commit_latency_rounds")
-            resubmitted = self.metrics.counter("ops_resubmitted")
             inflight = self._inflight
             results = self.results
             rounds = self.rounds
@@ -871,7 +941,6 @@ class RaftGroups:
                                 self._queues.setdefault(
                                     g, deque()).appendleft(
                                     (*self._inflight_ops[owner], owner))
-                                resubmitted.inc()
                         pend = self._placements.get(g)
                         if pend:  # refresh the stale lower bound
                             self._pend_min[g] = min(
@@ -1014,8 +1083,11 @@ class RaftGroups:
         done = np.zeros(n, bool)
         self.metrics.counter("ops_submitted").inc(n)
         remaining = n
-        for _ in range(max_rounds):
-            out = self.step_round()
+
+        def correlate(out: StepOutputs) -> None:
+            """This block's rows among the round's reports, in one numpy
+            pass (part of the round's harvest)."""
+            nonlocal remaining
             valid = np.asarray(out.out_valid)
             if valid.any():
                 gi, ii = np.nonzero(valid)
@@ -1041,6 +1113,9 @@ class RaftGroups:
                         res[k] = v
                         done[k] = True
                         remaining -= 1
+
+        for _ in range(max_rounds):
+            self.step_round(correlate=correlate)
             if remaining == 0:
                 self.metrics.counter("ops_committed").inc(n)
                 return res

@@ -172,7 +172,7 @@ class MultiHostRaftGroups(RaftGroups):
         for leaf in jax.tree.leaves(raw):
             for s in leaf.addressable_shards:
                 s.data.copy_to_host_async()
-        return jax.tree.map(self._local_block, raw)
+        return self._note_fetch(jax.tree.map(self._local_block, raw))
 
     def _stale_any(self, raw, out) -> bool:
         # the install decision must be GLOBALLY consistent (install runs
@@ -185,7 +185,8 @@ class MultiHostRaftGroups(RaftGroups):
             self._sub_sharding, np.ascontiguousarray(atomic))
         results, served = self._query(self.state, self._stage_submits(sub),
                                       g_atomic)
-        return self._local_block(results), self._local_block(served)
+        return self._note_fetch((self._local_block(results),
+                                 self._local_block(served)))
 
     # -- deep-plane hooks (models/bulk.py _drive_deep) --------------------
     # The deep drive stages submits through _stage_submits (above) and
@@ -209,7 +210,7 @@ class MultiHostRaftGroups(RaftGroups):
         for leaf in jax.tree.leaves(arrays):
             for s in leaf.addressable_shards:
                 s.data.copy_to_host_async()
-        return jax.tree.map(self._local_block, arrays)
+        return self._note_fetch(jax.tree.map(self._local_block, arrays))
 
     def _deep_fn(self):
         if self._deep_jit is None:

@@ -156,6 +156,7 @@ class RaftServer(Managed):
         # plane the ONE group shares this registry object, so the
         # pre-refactor names/values are preserved exactly.
         self._metrics = metrics or MetricsRegistry()
+        TRACER.register(self._metrics, "server.")
 
         # Health plane (docs/OBSERVABILITY.md "Health & diagnosis"):
         # online anomaly detectors at a fixed cadence + the durable
@@ -224,6 +225,12 @@ class RaftServer(Managed):
         # RaftGroup.__init__ reaches flush_fused via _restore_snapshot.
         self._fused_runs: list[tuple[RaftGroup, list]] = []
         self._fuse_scheduled = False
+        # batch-scope tracing of the command pump (utils/tracing.py): the
+        # id of the pump turn being staged, and its open apply.park span
+        # (first run staged -> the flush began); both None when idle or
+        # untraced
+        self._pump_batch: int | None = None
+        self._park_span: Any = None
         self._m_apply_fused = self._metrics.counter("apply.fused_dispatches")
         self._m_apply_fused_rows = self._metrics.histogram(
             "apply.fused_rows")
@@ -287,7 +294,7 @@ class RaftServer(Managed):
         try:
             # staged-but-undispatched fused rows complete (and ack)
             # before the groups fail whatever else is pending
-            self.flush_fused()
+            self.flush_fused("close")
         except Exception:  # noqa: BLE001 — close must proceed
             logger.exception("fused apply flush at close failed")
         if self.health is not None:
@@ -1080,6 +1087,9 @@ class RaftServer(Managed):
         dependency conflict before then forces :meth:`flush_fused`
         inline (the staged effects must land before the conflicting
         entry applies)."""
+        if TRACER.enabled and not self._fused_runs:
+            self._park_span = TRACER.open_span(
+                "apply.park", self.pump_batch(), "apply")
         self._fused_runs.append((grp, run))
         if self._fuse_scheduled:
             return
@@ -1088,7 +1098,7 @@ class RaftServer(Managed):
             asyncio.get_running_loop().call_soon(self._fused_tick)
         except RuntimeError:
             # no running loop (synchronous replay harness): dispatch now
-            self.flush_fused()
+            self.flush_fused("no_loop")
 
     def _fused_tick(self) -> None:
         try:
@@ -1096,13 +1106,22 @@ class RaftServer(Managed):
         except Exception:  # noqa: BLE001 — a loop callback must not raise
             logger.exception("fused apply dispatch failed")
 
-    def flush_fused(self) -> None:
+    def pump_batch(self) -> int:
+        """The id the current pump turn's batch-scope spans are recorded
+        under, minted on first use (call only when the tracer is on);
+        the turn's flush retires it."""
+        if self._pump_batch is None:
+            self._pump_batch = TRACER.new_trace()
+        return self._pump_batch
+
+    def flush_fused(self, forced: str | None = None) -> None:
         """Dispatch every staged run as ONE mixed-rows engine round,
         then finalize per group in staging (= per-group log) order.
         Forced synchronously by dependency conflicts, gated reads,
-        snapshot captures and server close; otherwise runs once per
-        event-loop turn. An empty collector is a free no-op (every
-        forced-flush site relies on that).
+        snapshot captures and server close (``forced`` says which, for
+        the trace); otherwise runs once per event-loop turn. An empty
+        collector is a free no-op (every forced-flush site relies on
+        that).
 
         The documented architecture shares ONE engine across groups
         (``_manager_factory``), so the partition below is normally a
@@ -1113,6 +1132,13 @@ class RaftServer(Managed):
         staged, self._fused_runs = self._fused_runs, []
         if not staged:
             return
+        batch, self._pump_batch = self._pump_batch, None
+        park, self._park_span = self._park_span, None
+        if park is not None:
+            if forced is not None:
+                park.close(forced=forced)
+            else:
+                park.close()
         engines: list = []   # insertion-ordered; runs stay in log order
         per_engine: dict[int, list] = {}
         for grp, run in staged:
@@ -1122,21 +1148,30 @@ class RaftServer(Managed):
                 bucket = per_engine[id(engine)] = []
                 engines.append(engine)
             bucket.append((grp, run))
-        for engine in engines:
-            self._flush_fused_engine(engine, per_engine[id(engine)])
+        if not TRACER.enabled:
+            for engine in engines:
+                self._flush_fused_engine(engine, per_engine[id(engine)])
+            return
+        # the turn's synchronous section: the engine records its stages
+        # under the turn's id, as children of the requests' apply spans
+        with TRACER.scope(batch or TRACER.new_trace(), "apply"):
+            for engine in engines:
+                self._flush_fused_engine(engine, per_engine[id(engine)])
 
     def _flush_fused_engine(self, engine, staged: list) -> None:
         rows = [row for _, run in staged for row in run]
         self._m_apply_fused.inc()
         self._m_apply_fused_rows.record(len(rows))
-        self._m_apply_fused_groups.record(
-            len({g.group_id for g, _ in staged}))
+        n_groups = len({g.group_id for g, _ in staged})
+        self._m_apply_fused_groups.record(n_groups)
         # mid-batch forced flushes drain the window's in-flight
         # generator chains from EARLIER entries inside the shared
         # dispatch helper, so each group's device-op order follows its
         # log
         raws, pump_error = dispatch_vector_rows(engine, engine.window,
                                                 rows)
+        finalize = (TRACER.open_span("apply.finalize")
+                    if TRACER.enabled else None)
         offset = 0
         for grp, run in staged:
             grp._finalize_vector_run(
@@ -1144,6 +1179,8 @@ class RaftServer(Managed):
                 raws[offset:offset + len(run)] if pump_error is None
                 else [], pump_error)
             offset += len(run)
+        if finalize is not None:
+            finalize.close(rows=len(rows), groups=n_groups)
 
     def drop_fused(self, grp: RaftGroup) -> None:
         """Discard ``grp``'s staged rows (group shutdown: its commit

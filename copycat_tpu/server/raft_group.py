@@ -86,13 +86,16 @@ def dispatch_vector_rows(engine: Any, window: Any, rows: list
     The ONE marshalling of the staged row shape, shared by the per-group
     lane (``RaftGroup._apply_vector_run``) and the server's fused
     cross-group dispatch (``RaftServer._flush_fused_engine``)."""
+    n = len(rows)
+    marshal = TRACER.open_span("apply.marshal") if TRACER.enabled else None
     if window is not None and window.busy:
         try:
             window.barrier()
         except Exception as e:  # noqa: BLE001 — fail rows, not hang
             logger.exception("window drain before vector dispatch failed")
+            if marshal is not None:
+                marshal.close(rows=n, error="barrier")
             return [], str(e)
-    n = len(rows)
     groups_idx = [0] * n
     opc = [0] * n
     av = [0] * n
@@ -101,6 +104,8 @@ def dispatch_vector_rows(engine: Any, window: Any, rows: list
     for k, (_clock, _e, _s, machine, _i, _op, spec) in enumerate(rows):
         groups_idx[k] = machine._group
         opc[k], av[k], bv[k], cv[k] = spec[0], spec[1], spec[2], spec[3]
+    if marshal is not None:
+        marshal.close(rows=n)
     try:
         return engine.run_vector(groups_idx, opc, av, bv, cv), None
     except Exception as e:  # liveness failure: fail loudly, not hang
@@ -289,6 +294,7 @@ class RaftGroup:
         # read pump windows (per group: the gate is per-group leadership)
         self._read_windows: dict[str, list] = {}
         self._read_flush_scheduled = False
+        self._read_queue_span: Any = None   # open read.queue when traced
 
         # Edge read tier (docs/EDGE_READS.md): member-local subscriber
         # registry next to the event channels — resource id -> {session
@@ -314,6 +320,7 @@ class RaftGroup:
         # bit-identical; a private registry merged under a group= label
         # into the stats surface otherwise).
         self.metrics = metrics
+        TRACER.register(metrics, "group.")
         m = metrics
         self._m_apply_entry = m.counter("applies_per_entry")
         self._m_append_entries = m.histogram("append_batch_entries")
@@ -404,6 +411,10 @@ class RaftGroup:
         self._trace_window_marks: dict[int, int] = {}
         self._trace_commit_t: dict[int, float] = {}
         self._trace_entry_marks: dict[int, int] = {}
+        # trace -> id of the pump turn (batch) that applied its block,
+        # stamped onto the request's apply span as ``batch=`` so the
+        # assembly lays the turn's stages inside it
+        self._trace_batch: dict[int, int] = {}
         self._member = str(self.address)
         self._trace_slow_ms = knobs.get_float("COPYCAT_TRACE_SLOW_MS")
 
@@ -620,6 +631,17 @@ class RaftGroup:
         if hist is not None:
             hist.record((t1 - t0) * 1e3)
 
+    def _trace_apply(self, trace: int, fallback: float, t2: float,
+                     index: int) -> None:
+        """The request's ``apply`` span: the commit instant (or
+        ``fallback``) -> ``t2``, linked to the pump turn that applied
+        the block where one was traced."""
+        t_commit = self._trace_commit_t.pop(trace, fallback)
+        batch = self._trace_batch.pop(trace, None)
+        link = {} if batch is None else {"batch": batch}
+        self._trace_span(trace, "apply", t_commit, t2, self._m_lat_apply,
+                         index=index, **link)
+
     def _trace_note_slow(self, trace: int, t0: float, t1: float) -> None:
         """Slow-trace exemplar: a traced request whose server residency
         exceeded ``COPYCAT_TRACE_SLOW_MS`` lands in the device-plane
@@ -637,6 +659,7 @@ class RaftGroup:
         self._trace_window_marks.clear()
         self._trace_commit_t.clear()
         self._trace_entry_marks.clear()
+        self._trace_batch.clear()
 
     def _flight_note(self, kind: str, **fields) -> None:
         """Best-effort note in the device-plane flight recorder (the ring
@@ -757,7 +780,7 @@ class RaftGroup:
             # staged-but-undispatched fused vector rows are device
             # effects the image at last_applied must include — drain
             # the collector before capturing (a no-op when empty)
-            self.server.flush_fused()
+            self.server.flush_fused("snapshot")
             self._take_snapshot()
 
     def _boot_recover(self) -> None:
@@ -799,7 +822,7 @@ class RaftGroup:
         # dispatch them against the PRE-restore state they were staged
         # on, or they would double-apply on top of the restored image
         # at the end-of-turn tick (a no-op at boot / when empty)
-        self.server.flush_fused()
+        self.server.flush_fused("restore")
         # decode EVERYTHING decodable into locals before the first
         # mutation of self, so a malformed image fails fast with this
         # server still pristine (the boot path then falls back to full
@@ -2098,6 +2121,7 @@ class RaftGroup:
             if trace is not None:
                 self._trace_watch.pop(index, None)
                 self._trace_commit_t.pop(trace, None)
+                self._trace_batch.pop(trace, None)
                 for i in range(index - len(entries) + 1, index + 1):
                     self._trace_entry_marks.pop(i, None)
             if e.code in (msg.NOT_LEADER, msg.NO_LEADER):
@@ -2112,9 +2136,7 @@ class RaftGroup:
                          for seq, _ in entries])
         if trace is not None:
             t2 = time.perf_counter()
-            t_commit = self._trace_commit_t.pop(trace, t1)
-            self._trace_span(trace, "apply", t_commit, t2,
-                             self._m_lat_apply, index=index)
+            self._trace_apply(trace, t1, t2, index)
         if self._event_pushes:
             # Events-before-response (reference Consistency.java:157-176):
             # the general path gates each LINEARIZABLE response on its
@@ -2290,6 +2312,7 @@ class RaftGroup:
             if trace is not None and fresh:
                 self._trace_watch.pop(index, None)
                 self._trace_commit_t.pop(trace, None)
+                self._trace_batch.pop(trace, None)
                 for i in range(index - len(fresh) + 1, index + 1):
                     self._trace_entry_marks.pop(i, None)
             return None, (e.code, e.detail, e.leader)
@@ -2297,9 +2320,7 @@ class RaftGroup:
         if trace is not None:
             t2 = time.perf_counter()
             if fresh:
-                t_commit = self._trace_commit_t.pop(trace, t1)
-                self._trace_span(trace, "apply", t_commit, t2,
-                                 self._m_lat_apply, index=index)
+                self._trace_apply(trace, t1, t2, index)
             else:
                 # nothing appended (pure dedup/in-flight waits): the
                 # coarse commit span is all there is to attribute
@@ -2395,7 +2416,7 @@ class RaftGroup:
         # at ``last_applied``, so those device effects must land first
         # (the read WINDOW flushes in ``run_query_window``; a free no-op
         # when nothing is staged)
-        self.server.flush_fused()
+        self.server.flush_fused("read")
         return None
 
     def _edge_seed_response(self, request: Any, response: Any,
@@ -2539,16 +2560,29 @@ class RaftGroup:
             (session_id, client_index, operation, fut))
         if not self._read_flush_scheduled:
             self._read_flush_scheduled = True
+            if TRACER.enabled:
+                # the turn's first staged read opens every level's queue
+                self._read_queue_span = TRACER.open_span("read.queue")
             loop.call_soon(self._launch_read_windows)
         return fut
 
     def _launch_read_windows(self) -> None:
         self._read_flush_scheduled = False
         windows, self._read_windows = self._read_windows, {}
+        queued, self._read_queue_span = self._read_queue_span, None
+        t_queued = queued.start if queued is not None else None
         for level, items in windows.items():
             if items:
-                spawn(self._flush_read_window(QueryConsistency(level), items),
+                if queued is None and t_queued is not None:
+                    # a further level flushed this turn is a window of
+                    # its own (its own id) that queued as long
+                    queued = TRACER.open_span("read.queue", start=t_queued)
+                spawn(self._flush_read_window(QueryConsistency(level), items,
+                                              queued),
                       name="read-window")
+                queued = None
+        if queued is not None:
+            queued.drop()
 
     @staticmethod
     def _resolve_read(fut: asyncio.Future, payload: tuple) -> None:
@@ -2556,26 +2590,32 @@ class RaftGroup:
             fut.set_result(payload)
 
     async def _flush_read_window(self, consistency: QueryConsistency,
-                                 items: list) -> None:
+                                 items: list, queued: Any = None) -> None:
         try:
-            await self._run_read_window(consistency, items)
+            await self._run_read_window(consistency, items, queued)
         except Exception as e:  # noqa: BLE001 — no staged read may hang
             logger.exception("read window failed")
             for _, _, _, fut in items:
                 self._resolve_read(fut, (0, None, msg.INTERNAL, str(e)))
 
     async def _run_read_window(self, consistency: QueryConsistency,
-                               items: list) -> None:
+                               items: list, queued: Any = None) -> None:
         """Serve one read window: the consistency gate ONCE, then the
         reads at an applied snapshot — device-eligible reads as tensors
         through one query_step engine round, the rest through the per-op
-        executor lane bit-identically."""
+        executor lane bit-identically. ``queued`` is the turn's open
+        ``read.queue`` span when traced: the window's stages are
+        recorded under one id of its own."""
         n = len(items)
         self._m_query_windows.inc()
         self._m_query_window_ops.record(n)
+        gate = (queued.then("read.gate", n=n, level=consistency.value)
+                if queued is not None else None)
         if consistency in (QueryConsistency.LINEARIZABLE,
                            QueryConsistency.BOUNDED_LINEARIZABLE):
             if self.role != LEADER:
+                if gate is not None:
+                    gate.close(refused=msg.NOT_LEADER)
                 for _, _, _, fut in items:
                     self._resolve_read(fut, (0, None, msg.NOT_LEADER, ""))
                 return
@@ -2585,6 +2625,8 @@ class RaftGroup:
             else:
                 ok = True
             if not ok:
+                if gate is not None:
+                    gate.close(refused=msg.NOT_LEADER)
                 for _, _, _, fut in items:
                     self._resolve_read(fut, (0, None, msg.NOT_LEADER, ""))
                 return
@@ -2601,7 +2643,7 @@ class RaftGroup:
             await self._wait_applied(self.commit_index)
             # the gate established the linearization point: serve at it
             # regardless of the client's (necessarily older) index
-            self._evaluate_reads(items, check_index=False)
+            self._evaluate_reads(items, check_index=False, gate=gate)
             return
         # SEQUENTIAL / CAUSAL: a read whose own index is already applied
         # serves NOW (the per-op lane's latency — no head-of-line wait
@@ -2611,50 +2653,70 @@ class RaftGroup:
         ready = [it for it in items if not it[1] or it[1] <= applied]
         lagging = [it for it in items if it[1] and it[1] > applied]
         if ready:
-            self._evaluate_reads(ready, check_index=True)
+            self._evaluate_reads(ready, check_index=True, gate=gate)
+            if gate is not None and lagging:
+                gate = TRACER.open_span("read.gate", gate.trace_id)
         if lagging:
             await self._wait_applied(max(it[1] for it in lagging),
                                      timeout=self.election_timeout * 4)
-            self._evaluate_reads(lagging, check_index=True)
+            self._evaluate_reads(lagging, check_index=True, gate=gate)
 
-    def _evaluate_reads(self, items: list, check_index: bool) -> None:
+    def _evaluate_reads(self, items: list, check_index: bool,
+                        gate: Any = None) -> None:
         """Serve one batch of gated reads at the current applied
         snapshot. ``check_index`` refuses reads still lagging the
         client's index (a timed-out applied wait) exactly like the
-        per-op lane's gate."""
+        per-op lane's gate. ``gate`` is the window's open ``read.gate``
+        span when traced; it ends where this begins, and the engine
+        records its query drive (and any settling round) under the
+        window's id for the length of this synchronous section."""
+        evaluate = scope = None
+        if gate is not None:
+            evaluate = gate.then("read.eval")
+            scope = TRACER.scope(evaluate.trace_id, "read.eval")
+            scope.__enter__()
+            drain = TRACER.open_span("read.drain")
         # ``last_applied`` may cover vector rows still parked in the
         # server's fused collector (their device/host effects land at
         # the turn's one engine round) — reads serve AT last_applied, so
         # those effects must land first (free no-op when nothing staged)
-        self.server.flush_fused()
+        self.server.flush_fused("read")
+        if evaluate is not None:
+            drain.close()
         applied = self.last_applied
         clock = self.context.clock
         route = getattr(self.state_machine, "query_route", None)
         rows: list = []  # (future, machine, instance, inner, spec)
-        for session_id, client_index, operation, fut in items:
-            if check_index and client_index and client_index > applied:
-                self._resolve_read(
-                    fut, (0, None, msg.INTERNAL,
-                          "state lagging behind client index"))
-                continue
-            rec = route(operation) if route is not None else None
-            if rec is not None:
-                rows.append((fut, *rec))
-                continue
-            self._m_query_per_op.inc()
-            session = self.sessions.get(session_id)
-            commit = Commit(applied, session, clock, operation, None)
-            try:
-                result = self.executor.execute(commit)
-            except Exception as e:  # noqa: BLE001 — app errors cross
-                self._resolve_read(
-                    fut, (applied, None, msg.APPLICATION, str(e)))
-            else:
-                self._resolve_read(fut, (applied, result, None, None))
-            finally:
-                commit.close()
-        if rows:
-            self._serve_query_rows(rows, applied)
+        try:
+            for session_id, client_index, operation, fut in items:
+                if check_index and client_index and client_index > applied:
+                    self._resolve_read(
+                        fut, (0, None, msg.INTERNAL,
+                              "state lagging behind client index"))
+                    continue
+                rec = route(operation) if route is not None else None
+                if rec is not None:
+                    rows.append((fut, *rec))
+                    continue
+                self._m_query_per_op.inc()
+                session = self.sessions.get(session_id)
+                commit = Commit(applied, session, clock, operation, None)
+                try:
+                    result = self.executor.execute(commit)
+                except Exception as e:  # noqa: BLE001 — app errors cross
+                    self._resolve_read(
+                        fut, (applied, None, msg.APPLICATION, str(e)))
+                else:
+                    self._resolve_read(fut, (applied, result, None, None))
+                finally:
+                    commit.close()
+            if rows:
+                self._serve_query_rows(rows, applied)
+        finally:
+            if evaluate is not None:
+                scope.__exit__()
+                evaluate.close(device=len(rows),
+                               per_op=len(items) - len(rows))
 
     def _serve_query_rows(self, rows: list, applied: int) -> None:
         """One query_step engine round for every device-eligible read in
@@ -2681,6 +2743,8 @@ class RaftGroup:
                 self._resolve_read(
                     fut, (applied, None, msg.APPLICATION, str(e)))
             return
+        finalize = (TRACER.open_span("read.finalize")
+                    if TRACER.enabled else None)
         for i, (fut, machine, _inst, inner, spec) in enumerate(rows):
             try:
                 result = machine.query_finalize(spec[4], inner, raws[i])
@@ -2689,6 +2753,8 @@ class RaftGroup:
                     fut, (applied, None, msg.APPLICATION, str(e)))
             else:
                 self._resolve_read(fut, (applied, result, None, None))
+        if finalize is not None:
+            finalize.close(rows=m)
 
     async def _wait_applied(self, index: int,
                             timeout: float | None = None) -> bool:
@@ -2729,6 +2795,16 @@ class RaftGroup:
                 # without apply_key) keeps the contiguous classifier
                 key_fn = getattr(self.state_machine, "apply_key", None)
         vrun: list = []  # staged rows: (clock, entry, session, *route rec)
+        # batch-scope tracing: this walk is the pump turn's classify
+        # stage, and the engine rounds the window lane drives inside it
+        # ride the turn's id (recorded only if the walk stages rows)
+        classify = scope = None
+        staged = False
+        if route is not None and TRACER.enabled:
+            classify = TRACER.open_span(
+                "apply.classify", self.server.pump_batch(), "apply")
+            scope = TRACER.scope(classify.trace_id, "apply")
+            scope.__enter__()
         # Timer deadline for the classify gate, recomputed only after
         # entries that can (un)schedule timers — the per-entry
         # ``next_deadline()`` heap peek was a measured share of the
@@ -2780,6 +2856,7 @@ class RaftGroup:
                         if key_fn is not None:
                             self._m_apply_conflicts.inc()
                         run, vrun = vrun, []
+                        staged = staged or bool(run)
                         try:
                             self._bound_vector_run(run, window)
                         except Exception:
@@ -2797,16 +2874,21 @@ class RaftGroup:
                 if route is not None:
                     deadline = self.executor.next_deadline()
             if vrun:
+                staged = True
                 try:
                     self._stage_vector_tail(vrun, window)
                 except Exception:
                     logger.exception("vector apply failed")
         finally:
+            if classify is not None:
+                self._trace_classified(classify, staged)
             if window is not None:
                 try:
                     window.close()
                 except Exception:
                     logger.exception("device window close failed")
+            if scope is not None:
+                scope.__exit__()
         if self._recovery_boot_last:
             # boot-tail replay accounting: cumulative apply time until the
             # restart's surviving log tail is fully re-applied — the
@@ -2818,6 +2900,22 @@ class RaftGroup:
                 self._recovery_boot_last = 0
         self._applied_event.set()
         self._maybe_snapshot()
+
+    def _trace_classified(self, classify: Any, staged: bool) -> None:
+        """Close the walk's ``apply.classify`` span (dropped when the
+        walk staged no vector rows: it was no pump stage), link the
+        traced blocks committed and not yet answered to the pump turn
+        that applies them, and retire the turn's id when nothing is
+        parked for a flush to retire it."""
+        if not staged:
+            classify.drop()
+        else:
+            classify.close(group=self.group_id)
+            batch = classify.trace_id
+            for trace in self._trace_commit_t:
+                self._trace_batch.setdefault(trace, batch)
+        if not self.server._fused_runs:
+            self.server._pump_batch = None
 
     # -- batched server-side pump (the vector lane) --------------------
 
@@ -2903,7 +3001,7 @@ class RaftGroup:
         if self._apply_fuse:
             if run:
                 self._stage_fused(run)
-            self.server.flush_fused()
+            self.server.flush_fused("conflict")
         elif run:
             self._apply_vector_run(run, window)
 
@@ -2933,9 +3031,16 @@ class RaftGroup:
         with zero generator/window machinery per op. A barrier failure
         is a pump error (rows fail explicitly, futures resolve) instead
         of an exception that would silently drop the run."""
-        raws, pump_error = dispatch_vector_rows(
-            self.state_machine.device_engine, window, run)
-        self._finalize_vector_run(run, raws, pump_error)
+        engine = self.state_machine.device_engine
+        if not TRACER.enabled:
+            raws, pump_error = dispatch_vector_rows(engine, window, run)
+            self._finalize_vector_run(run, raws, pump_error)
+            return
+        with TRACER.scope(self.server.pump_batch(), "apply"):
+            raws, pump_error = dispatch_vector_rows(engine, window, run)
+            finalize = TRACER.open_span("apply.finalize")
+            self._finalize_vector_run(run, raws, pump_error)
+            finalize.close(rows=len(run), groups=1)
 
     def _finalize_vector_run(self, run: list, raws: list,
                              pump_error: str | None) -> None:
@@ -3512,7 +3617,7 @@ class RaftGroup:
         # the dirty set is snapshotted: a fused write landing after the
         # swap would be certified "unchanged" by this flush's refresh
         # records at a version covering it (free no-op when empty)
-        self.server.flush_fused()
+        self.server.flush_fused("edge")
         dirty, self._edge_dirty = self._edge_dirty, {}
         version = self.last_applied
         # one push carries ONE trace (the first dirty entry's) — the

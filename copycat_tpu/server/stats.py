@@ -24,7 +24,10 @@ Routes:
   (``utils/tracing.py``); ``/traces.txt`` for the human rendering.
 - ``/traces/<id>`` — THIS member's spans for one trace id: the
   collection route ``copycat-tpu trace`` fans out across members to
-  assemble the cross-member causal waterfall.
+  assemble the cross-member causal waterfall (with the stages of the
+  batches the request waited on); ``/traces/report`` is the tracer's
+  whole-window report (span aggregates, timeline shares, counter
+  deltas) in the same family.
 - ``/flight`` — the device-plane flight recorder (telemetry spikes,
   injected faults, invariant violations in one fault-correlated ring —
   ``models/telemetry.py``); ``/flight.txt`` for the human rendering.
@@ -222,12 +225,20 @@ class StatsListener:
             # member and assembles the causal waterfall — utils/tracing
             # `assemble_trace`); unknown/evicted ids serve an empty span
             # list, which the assembler marks incomplete, never drops
+            if path == "/traces/report":
+                # the tracer's whole-window account (span aggregates,
+                # the timeline's shares, counter deltas), next to the
+                # slowest-N dump: frozen at disable(), live while on
+                return json.dumps(TRACER.report()).encode(), \
+                    "application/json"
             try:
                 trace_id = int(path.rsplit("/", 1)[1])
             except ValueError:
                 return (json.dumps({"error": "trace id must be an int"})
                         .encode(), "application/json")
-            spans = [s.as_dict() for s in TRACER.spans_for(trace_id)]
+            # with the stages of every batch the request waited on
+            spans = [s.as_dict()
+                     for s in TRACER.spans_for(trace_id, linked=True)]
             return (json.dumps({
                 "trace": trace_id,
                 "member": str(self._raft.address),

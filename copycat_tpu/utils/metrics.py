@@ -225,6 +225,11 @@ class MetricsRegistry:
 
     # -- exposition --------------------------------------------------------
 
+    def counter_values(self) -> dict[str, int]:
+        """Every counter's current value by flattened key — what a
+        before/after delta is taken from (``utils/tracing`` report)."""
+        return {_flat(key): ctr.value for key, ctr in self._counters.items()}
+
     def snapshot(self) -> dict:
         out: dict = {"uptime_s": round(time.perf_counter() - self._t0, 3)}
         for key, ctr in self._counters.items():

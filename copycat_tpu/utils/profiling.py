@@ -3,9 +3,9 @@
 The reference has no tracing beyond SLF4J loggers (§5.1 names the XLA
 profiler hook a "free win on TPU"). :func:`xla_trace` wraps any step-loop
 region in a ``jax.profiler`` trace whose artifacts open in
-TensorBoard/XProf (or parse with ``xprof.convert.raw_to_tool_data`` when no
-UI is available — that is how the one-hot rewrite in ``ops/consensus.py``
-was found; see PERF.md).
+TensorBoard/XProf, or are read here with ``jax.profiler.ProfileData``
+when no UI is available (:func:`summarize_trace` — a per-op table is how
+the one-hot rewrite in ``ops/consensus.py`` was found; see PERF.md).
 
 Usage::
 
@@ -77,7 +77,7 @@ def aggregate_trace_events(events: list[dict],
     events on device (TPU/accelerator) lanes are counted, so host-side
     spans and module wrappers don't drown the per-op numbers. Split out
     of :func:`summarize_trace` so the aggregation is testable against a
-    canned trace JSON without ``xprof`` or a TPU.
+    canned event list without a TPU.
     """
     import collections
 
@@ -101,25 +101,44 @@ def aggregate_trace_events(events: list[dict],
     return [(name, dur / 1e3, cnt[name]) for name, dur in agg.most_common(top)]
 
 
+#: the device line that holds one event per executed operation (module
+#: and step lines wrap them and would count the same time twice)
+OPS_LINE = "XLA Ops"
+
+
 def summarize_trace(trace_dir: str, top: int = 15) -> list[tuple[str, float, int]]:
     """Aggregate device-op time from the NEWEST captured trace session.
 
     Returns ``[(op_name, total_ms, count), ...]`` sorted by time — enough
-    to find the hot op without a TensorBoard UI. Requires the ``xprof``
-    package to parse the raw ``.xplane.pb`` capture; without it the
-    error says so instead of surfacing an opaque import chain.
+    to find the hot op without a TensorBoard UI. The raw ``.xplane.pb``
+    capture is read with ``jax.profiler.ProfileData`` (part of the
+    installed JAX, as ``benchmarks/trace_reduce.py`` reads it), so it
+    works wherever the trace could be taken. Device planes contribute
+    their per-operation line, named by the HLO instruction's own name
+    (``%fusion.12 = ...`` is ``fusion.12``).
     """
-    import json
+    from jax.profiler import ProfileData
 
-    try:
-        from xprof.convert import raw_to_tool_data as rtd
-    except ImportError as exc:
-        raise RuntimeError(
-            "summarize_trace needs the 'xprof' package to parse raw "
-            ".xplane.pb captures (pip install xprof, or open the trace "
-            f"dir in TensorBoard instead): {exc}") from exc
-
-    files = find_xplane_files(trace_dir)
-    data, _ = rtd.xspace_to_tool_data(files, "trace_viewer", {})
-    trace = json.loads(data.decode() if isinstance(data, bytes) else data)
-    return aggregate_trace_events(trace["traceEvents"], top)
+    events: list[dict] = []
+    for path in find_xplane_files(trace_dir):
+        try:
+            data = ProfileData.from_file(path)
+        except Exception as exc:  # noqa: BLE001 - a parse error, reworded
+            raise RuntimeError(
+                f"{path} is not a readable .xplane.pb capture (parsed with "
+                f"jax.profiler.ProfileData; no xprof package is needed): "
+                f"{exc}") from exc
+        for plane in data.planes:
+            if not plane.name.startswith("/device:"):
+                continue
+            pid = len(events)
+            events.append({"ph": "M", "name": "process_name", "pid": pid,
+                           "args": {"name": plane.name}})
+            lines = list(plane.lines)
+            ops = [line for line in lines if line.name == OPS_LINE] or lines
+            for line in ops:
+                for e in line.events:
+                    name = e.name.split(" = ", 1)[0].strip().lstrip("%")
+                    events.append({"ph": "X", "pid": pid, "name": name,
+                                   "dur": e.duration_ns / 1e3})
+    return aggregate_trace_events(events, top)

@@ -59,6 +59,51 @@ mapping in docs/OBSERVABILITY.md):
 - ``event.push`` — session event delivery send -> ack.
 - ``client.event`` — client-side receipt/dispatch of a traced publish.
 
+Batch-scope spans (one per batch and stage of the served path's two
+pumps, never per operation; ``parent`` in brackets). They are recorded
+under an id the server mints per pump turn or read window
+(:attr:`Tracer.batch`); the request-scope ``apply`` span that waited on
+a batch carries ``batch=<id>`` and :func:`assemble_trace` lays the
+batch's stages inside it. While the tracer is on, each is also a
+``jax.profiler.TraceAnnotation`` of the same name, so any XLA profile
+taken meanwhile shows them on the host lines beside the device ops.
+
+- ``client.stage`` — first operation staged into the client's
+  micro-batch -> its flush began.
+- ``client.query`` — the read lane's ``client.submit``: query flush ->
+  responses correlated (``n`` reads).
+- ``client.resolve`` — responses correlated -> the batch's last future
+  resolved.
+- ``apply.classify`` [apply] — ``_apply_up_to`` began on the committed
+  range -> its vector runs staged.
+- ``apply.park`` [apply] — first run staged this turn -> the fused flush
+  began (``forced=<why>`` when it did not wait for the turn's end).
+- ``apply.marshal`` [apply] — ``dispatch_vector_rows`` entered ->
+  ``engine.run_vector`` called (window barrier, rows into columns).
+- ``apply.finalize`` [apply] — ``dispatch_vector_rows`` returned -> the
+  flush's last run finalized (``rows``, ``groups``).
+- ``engine.stage`` [apply | read.eval | engine.query] — ``step_round``
+  entered -> the compiled step about to be called (host arrays, H2D).
+- ``engine.wait`` — step called -> ``block_until_ready`` returned (the
+  ``step_wall_ms`` interval).
+- ``engine.fetch`` — the D2H fetch of the step's outputs (``bytes``).
+- ``engine.harvest`` — after the fetch -> ``step_round`` returns, plus
+  ``drive_vector``'s correlation pass.
+- ``engine.query`` [read.eval] — ``drive_query_vector`` whole
+  (``attempts``, ``width``).
+- ``read.queue`` — the read window's first ``_stage_read`` ->
+  ``_run_read_window`` began (``n`` reads, ``level``).
+- ``read.gate`` — window began -> consistency gate passed.
+- ``read.eval`` — ``_evaluate_reads`` whole (``device``/``per_op`` rows).
+- ``read.drain`` [read.eval] — the forced fused flush at its head.
+- ``read.finalize`` [read.eval] — ``run_query_vector`` returned -> the
+  last read future resolved.
+
+:meth:`Tracer.report` is the whole-window account (docs/OBSERVABILITY.md
+"The window report"): per-name aggregates that do not depend on what the
+ring still holds, the window cut by innermost cover of the batch spans
+(plus ``unspanned``), and the delta of every registered counter.
+
 Every server-side span is tagged ``member=<address>`` and ``group=<id>``
 so the cross-member assembly below can attribute phases. Spans store
 ``time.perf_counter()`` instants plus a per-process wall-clock anchor
@@ -69,9 +114,12 @@ assembly orders causally either way.
 
 from __future__ import annotations
 
+import heapq
 import itertools
 import json
+import sys
 import time
+import weakref
 from collections import OrderedDict
 from typing import Any, Iterable
 
@@ -86,16 +134,34 @@ _ids = itertools.count(1)
 _WALL_OFFSET = time.time() - time.perf_counter()
 
 
+#: the spans the report's timeline is cut from: one per batch and stage
+#: (server pump turn, read window, engine round) or per client flush,
+#: so their number is bounded by turns, not by operations
+TIMELINE_SPANS = frozenset((
+    "client.stage", "client.submit", "client.query", "client.resolve",
+    "apply.classify", "apply.park", "apply.marshal", "apply.finalize",
+    "engine.stage", "engine.wait", "engine.fetch", "engine.harvest",
+    "engine.query", "read.queue", "read.gate", "read.eval", "read.drain",
+    "read.finalize"))
+
+#: cap on the intervals kept for the timeline (the ring's analogue): a
+#: served cell records about 1,000 a second, so minutes fit; past it the
+#: report says ``cut: true`` and the shares cover the kept part only
+MAX_INTERVALS = 1 << 17
+
+
 class Span:
-    __slots__ = ("trace_id", "name", "start", "end", "meta")
+    __slots__ = ("trace_id", "name", "start", "end", "meta", "parent")
 
     def __init__(self, trace_id: int, name: str, start: float, end: float,
-                 meta: dict | None = None) -> None:
+                 meta: dict | None = None, parent: str | None = None) -> None:
         self.trace_id = trace_id
         self.name = name
         self.start = start
         self.end = end
         self.meta = meta
+        #: name of the span that caused this one (batch-scope stages)
+        self.parent = parent
 
     @property
     def duration_ms(self) -> float:
@@ -106,6 +172,8 @@ class Span:
              "start": round(self.start, 6),
              "wall": round(self.start + _WALL_OFFSET, 6),
              "duration_ms": round(self.duration_ms, 3)}
+        if self.parent is not None:
+            d["parent"] = self.parent
         if self.meta:
             d.update(self.meta)
         return d
@@ -113,6 +181,75 @@ class Span:
     def __repr__(self) -> str:
         return (f"Span({self.name} trace={self.trace_id} "
                 f"{self.duration_ms:.3f}ms)")
+
+
+class OpenSpan:
+    """A batch-scope span that has begun: its start instant and the
+    profiler annotation opened with it. ``close`` records the span;
+    ``then`` closes it and opens the next stage at the same instant
+    (consecutive stages share one clock read per boundary)."""
+
+    __slots__ = ("_tracer", "name", "trace_id", "parent", "start", "_note")
+
+    def __init__(self, tracer: "Tracer", name: str, trace_id: int,
+                 parent: str | None, start: float) -> None:
+        self._tracer = tracer
+        self.name = name
+        self.trace_id = trace_id
+        self.parent = parent
+        self.start = start
+        self._note = _annotate(name)
+
+    def close(self, **meta: Any) -> float:
+        end = time.perf_counter()
+        if self._note is not None:
+            self._note.__exit__(None, None, None)
+        self._tracer.span(self.trace_id, self.name, self.start, end,
+                          parent=self.parent, **meta)
+        return end
+
+    def drop(self) -> None:
+        """End the annotation and record nothing."""
+        if self._note is not None:
+            self._note.__exit__(None, None, None)
+
+    def then(self, name: str, **meta: Any) -> "OpenSpan":
+        end = self.close(**meta)
+        return OpenSpan(self._tracer, name, self.trace_id, self.parent, end)
+
+
+def _annotate(name: str) -> Any:
+    """An entered ``jax.profiler.TraceAnnotation`` (a complete event on
+    the profiler's own clock when a profile is running, a few hundred
+    nanoseconds otherwise), or ``None`` in a process that never loaded
+    JAX: no profile can be running there, and tracing must not be what
+    imports it."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    note = jax.profiler.TraceAnnotation(name)
+    note.__enter__()
+    return note
+
+
+class _BatchScope:
+    """``with TRACER.scope(batch, parent):`` — the synchronous section
+    of a pump turn or read window, inside which the engine's stages are
+    recorded under ``batch`` with ``parent``; restores what was set
+    before (a read window's forced drain nests a pump turn)."""
+
+    __slots__ = ("_tracer", "_batch", "_parent", "_was")
+
+    def __init__(self, tracer: "Tracer", batch: int, parent: str) -> None:
+        self._tracer, self._batch, self._parent = tracer, batch, parent
+
+    def __enter__(self) -> None:
+        t = self._tracer
+        self._was = (t.batch, t.batch_parent)
+        t.batch, t.batch_parent = self._batch, self._parent
+
+    def __exit__(self, *exc: Any) -> None:
+        self._tracer.batch, self._tracer.batch_parent = self._was
 
 
 class Tracer:
@@ -140,6 +277,77 @@ class Tracer:
         # at 2x capacity (older tombstones age out; by then the id is
         # process-ancient and a late span for it is noise either way).
         self._tombstones: "OrderedDict[int, None]" = OrderedDict()
+        #: id and causing span of the batch whose synchronous section is
+        #: running (set by the fused flush or the read window, read by
+        #: the engine); ``None`` outside one
+        self.batch: int | None = None
+        self.batch_parent: str | None = None
+        # whole-window account (:meth:`report`): per-name [n, total ms,
+        # max ms], ms covered by direct children per parent name, the
+        # timeline's intervals, and the registered counters' baselines
+        self._agg: dict[str, list] = {}
+        self._child_ms: dict[str, float] = {}
+        self._intervals: list[tuple[float, float, str]] = []
+        self._cut = False
+        self._t_enabled: float | None = None
+        self._t_disabled: float | None = None
+        self._registries: list[list] = []   # [weakref, prefix, baseline]
+        self._counter_delta: dict[str, int] = {}
+        self._report: dict | None = None
+
+    # -- switch ------------------------------------------------------------
+
+    def enable(self) -> None:
+        """Start (or restart) the window the report covers."""
+        self.enabled = True
+        self._reset_window()
+        live = []
+        for ref, prefix, _ in self._registries:
+            registry = ref()
+            if registry is not None:
+                live.append([ref, prefix, registry.counter_values()])
+        self._registries = live
+
+    def disable(self) -> None:
+        """Stop recording and freeze the window for :meth:`report`."""
+        if self.enabled:
+            self._t_disabled = time.perf_counter()
+            self._counter_delta = self._read_counters()
+            self._report = None
+        self.enabled = False
+
+    def register(self, registry: Any, prefix: str) -> None:
+        """Tell the tracer of a ``MetricsRegistry`` whose counters the
+        report should account for, under ``prefix`` (``engine.``,
+        ``group.``, ``server.``, ``client.``). A weak reference; nothing
+        is read until :meth:`enable` (or now, if already enabled: the
+        registry's counters so far are not the window's)."""
+        self._registries.append([
+            weakref.ref(registry), prefix,
+            registry.counter_values() if self.enabled else {}])
+
+    def _read_counters(self) -> dict[str, int]:
+        """Every live registered registry's counters less its own
+        baseline, summed by prefixed name (a registry collected inside
+        the window takes its counts with it)."""
+        out: dict[str, int] = {}
+        for ref, prefix, base in self._registries:
+            registry = ref()
+            if registry is None:
+                continue
+            for name, value in registry.counter_values().items():
+                key = prefix + name
+                out[key] = out.get(key, 0) + value - base.get(name, 0)
+        return out
+
+    def _reset_window(self) -> None:
+        self._agg.clear()
+        self._child_ms.clear()
+        self._intervals.clear()
+        self._cut = False
+        self._report = None
+        self._t_enabled = time.perf_counter() if self.enabled else None
+        self._t_disabled = None
 
     # -- recording ---------------------------------------------------------
 
@@ -148,8 +356,27 @@ class Tracer:
         on the attribute first; ids are process-unique, not global)."""
         return next(_ids)
 
+    def open_span(self, name: str, trace_id: int | None = None,
+                  parent: str | None = None,
+                  start: float | None = None) -> OpenSpan:
+        """Begin a batch-scope span (call only when ``enabled``). Inside
+        a batch's synchronous section the id and parent default to the
+        batch's; outside one a fresh id is minted."""
+        if trace_id is None:
+            if self.batch is not None:
+                trace_id = self.batch
+                if parent is None:
+                    parent = self.batch_parent
+            else:
+                trace_id = next(_ids)
+        return OpenSpan(self, name, trace_id, parent,
+                        time.perf_counter() if start is None else start)
+
+    def scope(self, batch: int, parent: str) -> _BatchScope:
+        return _BatchScope(self, batch, parent)
+
     def span(self, trace_id: int, name: str, start: float, end: float,
-             **meta: Any) -> None:
+             parent: str | None = None, **meta: Any) -> None:
         """Record one completed span under ``trace_id``.
 
         Explicit timestamps fit the async call sites (the caller already
@@ -157,7 +384,27 @@ class Tracer:
         including one minted by a REMOTE client and carried in a frame —
         except ids evicted from this ring (tombstoned: late spans for
         them are dropped, never resurrected as partial traces).
+        ``parent`` names the span that caused this one. The running
+        aggregate counts every span, whatever the ring keeps.
         """
+        if self._t_disabled is None:    # a frozen window takes no more
+            ms = (end - start) * 1e3
+            agg = self._agg.get(name)
+            if agg is None:
+                self._agg[name] = [1, ms, ms]
+            else:
+                agg[0] += 1
+                agg[1] += ms
+                if ms > agg[2]:
+                    agg[2] = ms
+            if parent is not None:
+                self._child_ms[parent] = \
+                    self._child_ms.get(parent, 0.0) + ms
+            if name in TIMELINE_SPANS:
+                if len(self._intervals) < MAX_INTERVALS:
+                    self._intervals.append((start, end, name))
+                else:
+                    self._cut = True
         spans = self._traces.get(trace_id)
         if spans is None:
             if trace_id in self._tombstones:
@@ -169,15 +416,53 @@ class Tracer:
                     self._tombstones.popitem(last=False)
             spans = self._traces[trace_id] = []
         if len(spans) < self.MAX_SPANS_PER_TRACE:
-            spans.append(Span(trace_id, name, start, end, meta or None))
+            spans.append(Span(trace_id, name, start, end, meta or None,
+                              parent))
 
     # -- reading -----------------------------------------------------------
 
     def traces(self) -> dict[int, list[Span]]:
         return dict(self._traces)
 
-    def spans_for(self, trace_id: int) -> list[Span]:
-        return list(self._traces.get(trace_id, ()))
+    def spans_for(self, trace_id: int, linked: bool = False) -> list[Span]:
+        """The ring's spans for ``trace_id``; with ``linked`` also those
+        of every batch one of them waited on (``batch=<id>`` meta)."""
+        spans = list(self._traces.get(trace_id, ()))
+        if linked:
+            for batch in {s.meta["batch"] for s in spans
+                          if s.meta and "batch" in s.meta}:
+                spans += self._traces.get(batch, ())
+        return spans
+
+    def report(self) -> dict:
+        """The whole-window account: ``spans`` (per name ``n``,
+        ``total_ms``, ``mean_ms``, ``max_ms`` and ``self_ms`` = total
+        less what direct children cover), ``timeline`` (the window cut
+        by innermost cover of the batch spans, in % per name plus
+        ``unspanned``; sums to 100), ``counters`` (registered counters'
+        deltas over the window), ``window_s`` and ``cut``. Frozen by
+        :meth:`disable`; while enabled it reads up to now."""
+        if self._report is not None:
+            return self._report
+        frozen = not self.enabled and self._t_disabled is not None
+        t1 = self._t_disabled if frozen else time.perf_counter()
+        t0 = self._t_enabled
+        if t0 is None:      # ``enabled`` was set by hand: first span on
+            t0 = min((s for s, _, _ in self._intervals), default=t1)
+        counters = self._counter_delta if frozen else self._read_counters()
+        report = {
+            "window_s": t1 - t0,
+            "spans": {name: {"n": n, "total_ms": total,
+                             "mean_ms": total / n, "max_ms": worst,
+                             "self_ms": total - self._child_ms.get(name, 0.0)}
+                      for name, (n, total, worst) in self._agg.items()},
+            "timeline": _timeline_shares(self._intervals, t0, t1),
+            "counters": dict(counters),
+            "cut": self._cut,
+        }
+        if frozen:
+            self._report = report
+        return report
 
     def slowest(self, n: int = 10) -> list[tuple[int, float, list[Span]]]:
         """The slowest ``n`` traces as ``(trace_id, total_ms, spans)``,
@@ -240,6 +525,34 @@ class Tracer:
     def clear(self) -> None:
         self._traces.clear()
         self._tombstones.clear()
+        self._reset_window()
+
+
+def _timeline_shares(intervals: list, t0: float, t1: float) -> dict:
+    """``[t0, t1]`` cut by innermost cover (the rule of
+    :func:`_critical_path`: at every instant the open span that started
+    last owns it), as a share in % per span name; instants no span
+    covers go to ``unspanned``. One sweep over the sorted boundaries."""
+    if t1 <= t0:
+        return {"unspanned": 100.0}
+    ivs = sorted((s, e, name) for s, e, name in intervals
+                 if min(e, t1) > max(s, t0))
+    points = sorted({t0, t1}
+                    | {max(s, t0) for s, _, _ in ivs}
+                    | {min(e, t1) for _, e, _ in ivs})
+    held: dict[str, float] = {"unspanned": 0.0}
+    live: list = []         # max-heap on start: (-start, end, name)
+    i = 0
+    for lo, hi in zip(points, points[1:]):
+        while i < len(ivs) and ivs[i][0] <= lo:
+            s, e, name = ivs[i]
+            heapq.heappush(live, (-s, e, name))
+            i += 1
+        while live and live[0][1] <= lo:
+            heapq.heappop(live)
+        name = live[0][2] if live else "unspanned"
+        held[name] = held.get(name, 0.0) + (hi - lo)
+    return {name: 100.0 * secs / (t1 - t0) for name, secs in held.items()}
 
 
 #: the per-process tracer every layer records into (client + server in
@@ -248,15 +561,15 @@ class Tracer:
 TRACER = Tracer(capacity=max(16, knobs.get_int("COPYCAT_TRACE_CAPACITY")))
 
 if knobs.get_bool("COPYCAT_TRACE"):
-    TRACER.enabled = True
+    TRACER.enable()
 
 
 def enable() -> None:
-    TRACER.enabled = True
+    TRACER.enable()
 
 
 def disable() -> None:
-    TRACER.enabled = False
+    TRACER.disable()
 
 
 def now() -> float:
@@ -279,12 +592,12 @@ GROUP_PHASES = frozenset((
 
 def _norm_span(raw: Any) -> dict:
     """One span as an assembly row: accepts a :class:`Span` or the
-    ``as_dict``/JSON shape served by ``/traces/<id>``."""
+    ``as_dict``/JSON shape served by ``/traces/<id>``. ``member`` is
+    left unset where the span carries none (the assembly decides)."""
     if isinstance(raw, Span):
         d = raw.as_dict()
     else:
         d = dict(raw)
-    d.setdefault("member", "client")
     d.setdefault("wall", d.get("start", 0.0))
     return d
 
@@ -308,17 +621,24 @@ def assemble_trace(trace_id: int, spans_by_member: dict[str, Iterable],
     """
     seen: set = set()
     spans: list[dict] = []
-    for member, raw_spans in spans_by_member.items():
-        for raw in raw_spans:
-            d = _norm_span(raw)
-            if d.get("trace") not in (None, trace_id):
+    rows = [_norm_span(raw) for raw_spans in spans_by_member.values()
+            for raw in raw_spans]
+    # a request-scope span that waited on a batch (``batch=<id>``) pulls
+    # that batch's stages onto its timeline, under its own member
+    linked = {d["batch"]: d.get("member", "client") for d in rows
+              if d.get("trace") in (None, trace_id) and "batch" in d}
+    for d in rows:
+        if d.get("trace") not in (None, trace_id):
+            if d.get("trace") not in linked:
                 continue
-            key = (d["member"], d["name"], round(d["wall"], 6),
-                   d.get("duration_ms"))
-            if key in seen:  # in-process rings served by N listeners
-                continue
-            seen.add(key)
-            spans.append(d)
+            d.setdefault("member", linked[d["trace"]])
+        d.setdefault("member", "client")
+        key = (d["member"], d["name"], round(d["wall"], 6),
+               d.get("duration_ms"))
+        if key in seen:  # in-process rings served by N listeners
+            continue
+        seen.add(key)
+        spans.append(d)
     failed = sorted(set(failed_members))
     if not spans:
         return {"trace": trace_id, "members": [], "spans": [],

@@ -1,0 +1,187 @@
+"""The per-layer metrics that read the pumps' batch-scope spans and the
+tracer's whole-window report: their files, their reducer, and a traced run of
+both tiny served cells that has to print every one of them. The bench file is
+this test's own (``data_pump/BENCHMARK.json``: the two tiny served cells with
+the real file's metric entries); traffic and configurations are
+``tests/benchmark/data``'s, metric files and reducers ``benchmarks/``'s. No
+number from here is a device number.
+"""
+
+import importlib.util
+import json
+import os
+import re
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+BENCH = os.path.join(REPO, "benchmarks")
+DATA = os.path.join(REPO, "tests", "benchmark", "data")
+PUMP = os.path.join(REPO, "tests", "benchmark", "data_pump", "BENCHMARK.json")
+CELLS = {"served-1k.write": "served-tiny.write-tiny",
+         "served-1k.read90": "served-tiny.read90-tiny"}
+#: the metrics the benchmark had before the pumps were put on the record
+BEFORE = {"client.ack_p50_ms", "client.ack_p99_ms", "server.append_ms",
+          "server.append_ms.read", "engine.apply_ms", "engine.rounds_per_kop",
+          "step.ms_per_round", "step.commit_p99_rounds", "step.scan_roofline",
+          "device.idle_share.served", "device.idle_share.raw"}
+
+pytest.importorskip("jax")
+sys.path.insert(0, REPO)
+
+
+def load(path, name):
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.fixture(scope="module")
+def harness():
+    return load(os.path.join(BENCH, "run.py"), "benchmark_run_pump")
+
+
+@pytest.fixture(scope="module")
+def program_report():
+    return load(os.path.join(BENCH, "reducers", "program_report.py"),
+                "program_report")
+
+
+@pytest.fixture(scope="module")
+def bench():
+    return json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+
+
+def new_metrics(bench):
+    return [m for m in bench["per_layer"] if m["name"] not in BEFORE]
+
+
+def test_the_accepted_metrics_stand_first_and_unchanged(bench):
+    names = [m["name"] for m in bench["per_layer"]]
+    assert set(names[:len(BEFORE)]) == BEFORE
+    assert len(new_metrics(bench)) == 20
+    spec = lambda name: json.load(open(os.path.join(
+        BENCH, "layer_metrics", name + ".json")))
+    assert (spec("engine.apply_ms")["key"],
+            spec("server.append_ms")["key"],
+            spec("server.append_ms.read")["key"],
+            spec("engine.rounds_per_kop")["key"]) == (
+        "apply", "group.append", "group.append", "rounds")
+
+
+def test_every_new_metric_file_loads_and_resolves(bench):
+    perf = open(os.path.join(REPO, "PERF.md")).read()
+    section = perf[perf.index("## 3. Layers"):perf.index("## 4. Cells")]
+    layers = set(re.findall(r"^\| ([^|`]+?) \| `", section, re.M))
+    end_to_end = {m["name"]: m for m in bench["end_to_end"]}
+    cells = {w["name"] for w in bench["workloads"]}
+    vocabulary = open(os.path.join(REPO, "docs", "OBSERVABILITY.md")).read()
+    for m in new_metrics(bench):
+        spec = json.load(open(os.path.join(
+            BENCH, "layer_metrics", m["name"] + ".json")))
+        assert set(spec) >= {"name", "unit", "better", "layer", "source",
+                             "moves", "kind", "key", "reducer", "what"}
+        assert all(spec[k] == m[k] for k in (
+            "name", "unit", "better", "layer", "source", "moves"))
+        assert os.path.exists(os.path.join(
+            BENCH, "reducers", spec["reducer"] + ".py"))
+        assert spec["layer"] in layers, (spec["layer"], layers)
+        assert len(spec["unit"]) <= 16
+        moved = end_to_end[spec["moves"]]
+        assert set(m["workloads"]) <= set(moved.get("workloads", cells))
+        assert set(m["workloads"]) <= set(CELLS)      # never the raw cell
+        if spec["reducer"] == "span_mean_ms":
+            assert spec["source"] == "program_span"
+            assert f"| `{spec['key']}` |" in vocabulary, spec["key"]
+        else:
+            assert spec["reducer"] == "program_report"
+            assert isinstance(spec["key"], list)
+            assert spec["source"] == ("program_span" if spec["key"][0]
+                                      == "timeline" else "program_counter")
+
+
+RECORDED = {
+    "window_s": 20.0,
+    "spans": {"engine.wait": {"n": 4, "total_ms": 2.0, "mean_ms": 0.5,
+                              "max_ms": 0.75, "self_ms": 2.0}},
+    "timeline": {"engine.wait": 0.5, "client.submit": 87.0,
+                 "unspanned": 12.5},
+    "counters": {"engine.fetches": 1500, "engine.fetch_bytes": 3_000_000,
+                 "group.query_ops": 180_000, "group.query_windows": 1200,
+                 "engine.query_settle_rounds": 0, "group.idle": 0},
+    "cut": False,
+}
+
+
+@pytest.mark.parametrize("spec,expected", [
+    ({"key": ["counters", "engine.fetches"], "per": "kop"}, 1500 / 750.0),
+    ({"key": ["counters", "engine.fetch_bytes"], "per": "op"}, 4.0),
+    ({"key": ["counters", "group.query_ops"],
+      "over": ["counters", "group.query_windows"]}, 150.0),
+    ({"key": ["counters", "engine.query_settle_rounds"], "per": "kop"}, 0.0),
+    ({"key": ["timeline", "unspanned"]}, 12.5),
+    ({"key": ["spans", "engine.wait", "mean_ms"]}, 0.5),
+    ({"key": ["counters", "engine.absent"], "per": "kop"}, None),
+    ({"key": ["nothing", "here"]}, None),
+    ({"key": ["counters", "engine.fetches", "deeper"]}, None),
+    ({"key": ["counters", "group.query_ops"],
+      "over": ["counters", "group.idle"]}, None),
+    ({"key": ["counters", "group.query_ops"],
+      "over": ["counters", "group.absent"]}, None),
+], ids=["per-kop", "per-op", "over", "a-zero-is-a-reading", "plain",
+        "nested", "absent-key", "absent-block", "past-a-leaf",
+        "zero-divisor", "absent-divisor"])
+def test_program_report_reduces_a_recorded_report(program_report, spec,
+                                                  expected):
+    sources = {"clock": {"acked_ops": 750_000}}
+    got = program_report.reduce_report(RECORDED, sources, spec)
+    assert got == expected and (got is None or isinstance(got, float))
+
+
+def test_program_report_without_acknowledged_operations_or_a_report(
+        program_report, monkeypatch):
+    spec = {"key": ["counters", "engine.fetches"], "per": "kop"}
+    assert program_report.reduce_report(
+        RECORDED, {"clock": {"acked_ops": 0}}, spec) is None
+    assert program_report.reduce_report(RECORDED, {"clock": {}}, spec) is None
+    # a program that has no report (the parent of the PR that added it)
+    from copycat_tpu.utils import tracing
+
+    monkeypatch.delattr(tracing.Tracer, "report")
+    assert program_report.reduce({"clock": {"acked_ops": 5}}, spec) is None
+
+
+@pytest.mark.parametrize("real", sorted(CELLS), ids=["read90", "write"])
+def test_a_traced_run_prints_every_metric_of_its_cell(harness, bench, real):
+    cell = CELLS[real]
+    rc, line = harness.run_cell(cell, 2**31 + 99, 0.6, True, None,
+                                bench_file=PUMP, data_root=DATA,
+                                require_tpu=False)
+    assert rc == 0 and line["correct"] is True and line["failed"] == 0
+    json.dumps(line)
+    wanted = {m["name"]: m for m in bench["per_layer"]
+              if real in m["workloads"]}
+    pump = json.load(open(PUMP))
+    assert {m["name"] for m in harness.metrics_of(pump, "per_layer", cell)} \
+        == set(wanted)
+    missing = set(wanted) - set(line["metrics"])
+    assert not missing, missing
+    for name, got in line["metrics"].items():
+        assert got["unit"] == wanted[name]["unit"]
+        assert isinstance(got["value"], float) and got["value"] >= 0, name
+    assert 0 <= line["metrics"]["host.unspanned_share"]["value"] < 100
+    assert line["metrics"]["runtime.fetches_per_kop"]["value"] > 0
+    assert line["metrics"]["runtime.wait_ms"]["value"] > 0
+    if real.endswith("read90"):
+        assert line["metrics"]["server.reads_per_window"]["value"] >= 1
+        assert line["metrics"]["engine.query_drives_per_kop"]["value"] > 0
+    # what a run leaves in the tracer is the window's, frozen
+    from copycat_tpu.utils.tracing import TRACER
+
+    report = TRACER.report()
+    assert not TRACER.enabled and report is TRACER.report()
+    assert sum(report["timeline"].values()) == pytest.approx(100, abs=0.01)
+    assert report["window_s"] >= 0.6     # the profiler's holds lengthen it
