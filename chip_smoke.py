@@ -567,7 +567,7 @@ def _drive_mesh(devices, seed: int, G: int, P: int, L: int, S: int,
         census = None
         if mesh is not None:
             from copycat_tpu.parallel.scaling import census_text
-            sub, dl = rg._stage_submits(rg._empty_submits()), rg.deliver
+            sub, dl = rg._stage_round(rg._empty_submits()), rg.deliver
             census = census_text(rg._step.lower(
                 rg.state, sub, dl, rg._key).compile().as_text())
         final = jax.device_get((rg.state.term, rg.state.commit_index,

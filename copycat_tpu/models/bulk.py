@@ -41,6 +41,7 @@ Two dispatch modes, chosen by the engine's Config:
 
 from __future__ import annotations
 
+import dataclasses
 import time
 from functools import lru_cache, partial
 from typing import Any
@@ -49,6 +50,7 @@ import jax
 import numpy as np
 
 from ..ops.consensus import Submits, deep_scan, deep_step
+from .raft_groups import RaftGroups, _pack_host
 
 
 def _scatter(G: int, S: int, gi, slots, vals) -> np.ndarray:
@@ -157,7 +159,6 @@ class BulkDriver:
         # The DEEP drive (monotone-tag engines) goes through the
         # _stage_acc/_fetch_acc/_deep_fn/_stage_submits hooks and agrees
         # on every stop decision, so it runs on multihost engines too.
-        from .raft_groups import RaftGroups
         deep = bool(getattr(rg.config, "monotone_tag_accept", False))
         if not deep and (
                 getattr(rg, "process_count", 1) > 1
@@ -257,20 +258,13 @@ class BulkDriver:
             return sub, idx, gi, slots
 
         def harvest(r: int, raw) -> None:
-            tel_leaves = (jax.tree.leaves(raw.telemetry)
-                          if rg.telemetry is not None
-                          and raw.telemetry is not None else ())
-            for leaf in (raw.out_valid, raw.out_tag, raw.out_result,
-                         *tel_leaves):
-                leaf.copy_to_host_async()
-            if tel_leaves:
-                rg.telemetry.ingest(
-                    jax.tree.map(np.asarray, raw.telemetry),
-                    rg.rounds + r)
-            ov = np.asarray(raw.out_valid)
+            raw = rg._fetch_outputs(raw)
+            if rg.telemetry is not None and raw.telemetry is not None:
+                rg.telemetry.ingest(raw.telemetry, rg.rounds + r)
+            ov = raw.out_valid
             if ov.any():
-                tags = np.asarray(raw.out_tag)[ov]
-                vals = np.asarray(raw.out_result)[ov]
+                tags = raw.out_tag[ov]
+                vals = raw.out_result[ov]
                 keep = (tags >= tag0) & (tags < tag0 + n)
                 t = tags[keep] - tag0
                 results[t] = vals[keep]
@@ -304,12 +298,13 @@ class BulkDriver:
                     f"{max_rounds} rounds (fault-free liveness assumption"
                     f" violated? use the queue-managed path under faults)")
             sub, idx, gi, slots = build(r)
-            rg._key, key = jax.random.split(rg._key)
-            rg.state, raw = rg._step(rg.state, sub, deliver, key)
-            # small synchronous fetch: acceptance gates the NEXT round's
-            # dispatch window (FIFO safety)
+            rg.state, rg._key, raw = rg._step(
+                rg.state, rg._stage_round(sub), deliver, rg._key)
+            # small synchronous fetch (the bool slab): acceptance gates
+            # the NEXT round's dispatch window (FIFO safety)
             if idx.size:
-                acc = np.asarray(raw.accepted)
+                acc = dataclasses.replace(
+                    raw, bools=np.asarray(raw.bools)).accepted
                 accepted_ops[idx[acc[gi, slots]]] = True
             # big outputs: one round behind (double buffer)
             inflight.append((r, raw))
@@ -419,15 +414,16 @@ class BulkDriver:
                 atomic = np.zeros((G, S), bool)
                 if want_atomic:
                     atomic[gi, slots] = True
-                raw = rg._query(rg.state, sub, atomic)
+                raw = rg._query(rg.state, _pack_host((*sub, atomic)))
                 windows.append((pos, gi, slots, raw))
                 shadow[pos] = True
                 rounds += 1
             fetched = jax.device_get([raw for *_, raw in windows])
             any_miss = False
-            for (pos, gi, slots, _), (res, served) in zip(windows, fetched):
-                hit = np.asarray(served)[gi, slots]
-                res = np.asarray(res)
+            for (pos, gi, slots, _), slab in zip(windows, fetched):
+                # one slab a window: results beside served, S columns each
+                res, served = slab[:, :S], slab[:, S:]
+                hit = served[gi, slots] != 0
                 results[pos[hit]] = res[gi[hit], slots[hit]]
                 done[pos[hit]] = True
                 any_miss |= not hit.all()

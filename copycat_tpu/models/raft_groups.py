@@ -15,12 +15,14 @@ deterministic apply at batch scale.
 
 from __future__ import annotations
 
+import dataclasses
 import time
 from collections import deque
 from functools import lru_cache, partial
 from typing import Any
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..ops.apply import FAIL, OP_CFG_ADD, OP_CFG_REMOVE, QUERY_OPCODES
@@ -38,39 +40,128 @@ from ..ops.consensus import (
 from ..utils.tracing import TRACER
 
 
+def _pack_host(planes) -> np.ndarray:
+    """``[G, w]`` host planes side by side in one ``int32 [G, sum w]``
+    buffer: the runtime puts one buffer where it put one per plane."""
+    return np.concatenate(planes, axis=1, dtype=np.int32, casting="unsafe")
+
+
+def _unpack_submits(packed: jax.Array, planes: int = 6) -> tuple:
+    """Inside a program: ``(Submits, [further planes])`` out of
+    :func:`_pack_host`'s buffer of equally wide planes."""
+    S = packed.shape[1] // planes
+    cut = [packed[:, i * S:(i + 1) * S] for i in range(planes)]
+    return Submits(*cut[:5], valid=cut[5] != 0), cut[6:]
+
+
+@partial(jax.tree_util.register_dataclass,
+         data_fields=("ints", "bools", "telemetry"), meta_fields=("layout",))
+@dataclasses.dataclass(frozen=True)
+class PackedOutputs:
+    """A round's ``StepOutputs`` as the programs return them: one
+    group-leading slab per dtype (``[G]`` leaves are columns), so the
+    runtime allocates and fetches two buffers for twenty leaves and a mesh
+    engine's slabs stay shard-local. ``layout`` is static, one
+    ``(slab, start, width or None)`` per field in ``StepOutputs`` order;
+    it leaves the program in the pytree's structure. The telemetry
+    subtree (off unless asked for) rides unpacked."""
+
+    ints: Any      # int32 [..., G, K]
+    bools: Any     # bool  [..., G, Kb]
+    telemetry: Any
+    layout: tuple
+
+    @classmethod
+    def pack(cls, out: StepOutputs) -> "PackedOutputs":
+        cols: tuple[list, list] = ([], [])
+        at = [0, 0]
+        layout = []
+        for x in out[:-1]:  # telemetry is StepOutputs' last field
+            k = int(x.dtype == jnp.bool_)
+            width = None if x.ndim == 1 else x.shape[1]
+            layout.append((k, at[k], width))
+            cols[k].append(x[:, None] if width is None else x)
+            at[k] += width or 1
+        return cls(jnp.concatenate(cols[0], axis=1),
+                   jnp.concatenate(cols[1], axis=1),
+                   out.telemetry, tuple(layout))
+
+    def _field(self, i: int) -> Any:
+        k, at, width = self.layout[i]
+        slab = self.bools if k else self.ints
+        return slab[..., at] if width is None else slab[..., at:at + width]
+
+    def __getattr__(self, name: str) -> Any:
+        """A ``StepOutputs`` field by its name: its slice of the slab (of
+        a device slab too, at one dispatch: the rare snapshot install
+        reads ``stale`` and ``leader`` so)."""
+        if name in StepOutputs._fields:
+            return self._field(StepOutputs._fields.index(name))
+        raise AttributeError(name)
+
+    def unpack(self) -> StepOutputs:
+        """Views of the slabs under the fields' names (for host slabs,
+        after a fetch)."""
+        return StepOutputs(*map(self._field, range(len(self.layout))),
+                           telemetry=self.telemetry)
+
+
 @lru_cache(maxsize=None)
 def _jitted_programs(config: Config):
     """(step, query, install) jit wrappers shared across all RaftGroups
     instances with the same static Config (Config is a hashable NamedTuple,
-    so it keys the cache; shapes are handled inside each jit wrapper)."""
-    return (jax.jit(partial(step, config=config)),
-            jax.jit(partial(query_step, config=config)),
-            jax.jit(partial(install_snapshots, config=config)))
+    so it keys the cache; shapes are handled inside each jit wrapper).
+
+    The signatures are cut to what a call costs the runtime, which is per
+    buffer and not per byte: the state and the PRNG key are donated (their
+    outputs alias them), the submits arrive as one buffer, the key is split
+    inside (the same integers as an eager split) and the outputs leave as
+    :class:`PackedOutputs`. The query program reads the state again and
+    donates nothing; its ``(results, served)`` leave as one int32 slab."""
+
+    def round_(state, packed, deliver, key):
+        key, k = jax.random.split(key)
+        submits, _ = _unpack_submits(packed)
+        state, out = step(state, submits, deliver, k, config=config)
+        return state, key, PackedOutputs.pack(out)
+
+    def query(state, packed):
+        queries, (atomic,) = _unpack_submits(packed, planes=7)
+        results, served = query_step(state, queries, atomic != 0,
+                                     config=config)
+        return jnp.concatenate([results, served.astype(jnp.int32)], axis=1)
+
+    return (jax.jit(round_, donate_argnums=(0, 3)),
+            jax.jit(query),
+            jax.jit(partial(install_snapshots, config=config),
+                    donate_argnums=0))
 
 
 @lru_cache(maxsize=None)
 def _fused_rounds_program(config: Config, n: int):
     """``n`` consensus rounds in ONE compiled program: round 0 carries
     the caller's submits, rounds 1..n-1 run empty (the commit pipeline —
-    replicate, commit, report — advancing). Returns the new state, round
-    0's outputs, and the stacked outputs of the remaining rounds. One
-    dispatch + one blocking fetch instead of ``n`` per SPI window pump
-    cycle."""
-    import jax.numpy as jnp
+    replicate, commit, report — advancing). Returns the new state, the
+    carried key, round 0's outputs, and the stacked outputs of the
+    remaining rounds, both packed. One dispatch + one blocking fetch
+    instead of ``n`` per SPI window pump cycle; the call signature is
+    :func:`_jitted_programs`' step's."""
 
-    def fused(state, submits, deliver, key):
-        keys = jax.random.split(key, n)
+    def fused(state, packed, deliver, key):
+        key, k = jax.random.split(key)
+        keys = jax.random.split(k, n)
+        submits, _ = _unpack_submits(packed)
         state, out0 = step(state, submits, deliver, keys[0], config=config)
         empty = jax.tree.map(jnp.zeros_like, submits)
 
         def body(st, kk):
             st, out = step(st, empty, deliver, kk, config=config)
-            return st, out
+            return st, PackedOutputs.pack(out)
 
         state, outs = jax.lax.scan(body, state, keys[1:])
-        return state, out0, outs
+        return state, key, PackedOutputs.pack(out0), outs
 
-    return jax.jit(fused)
+    return jax.jit(fused, donate_argnums=(0, 3))
 
 
 def _group_slot_pack(g: np.ndarray
@@ -127,9 +218,10 @@ class RaftGroups:
         self.mesh = mesh
         if mesh is not None and self.config.use_pallas:
             self.config = self.config._replace(kernel_mesh=mesh)
-        # Deep-drive programs donate state + accumulators wherever the
-        # platform implements donation (every one but the CPU); asked of
-        # the device once, here, never while tracing.
+        # The deep-drive programs' own flag (models/bulk.py): they donate
+        # state + accumulators off the CPU only, though JAX 0.9 donates
+        # there too; asked of the device once, here, never while tracing.
+        # The round's programs (_jitted_programs) donate everywhere.
         device = mesh.devices.flat[0] if mesh is not None \
             else jax.devices()[0]
         self.donate = device.platform != "cpu"
@@ -150,7 +242,10 @@ class RaftGroups:
             build = partial(init_state, num_groups, num_peers, log_slots,
                             config=self.config, members=members)
             if mesh is None:
-                self.state: RaftState = build(init_key)
+                # jitted: init_state hands one zeros array to several
+                # fields, and a buffer cannot be donated twice; a
+                # program's outputs are buffers of their own
+                self.state: RaftState = jax.jit(build)(init_key)
                 self.deliver = full_delivery(num_groups, num_peers)
             else:
                 # Born sharded: each device builds only its own block of
@@ -222,6 +317,10 @@ class RaftGroups:
         self._m_fetches = self.metrics.counter("fetches")
         self._m_fetch_bytes = self.metrics.counter("fetch_bytes")
         self._m_settle_rounds = self.metrics.counter("query_settle_rounds")
+        # buffers the runtime handles per call of the round's and the
+        # query's programs (see _count_dispatch)
+        self._m_dispatch_leaves = self.metrics.counter("dispatch_leaves")
+        self._program_leaves: dict[Any, int] = {}
         TRACER.register(self.metrics, "engine.")
         # device-plane flight recorder: hub folds the step's telemetry
         # deltas into the device.* metric family, the flight ring, and
@@ -454,6 +553,13 @@ class RaftGroups:
     def _stage_submits(self, submits: Submits) -> Submits:
         return submits
 
+    def _stage_round(self, submits: Submits) -> Any:
+        """What the round's program takes for its submits: here the six
+        planes in one host buffer (one ``device_put``, unpacked inside
+        the program); a driver with programs of its own (multihost)
+        hands them the staged ``Submits``."""
+        return _pack_host(submits)
+
     def _stage_deliver(self, deliver: Any) -> Any:
         return deliver
 
@@ -465,20 +571,40 @@ class RaftGroups:
             sum(getattr(x, "nbytes", 0) for x in jax.tree.leaves(host)))
         return host
 
-    def _fetch_outputs(self, raw: StepOutputs) -> StepOutputs:
-        # ONE overlapped device->host transfer for all output arrays: the
-        # lazy per-array np.asarray calls in the harvest each paid a
-        # blocking transfer of their own.
+    def _fetch_outputs(self, raw: PackedOutputs) -> StepOutputs:
+        # ONE overlapped device->host transfer for both slabs; the harvest
+        # reads numpy views of them under StepOutputs' names.
         for leaf in jax.tree.leaves(raw):
             leaf.copy_to_host_async()
-        return self._note_fetch(jax.tree.map(np.asarray, raw))
+        return self._note_fetch(jax.tree.map(np.asarray, raw)).unpack()
 
-    def _stale_any(self, raw: StepOutputs, out: StepOutputs) -> bool:
+    def _stale_any(self, raw: Any, out: StepOutputs) -> bool:
         return bool(out.stale.any())
 
     def _run_query(self, sub: Submits, atomic) -> tuple[Any, Any]:
-        results, served = self._query(self.state, sub, atomic)
-        return self._note_fetch((np.asarray(results), np.asarray(served)))
+        packed = _pack_host((*sub, atomic))
+        raw = self._query(self.state, packed)
+        slab = self._note_fetch(np.asarray(raw))
+        self._count_dispatch(self._query, packed, raw)
+        S = slab.shape[1] // 2
+        return slab[:, :S], slab[:, S:] != 0
+
+    #: the round's programs donate the state and the key, so those
+    #: outputs alias their inputs and cost the runtime no buffer; a
+    #: driver whose own programs do not donate says so (multihost)
+    round_donates = True
+
+    def _count_dispatch(self, program: Any, put: Any, out: Any) -> None:
+        """``engine.dispatch_leaves``: the buffers one call of ``program``
+        costs the runtime — host leaves put, output leaves that alias no
+        donated input, leaves fetched (every fresh output is fetched) —
+        counted from the pytrees the first time the program is called.
+        The delivery mask lives on the device and is not in it."""
+        n = self._program_leaves.get(program)
+        if n is None:
+            n = self._program_leaves[program] = (
+                len(jax.tree.leaves(put)) + 2 * len(jax.tree.leaves(out)))
+        self._m_dispatch_leaves.inc(n)
 
     # Deep-plane hooks (models/bulk.py _drive_deep): accumulator staging,
     # fetch, and the jitted deep program. The multihost subclass overrides
@@ -530,15 +656,15 @@ class RaftGroups:
         explicit = submits is not None
         if submits is None:
             submits = self._build_submits()
-        self._key, key = jax.random.split(self._key)
         dl = self.deliver if deliver is None else self._stage_deliver(deliver)
-        staged = self._stage_submits(submits)
+        staged = self._stage_round(submits)
         if stage is not None:
             stage = stage.then("engine.wait")
             t0 = stage.start
         else:
             t0 = time.perf_counter()
-        self.state, raw = self._step(self.state, staged, dl, key)
+        self.state, self._key, raw = self._step(self.state, staged, dl,
+                                                self._key)
         raw = jax.block_until_ready(raw)  # time compute, not dispatch
         if stage is not None:
             stage = stage.then("engine.fetch")
@@ -548,6 +674,9 @@ class RaftGroups:
         self._m_step_wall.record((t1 - t0) * 1e3)
         fetched = self._m_fetch_bytes.value
         out = self._fetch_outputs(raw)
+        self._count_dispatch(
+            self._step, staged,
+            raw if self.round_donates else (self.state, self._key, raw))
         if stage is not None:
             stage = stage.then(
                 "engine.harvest", bytes=self._m_fetch_bytes.value - fetched)
@@ -605,15 +734,15 @@ class RaftGroups:
             return
         stage = TRACER.open_span("engine.stage") if TRACER.enabled else None
         submits = self._build_submits()
-        self._key, key = jax.random.split(self._key)
+        staged = self._stage_round(submits)
         fused = _fused_rounds_program(self.config, n)
         if stage is not None:
             stage = stage.then("engine.wait", rounds=n)
             t0 = stage.start
         else:
             t0 = time.perf_counter()
-        self.state, raw0, raws = fused(self.state, submits,
-                                       self.deliver, key)
+        self.state, self._key, raw0, raws = fused(
+            self.state, staged, self.deliver, self._key)
         raws = jax.block_until_ready(raws)
         if stage is not None:
             stage = stage.then("engine.fetch", rounds=n)
@@ -629,6 +758,8 @@ class RaftGroups:
         out0 = self._fetch_outputs(raw0)
         outs = jax.tree.map(np.asarray, raws)
         self._m_fetch_bytes.inc(sum(x.nbytes for x in jax.tree.leaves(outs)))
+        outs = outs.unpack()
+        self._count_dispatch(fused, staged, (raw0, raws))
         if stage is not None:
             stage = stage.then(
                 "engine.harvest", rounds=n,
@@ -652,8 +783,8 @@ class RaftGroups:
         # snapshot-install decision from the LAST round's view (deferring
         # a mid-scan stale follower one cycle is the same recovery path)
         if bool(outs.stale[-1].any()):
-            last = jax.tree.map(lambda x: x[-1], raws)
-            self.state = self._install(self.state, last.stale, last.leader)
+            self.state = self._install(self.state, raws.stale[-1],
+                                       raws.leader[-1])
         if stage is not None:
             stage.close(rounds=n)
 

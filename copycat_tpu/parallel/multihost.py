@@ -133,8 +133,17 @@ class MultiHostRaftGroups(RaftGroups):
         # on every output leaf being split by groups, and without the pin
         # the compiler is free to replicate an output.
         out_sh = NamedSharding(self.mesh, P("groups"))
-        self._step = jax.jit(partial(step, config=self.config),
-                             out_shardings=(state_sh, out_sh))
+        step_program = jax.jit(partial(step, config=self.config),
+                               out_shardings=(state_sh, out_sh))
+
+        def carried(state, submits, deliver, key):
+            # the base driver's calling convention (the key goes in and
+            # comes back split) around this driver's own program
+            key, k = jax.random.split(key)
+            state, out = step_program(state, submits, deliver, k)
+            return state, key, out
+
+        self._step = carried
         self._query = jax.jit(partial(query_step, config=self.config),
                               out_shardings=out_sh)
         self._install = jax.jit(partial(install_snapshots,
@@ -152,6 +161,10 @@ class MultiHostRaftGroups(RaftGroups):
             jax.make_array_from_process_local_data(
                 self._sub_sharding, np.ascontiguousarray(x))
             for x in submits])
+
+    # this driver's programs take the Submits pytree and donate nothing
+    _stage_round = _stage_submits
+    round_donates = False
 
     def _stage_deliver(self, deliver: Any) -> Any:
         return jax.make_array_from_process_local_data(

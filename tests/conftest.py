@@ -23,3 +23,28 @@ try:
     enable_compilation_cache()
 except ImportError:  # pragma: no cover - jax is part of the baked image
     pass
+
+import pytest  # noqa: E402
+
+#: XLA:CPU maps every compiled program into memory regions of its own (a
+#: consensus step: about 600) and JAX keeps each executable for the life of
+#: the process. The suite compiles over a hundred step-sized programs in one
+#: process and grew to ``vm.max_map_count`` (65,530 here): LLVM then reports
+#: "Cannot allocate memory" and the next compile segfaults (PR 25). Past this
+#: many regions a module's end drops JAX's compile caches, which unmaps them;
+#: later modules compile again or load from the persistent cache.
+_MAP_BUDGET = 30_000
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _bounded_memory_maps():
+    yield
+    try:
+        with open("/proc/self/maps") as f:
+            regions = sum(1 for _ in f)
+    except OSError:  # no procfs: nothing to bound
+        return
+    if regions > _MAP_BUDGET:
+        import jax
+
+        jax.clear_caches()

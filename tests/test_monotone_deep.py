@@ -10,6 +10,8 @@ device-enforced and duplicate re-sends idempotent, which is what lets
 fetches per round.
 """
 
+from functools import partial
+
 import numpy as np
 import pytest
 
@@ -22,6 +24,7 @@ from copycat_tpu.ops.consensus import (  # noqa: E402
     Config,
     Submits,
     full_delivery,
+    step,
 )
 
 
@@ -46,9 +49,9 @@ def _submit_window(rg, group, tags, opcode=ap.OP_LONG_ADD, a=1):
 
 
 def _step_raw(rg, sub):
-    rg._key, key = jax.random.split(rg._key)
-    rg.state, out = rg._step(rg.state, sub, rg.deliver, key)
-    return out
+    rg.state, rg._key, raw = rg._step(rg.state, rg._stage_round(sub),
+                                      rg.deliver, rg._key)
+    return rg._fetch_outputs(raw)
 
 
 def test_gate_accepts_dense_stream_rejects_duplicates_and_gaps(rg):
@@ -119,9 +122,18 @@ def test_compact_leaves_match_full_arrays():
                   b=np.int32(0), c=np.int32(0),
                   tag=np.ones((G, 1), np.int32),
                   valid=np.ones((G, S), bool))
+    # the plain step: compact leaves are the deep programs' own form (the
+    # served round's program takes six full planes in one buffer)
+    plain = jax.jit(partial(step, config=groups.config))
+
+    def _step_compact(rg, sub):
+        rg._key, key = jax.random.split(rg._key)
+        rg.state, out = plain(rg.state, sub, rg.deliver, key)
+        return out
+
     got = np.zeros((G, S), bool)
     for _ in range(10):  # retry: leaders elected late lack the lease;
-        out = _step_raw(groups, sub)  # duplicate re-sends are rejected,
+        out = _step_compact(groups, sub)  # duplicate re-sends are rejected,
         got |= np.asarray(out.accepted)  # so acceptance is once per op
         if got.all():
             break

@@ -247,6 +247,41 @@ def test_fetches_are_the_fetch_spans_plus_the_query_evaluations(recorded):
     assert 0 < in_spans < counters["engine.fetch_bytes"]   # + the queries'
 
 
+def test_dispatch_leaves_count_the_buffers_of_each_program(recorded):
+    """``engine.dispatch_leaves`` in the whole-window report: every round
+    here is one call of the step (1 buffer put, 2 fresh slabs, 2 fetched:
+    5), every read window one query evaluation (1 put, 1 slab out, 1
+    fetched: 3)."""
+    counters = recorded["report"]["counters"]
+    assert counters["engine.rounds"] == BURSTS
+    assert counters["engine.dispatch_leaves"] == \
+        5 * BURSTS + 3 * recorded["run_query_calls"]
+
+
+def test_one_write_round_is_five_buffers_and_one_query_three():
+    """Exactly: a round puts the six submit planes as 1 buffer, gets 2
+    fresh slabs (the state and the key alias their donated inputs) and
+    fetches those 2; a query evaluation puts 1, gets 1, fetches 1."""
+    rg = RaftGroups(num_groups=4, num_peers=3, log_slots=16, submit_slots=4)
+    rg.wait_for_leaders()
+    leaves = rg.metrics.counter("dispatch_leaves")
+    tag = rg.submit(0, ap.OP_LONG_ADD, 5)
+    before = leaves.value
+    rg.step_round()
+    assert leaves.value - before == 5
+    rg.run_until([tag])
+    rg.run(2)
+    before = leaves.value
+    tracing.enable()
+    assert rg.drive_query_vector([0], ap.OP_VALUE_GET).tolist() == [5]
+    tracing.disable()
+    assert leaves.value - before == 3
+    assert TRACER.report()["counters"]["engine.dispatch_leaves"] == 3
+    before = leaves.value
+    rg.step_rounds(3)
+    assert leaves.value - before == 9
+
+
 def test_one_append_and_one_apply_per_traced_block_as_before(recorded):
     """The spans the benchmark already reads keep their count: one
     ``group.append`` and one ``apply`` per write flush, each ``apply``

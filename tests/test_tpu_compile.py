@@ -27,6 +27,7 @@ from jax.sharding import (  # noqa: E402
     SingleDeviceSharding,
 )
 
+from copycat_tpu.models.raft_groups import _jitted_programs  # noqa: E402
 from copycat_tpu.ops.apply import ResourceConfig  # noqa: E402
 from copycat_tpu.ops.consensus import (  # noqa: E402
     Config,
@@ -133,6 +134,16 @@ def compile_step(G, P_, L, S, config, sharding):
         *step_args(G, P_, L, S, config, sharding)).compile()
 
 
+def round_args(G, P_, L, S, config, sharding, planes=6):
+    """Arguments of the served round's programs as ``RaftGroups`` calls
+    them (``models/raft_groups.py:_jitted_programs``, the fused rounds
+    too): the submits' six planes side by side in one buffer; a query's
+    seven (``planes=7``) follow the state alone."""
+    state, _, deliver, key = step_args(G, P_, L, S, config, sharding)
+    packed = _struct((G, planes * S), jnp.int32, group_placer(sharding)(2))
+    return (state, packed, deliver, key) if planes == 6 else (state, packed)
+
+
 def deep_args(G, P_, L, S, B, config, sharding, windows=None):
     """Arguments of ``deep_step`` (``windows=None``) or ``deep_scan``."""
     place = group_placer(sharding)
@@ -216,6 +227,46 @@ def test_deep_scan_compiles_with_donation(one_chip):
 
 def test_query_step_compiles(one_chip):
     compile_query(1024, 3, 64, 8, Config(), one_chip)
+
+
+def aliased_outputs(compiled) -> int:
+    """Outputs the compiled module writes over a donated input."""
+    header = compiled.as_text().split("\n", 1)[0]
+    return header.count("may-alias") + header.count("must-alias")
+
+
+def test_served_round_aliases_the_state_and_packs_the_rest(one_chip):
+    """The served cells' step (DeviceEngineConfig defaults: G 1,024, P 3,
+    L 64, S 4): every state leaf and the key are written in place, and
+    what is left for the runtime to allocate and the host to fetch is one
+    slab per dtype."""
+    config = Config()
+    args = round_args(1024, 3, 64, 4, config, one_chip)
+    step_program, query_program, _ = _jitted_programs(config)
+    compiled = step_program.lower(*args).compile()
+    donated = len(jax.tree.leaves(args[0])) + 1
+    outputs = len(jax.tree.leaves(compiled.output_shardings))
+    assert aliased_outputs(compiled) == donated
+    assert outputs - donated == 2
+    donated_bytes = sum(x.size * x.dtype.itemsize
+                        for x in jax.tree.leaves((args[0], args[3])))
+    assert compiled.memory_analysis().alias_size_in_bytes >= donated_bytes
+    # the query reads the state again: nothing donated, one slab out
+    query = query_program.lower(
+        *round_args(1024, 3, 64, 4, config, one_chip, planes=7)).compile()
+    assert aliased_outputs(query) == 0
+    assert len(jax.tree.leaves(query.output_shardings)) == 1
+
+
+def test_group_sharded_served_round_has_zero_collectives(four_chips):
+    """Packed group-leading, the round's slabs stay shard-local: the
+    engine's program over a ('groups',) mesh still talks to no other
+    chip, and still writes its state in place."""
+    config = Config()
+    args = round_args(4096, 3, 64, 4, config, four_chips)
+    compiled = _jitted_programs(config)[0].lower(*args).compile()
+    assert collectives_in(compiled) == {}
+    assert aliased_outputs(compiled) == len(jax.tree.leaves(args[0])) + 1
 
 
 @pytest.mark.parametrize("pallas", [False, True], ids=["jnp", "pallas"])

@@ -28,7 +28,10 @@ from jax.sharding import Mesh, SingleDeviceSharding  # noqa: E402
 
 import chip_smoke  # noqa: E402
 import test_tpu_compile as t  # noqa: E402
-from copycat_tpu.models.raft_groups import _fused_rounds_program  # noqa: E402
+from copycat_tpu.models.raft_groups import (  # noqa: E402
+    _fused_rounds_program,
+    _jitted_programs,
+)
 from copycat_tpu.ops.apply import ResourceConfig  # noqa: E402
 from copycat_tpu.ops.consensus import Config  # noqa: E402
 
@@ -68,14 +71,16 @@ def one_chip(chip) -> None:
            lambda: t.compile_deep(*shape, windows=64 // S + 3))
     # served path: the engine's programs at DeviceEngineConfig defaults
     engine = Config()
-    report("engine step 1024x3", lambda: t.compile_step(1024, 3, 64, 4, engine, chip))
-    args = t.step_args(1024, 3, 64, 4, engine, chip)
+    step_program, query_program, _ = _jitted_programs(engine)
+    args = t.round_args(1024, 3, 64, 4, engine, chip)
+    report("engine step 1024x3", lambda: step_program.lower(*args).compile())
     for n in (2, 3, 4):
         report(f"engine fused rounds n={n}",
                lambda: _fused_rounds_program(engine, n).lower(*args).compile())
     for width in (1, 4, 16):
         report(f"engine query_step S={width}",
-               lambda: t.compile_query(1024, 3, 64, width, engine, chip))
+               lambda: query_program.lower(*t.round_args(
+                   1024, 3, 64, width, engine, chip, planes=7)).compile())
 
 
 def four_chips(mesh) -> None:
