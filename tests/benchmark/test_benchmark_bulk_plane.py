@@ -1,0 +1,268 @@
+"""The bulk client plane rehearsed on the CPU at a tiny size, on one device and
+over a mesh of four virtual ones: the contract's line with ``correct: true``,
+every per-layer metric a CPU run can read, no collective in the sharded scan; a
+fault in the harness's inputs, or the drive broken underneath it, gives
+``correct: false``; what the root ``BENCHMARK.json`` names for the plane
+resolves. Sizes come from ``tests/benchmark/data_bulk``, never from the cell's
+own files. No number from here is a device number.
+"""
+
+import importlib.util
+import json
+import os
+import sys
+import time
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+BENCH = os.path.join(REPO, "benchmarks")
+DATA = os.path.join(REPO, "tests", "benchmark", "data_bulk")
+ONE, FOUR = "mixed-tiny-bulk.bulk-tiny", "mixed-tiny-bulk.bulk-tiny4"
+CELL = "mixed-400kx5-4chip.bulk"
+#: what a CPU run cannot read: its devices report no memory, and
+#: ``peaks.json`` holds no peak for them
+CHIP_ONLY = {"placement.peak_skew", "step.deep_scan_roofline"}
+
+pytest.importorskip("jax")
+sys.path.insert(0, REPO)
+
+
+def load(path, name):
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.fixture(scope="module")
+def harness():
+    return load(os.path.join(BENCH, "run.py"), "benchmark_run_bulk")
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    return json.load(open(os.path.join(DATA, "BENCHMARK.json")))
+
+
+@pytest.fixture(scope="module")
+def bench():
+    return json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+
+
+def drive(harness, cell, trace=False, fault=None, seed=2**31 + 26):
+    rc, line = harness.run_cell(
+        cell, seed, 0.3, trace, fault,
+        bench_file=os.path.join(DATA, "BENCHMARK.json"), data_root=DATA,
+        require_tpu=False)
+    assert rc == 0
+    json.dumps(line)                       # the line is plain JSON
+    return line
+
+
+@pytest.mark.parametrize("cell,trace", [
+    (ONE, False), (ONE, True), (FOUR, False), (FOUR, True)],
+    ids=["one-device", "one-device-traced", "mesh-of-4", "mesh-of-4-traced"])
+def test_cell_prints_the_contracts_line_and_is_correct(harness, tiny, cell,
+                                                       trace):
+    line = drive(harness, cell, trace)
+    assert set(line) >= {"correct", "attempted", "failed", "metrics", "device"}
+    assert line["correct"] is True and line["failed"] == 0
+    # three drives at least, of 32 operations for each of 64 groups
+    assert line["attempted"] >= 3 * 32 * 64 and line["attempted"] % 2048 == 0
+    assert line["device"]["count"] == (4 if cell == FOUR else 1)
+    group = "per_layer" if trace else "end_to_end"
+    wanted = {m["name"]: m for m in harness.metrics_of(tiny, group, cell)}
+    assert all(v["unit"] == wanted[k]["unit"]
+               for k, v in line["metrics"].items())
+    assert all(isinstance(v["value"], float) and v["value"] >= 0
+               for v in line["metrics"].values())
+    if not trace:
+        assert set(line["metrics"]) == {"bulk_ops_per_s", "setup_s"}
+        assert line["metrics"]["bulk_ops_per_s"]["value"] > 0
+        return
+    assert set(line["metrics"]) == set(wanted) - CHIP_ONLY
+    assert {"busy_s", "window_s"} <= set(line["device"])
+    assert len(line["breakdown"]["device_ops"]) <= 10
+    got = {k: v["value"] for k, v in line["metrics"].items()}
+    assert got["bulk.fetches_per_drive"] == 1.0
+    assert got["bulk.rounds_per_drive"] == 5.0     # two windows, three settle
+    # res int32 + valid bool + round int32 an operation, one flag a group
+    assert got["bulk.d2h_bytes_per_op"] == 9 + 1 / 32
+    assert got["bulk.drive_max_ms"] >= got["bulk.drive_ms"] > 0
+    if cell == FOUR:
+        assert got["placement.collectives"] == 0.0
+    else:
+        assert "placement.collectives" not in wanted
+
+
+@pytest.mark.parametrize("fault", ["drop-ack", "flip-result"])
+def test_a_fault_in_the_harness_gives_correct_false(harness, fault):
+    line = drive(harness, ONE, fault=fault)
+    assert line["correct"] is False and line["failed"] == 1
+
+
+def test_a_result_altered_where_it_is_produced_gives_correct_false(
+        harness, monkeypatch):
+    """The timed path broken underneath the harness: the window's second
+    drive hands back one group's first result one too high."""
+    from copycat_tpu.models import bulk
+
+    real, state = bulk.BulkDriver.drive, {"calls": 0}
+
+    def broken(self, groups, *args, **kwargs):
+        res = real(self, groups, *args, **kwargs)
+        state["calls"] += 1
+        if state["calls"] == 4:          # two warm-up drives come first
+            res.results[:] += 1
+        return res
+
+    monkeypatch.setattr(bulk.BulkDriver, "drive", broken)
+    line = drive(harness, ONE)
+    assert state["calls"] >= 5
+    assert line["correct"] is False and line["failed"] > 0
+
+
+def test_the_programs_own_mark_for_no_result_counts_as_unresolved(
+        harness, monkeypatch):
+    """The plane takes a result as resolved where it names a round of its
+    drive. The mark the program leaves where none came back is read here
+    from the program's source, put into one operation of a window's drive
+    underneath the harness, and has to be counted."""
+    import inspect
+    import re
+
+    from copycat_tpu.models import bulk
+
+    mark = re.search(r"rndbuf = rg\._stage_acc\(\s*np\.full\(\(G, Bpad\), "
+                     r"([^,]+), np\.int32\)\)", inspect.getsource(bulk))
+    assert mark, "models/bulk.py stages its round accumulator otherwise now"
+    unresolved = eval(mark.group(1), {})
+    real, state = bulk.BulkDriver.drive, {"calls": 0}
+
+    def lossy(self, groups, *args, **kwargs):
+        res = real(self, groups, *args, **kwargs)
+        state["calls"] += 1
+        assert 0 <= res.resolve_round.min()
+        assert res.resolve_round.max() < res.rounds < unresolved
+        if state["calls"] == 4:          # two warm-up drives come first
+            res.resolve_round[7] = unresolved
+        return res
+
+    monkeypatch.setattr(bulk.BulkDriver, "drive", lossy)
+    line = drive(harness, ONE)
+    assert state["calls"] >= 5
+    assert line["correct"] is False and line["failed"] == 1
+
+
+def test_a_drive_that_commits_nothing_gives_correct_false(harness,
+                                                          monkeypatch):
+    """A drive that returns its state unchanged: the window's drives hand
+    back the last warm-up drive's results and leave the engine alone."""
+    from copycat_tpu.models import bulk
+
+    real, state = bulk.BulkDriver.drive, {"calls": 0, "last": None}
+
+    def idle(self, groups, *args, **kwargs):
+        state["calls"] += 1
+        if state["calls"] <= 2:
+            state["last"] = real(self, groups, *args, **kwargs)
+        time.sleep(0.05)                 # a drive's time, so the window is few
+        return state["last"]
+
+    monkeypatch.setattr(bulk.BulkDriver, "drive", idle)
+    line = drive(harness, ONE)
+    assert line["correct"] is False and line["failed"] > 0
+
+
+def test_the_plain_replay_catches_a_flipped_result_and_a_lost_add(harness):
+    import numpy as np
+
+    from benchmarks import generators as gen
+    from benchmarks import reference
+
+    plane = harness.load_module("planes", "bulk", DATA)
+    S, B, G = 16, 32, 3
+    ops = tuple(np.tile(np.tile(x, B // S), (G, 1))
+                for x in gen.mixed_pattern(S))
+    groups = np.asarray([0, 2])
+    models = [reference.PlainGroup() for _ in groups]
+    drives = [np.asarray([[model.apply(*(int(x[g, j]) for x in ops), None)
+                           or 0 for j in range(B)]
+                          for g, model in zip(groups, models)])
+              for _ in range(3)]
+    compared, wrong, first, counters = plane.replay_drives(drives, groups, ops)
+    # the two election listens of a round return a log index: not compared
+    assert (compared, wrong, first) == (2 * 3 * (B - 2), 0, "")
+    assert counters == [3 * B // S * 2] * 2     # two adds of 1 a round
+    drives[1][1, 5] ^= 1
+    compared, wrong, first, _ = plane.replay_drives(drives, groups, ops)
+    assert wrong == 1 and "group 2 drive 1 op 5" in first
+
+
+@pytest.mark.parametrize("counters,clock,spec,expected", [
+    ({"fetches": 7}, {"drives": 7}, {"key": "fetches", "over": "drives"}, 1.0),
+    ({"fetch_bytes": 900}, {"acked_ops": 100},
+     {"key": "fetch_bytes", "over": "acked_ops"}, 9.0),
+    ({"fetches": 0}, {"drives": 7}, {"key": "fetches", "over": "drives"}, 0.0),
+    ({}, {"drives": 7}, {"key": "fetches", "over": "drives"}, None),
+    ({"fetches": 7}, {"drives": 0}, {"key": "fetches", "over": "drives"},
+     None),
+    ({"fetches": 7}, {}, {"key": "fetches", "over": "drives"}, None),
+], ids=["per-drive", "per-op", "a-zero-is-a-reading", "absent-counter",
+        "zero-divisor", "absent-divisor"])
+def test_counter_over_clock(counters, clock, spec, expected):
+    reducer = load(os.path.join(BENCH, "reducers", "counter_over_clock.py"),
+                   "counter_over_clock")
+    assert reducer.reduce({"counters": counters, "clock": clock},
+                          spec) == expected
+
+
+# -- what the root BENCHMARK.json names for the plane -----------------------
+
+def test_the_cell_its_configuration_and_its_traffic_resolve(bench, harness):
+    cell = next(w for w in bench["workloads"] if w["name"] == CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "mixed-400kx5-4chip", "bulk", 4)
+    entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
+    # the source's own words, and every deviation from it declared
+    north_star = json.load(open(os.path.join(REPO, "BASELINE.json")))[
+        "north_star"]
+    quoted = entry["source"].split('"')[1]
+    assert quoted in north_star and "100k Raft groups" in quoted
+    assert entry["reduced"] == ["chips", "groups"]
+    _, config, traffic = harness.load_cell(bench, CELL, BENCH)
+    one_chip = json.load(open(os.path.join(
+        BENCH, "configs", "mixed-100kx5.json")))
+    # the one-chip configuration's every value, at four times the groups
+    sizes = ("peers", "log_slots", "submit_slots", "use_pallas",
+             "append_window", "applies_per_round", "pool_budgets",
+             "timer_min", "timer_max", "resource")
+    assert all(config[k] == one_chip[k] for k in sizes)
+    assert config["groups"] == 4 * one_chip["groups"] == 400_000
+    assert config["chips"] == 4
+    assert set(config["reduced_from"]) == set(entry["reduced"])
+    assert config["source"] == entry["source"]
+    assert config["monotone_tag_accept"] is True
+    assert {"source", "guarantees", "assumed", "memory"} <= set(config)
+    assert traffic["plane"] == "bulk" and traffic["deep_scan"] is True
+    assert traffic["ops_per_group"] == 2 * config["submit_slots"]
+    assert os.path.exists(os.path.join(BENCH, "planes", "bulk.py"))
+
+
+def test_the_cells_metrics_are_the_tiny_cells_metrics(bench, tiny, harness):
+    rate = next(m for m in bench["end_to_end"]
+                if m["name"] == "bulk_ops_per_s")
+    assert rate["workloads"] == [CELL] and 0.01 <= rate["bound"] <= 0.25
+    assert (rate["unit"], rate["better"], rate["source"]) == (
+        "ops/s", "higher", "host_clock")
+    assert {m["name"] for m in harness.metrics_of(
+        bench, "end_to_end", CELL)} == {"bulk_ops_per_s", "setup_s"}
+    keys = ("name", "unit", "better", "source", "layer", "moves")
+    real = [{k: m[k] for k in keys}
+            for m in harness.metrics_of(bench, "per_layer", CELL)]
+    rehearsed = [{k: m[k] for k in keys}
+                 for m in harness.metrics_of(tiny, "per_layer", FOUR)]
+    assert real == rehearsed and len(real) == 9
+    assert all(m["moves"] == "bulk_ops_per_s" for m in real)
