@@ -46,7 +46,7 @@ import inspect
 import logging
 from collections import deque
 
-from typing import Any, NamedTuple
+from typing import Any, Iterable, NamedTuple
 
 from ..resource.state_machine import ResourceStateMachine
 from ..server.state_machine import Commit
@@ -1664,6 +1664,36 @@ class DeviceQueueState(DeviceBackedStateMachine):
 
 
 # ---------------------------------------------------------------------------
+# restored waiters and listeners (lock, leader election)
+# ---------------------------------------------------------------------------
+
+class _UnboundSession:
+    """What a restored waiter or listener holds until the manager
+    re-registers its instance's session (``ResourceManager.restore_state``
+    calls ``register`` for every restored instance, after the machines'
+    ``restore_state``): it carries the instance id and counts as closed,
+    so nothing is published to it."""
+
+    __slots__ = ("id",)
+    is_open = False
+
+    def __init__(self, instance_id: int) -> None:
+        self.id = instance_id
+
+    def publish(self, event: str, message: Any = None) -> None:
+        pass
+
+
+def _rebind(commits: Iterable[Commit], session: Any) -> None:
+    """Give restored stand-in commits of ``session``'s instance the live
+    session back."""
+    for commit in commits:
+        if type(commit.session) is _UnboundSession \
+                and commit.session.id == session.id:
+            commit.session = session
+
+
+# ---------------------------------------------------------------------------
 # lock
 # ---------------------------------------------------------------------------
 
@@ -1812,6 +1842,36 @@ class DeviceLockState(DeviceBackedStateMachine):
         yield from self._cmd(ops().OP_LOCK_RELEASE, wid)
         yield from self._pump()
 
+    # -- snapshot hooks (crash-recovery plane, docs/DURABILITY.md) --------
+    # The device lock (holder, wait ring) rides the engine's checkpoint
+    # blob; the host bookkeeping is the holder's id, the waiters in
+    # arrival order with their instance's session id, and the ids the
+    # device ring rejected. The Lock commits themselves are behind the
+    # snapshot boundary: log-less stand-ins (clean() is a no-op) take
+    # their place, as in the value machine, and ``register`` re-binds
+    # their sessions. An armed acquire timeout holds a timer closed over
+    # its commit, which cannot round-trip: such a state opts out
+    # (NotImplemented) and keeps the manager on replay-only recovery.
+
+    def snapshot_state(self) -> Any:
+        if self._timers:
+            return NotImplemented
+        return {"holder": self._holder_id,
+                "waiters": [(wid, c.session.id)
+                            for wid, c in self._waiters.items()],
+                "overflow": list(self._overflow)}
+
+    def restore_state(self, data: Any, sessions: dict) -> None:
+        self._holder_id = data["holder"]
+        for wid, sid in data["waiters"]:
+            self._waiters[wid] = Commit(wid, _UnboundSession(sid), 0.0,
+                                        None, None)
+        self._overflow = deque(data["overflow"])
+
+    def register(self, session: Any) -> None:
+        super().register(session)
+        _rebind(self._waiters.values(), session)
+
     # -- session lifecycle -------------------------------------------------
 
     def close(self, session: Any) -> None:
@@ -1955,6 +2015,29 @@ class DeviceLeaderElectionState(DeviceBackedStateMachine):
         if self._leader == sid:
             self._leader = self._epoch = None
         yield from self._pump()
+
+    # -- snapshot hooks (crash-recovery plane, docs/DURABILITY.md) --------
+    # The device election (leader, listener ring, epoch) rides the
+    # engine's checkpoint blob; the host mirror is the listeners'
+    # instance session ids in arrival order, the leader with its epoch
+    # and the ids the device ring rejected. No timers: it always
+    # round-trips. Listen commits become log-less stand-ins whose
+    # sessions ``register`` re-binds, as in the lock machine.
+
+    def snapshot_state(self) -> Any:
+        return {"listens": list(self._listens), "leader": self._leader,
+                "epoch": self._epoch, "overflow": list(self._overflow)}
+
+    def restore_state(self, data: Any, sessions: dict) -> None:
+        for sid in data["listens"]:
+            self._listens[sid] = Commit(0, _UnboundSession(sid), 0.0,
+                                        None, None)
+        self._leader, self._epoch = data["leader"], data["epoch"]
+        self._overflow = deque(data["overflow"])
+
+    def register(self, session: Any) -> None:
+        super().register(session)
+        _rebind(self._listens.values(), session)
 
     def close(self, session: Any) -> None:
         self._run_excl(self._resign(session.id))

@@ -22,6 +22,7 @@ from typing import Any, Callable
 from ..server.session import ServerSession, SessionState
 from ..server.state_machine import Commit, StateMachine, StateMachineExecutor
 from ..utils.metrics import MetricsRegistry
+from ..utils.tracing import TRACER
 from ..resource.operations import ResourceCommand, ResourceQuery
 from ..resource.state_machine import ResourceStateMachine, ResourceStateMachineExecutor
 from .operations import (
@@ -473,7 +474,13 @@ class ResourceManager(StateMachine):
         free: list[int] = []
         if self._engine is not None and self._engine._groups is not None:
             from ..models import checkpoint
-            engine_blob = checkpoint.save_bytes(self._engine._groups)
+            fetch = (TRACER.open_span("snapshot.fetch")
+                     if TRACER.enabled else None)
+            try:
+                engine_blob = checkpoint.save_bytes(self._engine._groups)
+            finally:
+                if fetch is not None:
+                    fetch.close()
             next_group = self._engine._next_group
             free = sorted(self._engine._free)
         return {"keys": dict(self.keys), "resources": resources,

@@ -24,6 +24,7 @@ from typing import Any, Iterator
 from ..io.buffer import BufferInput, BufferOutput
 from ..io.serializer import Serializer, serialize_with
 from ..utils.fields import compile_field_init
+from ..utils.metrics import Counter
 
 
 class StorageLevel(enum.Enum):
@@ -334,6 +335,15 @@ class Log:
         self._segment_file = None          # DISK: buffered append file
         self._mapped: _MappedSegment | None = None  # MAPPED: mmap segment
         self._segment_count = 0
+        # what the deployment's accounting reads (docs/OBSERVABILITY.md):
+        # calls of sync() and frame bytes written since open (recovery's
+        # replay appends nothing). The owning group swaps in counters of
+        # its registry, so the tracer's window report sees them.
+        self.syncs = Counter()
+        self.bytes_appended = Counter()
+        # DISK: (path, length) of the newest segment as of the last
+        # fsync — what a power loss is promised to leave of it
+        self._synced: tuple[str, int] | None = None
         if storage.level in (StorageLevel.DISK, StorageLevel.MAPPED):
             assert storage.directory, "DISK/MAPPED storage requires a directory"
             os.makedirs(storage.directory, exist_ok=True)
@@ -363,6 +373,15 @@ class Log:
     @property
     def empty(self) -> bool:
         return not self._entries
+
+    @property
+    def synced_tail(self) -> tuple[str, int] | None:
+        """``(path, length)`` of the newest DISK segment as of the last
+        fsync (``sync()`` or a segment roll): everything before it is
+        on stable storage, bytes of that file past ``length`` are not
+        promised. ``None`` before the first fsync and on the other
+        levels. Read-only; the crash tests cut the file back to it."""
+        return self._synced
 
     def _note_term(self, index: int, term: int) -> None:
         if not self._term_starts or self._term_starts[-1][1] != term:
@@ -598,11 +617,17 @@ class Log:
         """Force appended entries to stable storage (fsync/msync) — the
         ``fsync="commit"`` policy's durability point, called by the server
         whenever its commit index advances."""
+        self.syncs.inc()
         if self._segment_file is not None:
             self._segment_file.flush()
-            os.fsync(self._segment_file.fileno())
+            self._fsync_segment()
         if self._mapped is not None:
             self._mapped.flush()
+
+    def _fsync_segment(self) -> None:
+        """fsync the (flushed) DISK segment and note how far it reaches."""
+        os.fsync(self._segment_file.fileno())
+        self._synced = (self._segment_file.name, self._segment_file.tell())
 
     # -- disk persistence --------------------------------------------------
 
@@ -639,6 +664,7 @@ class Log:
                 if not self._mapped.append(data):
                     raise AssertionError("fresh mapped segment rejected frame")
             self._segment_count += 1
+            self.bytes_appended.inc(_MappedSegment.FRAME_HEADER + len(data))
             if self._storage.fsync == "always":
                 self._mapped.flush()
             return
@@ -659,10 +685,13 @@ class Log:
                 self._segment_file.close()
             self._segment_file = open(self._segment_path(entry.index), "ab")
             self._segment_count = 0
+            if self._storage.fsync != "never":
+                self._synced = (self._segment_file.name, 0)
         self._segment_file.write(frame)
         self._segment_file.flush()
+        self.bytes_appended.inc(len(frame))
         if self._storage.fsync == "always":
-            os.fsync(self._segment_file.fileno())
+            self._fsync_segment()
         self._segment_count += 1
 
     def _persist_truncate(self, from_index: int) -> None:

@@ -289,6 +289,8 @@ class RaftGroup:
         self._stage_rows = 0
 
         self._election_timer: Scheduled | None = None
+        self._election_due = 0.0        # monotonic deadline of that timer
+        self._election_deferred = False
         self._leader_timer: Scheduled | None = None
 
         # read pump windows (per group: the gate is per-group leadership)
@@ -359,6 +361,10 @@ class RaftGroup:
         self._m_repl_backpressure = m.counter("repl.backpressure_waits")
         self._m_repl_inflight_windows = m.gauge("repl.windows_inflight")
         self._m_repl_inflight_entries = m.gauge("repl.entries_inflight")
+        # the log's own accounting, on this registry so the tracer's
+        # window report reads it (group.log.syncs, group.log.bytes_appended)
+        self.log.syncs = m.counter("log.syncs")
+        self.log.bytes_appended = m.counter("log.bytes_appended")
         self._m_snap_taken = m.counter("snap.snapshots_taken")
         self._m_snap_bytes = m.counter("snap.snapshot_bytes")
         self._m_snap_ms = m.histogram("snap.snapshot_ms")
@@ -372,6 +378,9 @@ class RaftGroup:
         self._m_snap_restore_ms = m.histogram("snap.restore_ms")
         self._m_snap_meta_fallback = m.counter("snap.meta_fallbacks")
         self._m_snap_capture_fail = m.counter("snap.capture_failures")
+        # 1 on the snapshot lane (captures bound recovery and truncate
+        # the log), 0 on replay-only recovery; set once the group knows
+        self._m_snap_lane = m.gauge("snap.lane")
         # Edge read tier (docs/EDGE_READS.md): subscription registry +
         # delta publication accounting. Pre-created so the family is
         # present (count 0) in every snapshot the CI asserts.
@@ -422,7 +431,6 @@ class RaftGroup:
         # per-window max over the commit-boundary fsyncs, fed only when
         # the server's health plane is on (COPYCAT_HEALTH=0 keeps the
         # bare log.sync() calls — the A/B discipline)
-        self._fsync_count = 0
         self._fsync_last_ms = 0.0
         self._fsync_ewma_ms = 0.0
         self._fsync_recent_max_ms = 0.0
@@ -434,6 +442,8 @@ class RaftGroup:
                 self.storage.directory, f"{self.name}-{self.address.port}")
         self._snap_index = 0
         self._snap_supported = True
+        self._m_snap_lane.set(
+            1 if self._snap_enabled and self._snapshots is not None else 0)
         self._installing: dict | None = None
         self._install_term_cache: tuple[int, int] | None = None
         self._recovery_replay_s = 0.0
@@ -676,7 +686,6 @@ class RaftGroup:
         """Health-plane fsync accounting: last/max/EWMA of the
         commit-boundary fsync latency (the fsync-spike detector's
         input; ``fsync_recent_max`` is consumed by ``health_sample``)."""
-        self._fsync_count += 1
         self._fsync_last_ms = ms
         if ms > self._fsync_recent_max_ms:
             self._fsync_recent_max_ms = ms
@@ -726,6 +735,7 @@ class RaftGroup:
         if machine_state is NotImplemented:
             if self._snap_supported:
                 self._snap_supported = False
+                self._m_snap_lane.set(0)
                 logger.info(
                     "%s state machine %s does not support snapshots; "
                     "staying on the replay-only recovery path", self.name,
@@ -748,12 +758,31 @@ class RaftGroup:
         entries so slightly-lagging followers avoid an install)."""
         index = self.last_applied
         t0 = time.perf_counter()
+        # batch-scope tracing: the capture, with the engine's fetch (the
+        # manager opens snapshot.fetch inside the scope) and the file's
+        # write and sync under it
+        capture = write = None
+        if TRACER.enabled:
+            capture = TRACER.open_span("snapshot.capture", start=t0)
         try:
-            data = self._snapshot_payload()
+            if capture is None:
+                data = self._snapshot_payload()
+            else:
+                with TRACER.scope(capture.trace_id, "snapshot.capture"):
+                    data = self._snapshot_payload()
             if data is None:
+                if capture is not None:
+                    capture.drop()
+                    capture = None
                 return False
+            if capture is not None:
+                write = TRACER.open_span("snapshot.write", capture.trace_id,
+                                         "snapshot.capture")
             self._snapshots.save(index, data)
             self._snapshots.gc(keep=2)
+            if write is not None:
+                write.close(bytes=len(data))
+                write = None
             self._snap_index = index
             self._m_snap_taken.inc()
             self._m_snap_bytes.inc(len(data))
@@ -768,7 +797,12 @@ class RaftGroup:
                              index)
             self._m_snap_capture_fail.inc()
             self._flight_note("snapshot_failed", index=index)
+            for span in (write, capture):
+                if span is not None:
+                    span.drop()
             return False
+        if capture is not None:
+            capture.close(index=index, member=self._member)
         logger.debug("%s snapshot at %d (%d bytes, %d entries released)",
                      self.name, index, len(data), released)
         return True
@@ -894,6 +928,7 @@ class RaftGroup:
     def _reset_election_timer(self) -> None:
         if self._election_timer is not None:
             self._election_timer.cancel()
+        self._election_deferred = False
         base = self.election_timeout
         if self.server.single:
             timeout = random.uniform(base, base * 2)
@@ -919,10 +954,26 @@ class RaftGroup:
             else:
                 timeout = (random.uniform(base, base * 2)
                            + base * 0.3 * rank)
+        self._election_due = time.monotonic() + timeout
         self._election_timer = Scheduled(timeout, None, self._start_election)
 
     async def _start_election(self) -> None:
         if self._closing or self.role == LEADER:
+            return
+        if (self.leader_address is not None and not self._election_deferred
+                and time.monotonic() - self._election_due
+                > self.heartbeat_interval):
+            # A follower's timer fired more than a heartbeat late: this
+            # member's own event loop stood still past the deadline (a
+            # snapshot capture, a collection), so it was not listening
+            # and its leader's silence is not shown; whatever the leader
+            # sent meanwhile is still queued behind this callback.
+            # Listen for one more timeout, once per silence: any contact
+            # resets the timer and the flag, a leader that is really
+            # gone is replaced one timeout later.
+            self._reset_election_timer()
+            self._election_deferred = True
+            self.metrics.counter("raft_elections_deferred").inc()
             return
         self.role = CANDIDATE
         self.term += 1
@@ -3722,7 +3773,7 @@ class RaftGroup:
             "stalls": self._m_repl_stalls.value,
             "repl_windows": {str(p): (s.window, s.floor, s.floor_hits)
                              for p, s in self._peer_streams.items()},
-            "fsyncs": self._fsync_count,
+            "fsyncs": self.log.syncs.value,
             "fsync_max_ms": recent,
             "fsync_ewma_ms": self._fsync_ewma_ms,
             "sessions_expired": m.counter("sessions_expired_total").value,
