@@ -441,7 +441,15 @@ class DeviceEngine:
             # exists — never as a hidden stall inside the first
             # create()'s apply.
             self._groups.wait_for_leaders(max_rounds=200)
+            self._warm_capture()
         return self._groups
+
+    def _warm_capture(self) -> None:
+        """Compile the snapshot cut's program with the round's programs
+        (one cut, thrown away), so that the first capture stalls no apply
+        path; engines of one shape share the compiled program."""
+        from ..models import checkpoint
+        checkpoint.cut(self._groups)
 
     def allocate(self) -> int | None:
         """Lowest free device group, or ``None`` when all are live."""
@@ -470,6 +478,7 @@ class DeviceEngine:
         the crash-recovery plane (docs/DURABILITY.md)."""
         from ..models import checkpoint
         self._groups = checkpoint.load_bytes(blob, mesh=self.config.mesh)
+        self._warm_capture()
         self._next_group = int(next_group)
         self._free = sorted(int(g) for g in free)
 

@@ -20,9 +20,13 @@ import zlib
 from typing import Any, Callable
 
 from ..server.session import ServerSession, SessionState
-from ..server.state_machine import Commit, StateMachine, StateMachineExecutor
+from ..server.state_machine import (
+    Commit,
+    SnapshotCut,
+    StateMachine,
+    StateMachineExecutor,
+)
 from ..utils.metrics import MetricsRegistry
-from ..utils.tracing import TRACER
 from ..resource.operations import ResourceCommand, ResourceQuery
 from ..resource.state_machine import ResourceStateMachine, ResourceStateMachineExecutor
 from .operations import (
@@ -469,23 +473,21 @@ class ResourceManager(StateMachine):
             {"id": iid, "resource": inst.resource.resource_id,
              "owner": inst.owner.id}
             for iid, inst in self.instances.items()]
-        engine_blob = None
-        next_group = 0
-        free: list[int] = []
-        if self._engine is not None and self._engine._groups is not None:
-            from ..models import checkpoint
-            fetch = (TRACER.open_span("snapshot.fetch")
-                     if TRACER.enabled else None)
-            try:
-                engine_blob = checkpoint.save_bytes(self._engine._groups)
-            finally:
-                if fetch is not None:
-                    fetch.close()
-            next_group = self._engine._next_group
-            free = sorted(self._engine._free)
-        return {"keys": dict(self.keys), "resources": resources,
-                "instances": instances, "engine": engine_blob,
-                "engine_next_group": next_group, "engine_free": free}
+        image = {"keys": dict(self.keys), "resources": resources,
+                 "instances": instances, "engine": None,
+                 "engine_next_group": 0, "engine_free": []}
+        if self._engine is None or self._engine._groups is None:
+            return image
+        from ..models import checkpoint
+        image["engine_next_group"] = self._engine._next_group
+        image["engine_free"] = sorted(self._engine._free)
+        engine = checkpoint.cut(self._engine._groups)
+
+        def finish() -> dict:
+            image["engine"] = engine.to_bytes()
+            return image
+
+        return SnapshotCut(finish)
 
     def restore_state(self, data: Any, sessions: dict) -> None:
         # build the whole catalog into locals FIRST: a failure partway

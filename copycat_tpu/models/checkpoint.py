@@ -14,9 +14,12 @@ from __future__ import annotations
 
 import io
 import json
+import math
 import pathlib
+from typing import Any
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..ops.consensus import Config
@@ -28,16 +31,78 @@ def _leaf_name(path) -> str:
     return ".".join(getattr(p, "name", str(p)) for p in path)
 
 
-def save(rg, path: str | pathlib.Path) -> None:
-    """Snapshot a ``RaftGroups`` driver to ``path`` (.npz).
+@jax.jit
+def _cut_program(state, deliver, key):
+    """Everything of a driver that lives on the device, in buffers of its
+    own: the round's programs donate the state and the key
+    (``raft_groups._jitted_programs``), so a cut may hold no reference
+    into the live pytree past the next round. One group-leading slab per
+    dtype in the round's packing idiom (``raft_groups.PackedOutputs``):
+    the host fetches two or three buffers where it fetched one per leaf,
+    and a mesh engine's slabs stay shard-local."""
+    groups = deliver.shape[0]
+    cols: dict[str, list] = {}
+    for x in (*jax.tree.leaves(state), deliver):
+        cols.setdefault(x.dtype.name, []).append(x.reshape(groups, -1))
+    # lax.concatenate is an operation of its own even over one operand: a
+    # jitted function's output that IS its input comes back as the
+    # caller's own buffer
+    return ({name: jax.lax.concatenate(v, 1) for name, v in cols.items()},
+            jnp.copy(key))
 
-    State leaves are stored BY FIELD PATH (``state.resources.mm_key``),
+
+class StateCut:
+    """A driver's image cut at one instant (:func:`cut`): the device
+    state as fresh slabs whose transfer to the host has begun, and copies
+    of the host fields. The driver may run on — and donate its state —
+    while :meth:`write` waits, splits and compresses on any thread."""
+
+    __slots__ = ("_fields", "_slabs", "_key", "_meta")
+
+    def __init__(self, fields: list, slabs: dict, key: Any,
+                 meta: dict) -> None:
+        self._fields, self._slabs, self._key, self._meta = (
+            fields, slabs, key, meta)
+
+    def write(self, target) -> None:
+        """The field-path ``.npz`` of :func:`save`, to a path or an open
+        binary file."""
+        slabs = {name: np.asarray(x) for name, x in self._slabs.items()}
+        at = dict.fromkeys(slabs, 0)
+        arrays = {}
+        for name, shape, dtype in self._fields:
+            width = math.prod(shape[1:])
+            start = at[dtype]
+            at[dtype] = start + width
+            arrays[name] = np.ascontiguousarray(
+                slabs[dtype][:, start:start + width]).reshape(shape)
+        meta = self._meta | {"key": np.asarray(self._key).tolist()}
+        np.savez_compressed(target, meta=json.dumps(meta), **arrays)
+
+    def to_bytes(self) -> bytes:
+        bio = io.BytesIO()
+        self.write(bio)
+        return bio.getvalue()
+
+
+def cut(rg) -> StateCut:
+    """Take from a ``RaftGroups`` driver everything a later round can
+    change, and nothing else: one dispatch of :func:`_cut_program`, the
+    start of its transfer, and copies of the host fields. Costs the
+    caller no fetch and no compression: those are :meth:`StateCut.write`'s.
+
+    State leaves are named BY FIELD PATH (``state.resources.mm_key``),
     not positionally, so restoring stays correct no matter where future
     fields are inserted in ``RaftState``/``ResourceState`` — a missing
     (newer) field simply keeps the fresh template value on load.
     """
-    flat, treedef = jax.tree_util.tree_flatten_with_path(rg.state)
-    arrays = {f"state.{_leaf_name(p)}": np.asarray(x) for p, x in flat}
+    flat = jax.tree_util.tree_flatten_with_path(rg.state)[0]
+    fields = [(f"state.{_leaf_name(p)}", x.shape, x.dtype.name)
+              for p, x in flat]
+    fields.append(("deliver", rg.deliver.shape, rg.deliver.dtype.name))
+    slabs, key = _cut_program(rg.state, rg.deliver, rg._key)
+    for x in (*slabs.values(), key):
+        x.copy_to_host_async()
     meta = {
         "num_groups": rg.num_groups,
         "num_peers": rg.num_peers,
@@ -48,29 +113,28 @@ def save(rg, path: str | pathlib.Path) -> None:
         "rounds": rg.rounds,
         "clock": rg.clock,
         "next_tag": rg._next_tag,
-        "ev_seen": rg._ev_seen,
+        "ev_seen": dict(rg._ev_seen),
         # the host-side event buffer (consumption cursors are facade-local,
         # so this includes consumed events): restores the buffer faithfully
         # and keeps seq dedup (_ev_seen) consistent with it. Facades
         # created after restore start their cursor past these (session
         # events die with the session) and re-query authoritative state.
-        "events": {str(g): evs for g, evs in rg.events.items()},
-        "key": np.asarray(rg._key).tolist(),
+        "events": {str(g): list(evs) for g, evs in rg.events.items()},
         "num_leaves": len(flat),
     }
-    arrays["deliver"] = np.asarray(rg.deliver)
-    target = path if hasattr(path, "write") else str(path)
-    np.savez_compressed(target, meta=json.dumps(meta), **arrays)
-    del treedef  # structure is reconstructed from a fresh init on load
+    return StateCut(fields, slabs, key, meta)
+
+
+def save(rg, path: str | pathlib.Path) -> None:
+    """Snapshot a ``RaftGroups`` driver to ``path`` (.npz)."""
+    cut(rg).write(path if hasattr(path, "write") else str(path))
 
 
 def save_bytes(rg) -> bytes:
     """Snapshot a ``RaftGroups`` driver to in-memory bytes (the same
     field-path ``.npz`` format as :func:`save`) — the server-plane
     snapshot subsystem embeds this blob for device-backed machines."""
-    bio = io.BytesIO()
-    save(rg, bio)
-    return bio.getvalue()
+    return cut(rg).to_bytes()
 
 
 def load_bytes(data: bytes, mesh=None):
@@ -124,16 +188,15 @@ def load(path: str | pathlib.Path, mesh=None):
             from ..parallel import shard_state
             state = shard_state(state, mesh)
         else:
-            state = jax.tree.map(jax.numpy.asarray, state)
+            state = jax.tree.map(jnp.asarray, state)
         rg.state = state
-        rg.deliver = jax.numpy.asarray(data["deliver"])
+        rg.deliver = jnp.asarray(data["deliver"])
         rg.rounds = meta["rounds"]
         rg.clock = meta["clock"]
         rg._next_tag = meta["next_tag"]
         rg._ev_seen = {int(k): int(v) for k, v in meta["ev_seen"].items()}
         rg.events = {int(g): [tuple(e) for e in evs]
                      for g, evs in meta.get("events", {}).items()}
-        import jax.numpy as jnp
         rg._key = jnp.asarray(np.asarray(meta["key"], np.uint32))
         if config.monotone_tag_accept:
             # the monotone stream cursor is DERIVED, not stored: the

@@ -35,6 +35,7 @@ import asyncio
 import logging
 import os
 import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable
 
 from ..io.serializer import Serializer
@@ -141,6 +142,9 @@ class RaftServer(Managed):
         # client side so one env var flips the whole plane)
         self._edge_enabled = knobs.get_bool("COPYCAT_EDGE_READS")
         self._snap_serializer = Serializer()
+        # phase 2 of every group's snapshot capture runs here (one thread
+        # a server, started by the first capture): docs/DURABILITY.md
+        self._snap_worker: ThreadPoolExecutor | None = None
         self._fsync_on_commit = (
             self.storage.fsync == "commit"
             and self.storage.level is not StorageLevel.MEMORY)
@@ -299,6 +303,12 @@ class RaftServer(Managed):
             logger.exception("fused apply flush at close failed")
         if self.health is not None:
             self.health.stop()
+        if self._snap_worker is not None:
+            # a capture still running ends before the logs close: its
+            # file is whole or was never begun (``_closing`` is set), and
+            # its completion will find ``_closing`` and change nothing
+            self._snap_worker.shutdown(wait=True)
+            self._snap_worker = None
         for grp in self.groups:
             grp.shutdown()
         await self._server.close()
@@ -311,6 +321,19 @@ class RaftServer(Managed):
         # does NOT release — a crash doesn't run destructors either
         profiler.release(self.profiler, self._metrics)
         self.profiler = None
+
+    def snapshot_worker(self) -> ThreadPoolExecutor:
+        """The thread the groups' captures finish on, off the loop."""
+        if self._snap_worker is None:
+            self._snap_worker = ThreadPoolExecutor(
+                1, thread_name_prefix=f"{self.name}-snapshot")
+        return self._snap_worker
+
+    async def snapshots_settled(self) -> None:
+        """Return once no group has a capture in flight
+        (:meth:`RaftGroup.snapshot_settled`)."""
+        for grp in self.groups:
+            await grp.snapshot_settled()
 
     def _cancel_timers(self) -> None:
         # crash_server (testing/nemesis.py) calls this for its

@@ -42,6 +42,8 @@ import logging
 import os
 import random
 import time
+from concurrent.futures import Future
+from functools import partial
 from typing import Any
 
 from ..io.serializer import Serializer
@@ -63,7 +65,12 @@ from .log import (
 )
 from .session import ServerSession, SessionState
 from .snapshot import SnapshotStore, write_atomic
-from .state_machine import Commit, StateMachine, StateMachineExecutor
+from .state_machine import (
+    Commit,
+    SnapshotCut,
+    StateMachine,
+    StateMachineExecutor,
+)
 
 FOLLOWER, CANDIDATE, LEADER = "follower", "candidate", "leader"
 
@@ -73,6 +80,26 @@ FOLLOWER, CANDIDATE, LEADER = "follower", "candidate", "leader"
 _EDGE_REFRESH = ("r", None)
 
 logger = logging.getLogger(__name__)
+
+
+class _Abandoned(Exception):
+    """A capture whose member stopped before its file was begun."""
+
+
+class _Capture:
+    """One snapshot capture between its cut and its completion."""
+
+    __slots__ = ("index", "t0", "image", "trace", "loop", "deferred",
+                 "settled")
+
+    def __init__(self, index: int, t0: float, image: dict,
+                 trace: int | None, loop: asyncio.AbstractEventLoop) -> None:
+        self.index, self.t0, self.image, self.trace = index, t0, image, trace
+        self.loop = loop
+        #: a capture fell due while this one was in flight (counted once)
+        self.deferred = False
+        #: resolved on the loop when the completion has run
+        self.settled: asyncio.Future = loop.create_future()
 
 
 def dispatch_vector_rows(engine: Any, window: Any, rows: list
@@ -378,6 +405,12 @@ class RaftGroup:
         self._m_snap_restore_ms = m.histogram("snap.restore_ms")
         self._m_snap_meta_fallback = m.counter("snap.meta_fallbacks")
         self._m_snap_capture_fail = m.counter("snap.capture_failures")
+        # the capture's two phases (docs/DURABILITY.md): files the worker
+        # made durable, captures that fell due while one was in flight,
+        # and how far the apply ran on between a cut and its log release
+        self._m_snap_finished = m.counter("snap.captures_finished")
+        self._m_snap_deferred = m.counter("snap.captures_deferred")
+        self._m_snap_lag = m.gauge("snap.capture_lag_entries")
         # 1 on the snapshot lane (captures bound recovery and truncate
         # the log), 0 on replay-only recovery; set once the group knows
         self._m_snap_lane = m.gauge("snap.lane")
@@ -442,6 +475,7 @@ class RaftGroup:
                 self.storage.directory, f"{self.name}-{self.address.port}")
         self._snap_index = 0
         self._snap_supported = True
+        self._snap_inflight: _Capture | None = None
         self._m_snap_lane.set(
             1 if self._snap_enabled and self._snapshots is not None else 0)
         self._installing: dict | None = None
@@ -728,9 +762,11 @@ class RaftGroup:
 
         session.publish = tracked_publish  # type: ignore[method-assign]
 
-    def _snapshot_payload(self) -> bytes | None:
-        """Serialize the full replicated image at ``last_applied``, or
-        ``None`` when the state machine opts out of snapshotting."""
+    def _snapshot_cut(self) -> dict | None:
+        """Everything of the replicated image at ``last_applied`` that a
+        later entry can change, copied; ``None`` when the state machine
+        opts out of snapshotting. A machine's deferred part (a
+        :class:`SnapshotCut`) waits for :meth:`_finish_capture`."""
         machine_state = self.state_machine.snapshot_state()
         if machine_state is NotImplemented:
             if self._snap_supported:
@@ -741,7 +777,7 @@ class RaftGroup:
                     "staying on the replay-only recovery path", self.name,
                     type(self.state_machine).__name__)
             return None
-        payload = {
+        return {
             "version": 1,
             "index": self.last_applied,
             "term": self.log.term_at(self.last_applied) or self.term,
@@ -750,67 +786,143 @@ class RaftGroup:
             "sessions": [s.snapshot_dict() for s in self.sessions.values()],
             "machine": machine_state,
         }
-        return self._snap_serializer.write(payload)
 
-    def _take_snapshot(self) -> bool:
-        """Capture + persist one snapshot at ``last_applied``, then release
-        the log prefix behind it (keeping ``COPYCAT_SNAPSHOT_RETAIN``
-        entries so slightly-lagging followers avoid an install)."""
+    def _take_snapshot(self) -> None:
+        """Phase 1 of a capture, on the loop and inside the apply path:
+        cut the image at ``last_applied`` and hand it to the server's
+        snapshot worker. Nothing here waits for the device, compresses or
+        writes; the log keeps its prefix until :meth:`_capture_done`."""
         index = self.last_applied
         t0 = time.perf_counter()
-        # batch-scope tracing: the capture, with the engine's fetch (the
-        # manager opens snapshot.fetch inside the scope) and the file's
-        # write and sync under it
-        capture = write = None
-        if TRACER.enabled:
-            capture = TRACER.open_span("snapshot.capture", start=t0)
+        span = (TRACER.open_span("snapshot.capture", start=t0)
+                if TRACER.enabled else None)
         try:
-            if capture is None:
-                data = self._snapshot_payload()
-            else:
-                with TRACER.scope(capture.trace_id, "snapshot.capture"):
-                    data = self._snapshot_payload()
-            if data is None:
-                if capture is not None:
-                    capture.drop()
-                    capture = None
-                return False
-            if capture is not None:
-                write = TRACER.open_span("snapshot.write", capture.trace_id,
-                                         "snapshot.capture")
-            self._snapshots.save(index, data)
-            self._snapshots.gc(keep=2)
-            if write is not None:
-                write.close(bytes=len(data))
-                write = None
-            self._snap_index = index
-            self._m_snap_taken.inc()
-            self._m_snap_bytes.inc(len(data))
-            self._m_snap_ms.record((time.perf_counter() - t0) * 1e3)
-            released = self.log.truncate_prefix(index - self._snap_retain)
-            self._m_snap_trunc.inc(released)
+            image = self._snapshot_cut()
         except Exception:  # noqa: BLE001 - capture must never kill apply
+            logger.exception("%s snapshot cut at %d failed", self.name, index)
+            self._capture_failed(index)
+            image = None
+        if image is None:
+            if span is not None:
+                span.drop()
+            return
+        cap = _Capture(index, t0, image,
+                       None if span is None else span.trace_id,
+                       asyncio.get_running_loop())
+        if span is not None:    # the cut ends where the worker may begin
+            span.close(index=index, member=self._member)
+        worker = self.server.snapshot_worker().submit(
+            self._finish_capture, cap)
+        self._snap_inflight = cap
+        worker.add_done_callback(partial(self._hand_back, cap))
+
+    def _hand_back(self, cap: "_Capture", worker: Future) -> None:
+        """The worker future's callback, on the worker's thread: pass the
+        completion to the loop the cut was taken on. A member stopped
+        without its close (``testing/nemesis.crash_server``) may outlive
+        that loop: then nobody is left to tell."""
+        try:
+            cap.loop.call_soon_threadsafe(self._capture_done, cap, worker)
+        except RuntimeError:
+            pass
+
+    def _finish_capture(self, cap: "_Capture") -> tuple:
+        """Phase 2 of a capture, on the snapshot worker: whatever waits
+        (the machine's deferred part: device transfer, split,
+        compression), the serializer, and the file with its fsync, rename
+        and directory fsync. Reads nothing of this group but the cut, so
+        the loop runs on beside it. Returns the payload's size and the
+        instants the loop records spans from (the tracer has no lock):
+        begun, the deferred part done (``None`` without one), serialized,
+        saved. A member stopped meanwhile publishes nothing."""
+        clock = time.perf_counter
+        image, begun, fetched = cap.image, clock(), None
+        if isinstance(image["machine"], SnapshotCut):
+            image["machine"] = image["machine"].finish()
+            fetched = clock()
+        data = self._snap_serializer.write(image)
+        cap.image = None
+        if self._closing:
+            raise _Abandoned
+        serialized = clock()
+        self._snapshots.save(cap.index, data)
+        return len(data), (begun, fetched, serialized, clock())
+
+    def _capture_done(self, cap: "_Capture", worker: Future) -> None:
+        """A capture's end, back on the loop. Only here, with the file
+        durable under its final name, do ``_snap_index`` and the log's
+        prefix move. A capture that failed leaves the log whole and the
+        next one is tried; one that outlived its member (``_closing``) or
+        was overtaken by an installed snapshot changes nothing."""
+        self._snap_inflight = None
+        index = cap.index
+        try:
+            size, times = worker.result()
+            self._m_snap_finished.inc()
+            if self._closing or index <= self._snap_index:
+                return
+            self._snap_index = index
+            self._snapshots.gc(keep=2)
+            released = self.log.truncate_prefix(index - self._snap_retain)
+        except _Abandoned:
+            return
+        except Exception:  # noqa: BLE001 - a capture never stops the member
             # serialization bugs AND storage I/O (disk full, EIO on the
-            # tmp write/rename, segment deletion): the apply/commit path
-            # that called us must keep running either way
+            # tmp write/rename, segment deletion)
             logger.exception("%s snapshot capture at %d failed", self.name,
                              index)
-            self._m_snap_capture_fail.inc()
-            self._flight_note("snapshot_failed", index=index)
-            for span in (write, capture):
-                if span is not None:
-                    span.drop()
-            return False
-        if capture is not None:
-            capture.close(index=index, member=self._member)
+            if not self._closing:
+                self._capture_failed(index)
+            return
+        finally:
+            cap.settled.set_result(None)
+        end = time.perf_counter()
+        self._m_snap_taken.inc()
+        self._m_snap_bytes.inc(size)
+        self._m_snap_trunc.inc(released)
+        self._m_snap_ms.record((end - cap.t0) * 1e3)
+        self._m_snap_lag.set(self.last_applied - index)
+        if cap.trace is not None:
+            # the worker's instants, recorded here
+            begun, fetched, serialized, saved = times
+            if fetched is not None:
+                TRACER.span(cap.trace, "snapshot.fetch", begun, fetched,
+                            parent="snapshot.finish")
+            TRACER.span(cap.trace, "snapshot.write", serialized, saved,
+                        parent="snapshot.finish", bytes=size)
+            TRACER.span(cap.trace, "snapshot.finish", begun, end,
+                        index=index, member=self._member,
+                        lag=self.last_applied - index)
         logger.debug("%s snapshot at %d (%d bytes, %d entries released)",
-                     self.name, index, len(data), released)
-        return True
+                     self.name, index, size, released)
+
+    def _capture_failed(self, index: int) -> None:
+        self._m_snap_capture_fail.inc()
+        self._flight_note("snapshot_failed", index=index)
+
+    async def snapshot_settled(self) -> None:
+        """Return once no capture of this group is in flight: its file is
+        durable and its completion has run on the loop (or it failed).
+        For callers that read ``snap.*`` or the ``.snap`` file right
+        after an apply."""
+        while self._snap_inflight is not None:
+            await self._snap_inflight.settled
 
     def _maybe_snapshot(self) -> None:
-        if (self._snap_enabled and self._snap_supported
-                and self._snapshots is not None
-                and self.last_applied - self._snap_index >= self._snap_every):
+        if (not self._snap_enabled or not self._snap_supported
+                or self._snapshots is None or self._closing):
+            return
+        cap = self._snap_inflight
+        if cap is not None:
+            # one capture in flight a member, and none queued: the one
+            # that falls due meanwhile is taken by the first call after
+            # the completion, so the log holds two cadences at most
+            if (not cap.deferred
+                    and self.last_applied - cap.index >= self._snap_every):
+                cap.deferred = True
+                self._m_snap_deferred.inc()
+            return
+        if self.last_applied - self._snap_index >= self._snap_every:
             # staged-but-undispatched fused vector rows are device
             # effects the image at last_applied must include — drain
             # the collector before capturing (a no-op when empty)

@@ -113,14 +113,21 @@ class ServerSession:
         """The REPLICATED half of this session as a serializer-writable
         dict (leader-local state — connection, futures, pending ops — is
         deliberately absent: it is rebuilt by live traffic, the same
-        contract as leader failover)."""
+        contract as leader failover).
+
+        A cut, cheap enough for the apply path: the containers a later
+        entry changes are copied and nothing is walked per entry. The
+        cached responses are tuples and a sealed event batch is never
+        written again, so the copies share them with the live session;
+        the serializer writes a tuple as it writes a list, and
+        :meth:`from_snapshot` reads either."""
         return {
             "id": self.id,
             "client_id": self.client_id,
             "timeout": self.timeout,
             "state": self.state.value,
             "command_high": self.command_high,
-            "responses": {seq: list(r) for seq, r in self.responses.items()},
+            "responses": dict(self.responses),
             "event_index": self.event_index,
             "event_ack_index": self.event_ack_index,
             "event_queue": [

@@ -158,6 +158,19 @@ class StateMachineExecutor:
 _AUTO_REG_TABLES: dict[type, list] = {}
 
 
+class SnapshotCut:
+    """A :meth:`StateMachine.snapshot_state` image whose expensive part is
+    still to do. Everything a later entry can change has been copied
+    when this is returned; ``finish()`` is called once, off the server's
+    event loop, and returns the serializer-writable image. It may touch
+    nothing but what the cut took."""
+
+    __slots__ = ("finish",)
+
+    def __init__(self, finish: Callable[[], Any]) -> None:
+        self.finish = finish
+
+
 class StateMachine:
     """Base replicated state machine.
 
@@ -235,7 +248,15 @@ class StateMachine:
         bytes, registered classes), and machines owning log-time timers
         must include enough information to RE-SCHEDULE them in
         :meth:`restore_state` (deadlines are absolute log-clock values;
-        re-schedule with ``deadline - context.clock``)."""
+        re-schedule with ``deadline - context.clock``).
+
+        The server calls this on its event loop, inside the apply path,
+        so the image must be a CUT: copies of whatever a later entry can
+        change, nothing that waits. A machine whose image has an
+        expensive part (a device fetch, compression) returns a
+        :class:`SnapshotCut` whose ``finish`` does that part on the
+        server's snapshot worker (docs/DURABILITY.md, "Capture in two
+        phases")."""
         return NotImplemented
 
     def restore_state(self, data: Any, sessions: dict[int, Any]) -> None:

@@ -16,7 +16,11 @@ from copycat_tpu.protocol.messages import Message
 from copycat_tpu.protocol.operations import Command, Query
 from copycat_tpu.server.log import Storage, StorageLevel
 from copycat_tpu.server.raft import LEADER, RaftServer
-from copycat_tpu.server.state_machine import Commit, StateMachine
+from copycat_tpu.server.state_machine import (
+    Commit,
+    SnapshotCut,
+    StateMachine,
+)
 from copycat_tpu.client.client import RaftClient
 
 
@@ -183,6 +187,8 @@ def server_fingerprint(server: RaftServer, from_index: int | None = None):
         e = log.get(i)
         entries.append(None if e is None else ser.write(e))
     machine = server.state_machine.snapshot_state()
+    if isinstance(machine, SnapshotCut):
+        machine = machine.finish()
     sessions = sorted(
         (sid, _norm(s.snapshot_dict())) for sid, s in server.sessions.items())
     return {

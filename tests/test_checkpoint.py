@@ -174,3 +174,43 @@ def test_restore_onto_different_device_layout(tmp_path):
     back = checkpoint.load(path2)
     assert len(back.state.term.devices()) == 1
     assert_states_equal(onto_mesh.state, back.state)
+
+
+def test_cut_is_the_state_at_its_instant_whatever_runs_after():
+    """``cut`` returns before anything is fetched, and the rounds after it
+    donate the very buffers it was taken from: the image written later,
+    from another thread, is still the driver at the cut, leaf for leaf,
+    with the host fields and the key as they were."""
+    import threading
+
+    rg = RaftGroups(4, 3, log_slots=32)
+    rg.wait_for_leaders()
+    rg.run_until([rg.submit(g, ap.OP_LONG_ADD, g + 1) for g in range(4)])
+    names = [checkpoint._leaf_name(p) for p, _ in
+             jax.tree_util.tree_flatten_with_path(rg.state)[0]]
+    before = [np.asarray(x).copy()
+              for x in jax.tree_util.tree_leaves(rg.state)]
+    fields = (rg.rounds, rg.clock, rg._next_tag, np.asarray(rg._key).tolist())
+    taken = checkpoint.cut(rg)
+    old_leaves = jax.tree_util.tree_leaves(rg.state)
+
+    rg.run_until([rg.submit(g, ap.OP_LONG_ADD, 100) for g in range(4)])
+    rg.run(3)
+    assert all(x.is_deleted() for x in old_leaves)     # donated since
+
+    blob = []
+    worker = threading.Thread(target=lambda: blob.append(taken.to_bytes()))
+    worker.start()
+    worker.join(60)
+    assert not worker.is_alive() and blob
+    restored = checkpoint.load_bytes(blob[0])
+    for name, a, b in zip(names, before,
+                          jax.tree_util.tree_leaves(restored.state),
+                          strict=True):
+        assert np.array_equal(a, np.asarray(b)), name
+    assert (restored.rounds, restored.clock, restored._next_tag,
+            np.asarray(restored._key).tolist()) == fields
+    assert rg.rounds > restored.rounds
+    t = [restored.submit(g, ap.OP_LONG_ADD, 0) for g in range(4)]
+    restored.run_until(t)
+    assert [restored.results[x] for x in t] == [1, 2, 3, 4]

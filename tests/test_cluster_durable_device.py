@@ -104,6 +104,7 @@ async def test_lock_and_election_state_round_trip_a_snapshot(
         for _ in range(20):
             await ctr.increment_and_get()
         raft = server.server
+        await raft.snapshots_settled()
         assert raft._snap_index > 0 and raft.groups[0]._snap_supported
         assert raft.groups[0].metrics.gauge("snap.lane").value == 1
         before_lock = machines(server, DeviceLockState)["lock"]
@@ -187,6 +188,7 @@ async def test_a_lock_with_an_armed_timeout_opts_out(tmp_path, monkeypatch):
         state = machines(server, DeviceLockState)["lock"]
         assert state._timers and state.snapshot_state() is NotImplemented
         raft = server.server
+        await raft.snapshots_settled()
         assert raft._snap_index == 0 and not raft.groups[0]._snap_supported
         assert raft.groups[0].metrics.gauge("snap.lane").value == 0
         assert raft.groups[0].metrics.counter("snap.snapshots_taken").value == 0
@@ -315,6 +317,8 @@ async def test_three_durable_device_members_against_the_plain_reference(
         await cluster.caught_up()
         assert cluster.device_values(names) == [
             [model.get(n) for n in names]] * 3
+        for g in cluster.groups:
+            await g.snapshot_settled()
         taken = [g.metrics.counter("snap.snapshots_taken").value
                  for g in cluster.groups]
         lanes = [g.metrics.gauge("snap.lane").value for g in cluster.groups]

@@ -545,6 +545,7 @@ def test_snapshot_failures_graded(monkeypatch, tmp_path):
             server.groups[0]._snapshots.save = broken_save
             for i in range(12):
                 await client.submit(Put(key=f"k{i}", value=i))
+            await server.snapshots_settled()
             v = server.health.tick()
             f = v["detectors"]["snapshot_failure"]["groups"]["0"]
             assert f["status"] in (WARN, CRITICAL)

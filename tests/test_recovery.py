@@ -130,6 +130,7 @@ def test_restart_recovery_differential(tmp_path, monkeypatch, level,
             leader = cluster.leader
             victim = next(s for s in cluster.servers if s is not leader)
             vic = cluster.servers.index(victim)
+            await victim.snapshots_settled()
 
             # kill mid-append: a burst is in flight when the process dies
             burst = [
@@ -186,6 +187,7 @@ def test_recovery_with_ttl_timers(tmp_path, monkeypatch, level):
             leader = cluster.leader
             victim = next(s for s in cluster.servers if s is not leader)
             vic = cluster.servers.index(victim)
+            await victim.snapshots_settled()
             assert victim._snap_index > 0
             # the snapshot image carries the pending deadline
             await crash_server(victim)
@@ -244,6 +246,7 @@ def test_install_streaming_catches_up_wiped_follower(tmp_path, monkeypatch,
                 await client.submit(
                     Put(key=f"k{i % 9}", value="v" * 200 + str(i)))
             leader = cluster.leader
+            await leader.snapshots_settled()
             assert leader.log.prefix_index > 0
             shutil.rmtree(dirs[vic])
             os.makedirs(dirs[vic])
@@ -353,6 +356,7 @@ def test_corrupt_snapshot_falls_back_to_older_then_replay(tmp_path,
             for i in range(25):
                 await client.submit(Put(key=f"k{i % 3}", value=i))
             server = cluster.servers[0]
+            await server.snapshots_settled()
             store = server._snapshots
             assert len(store.indexes()) == 2
             newest = store.indexes()[-1]
@@ -406,6 +410,7 @@ def test_torn_tail_past_snapshot_index(tmp_path, monkeypatch, level):
             leader = cluster.leader
             victim = next(s for s in cluster.servers if s is not leader)
             vic = cluster.servers.index(victim)
+            await victim.snapshots_settled()
             snap_index = victim._snap_index
             assert snap_index > 0
             await crash_server(victim)
@@ -466,6 +471,7 @@ def test_manager_tpu_snapshot_restores_device_values(tmp_path, monkeypatch):
             for i in range(12):
                 await value.set(100 + i)
             raft = server.server
+            await raft.snapshots_settled()
             assert raft._snap_index > 0  # the manager snapshot happened
             await client.close()
             await crash_server(raft)
@@ -560,6 +566,7 @@ def test_manager_tpu_snapshot_restores_device_map_and_set(tmp_path,
             await s.add("x")                    # host shadow
             await s.remove(6)
             raft = server.server
+            await raft.snapshots_settled()
             assert raft._snap_index > 0, \
                 "map/set hooks must not opt the manager out of snapshots"
             before = await probe(client)

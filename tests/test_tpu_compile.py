@@ -269,6 +269,30 @@ def test_group_sharded_served_round_has_zero_collectives(four_chips):
     assert aliased_outputs(compiled) == len(jax.tree.leaves(args[0])) + 1
 
 
+@pytest.mark.parametrize("chips", [1, 4])
+def test_snapshot_cut_is_fresh_slabs_and_shard_local(one_chip, four_chips,
+                                                     chips):
+    """The snapshot capture's cut (``models/checkpoint._cut_program``) at
+    the served cells' size: three buffers out for 56 in (one slab per
+    dtype and the key), none of them an input's, and over a ('groups',)
+    mesh no chip asks another for anything."""
+    from copycat_tpu.models.checkpoint import _cut_program
+
+    sharding = one_chip if chips == 1 else four_chips
+    state, _, deliver, key = round_args(1024 * chips, 3, 64, 4, Config(),
+                                        sharding)
+    compiled = _cut_program.lower(state, deliver, key).compile()
+    slabs, key_out = compiled.output_shardings
+    assert sorted(slabs) == ["bool", "int32"] and key_out is not None
+    assert aliased_outputs(compiled) == 0
+    assert collectives_in(compiled) == {}
+    state_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves((state, deliver)))
+    # (the analysis counts one chip's share)
+    assert compiled.memory_analysis().output_size_in_bytes \
+        >= state_bytes // chips
+
+
 @pytest.mark.parametrize("pallas", [False, True], ids=["jnp", "pallas"])
 def test_group_sharded_step_has_zero_collectives(four_chips, pallas):
     # RaftGroups(mesh=...) hands the kernel its mesh the same way: left
