@@ -29,16 +29,15 @@ Scenarios (``COPYCAT_BENCH_SCENARIO``, BASELINE.md benchmark configs):
   ``DistributedAtomicLong``s on an ``AtomixServer(executor="tpu")``,
   pipelined increments over real sessions, ``COPYCAT_BENCH_SPI_BURSTS``
   bursts; reports on-device instance count + total engine rounds.
-- ``readmix``: read-dominated (90/10) traffic through the public API —
-  the batched read pump's A/B scenario (``COPYCAT_SERVER_READ_PUMP``);
-  headline value is client-visible reads/sec.
+- ``readmix``: read-dominated (90/10) traffic through the public API
+  and the batched read pump; headline value is client-visible
+  reads/sec.
 - ``cluster``: the first REPLICATED-cluster scenario — a 3-member
   ``RaftServer`` cluster over the local transport with a nemesis-injected
   per-message latency (a realistic LAN RTT; without it an in-process
-  "network" hides exactly the stop-and-wait stall this scenario exists
-  to measure), writes through the public ``RaftClient`` API; headline
-  value is committed ops/sec. The pipelined replication plane's A/B
-  knob is ``COPYCAT_REPL_PIPELINE`` (docs/REPLICATION.md); ``--storage
+  "network" hides the wire stall pipelined replication
+  (docs/REPLICATION.md) exists to cover), writes through the public
+  ``RaftClient`` API; headline value is committed ops/sec. ``--storage
   {memory,mapped,disk}`` runs the same workload on a durable log level
   (the durability A/B, docs/DURABILITY.md).
 - ``sharded``: the multi-raft keyspace-sharding scenario
@@ -52,12 +51,11 @@ Scenarios (``COPYCAT_BENCH_SCENARIO``, BASELINE.md benchmark configs):
 - ``apply``: the apply-limited scenario (docs/SHARDING.md "Apply
   ordering") — a single member hosting ``--groups N`` Raft groups,
   many sessions, hot/cold zipfian device counters, and an interleaved
-  eligible/ineligible op stream that collapses the contiguous vector
-  classifier to the per-entry lane; headline value is committed
-  ops/sec, with the ``apply.*`` family (spans, conflicts, fused
-  dispatches, rows/runs per dispatch) in the artifact. The A/B knobs
-  are ``COPYCAT_PARALLEL_APPLY=0`` / ``COPYCAT_APPLY_FUSE=0`` (the
-  contiguous/per-group plane).
+  eligible/ineligible op stream that the dependency classifier spans
+  where a contiguous one would collapse to the per-entry path;
+  headline value is committed ops/sec, with the ``apply.*`` family
+  (spans, conflicts, fused dispatches, rows/runs per dispatch) in the
+  artifact.
 - ``recovery``: the crash-recovery scenario — a fresh member catching up
   to a loaded, compacted cluster via snapshot-install streaming vs full
   log replay (``COPYCAT_SNAPSHOTS`` A/B inside one run); headline value
@@ -906,8 +904,7 @@ def run_readmix() -> dict:
     ONE increment and serves ``COPYCAT_BENCH_READMIX_READS`` (default 9)
     gets. Reads ride the no-append query lane: client-side they coalesce
     into per-consistency ``QueryBatchRequest``s, server-side the batched
-    read pump (``COPYCAT_SERVER_READ_PUMP`` — the A/B knob this
-    scenario exists to measure) windows them across sessions, pays the
+    read pump windows them across sessions, pays the
     consistency gate once per window, and evaluates the device-eligible
     set through one ``query_step`` engine round. Headline value =
     client-visible READS/sec; writes and total ops ride along in the
@@ -934,7 +931,6 @@ def run_readmix() -> dict:
         raise SystemExit(
             f"COPYCAT_BENCH_READMIX_LEVEL={level!r}: "
             "atomic|sequential|none|linearizable")
-    read_pump = knobs.get_bool("COPYCAT_SERVER_READ_PUMP")
     capacity = 1 << max(4, (instances - 1).bit_length())
     log_slots = knobs.get_int("COPYCAT_BENCH_SPI_LOG_SLOTS")
     registry = LocalServerRegistry()
@@ -971,8 +967,7 @@ def run_readmix() -> dict:
             on_device = engine._next_group
             log(f"bench[readmix:{level}]: {instances} instances in "
                 f"{time.perf_counter() - t0:.1f}s; {on_device} on-device; "
-                f"read pump {'ON' if read_pump else 'OFF'}; device="
-                f"{jax.devices()[0].platform}")
+                f"device={jax.devices()[0].platform}")
             _bench_gc_tune()
 
             async def one(c) -> None:
@@ -1001,12 +996,10 @@ def run_readmix() -> dict:
             best = max(reps)
             return {
                 "metric": (f"readmix_client_visible_reads_per_sec_"
-                           f"{instances}_device_instances_{level}"
-                           + ("" if read_pump else "_per_op")),
+                           f"{instances}_device_instances_{level}"),
                 "value": round(best, 1),
                 "unit": "reads/sec",
                 "vs_baseline": round(best / NORTH_STAR_OPS, 4),
-                "read_pump": read_pump,
                 "read_level": level,
                 "reads_per_write": reads_per_write,
                 "ops_per_sec": round(best * (reads_per_write + 1)
@@ -1273,11 +1266,10 @@ def run_cluster() -> dict:
     A fixed per-message-leg delay (``COPYCAT_BENCH_CLUSTER_DELAY_MS``,
     default 2.0 ms — a realistic same-region cross-AZ RTT of ~4 ms) is
     injected via the transport nemesis so the leader->follower
-    replication stream actually pays wire latency: stop-and-wait
-    replication (``COPYCAT_REPL_PIPELINE=0``) is then capped at
-    window/RTT entries/s per peer, which is exactly what the pipelined
-    plane exists to break. The A/B pair for PERF.md round 10 is this
-    scenario run twice, once per lane.
+    replication stream actually pays wire latency: one window in
+    flight (``COPYCAT_REPL_DEPTH=1``) is then capped at window/RTT
+    entries/s per peer, which is exactly what the pipeline's depth
+    exists to break.
 
     ``--storage {memory,mapped,disk}`` (env
     ``COPYCAT_BENCH_CLUSTER_STORAGE``, default memory) runs the same
@@ -1299,7 +1291,6 @@ def run_cluster() -> dict:
     ops_per_client = knobs.get_int("COPYCAT_BENCH_CLUSTER_OPS")
     bursts = knobs.get_int("COPYCAT_BENCH_CLUSTER_BURSTS")
     delay_ms = knobs.get_float("COPYCAT_BENCH_CLUSTER_DELAY_MS")
-    pipelined = knobs.get_bool("COPYCAT_REPL_PIPELINE")
 
     async def drive() -> dict:
         registry = LocalServerRegistry()
@@ -1333,8 +1324,7 @@ def run_cluster() -> dict:
         nem.set_delay(delay_ms / 1e3)
         log(f"bench[cluster]: {members} members, {n_clients} clients x "
             f"{ops_per_client} ops/burst, {delay_ms} ms/leg, "
-            f"storage={storage_level} "
-            f"({'pipelined' if pipelined else 'stop-and-wait'} replication, "
+            f"storage={storage_level} (replication "
             f"window {leader._repl_window}, depth {leader._repl_depth})")
         _bench_gc_tune()
         burst_ops = n_clients * ops_per_client
@@ -1375,12 +1365,10 @@ def run_cluster() -> dict:
             return {
                 "metric": (f"cluster_committed_ops_per_sec_{members}_members"
                            + ("" if storage_level == "memory"
-                              else f"_{storage_level}")
-                           + ("" if pipelined else "_stop_and_wait")),
+                              else f"_{storage_level}")),
                 "value": round(best, 1),
                 "unit": "ops/sec",
                 "vs_baseline": round(best / NORTH_STAR_OPS, 4),
-                "repl_pipeline": pipelined,
                 "repl_window": leader._repl_window,
                 "repl_depth": leader._repl_depth,
                 "delay_ms_per_leg": delay_ms,
@@ -1672,8 +1660,8 @@ def run_apply() -> dict:
     committed ops/sec through the public resource API on a single
     member hosting ``--groups N`` Raft groups, many sessions, a
     hot/cold zipfian key mix over device counters, and an INTERLEAVED
-    eligible/ineligible op stream — the shape that collapses the
-    contiguous vector classifier to the per-entry lane.
+    eligible/ineligible op stream — the shape that would collapse a
+    contiguous vector classifier to the per-entry path.
 
     No replication wire, no nemesis delay: commit is immediate, so the
     apply path IS the bottleneck. Eligible sessions stream single-
@@ -1684,13 +1672,10 @@ def run_apply() -> dict:
     keyspace; a ``COPYCAT_BENCH_APPLY_INELIGIBLE`` fraction of sessions
     streams host-shadow STRING sets instead — every shadow entry is an
     ineligible log entry interleaved between other sessions' device
-    rows. The A/B is this scenario with ``COPYCAT_PARALLEL_APPLY=0
-    COPYCAT_APPLY_FUSE=0`` (the contiguous/per-group plane): there each
-    interleaved ineligible entry CUTS the vector run (toward the
-    per-entry lane as the mix rises), while the dependency classifier
-    spans them — disjoint keys, disjoint sessions — and the fused lane
-    merges all groups' staged runs into ONE ``DeviceEngine.run_vector``
-    per server turn (``apply.*`` family in the artifact;
+    rows. The dependency classifier spans them — disjoint keys,
+    disjoint sessions — and the fused collector merges all groups'
+    staged runs into ONE ``DeviceEngine.run_vector`` per server turn
+    (``apply.*`` family in the artifact;
     ``runs_per_dispatch`` ≈ groups is the one-device-round-per-turn
     evidence)."""
     import asyncio
@@ -1772,8 +1757,7 @@ def run_apply() -> dict:
             log(f"bench[apply]: 1 member x {groups} groups, "
                 f"{n_elig} device + {n_shadow} host-shadow sessions "
                 f"x {ops_per_session} ops/burst, zipf s={zipf_s} over "
-                f"{n_keys} keys, parallel_apply={rs._parallel_apply} "
-                f"fuse={rs._apply_fuse}")
+                f"{n_keys} keys")
             _bench_gc_tune()
 
             # Continuous submission under a bounded-in-flight window per
@@ -1902,8 +1886,6 @@ def run_apply() -> dict:
                 "keys": n_keys,
                 "zipf_s": zipf_s,
                 "ineligible_fraction": ineligible,
-                "parallel_apply": rs._parallel_apply,
-                "apply_fuse": rs._apply_fuse,
                 "latency_apply_p99_ms": max(lat.values()) if lat else 0.0,
                 "latency_apply_p99_ms_per_group": lat,
                 "apply": {

@@ -725,7 +725,8 @@ class DeviceBackedStateMachine(ResourceStateMachine):
     # stage time (None = take the generator slow path), ``vector_finalize``
     # consumes the device result in log order. The pair must be
     # bit-identical in visible state evolution to the generator handler —
-    # tests/test_spi_vector_pump.py proves it differentially.
+    # tests/test_spi_vector_pump.py proves it differentially against the
+    # host state machines (``executor="cpu"``).
 
     def vector_spec(self, operation: Any
                     ) -> tuple[int, int, int, int, int] | None:
@@ -746,7 +747,7 @@ class DeviceBackedStateMachine(ResourceStateMachine):
     # window, which evaluates the whole window through one query_step
     # engine round. The pair must return exactly what the plain query
     # handler returns — tests/test_spi_read_pump.py proves it
-    # differentially against the per-op lane.
+    # differentially against the host state machines.
 
     def query_spec(self, operation: Any
                    ) -> tuple[int, int, int, int, int] | None:

@@ -109,10 +109,8 @@ class RaftServer(Managed):
         self.num_groups = groups
         self.single = groups == 1
 
-        # knob-derived shared config (live on the server so tests can
-        # flip the pump/lane attributes mid-run; groups read through
-        # delegation properties)
-        self._repl_pipeline = knobs.get_bool("COPYCAT_REPL_PIPELINE")
+        # knob-derived shared config (groups read through delegation
+        # properties)
         self._repl_window = max(1, knobs.get_int("COPYCAT_REPL_WINDOW"))
         self._repl_depth = max(1, knobs.get_int("COPYCAT_REPL_DEPTH"))
         self._repl_max_inflight = max(self._repl_window, knobs.get_int(
@@ -120,10 +118,6 @@ class RaftServer(Managed):
             default=self._repl_window * self._repl_depth))
         self._strict_invariants = knobs.get_str(
             "COPYCAT_INVARIANTS", default="") == "strict"
-        self._vector_pump = knobs.get_bool("COPYCAT_SERVER_VECTOR_PUMP")
-        self._read_pump = knobs.get_bool("COPYCAT_SERVER_READ_PUMP")
-        self._parallel_apply = knobs.get_bool("COPYCAT_PARALLEL_APPLY")
-        self._apply_fuse = knobs.get_bool("COPYCAT_APPLY_FUSE")
         self._snap_enabled = knobs.get_bool("COPYCAT_SNAPSHOTS")
         self._snap_every = max(1, knobs.get_int("COPYCAT_SNAPSHOT_ENTRIES"))
         self._snap_retain = max(0, knobs.get_int(
@@ -220,8 +214,7 @@ class RaftServer(Managed):
         # paying one engine round each; the collector dispatches ONCE at
         # the end of the event-loop turn with mixed groups_idx rows —
         # one DeviceEngine.run_vector per server turn no matter how many
-        # groups' commits advanced. COPYCAT_APPLY_FUSE=0 keeps the
-        # per-group dispatch (the A/B lane). All groups share one engine
+        # groups' commits advanced. All groups share one engine
         # (docs/SHARDING.md), so mixing rows is free; per-group FIFO
         # holds because runs are staged in per-group log order and the
         # engine's stable group sort preserves row order within a group.

@@ -110,9 +110,8 @@ def dispatch_vector_rows(engine: Any, window: Any, rows: list
     dispatch. Returns ``(raws, pump_error)`` — a barrier or pump failure
     yields empty ``raws`` with the error set, for the caller's explicit
     per-entry failure branch (:meth:`RaftGroup._finalize_vector_run`).
-    The ONE marshalling of the staged row shape, shared by the per-group
-    lane (``RaftGroup._apply_vector_run``) and the server's fused
-    cross-group dispatch (``RaftServer._flush_fused_engine``)."""
+    Called by the server's fused cross-group dispatch
+    (``RaftServer._flush_fused_engine``)."""
     n = len(rows)
     marshal = TRACER.open_span("apply.marshal") if TRACER.enabled else None
     if window is not None and window.busy:
@@ -309,8 +308,8 @@ class RaftGroup:
         # parallel-apply dependency tracking: resource keys / session
         # ids with vector rows staged (locally or in the server's fused
         # collector) whose device effects have not been dispatched yet;
-        # _stage_rows counts them so the contiguous plane (which tracks
-        # no keys) still bounds pending fused rows correctly
+        # _stage_rows counts them so a machine without apply_key (no
+        # keys tracked) still bounds pending fused rows correctly
         self._stage_keys: set = set()
         self._stage_sessions: set[int] = set()
         self._stage_rows = 0
@@ -515,10 +514,6 @@ class RaftGroup:
         return self.server._closing
 
     @property
-    def _repl_pipeline(self) -> bool:
-        return self.server._repl_pipeline
-
-    @property
     def _repl_window(self) -> int:
         return self.server._repl_window
 
@@ -533,22 +528,6 @@ class RaftGroup:
     @property
     def _strict_invariants(self) -> bool:
         return self.server._strict_invariants
-
-    @property
-    def _vector_pump(self) -> bool:
-        return self.server._vector_pump
-
-    @property
-    def _read_pump(self) -> bool:
-        return self.server._read_pump
-
-    @property
-    def _parallel_apply(self) -> bool:
-        return self.server._parallel_apply
-
-    @property
-    def _apply_fuse(self) -> bool:
-        return self.server._apply_fuse
 
     @property
     def _snap_enabled(self) -> bool:
@@ -1245,36 +1224,15 @@ class RaftGroup:
 
     async def _replicate_loop(self, peer: Address) -> None:
         try:
-            if self._repl_pipeline:
-                await self._replicate_pipelined(peer)
-            else:
-                await self._replicate_stop_and_wait(peer)
+            await self._replicate_pipelined(peer)
         except asyncio.CancelledError:
             pass
         except Exception:
             logger.exception("replication loop to %s failed", peer)
 
-    # -- stop-and-wait lane (COPYCAT_REPL_PIPELINE=0): one window in
-    # -- flight per peer, the pre-pipeline behavior bit-identically —
-    # -- the cluster bench's A/B baseline
-    async def _replicate_stop_and_wait(self, peer: Address) -> None:
-        event = self._replication_events[peer]
-        while self.role == LEADER and not self._closing:
-            event.clear()
-            await self._replicate_once(peer)
-            if self.role != LEADER:
-                return
-            if self.next_index.get(peer, 1) > self.log.last_index:
-                try:
-                    await asyncio.wait_for(event.wait(),
-                                           self.heartbeat_interval)
-                except asyncio.TimeoutError:
-                    pass
-
     def _stage_window(self, next_index: int,
                       limit: int) -> tuple[msg.AppendRequest, int, int]:
-        """Build one append window [next_index, covered_end] — shared by
-        both lanes so their wire shape can never drift apart. The end of
+        """Build one append window [next_index, covered_end]. The end of
         the covered index range may omit compacted (cleaned) entries:
         they are only ever compacted once replicated to ALL members, so
         the follower already has them (it gap-fills via ``fill_to``)."""
@@ -1312,57 +1270,7 @@ class RaftGroup:
             self._m_repl_window_entries.record(len(entries))
         return request, prev_index, covered_end
 
-    async def _replicate_once(self, peer: Address) -> None:
-        conn = await self._peer_connection(peer)
-        if conn is None:
-            await asyncio.sleep(self.heartbeat_interval)
-            return
-        next_index = self.next_index.get(peer, self.log.last_index + 1)
-        if next_index <= self.log.prefix_index:
-            # the entries this follower needs were released behind a
-            # snapshot: stream the snapshot, then resume appending
-            await self._install_to_peer(peer, conn)
-            return
-        request, prev_index, covered_end = self._stage_window(
-            next_index, self._repl_window)
-        t0 = time.perf_counter()
-        try:
-            response = await asyncio.wait_for(conn.send(request),
-                                              self.election_timeout)
-        except (TransportError, OSError, asyncio.TimeoutError):
-            self._m_repl_stalls.inc()
-            await asyncio.sleep(self.heartbeat_interval)
-            return
-        if self.role != LEADER:
-            return
-        if response.term is not None and response.term > self.term:
-            self._become_follower(response.term, None)
-            return
-        self._last_quorum_contact[peer] = time.monotonic()
-        self._m_repl_ack_ms.record((time.perf_counter() - t0) * 1e3)
-        if response.success:
-            match = max(prev_index, covered_end)
-            if match > self.match_index.get(peer, 0):
-                self.match_index[peer] = match
-            self.next_index[peer] = max(self.next_index.get(peer, 1),
-                                        match + 1)
-            self._advance_commit()
-            if self.next_index[peer] <= self.log.last_index:
-                self._replication_events[peer].set()  # keep streaming
-        else:
-            self._m_repl_rewinds.inc()
-            hint = (response.last_index
-                    if response.last_index is not None else prev_index - 1)
-            new_next = max(1, min(prev_index, hint + 1))
-            if new_next == next_index:
-                # No rewind progress (e.g. follower in a weird state): back
-                # off instead of hot-spinning the failure path.
-                self._m_repl_stalls.inc()
-                await asyncio.sleep(self.heartbeat_interval)
-            self.next_index[peer] = new_next
-            self._replication_events[peer].set()
-
-    # -- pipelined lane (default): up to REPL_DEPTH windows in flight
+    # -- replication: up to REPL_DEPTH windows in flight
     # -- per peer over the transport's correlated multiplexing; acks may
     # -- land out of order, match only moves forward, commit advances
     # -- per ack, a failed consistency check drains + rewinds the stream
@@ -1544,16 +1452,15 @@ class RaftGroup:
     # -- snapshot-install streaming (leader side) ----------------------
 
     async def _install_to_peer(self, peer: Address, conn: Connection,
-                               ps: _PeerStream | None = None) -> bool:
+                               ps: _PeerStream) -> bool:
         """Stream the newest snapshot to a follower whose ``next_index``
         fell behind the prefix-truncated log, then point the append
         stream just past the snapshot.  Chunks ride the connection's
-        correlated multiplexing — up to the pipeline depth in flight
-        (one at a time on the stop-and-wait lane) with each ack feeding
-        the stream's AIMD/EWMA accounting.  Any failed or refused chunk
-        aborts the attempt; the driver loop retries from scratch on its
-        next beat (installs are rare and whole-retry keeps the follower
-        assembly state trivial)."""
+        correlated multiplexing — up to the pipeline depth in flight,
+        with each ack feeding the stream's AIMD/EWMA accounting.  Any
+        failed or refused chunk aborts the attempt; the driver loop
+        retries from scratch on its next beat (installs are rare and
+        whole-retry keeps the follower assembly state trivial)."""
         snap = (self._snapshots.newest()
                 if self._snap_enabled and self._snapshots is not None
                 else None)
@@ -1585,7 +1492,7 @@ class RaftGroup:
         term = self.term
         total = len(payload)
         chunk = self._snap_chunk
-        sem = asyncio.Semaphore(self._repl_depth if ps is not None else 1)
+        sem = asyncio.Semaphore(self._repl_depth)
         failed = False
 
         async def send_chunk(offset: int) -> None:
@@ -1615,8 +1522,7 @@ class RaftGroup:
                     return
                 self._m_snap_chunks_sent.inc()
                 self._last_quorum_contact[peer] = time.monotonic()
-                if ps is not None:
-                    ps.observe_ack((time.perf_counter() - t0) * 1e3)
+                ps.observe_ack((time.perf_counter() - t0) * 1e3)
 
         await asyncio.gather(
             *(send_chunk(o) for o in range(0, total, chunk)))
@@ -1639,10 +1545,7 @@ class RaftGroup:
                     failed = True
         if failed or self.role != LEADER:
             self._m_snap_install_fail.inc()
-            if ps is not None:
-                ps.backoff = True
-            else:
-                await asyncio.sleep(self.heartbeat_interval)
+            ps.backoff = True
             return False
         self._m_snap_installs_sent.inc()
         self._last_quorum_contact[peer] = time.monotonic()
@@ -2522,15 +2425,6 @@ class RaftGroup:
         ``(result, code, detail)`` — or ``(0, None, (code, detail,
         leader))`` for a request-level refusal."""
         self._m_query_level[consistency.value].inc(len(operations))
-        if not self._read_pump:
-            request = msg.QueryBatchRequest(
-                session_id=session_id, index=client_index,
-                consistency=consistency.value, operations=operations)
-            response = await self._query_batch_direct(request, consistency)
-            if response.error:
-                return 0, None, (response.error, response.error_detail or "",
-                                 getattr(response, "leader", None))
-            return response.index or 0, response.entries, None
         self._m_query_ops.inc(len(operations))
         futs = [self._stage_read(consistency, session_id, client_index, op)
                 for op in operations]
@@ -2575,10 +2469,9 @@ class RaftGroup:
             if not ok:
                 return (msg.INTERNAL, "state lagging behind client index")
         # ``last_applied`` may cover vector rows parked in the server's
-        # fused collector — the per-op read lanes behind this gate serve
-        # at ``last_applied``, so those device effects must land first
-        # (the read WINDOW flushes in ``run_query_window``; a free no-op
-        # when nothing is staged)
+        # fused collector: a caller that serves at ``last_applied``
+        # needs those device effects landed first (a free no-op when
+        # nothing is staged)
         self.server.flush_fused("read")
         return None
 
@@ -2599,10 +2492,6 @@ class RaftGroup:
     async def _on_query(self, request: msg.QueryRequest) -> msg.QueryResponse:
         consistency = QueryConsistency(request.consistency or "linearizable")
         self._m_query_level[consistency.value].inc()
-        if not self._read_pump:
-            return self._edge_seed_response(
-                request, await self._query_direct(request, consistency),
-                [request.operation])
         self._m_query_ops.inc()
         fut = self._stage_read(consistency, request.session_id,
                                request.index or 0, request.operation)
@@ -2618,48 +2507,29 @@ class RaftGroup:
             request, msg.QueryResponse(index=index, result=result),
             [request.operation])
 
-    async def _query_direct(self, request: msg.QueryRequest,
-                            consistency: QueryConsistency
-                            ) -> msg.QueryResponse:
-        """The per-op read lane (COPYCAT_SERVER_READ_PUMP=0): gate and
-        execute this request alone — the pre-pump server bit-identically,
-        the readmix A/B baseline."""
-        refused = await self._gate_query(consistency, request.index or 0)
-        if refused is not None:
-            code, detail = refused
-            if code == msg.NOT_LEADER:
-                return self._not_leader(msg.QueryResponse)
-            return msg.QueryResponse(error=code, error_detail=detail)
-        session = self.sessions.get(request.session_id)
-        commit = Commit(self.last_applied, session, self.context.clock,
-                        request.operation, None)
-        try:
-            result = self.executor.execute(commit)
-        except Exception as e:  # noqa: BLE001 - application errors cross
-            return msg.QueryResponse(error=msg.APPLICATION,
-                                     error_detail=str(e),
-                                     index=self.last_applied)
-        finally:
-            commit.close()
-        return msg.QueryResponse(index=self.last_applied, result=result)
-
     async def _on_query_batch(self, request: msg.QueryBatchRequest
                               ) -> msg.QueryBatchResponse:
         """Batched reads of one consistency level: the gate (leadership
         confirmation / applied wait) runs ONCE for the whole batch — a
-        quorum round amortized over N linearizable reads. With the read
-        pump on, the batch joins the server-wide per-consistency read
-        window, sharing that one gate round with every other session's
-        same-turn reads and the device-eligible subset of the window's
-        tensor evaluation."""
+        quorum round amortized over N linearizable reads. The batch
+        joins the server-wide per-consistency read window, sharing that
+        one gate round with every other session's same-turn reads and
+        the device-eligible subset of the window's tensor evaluation."""
         consistency = QueryConsistency(request.consistency or "linearizable")
         operations = request.operations or []
         self._m_query_level[consistency.value].inc(len(operations))
-        if not self._read_pump or not operations:
-            return self._edge_seed_response(
-                request,
-                await self._query_batch_direct(request, consistency),
-                operations)
+        if not operations:
+            # nothing to stage in a read window: the gate alone, then an
+            # empty answer at ``last_applied``
+            refused = await self._gate_query(consistency,
+                                             request.index or 0)
+            if refused is None:
+                return msg.QueryBatchResponse(index=self.last_applied,
+                                              entries=[])
+            code, detail = refused
+            if code == msg.NOT_LEADER:
+                return self._not_leader(msg.QueryBatchResponse)
+            return msg.QueryBatchResponse(error=code, error_detail=detail)
         self._m_query_ops.inc(len(operations))
         idx = request.index or 0
         futs = [self._stage_read(consistency, request.session_id, idx, op)
@@ -2672,8 +2542,7 @@ class RaftGroup:
                 return self._not_leader(msg.QueryBatchResponse)
             if code and code != msg.APPLICATION:
                 # gate refusal: identical for every entry of this request
-                # (they share index + consistency) — response-level, like
-                # the per-op lane
+                # (they share index + consistency), so response-level
                 return msg.QueryBatchResponse(error=code, error_detail=detail)
             if code:
                 entries.append((None, code, detail))
@@ -2683,30 +2552,6 @@ class RaftGroup:
         return self._edge_seed_response(
             request, msg.QueryBatchResponse(index=index, entries=entries),
             operations)
-
-    async def _query_batch_direct(self, request: msg.QueryBatchRequest,
-                                  consistency: QueryConsistency
-                                  ) -> msg.QueryBatchResponse:
-        """Per-op lane for one batch request (pump off / empty batch)."""
-        refused = await self._gate_query(consistency, request.index or 0)
-        if refused is not None:
-            code, detail = refused
-            if code == msg.NOT_LEADER:
-                return self._not_leader(msg.QueryBatchResponse)
-            return msg.QueryBatchResponse(error=code, error_detail=detail)
-        session = self.sessions.get(request.session_id)
-        entries = []
-        for operation in (request.operations or []):
-            commit = Commit(self.last_applied, session, self.context.clock,
-                            operation, None)
-            try:
-                entries.append((self.executor.execute(commit), None, None))
-            except Exception as e:  # noqa: BLE001 — per-entry app errors
-                entries.append((None, msg.APPLICATION, str(e)))
-            finally:
-                commit.close()
-        return msg.QueryBatchResponse(index=self.last_applied,
-                                      entries=entries)
 
     # -- batched read pump (the read window) ---------------------------
 
@@ -2765,10 +2610,10 @@ class RaftGroup:
                                items: list, queued: Any = None) -> None:
         """Serve one read window: the consistency gate ONCE, then the
         reads at an applied snapshot — device-eligible reads as tensors
-        through one query_step engine round, the rest through the per-op
-        executor lane bit-identically. ``queued`` is the turn's open
-        ``read.queue`` span when traced: the window's stages are
-        recorded under one id of its own."""
+        through one query_step engine round, the rest one by one through
+        the executor. ``queued`` is the turn's open ``read.queue`` span
+        when traced: the window's stages are recorded under one id of
+        its own."""
         n = len(items)
         self._m_query_windows.inc()
         self._m_query_window_ops.record(n)
@@ -2794,14 +2639,12 @@ class RaftGroup:
                     self._resolve_read(fut, (0, None, msg.NOT_LEADER, ""))
                 return
             if linear:
-                # ONE leadership-confirm round served the whole window;
-                # the per-op lane pays one per LINEARIZABLE read — the
-                # N-1 amortized rounds are the counter the differential
-                # test asserts. Bounded windows never count here: the
-                # per-op lane's first confirm renews the lease
-                # (_last_quorum_contact), so its reads 2..N are
-                # confirm-free too — nothing is actually saved. A failed
-                # confirm (refused window) amortizes nothing either.
+                # ONE leadership-confirm round served the whole window
+                # where N reads served alone would pay N. Bounded windows
+                # never count here: a first confirm renews the lease
+                # (_last_quorum_contact), so reads 2..N would be
+                # confirm-free anyway. A failed confirm (refused window)
+                # amortizes nothing either.
                 self._m_query_gate_saved.inc(n - 1)
             await self._wait_applied(self.commit_index)
             # the gate established the linearization point: serve at it
@@ -2809,9 +2652,9 @@ class RaftGroup:
             self._evaluate_reads(items, check_index=False, gate=gate)
             return
         # SEQUENTIAL / CAUSAL: a read whose own index is already applied
-        # serves NOW (the per-op lane's latency — no head-of-line wait
-        # behind an unrelated session's lagging index); stragglers share
-        # one wait on their max index and refuse per-op at timeout.
+        # serves NOW (no head-of-line wait behind an unrelated session's
+        # lagging index); stragglers share one wait on their max index
+        # and refuse per-op at timeout.
         applied = self.last_applied
         ready = [it for it in items if not it[1] or it[1] <= applied]
         lagging = [it for it in items if it[1] and it[1] > applied]
@@ -2828,11 +2671,11 @@ class RaftGroup:
                         gate: Any = None) -> None:
         """Serve one batch of gated reads at the current applied
         snapshot. ``check_index`` refuses reads still lagging the
-        client's index (a timed-out applied wait) exactly like the
-        per-op lane's gate. ``gate`` is the window's open ``read.gate``
-        span when traced; it ends where this begins, and the engine
-        records its query drive (and any settling round) under the
-        window's id for the length of this synchronous section."""
+        client's index (a timed-out applied wait) as ``_gate_query``
+        does. ``gate`` is the window's open ``read.gate`` span when
+        traced; it ends where this begins, and the engine records its
+        query drive (and any settling round) under the window's id for
+        the length of this synchronous section."""
         evaluate = scope = None
         if gate is not None:
             evaluate = gate.then("read.eval")
@@ -2883,10 +2726,10 @@ class RaftGroup:
 
     def _serve_query_rows(self, rows: list, applied: int) -> None:
         """One query_step engine round for every device-eligible read in
-        the window (the read analog of ``_apply_vector_run``): stage [N]
-        rows, evaluate from the leader lane's applied state, correlate
-        results in a single pass — no per-op Commit objects, no per-op
-        executor dispatch."""
+        the window (the read analog of the command pump's vector run):
+        stage [N] rows, evaluate from the leader lane's applied state,
+        correlate results in a single pass — no per-op Commit objects,
+        no per-op executor dispatch."""
         m = len(rows)
         self._m_query_device.inc(m)
         engine = self.state_machine.device_engine
@@ -2946,17 +2789,16 @@ class RaftGroup:
             begin = getattr(self.state_machine, "begin_window", None)
             if begin is not None:
                 window = begin()  # None on the CPU executor
-            if window is not None and self._vector_pump:
+            if window is not None:
                 route = getattr(self.state_machine, "vector_route", None)
         key_fn = None
         if route is not None:
             self._m_apply_window.record(commit_index - self.last_applied)
-            if self._parallel_apply:
-                # dependency-classified windows (docs/SHARDING.md "Apply
-                # ordering"): runs span ineligible entries on disjoint
-                # keys; COPYCAT_PARALLEL_APPLY=0 (or a state machine
-                # without apply_key) keeps the contiguous classifier
-                key_fn = getattr(self.state_machine, "apply_key", None)
+            # dependency-classified windows (docs/SHARDING.md "Apply
+            # ordering"): runs span ineligible entries on disjoint keys;
+            # for a state machine without apply_key every ineligible
+            # entry bounds the run
+            key_fn = getattr(self.state_machine, "apply_key", None)
         vrun: list = []  # staged rows: (clock, entry, session, *route rec)
         # batch-scope tracing: this walk is the pump turn's classify
         # stage, and the engine rounds the window lane drives inside it
@@ -3001,12 +2843,15 @@ class RaftGroup:
                     self._m_vector_refused.inc()
                 if vrun or self._stage_rows:
                     # An ineligible entry bounds the staged run — always
-                    # on the contiguous plane (key_fn None), only on a
-                    # dependency/session/timer conflict on the parallel
-                    # plane (a disjoint-key entry is spanned; per-key
-                    # FIFO still holds because a colliding entry forces
-                    # the dispatch below BEFORE it applies). vrun is
-                    # emptied BEFORE the call — if the run raises
+                    # without an apply_key (key_fn None), else only on a
+                    # dependency/session/timer conflict (a disjoint-key
+                    # entry is spanned; per-key FIFO still holds because
+                    # a colliding entry forces the dispatch below BEFORE
+                    # it applies): the bounding entry applies only after
+                    # the staged device effects land, so the SERVER's
+                    # collector is forced synchronously (other groups'
+                    # staged rows ride along in the same engine round).
+                    # vrun is emptied BEFORE the call — if the run raises
                     # (window barrier timeout), replaying it at the next
                     # flush point would double-apply. Its try is
                     # SEPARATE from the bounding entry's: a failed run
@@ -3021,7 +2866,9 @@ class RaftGroup:
                         run, vrun = vrun, []
                         staged = staged or bool(run)
                         try:
-                            self._bound_vector_run(run, window)
+                            if run:
+                                self._stage_fused(run)
+                            self.server.flush_fused("conflict")
                         except Exception:
                             logger.exception(
                                 "vector apply failed before index %d", index)
@@ -3039,7 +2886,9 @@ class RaftGroup:
             if vrun:
                 staged = True
                 try:
-                    self._stage_vector_tail(vrun, window)
+                    # end of the window: the run parks in the server's
+                    # collector and rides the turn's ONE engine round
+                    self._stage_fused(vrun)
                 except Exception:
                     logger.exception("vector apply failed")
         finally:
@@ -3155,29 +3004,6 @@ class RaftGroup:
         key = key_fn(entry.operation)
         return key is None or key in self._stage_keys
 
-    def _bound_vector_run(self, run: list, window: Any) -> None:
-        """Dispatch every staged row at a conflict bound: the bounding
-        entry applies only after the staged device effects land. On the
-        fused plane this forces the SERVER's collector synchronously
-        (other groups' staged rows ride along in the same engine round);
-        per-group otherwise."""
-        if self._apply_fuse:
-            if run:
-                self._stage_fused(run)
-            self.server.flush_fused("conflict")
-        elif run:
-            self._apply_vector_run(run, window)
-
-    def _stage_vector_tail(self, run: list, window: Any) -> None:
-        """End-of-window dispatch point: on the fused plane the run
-        parks in the server's collector and rides the turn's ONE engine
-        round (``RaftServer.flush_fused``); per-group it dispatches
-        now."""
-        if self._apply_fuse:
-            self._stage_fused(run)
-        else:
-            self._apply_vector_run(run, window)
-
     def _stage_fused(self, run: list) -> None:
         """Hand one run to the server's cross-group collector.
         ``_stage_rows`` counts this group's parked rows so the next
@@ -3186,31 +3012,12 @@ class RaftGroup:
         self._stage_rows += len(run)
         self.server.stage_vector_run(self, run)
 
-    def _apply_vector_run(self, run: list, window: Any) -> None:
-        """Apply one run of vector-eligible commands on the PER-GROUP
-        lane (``COPYCAT_APPLY_FUSE=0``): ONE vectorized engine round for
-        the whole run (``DeviceEngine.run_vector``), then per-entry
-        finalization in log order via :meth:`_finalize_vector_run` —
-        with zero generator/window machinery per op. A barrier failure
-        is a pump error (rows fail explicitly, futures resolve) instead
-        of an exception that would silently drop the run."""
-        engine = self.state_machine.device_engine
-        if not TRACER.enabled:
-            raws, pump_error = dispatch_vector_rows(engine, window, run)
-            self._finalize_vector_run(run, raws, pump_error)
-            return
-        with TRACER.scope(self.server.pump_batch(), "apply"):
-            raws, pump_error = dispatch_vector_rows(engine, window, run)
-            finalize = TRACER.open_span("apply.finalize")
-            self._finalize_vector_run(run, raws, pump_error)
-            finalize.close(rows=len(run), groups=1)
-
     def _finalize_vector_run(self, run: list, raws: list,
                              pump_error: str | None) -> None:
         """Per-entry finalization of one DISPATCHED run in log order —
-        response cache, commit futures, held-commit bookkeeping — shared
-        by the per-group lane (:meth:`_apply_vector_run`) and the
-        server's fused cross-group dispatch (``RaftServer.flush_fused``).
+        response cache, commit futures, held-commit bookkeeping — called
+        by the server's fused cross-group dispatch
+        (``RaftServer.flush_fused``).
 
         A failed pump (``pump_error`` set) takes an EXPLICIT per-entry
         failure branch: ``raws`` is never indexed (it is empty then —
@@ -3268,8 +3075,7 @@ class RaftGroup:
                 self._complete_command(entry, result, error, [])
         # dependency bookkeeping: this run's rows are no longer staged.
         # The collector drains whole (never partially), so a zero count
-        # retires the key/session sets; the per-group lane enters with
-        # _stage_rows == 0 and clears them here too.
+        # retires the key/session sets.
         if self._stage_rows > n:
             self._stage_rows -= n
         else:

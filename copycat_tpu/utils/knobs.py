@@ -60,7 +60,7 @@ REGISTRY: dict[str, Knob] = {}
 
 # README section order; every knob names one of these.
 SECTIONS = (
-    ("server", "Server planes (vector + read pump)"),
+    ("server", "Server planes"),
     ("replication", "Replication pipeline"),
     ("deploy", "Deployment plane (`copycat-tpu cluster`)"),
     ("durability", "Snapshots & durability"),
@@ -89,29 +89,11 @@ _knob("COPYCAT_GROUPS", "int", 1,
 _knob("COPYCAT_MULTI_GROUP", "bool", True,
       "`0` forces the single-group plane regardless of `COPYCAT_GROUPS` "
       "(the sharding A/B)", section="server")
-_knob("COPYCAT_SERVER_VECTOR_PUMP", "bool", True,
-      "`0` restores the per-op command apply lane (the spi A/B)",
-      section="server")
-_knob("COPYCAT_SERVER_READ_PUMP", "bool", True,
-      "`0` restores the per-op read lane (the readmix A/B)",
-      section="server")
-_knob("COPYCAT_PARALLEL_APPLY", "bool", True,
-      "`0` restores the contiguous-run vector classifier — runs no "
-      "longer span ineligible entries on disjoint keys (the "
-      "dependency-classified parallel-apply A/B, docs/SHARDING.md)",
-      section="server")
-_knob("COPYCAT_APPLY_FUSE", "bool", True,
-      "`0` restores one engine dispatch per group per run — staged "
-      "vector runs no longer fuse across groups into one device round "
-      "per server turn (the cross-group fusion A/B)", section="server")
 
 # --- replication -----------------------------------------------------------
-_knob("COPYCAT_REPL_PIPELINE", "bool", True,
-      "`0` restores stop-and-wait replication (the A/B lane)",
-      section="replication")
 _knob("COPYCAT_REPL_WINDOW", "int", 64,
-      "append window size: pipeline initial/ceiling AND the stop-and-wait "
-      "window", section="replication")
+      "append window size: the pipeline's initial size and ceiling",
+      section="replication")
 _knob("COPYCAT_REPL_DEPTH", "int", 8,
       "max append windows in flight per peer", section="replication")
 _knob("COPYCAT_REPL_MAX_INFLIGHT", "int", None, default_doc="window×depth",

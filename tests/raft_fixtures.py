@@ -9,6 +9,8 @@ from __future__ import annotations
 import asyncio
 from typing import Any
 
+import pytest
+
 from copycat_tpu.io.local import LocalServerRegistry, LocalTransport
 from copycat_tpu.io.transport import Address
 from copycat_tpu.io.serializer import serialize_with
@@ -16,6 +18,12 @@ from copycat_tpu.protocol.messages import Message
 from copycat_tpu.protocol.operations import Command, Query
 from copycat_tpu.server.log import Storage, StorageLevel
 from copycat_tpu.server.raft import LEADER, RaftServer
+
+#: the replication stream at its default depth and at its edge of ONE
+#: window in flight (the shape stop-and-wait replication has); the test
+#: sets ``COPYCAT_REPL_DEPTH`` to ``depth`` before it builds its cluster
+REPL_DEPTHS = pytest.mark.parametrize("depth", ("8", "1"),
+                                      ids=("depth8", "depth1"))
 from copycat_tpu.server.state_machine import (
     Commit,
     SnapshotCut,

@@ -1,26 +1,24 @@
 """Differential proof for the dependency-classified parallel apply +
 cross-group engine fusion (docs/SHARDING.md "Apply ordering").
 
-The parallel plane generalizes the vector classifier from "contiguous
-runs" to "dependency-classified windows": device-eligible entries on
-disjoint resource keys join a staged run ACROSS interleaved ineligible
-entries, per-key/per-session FIFO is preserved by the conflict gate
-(a colliding entry forces the staged dispatch before it applies), and
-staged runs from every Raft group fuse into ONE engine round per
-server turn (``RaftServer.flush_fused``). Its contract is BIT-IDENTICAL
-observable behavior to the contiguous/per-group plane on every knob
-combination:
-
-- ``COPYCAT_PARALLEL_APPLY=0`` restores the contiguous classifier;
-- ``COPYCAT_APPLY_FUSE=0`` restores one dispatch per group per run.
+The vector classifier works on dependency-classified windows:
+device-eligible entries on disjoint resource keys join a staged run
+ACROSS interleaved ineligible entries, per-key/per-session FIFO is
+preserved by the conflict gate (a colliding entry forces the staged
+dispatch before it applies), and staged runs from every Raft group fuse
+into ONE engine round per server turn (``RaftServer.flush_fused``). Its
+contract is BIT-IDENTICAL observable behavior to the host state
+machines (``executor="cpu"``), which apply entry by entry in log order.
 
 These tests prove it by running one seeded interleaved-eligibility
-script through all four knob planes and comparing everything the client
-can see plus the committed per-group command streams, then racing the
-parallel plane against partition + leader-deposition nemeses under
-``COPYCAT_INVARIANTS=strict``. The mid-run engine-failure test covers
-the explicit failed-pump branch of ``_finalize_vector_run`` (ISSUE 11
-satellite: no ``raws[k]`` walk behind a short-circuit guard).
+script through a device server and a CPU server and comparing
+everything the client can see, then racing the device plane against
+partition + leader-deposition nemeses under
+``COPYCAT_INVARIANTS=strict``, once spread over groups and keys and once
+at the classifier's edge where every entry conflicts. The mid-run
+engine-failure test covers the explicit failed-pump branch of
+``_finalize_vector_run`` (ISSUE 11 satellite: no ``raws[k]`` walk behind
+a short-circuit guard).
 """
 
 import asyncio
@@ -44,24 +42,17 @@ from raft_fixtures import next_ports  # noqa: E402
 
 ENGINE = DeviceEngineConfig(capacity=32, num_peers=3, log_slots=32)
 
-#: (parallel_apply, apply_fuse) — plane 0 is today's default (both on),
-#: plane 3 is the pre-PR contiguous/per-group plane.
-PLANES = ((True, True), (True, False), (False, True), (False, False))
 
-
-async def _cluster(registry, parallel: bool, fuse: bool, *,
+async def _cluster(registry, *, executor: str = "tpu",
                    members: int = 1, groups: int = 4,
                    election_timeout: float = 0.5, clients: int = 1):
     addrs = next_ports(members)
     servers = [AtomixServer(a, addrs, LocalTransport(registry),
                             election_timeout=election_timeout,
                             heartbeat_interval=election_timeout / 5,
-                            session_timeout=30.0, executor="tpu",
+                            session_timeout=30.0, executor=executor,
                             engine_config=ENGINE, groups=groups)
                for a in addrs]
-    for s in servers:
-        s.server._parallel_apply = parallel
-        s.server._apply_fuse = fuse
     await asyncio.gather(*(s.open() for s in servers))
     cs = [AtomixClient(addrs, LocalTransport(registry),
                        session_timeout=30.0) for _ in range(clients)]
@@ -77,7 +68,7 @@ def _script(seed: int, n_waves: int, wave: int):
     entries from DIFFERENT sessions — the contiguity-collapsing shape
     the dependency classifier spans; same-session interleaving always
     conflicts, by the session-FIFO gate). Values hash-route across all
-    4 groups, so the fused plane mixes groups in one round."""
+    4 groups, so the fused flush mixes groups in one round."""
     rng = random.Random(seed)
     waves = []
     for _ in range(n_waves):
@@ -131,95 +122,69 @@ async def _run_script(clients, waves):
     return results, events, finals
 
 
-def _command_streams(server) -> dict[int, list[bytes]]:
-    """Per-group committed command content in log order — serialized
-    operation bytes, the cross-plane comparable view."""
-    ser = Serializer()
-    out: dict[int, list[bytes]] = {}
+def _lane_counters(server) -> dict[str, int]:
+    flat: dict[str, int] = {}
     for grp in server.groups:
-        stream = []
-        for i in range(1, grp.commit_index + 1):
-            e = grp.log.get(i)
-            if isinstance(e, CommandEntry):
-                stream.append(ser.write(e.operation))
-        out[grp.group_id] = stream
-    return out
+        for name in ("apply.parallel_spans", "apply.conflict_flushes",
+                     "vector_runs", "vector_ops"):
+            flat[name] = flat.get(name, 0) + grp.metrics.counter(name).value
+    flat["apply.fused_dispatches"] = server._metrics.counter(
+        "apply.fused_dispatches").value
+    return flat
 
 
 @async_test(timeout=600)
-async def test_parallel_apply_bit_identical_across_knob_planes():
-    """Same seeded interleaved script, four knob planes: results,
-    per-session event order, final state, and the committed per-group
-    command streams must all be identical — COPYCAT_PARALLEL_APPLY=0
-    and COPYCAT_APPLY_FUSE=0 each restore the pre-PR plane exactly."""
+async def test_parallel_apply_bit_identical_to_host_machines():
+    """Same seeded interleaved script, the device server against the
+    host state machines: results, per-session event order and final
+    state must all be identical, with work committed in every group."""
     waves = _script(seed=11, n_waves=5, wave=32)
     histories = []
-    streams = []
     metrics = []
-    for parallel, fuse in PLANES:
+    for executor in ("tpu", "cpu"):
         registry = LocalServerRegistry()
-        servers, clients = await _cluster(registry, parallel, fuse,
+        servers, clients = await _cluster(registry, executor=executor,
                                           clients=4)
         try:
             histories.append(await _run_script(clients, waves))
-            streams.append(_command_streams(servers[0].server))
-            snap = servers[0].server.stats_snapshot()
-            flat = {}
-            for grp in servers[0].server.groups:
-                for name in ("apply.parallel_spans",
-                             "apply.conflict_flushes", "vector_runs",
-                             "vector_ops"):
-                    flat[name] = flat.get(name, 0) + \
-                        grp.metrics.counter(name).value
-            flat["apply.fused_dispatches"] = servers[0].server._metrics \
-                .counter("apply.fused_dispatches").value
-            metrics.append(flat)
-            assert "apply.fused_dispatches" in str(snap), \
+            server = servers[0].server
+            # every group took work (the fused flush had cross-group
+            # rows to merge)
+            for grp in server.groups:
+                assert any(isinstance(grp.log.get(i), CommandEntry)
+                           for i in range(1, grp.commit_index + 1)), \
+                    f"{executor}: group {grp.group_id} committed no command"
+            metrics.append(_lane_counters(server))
+            assert "apply.fused_dispatches" in str(server.stats_snapshot()), \
                 "apply.* family missing from the stats surface"
         finally:
             for c in clients:
                 await asyncio.wait_for(c.close(), 5)
             for s in servers:
                 await asyncio.wait_for(s.close(), 5)
-    base = histories[0]
-    for plane, hist in zip(PLANES, histories[1:], strict=False):
-        assert hist[0] == base[0], f"results diverged vs plane {plane}"
-        assert hist[1] == base[1], f"event order diverged vs plane {plane}"
-        assert hist[2] == base[2], f"final state diverged vs plane {plane}"
-    # Every plane routed work to every group (the fused plane had
-    # cross-group rows to merge). Raw LOG bytes are deliberately not
-    # compared across planes: held-commit ``clean()`` timing differs by
-    # plane, so compaction legitimately retains different entry sets —
-    # cross-MEMBER byte identity (the Raft safety property) is asserted
-    # per plane in the nemesis differential below, and the client-
-    # observable history above is the full cross-plane contract.
-    for plane, stream in zip(PLANES, streams):
-        assert all(stream[g] for g in stream), \
-            f"plane {plane} left a group without committed commands"
-    # the script genuinely exercised the planes it compares:
-    on = metrics[0]           # (parallel=1, fuse=1)
-    contiguous = metrics[2]   # (parallel=0, fuse=1)
+    device, host = histories
+    assert device[0] == host[0], "results diverged from the host machines"
+    assert device[1] == host[1], "event order diverged from the host machines"
+    assert device[2] == host[2], "final state diverged from the host machines"
+    # the script genuinely exercised what it compares: the device server
+    # spanned ineligible entries, fused its dispatches and ran vector
+    # rows; the reference never entered the vector lane
+    on, ref = metrics
     assert on["apply.parallel_spans"] > 0, \
-        "parallel plane never spanned an ineligible entry"
+        "the classifier never spanned an ineligible entry"
     assert on["apply.fused_dispatches"] > 0, "fusion never dispatched"
-    assert contiguous["apply.parallel_spans"] == 0, \
-        "knobs-off plane must not classify dependency windows"
-    assert on["vector_ops"] > 0 and contiguous["vector_ops"] > 0
-    # spanning can only merge runs, never split them (run count is also
-    # bounded by commit-window cuts, so equality is legitimate when the
-    # windows were small)
-    assert on["vector_runs"] <= contiguous["vector_runs"], (
-        on["vector_runs"], contiguous["vector_runs"])
+    assert on["vector_ops"] > 0
+    assert ref["vector_ops"] == 0 and ref["apply.fused_dispatches"] == 0
 
 
 @async_test(timeout=600)
 async def test_fused_dispatch_merges_groups_per_turn():
-    """A concurrent burst across all 4 groups on the fused plane:
+    """A concurrent burst across all 4 groups:
     staged runs from different groups land in shared engine rounds —
     the fused-dispatch count stays BELOW the per-group run count, and
     at least one dispatch carried rows from 2+ groups."""
     registry = LocalServerRegistry()
-    servers, client = await _cluster(registry, parallel=True, fuse=True)
+    servers, client = await _cluster(registry)
     try:
         counters = await asyncio.gather(
             *(client.get(f"fc{i}", DistributedAtomicLong)
@@ -240,7 +205,7 @@ async def test_fused_dispatch_merges_groups_per_turn():
             f"(max {groups_hist.max_value})")
         assert rows.sum == sum(
             g.metrics.counter("vector_ops").value for g in server.groups)
-        # exactly-once across the fused plane
+        # exactly-once across the fused flush
         got = await asyncio.gather(*(c.get() for c in counters))
         assert got == [24] * 16, got
     finally:
@@ -256,7 +221,7 @@ async def test_mid_run_engine_failure_fails_rows_explicitly():
     ``raws`` indexing — and the engine must serve the NEXT burst
     normally with exactly-once bookkeeping intact."""
     registry = LocalServerRegistry()
-    servers, client = await _cluster(registry, parallel=True, fuse=True)
+    servers, client = await _cluster(registry)
     try:
         counter = await client.get("mc", DistributedAtomicLong)
         assert await counter.add_and_get(1) == 1  # settle on the device
@@ -323,23 +288,25 @@ def _assert_no_invariant_violations(servers) -> None:
                 f"{s.address} group {grp.group_id}: strict check fired"
 
 
-@pytest.mark.parametrize("plane", ((True, True), (False, False)),
-                         ids=("knobs-on", "knobs-off"))
-def test_nemesis_partition_and_deposition_strict(plane, monkeypatch):
+@pytest.mark.parametrize("all_conflict", (False, True),
+                         ids=("spread", "all-conflict"))
+def test_nemesis_partition_and_deposition_strict(all_conflict, monkeypatch):
     """Partition a follower mid-storm, heal, then depose a leader-
-    hosting member mid-storm — on BOTH knob planes, under the strict
-    commit invariant: every acked op applies exactly once, survivors'
-    per-group logs are bit-identical, and the strict check never
-    fires. This is the acceptance differential: the knobs-off run IS
-    the pre-PR plane, racing the same faults."""
-    parallel, fuse = plane
+    hosting member mid-storm, under the strict commit invariant: every
+    acked op applies exactly once, survivors' per-group logs are
+    bit-identical, and the strict check never fires. ``spread``: two
+    groups, six counters and a listened value. ``all-conflict``: the
+    classifier's edge — ONE group and every operation on ONE key (the
+    increments, and a listener that comes and goes on the same counter,
+    so its ops move between the vector lane and the per-entry path), so
+    every bounding entry forces the flush and none is spanned."""
     monkeypatch.setenv("COPYCAT_INVARIANTS", "strict")
 
     @async_test(timeout=600)
     async def run():
         registry = LocalServerRegistry()
         servers, client = await _cluster(
-            registry, parallel, fuse, members=3, groups=2,
+            registry, members=3, groups=1 if all_conflict else 2,
             election_timeout=0.25)
         live = [s for s in servers]
         try:
@@ -347,11 +314,12 @@ def test_nemesis_partition_and_deposition_strict(plane, monkeypatch):
                 assert s.server.groups[0]._strict_invariants
             counters = await asyncio.gather(
                 *(client.get(f"nc{i}", DistributedAtomicLong)
-                  for i in range(6)))
-            listened = await client.get("nv", DistributedAtomicValue)
-            await listened.set(0)
+                  for i in range(1 if all_conflict else 6)))
             seen: list = []
-            listener = await listened.on_change(seen.append)
+            if not all_conflict:
+                listened = await client.get("nv", DistributedAtomicValue)
+                await listened.set(0)
+                listener = await listened.on_change(seen.append)
             acked = [0] * len(counters)
             unknown = [0] * len(counters)
 
@@ -363,11 +331,21 @@ def test_nemesis_partition_and_deposition_strict(plane, monkeypatch):
                 except Exception:
                     unknown[i] += 1
 
+            async def ineligible(r: int) -> None:
+                """The round's entry for the per-entry path: a write to
+                the listened value, or (all-conflict) a listener that
+                registers on THE counter and leaves again."""
+                if not all_conflict:
+                    await listened.set(r)
+                    return
+                watch = await asyncio.wait_for(
+                    counters[0].on_change(seen.append), 30)
+                watch.close()
+
             async def storm(rounds: int) -> None:
                 for r in range(rounds):
-                    ops = [one(i) for i in range(len(counters))]
-                    # interleave an ineligible (listened) write per round
-                    ops.append(listened.set(r))
+                    ops = [one(i % len(counters)) for i in range(6)]
+                    ops.append(ineligible(r))
                     await asyncio.gather(*ops, return_exceptions=True)
 
             await storm(3)  # steady state
@@ -410,7 +388,14 @@ def test_nemesis_partition_and_deposition_strict(plane, monkeypatch):
                 await asyncio.sleep(0.05)
             _assert_members_bit_identical(live)
             _assert_no_invariant_violations(live)
-            listener.close()
+            if all_conflict:
+                lanes = [_lane_counters(s.server) for s in live]
+                assert sum(c["vector_ops"] for c in lanes) > 0
+                assert sum(c["apply.conflict_flushes"] for c in lanes) > 0
+                assert not any(c["apply.parallel_spans"] for c in lanes), \
+                    "an entry was spanned where every entry conflicts"
+            else:
+                listener.close()
         finally:
             nem = registry.attach_nemesis()
             nem.heal()

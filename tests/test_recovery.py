@@ -21,6 +21,7 @@ from copycat_tpu.server.snapshot import SnapshotStore, frame, unframe
 from copycat_tpu.testing.nemesis import StorageNemesis, crash_server
 
 from raft_fixtures import (
+    REPL_DEPTHS,
     Get,
     KVStateMachine,
     Put,
@@ -216,15 +217,15 @@ def test_recovery_with_ttl_timers(tmp_path, monkeypatch, level):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("pipeline", ["1", "0"], ids=["pipelined", "stopwait"])
+@REPL_DEPTHS
 def test_install_streaming_catches_up_wiped_follower(tmp_path, monkeypatch,
-                                                     pipeline):
+                                                     depth):
     """A follower with total data loss reboots empty while the leader's
     log is prefix-truncated: the append stream cannot serve it, so the
     leader streams the snapshot (chunked, through the replication plane)
-    and resumes appends where the snapshot ends — on BOTH replication
-    lanes."""
-    monkeypatch.setenv("COPYCAT_REPL_PIPELINE", pipeline)
+    and resumes appends where the snapshot ends — with eight chunks in
+    flight and with one."""
+    monkeypatch.setenv("COPYCAT_REPL_DEPTH", depth)
     monkeypatch.setenv("COPYCAT_SNAPSHOTS", "1")
     monkeypatch.setenv("COPYCAT_SNAPSHOT_ENTRIES", "25")
     monkeypatch.setenv("COPYCAT_SNAPSHOT_RETAIN", "2")

@@ -1,31 +1,26 @@
 """Replication differential suite: safety of the pipelined plane is
 DEMONSTRATED, not asserted (ISSUE 5 acceptance).
 
-- The same seeded workload runs through BOTH replication lanes
-  (``COPYCAT_REPL_PIPELINE=1`` and ``=0``) and the committed logs are
-  compared: bit-for-bit across the members of each cluster (replicated
-  entries carry the leader's term/timestamp — any pipelining bug that
-  reorders, drops or duplicates an entry breaks byte equality), and as
-  the exact same committed command sequence + final state across lanes
-  (timestamps/terms are leader-local wall clock, so cross-lane equality
-  is over the replicated COMMAND content).
+- The same seeded workload runs with eight append windows in flight
+  per peer (the default) and with ONE (``COPYCAT_REPL_DEPTH=1``, the
+  stream's edge: the shape stop-and-wait replication has), and the
+  committed logs are compared: bit-for-bit across the members of each
+  cluster (replicated entries carry the leader's term/timestamp — any
+  pipelining bug that reorders, drops or duplicates an entry breaks byte
+  equality), and against the SUBMITTED sequence: exactly those commands,
+  once each, in submission order, and the state they produce.
 - Nemesis tests (delayed+reordered messages, partitioned peers, leader
   deposition mid-stream) run with ``COPYCAT_INVARIANTS=strict``: every
   commit advance re-verifies quorum support from first principles and
   raises on violation, so a pipelined ack stream that ever outran real
   replication would fail these loudly.
-
-CI runs this module twice — pipeline on AND off (the strict re-check
-guards both lanes).
 """
 
 import asyncio
 import random
 
-import pytest
-
 from helpers import async_test
-from raft_fixtures import Get, Put, create_cluster
+from raft_fixtures import REPL_DEPTHS, Get, Put, create_cluster
 
 from copycat_tpu.io.serializer import Serializer
 from copycat_tpu.server.log import CommandEntry
@@ -55,8 +50,9 @@ def _member_log_bytes(server, up_to):
 
 
 def _command_stream(server, up_to):
-    """The committed command content in log order — the cross-lane
-    comparable view (indices/terms/timestamps are lane-local)."""
+    """The committed command content in log order — the view that
+    compares with what was submitted (indices/terms/timestamps are the
+    leader's own)."""
     out = []
     for i in range(1, up_to + 1):
         e = server.log.get(i)
@@ -73,15 +69,20 @@ async def _drive_workload():
         await cluster.await_leader()
         client = await cluster.client(session_timeout=30.0)
         rng = random.Random(SEED)
+        submitted = []
         for _ in range(PHASES):
-            futs = [client.submit_command_nowait(
-                Put(key=f"k{rng.randrange(8)}", value=rng.randrange(100)))
-                for _ in range(OPS_PER_PHASE)]
-            await asyncio.gather(*futs)
+            burst = [(f"k{rng.randrange(8)}", rng.randrange(100))
+                     for _ in range(OPS_PER_PHASE)]
+            submitted += burst
+            await asyncio.gather(*(
+                client.submit_command_nowait(Put(key=k, value=v))
+                for k, v in burst))
         leader = await _wait_converged(cluster)
         up_to = leader.commit_index
         member_logs = [_member_log_bytes(s, up_to) for s in cluster.servers]
         return {
+            "submitted": submitted,
+            "depth": leader._repl_depth,
             "commands": _command_stream(leader, up_to),
             "member_logs": member_logs,
             "state": dict(leader.state_machine.data),
@@ -111,27 +112,31 @@ def _assert_members_bit_identical(member_logs):
     assert compared >= PHASES * OPS_PER_PHASE, compared
 
 
-def test_lanes_commit_identical_logs(monkeypatch):
-    results = {}
-    for lane in ("1", "0"):
-        monkeypatch.setenv("COPYCAT_REPL_PIPELINE", lane)
+@REPL_DEPTHS
+def test_members_commit_the_submitted_sequence(depth, monkeypatch):
+    monkeypatch.setenv("COPYCAT_REPL_DEPTH", depth)
+    results = []
 
-        @async_test(timeout=120)
-        async def run(lane=lane):
-            results[lane] = await _drive_workload()
+    @async_test(timeout=120)
+    async def run():
+        results.append(await _drive_workload())
 
-        run()
-    for lane, r in results.items():
-        # within a lane: every member holds bit-identical committed bytes
-        _assert_members_bit_identical(r["member_logs"])
-        # and identical applied state
-        for st in r["states"]:
-            assert st == r["state"], f"lane {lane} member state diverged"
-    # across lanes: the exact same command sequence committed, in the
-    # same order, producing the same final state
-    assert results["1"]["commands"] == results["0"]["commands"]
-    assert len(results["1"]["commands"]) == PHASES * OPS_PER_PHASE
-    assert results["1"]["state"] == results["0"]["state"]
+    run()
+    (r,) = results
+    assert r["depth"] == int(depth)
+    # every member holds bit-identical committed bytes
+    _assert_members_bit_identical(r["member_logs"])
+    # and identical applied state
+    for st in r["states"]:
+        assert st == r["state"], "member state diverged"
+    # against what was submitted: exactly that command sequence, once
+    # each (session seqs strictly rising), in submission order,
+    # producing the state a plain dict holds after it
+    seqs = [seq for seq, _, _ in r["commands"]]
+    assert seqs == sorted(set(seqs)), "a command committed twice"
+    assert [(k, v) for _, k, v in r["commands"]] == r["submitted"]
+    assert len(r["submitted"]) == PHASES * OPS_PER_PHASE
+    assert r["state"] == dict(r["submitted"])
 
 
 # ---------------------------------------------------------------------------
@@ -139,13 +144,13 @@ def test_lanes_commit_identical_logs(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("lane", ("1", "0"))
-def test_delayed_reordered_peers_strict(lane, monkeypatch):
+@REPL_DEPTHS
+def test_delayed_reordered_peers_strict(depth, monkeypatch):
     """Per-message random delays reorder in-flight append windows on the
     local transport (plus response loss for at-most-once ambiguity); the
     stream must stay exactly-once and commit must never outrun a real
     quorum (strict check raises inside _advance_commit if it does)."""
-    monkeypatch.setenv("COPYCAT_REPL_PIPELINE", lane)
+    monkeypatch.setenv("COPYCAT_REPL_DEPTH", depth)
     monkeypatch.setenv("COPYCAT_INVARIANTS", "strict")
 
     @async_test(timeout=240)
@@ -180,7 +185,6 @@ def test_partitioned_peer_mid_stream_strict(monkeypatch):
     """A peer partitioned away mid-stream must not stall commit (quorum
     via the healthy follower), must not pin unbounded in-flight state,
     and must catch up on heal — all under the strict commit check."""
-    monkeypatch.setenv("COPYCAT_REPL_PIPELINE", "1")
     monkeypatch.setenv("COPYCAT_INVARIANTS", "strict")
 
     @async_test(timeout=240)
@@ -227,7 +231,6 @@ def test_leader_deposition_mid_stream_strict(monkeypatch):
     """Close the leader while a multi-window stream is in flight: the
     client re-routes, every ACKED write is applied exactly once on the
     survivors, and the survivors' logs are identical."""
-    monkeypatch.setenv("COPYCAT_REPL_PIPELINE", "1")
     monkeypatch.setenv("COPYCAT_INVARIANTS", "strict")
 
     @async_test(timeout=240)

@@ -1,6 +1,7 @@
 """Replication-plane tests: the pipelined leader->follower stream
-(server/raft.py `_replicate_pipelined`), the stop-and-wait lane it
-A/Bs against (COPYCAT_REPL_PIPELINE=0), the log-rewind path (conflicting
+(server/raft_group.py `_replicate_pipelined`) at its default depth and
+at its edge of ONE window in flight (COPYCAT_REPL_DEPTH=1, the shape
+stop-and-wait replication has), the log-rewind path (conflicting
 suffix -> truncate -> last_index hint rewind -> reconverge), the
 no-progress backoff branch, backpressure caps, the COPYCAT_REPL_WINDOW
 knob, and the transport-level pending-correlation leak fix.
@@ -11,7 +12,7 @@ import asyncio
 import pytest
 
 from helpers import async_test
-from raft_fixtures import Get, Put, create_cluster
+from raft_fixtures import REPL_DEPTHS, Get, Put, create_cluster
 
 from copycat_tpu.client.client import RaftClient
 from copycat_tpu.io.local import LocalTransport
@@ -20,8 +21,6 @@ from copycat_tpu.io.transport import Address
 from copycat_tpu.protocol import messages as msg
 from copycat_tpu.server.log import NoOpEntry
 from copycat_tpu.server.raft import FOLLOWER, LEADER, _PeerStream
-
-LANES = ("1", "0")  # pipelined, stop-and-wait
 
 
 async def _await_leader_among(servers, timeout=15.0):
@@ -54,13 +53,13 @@ def _assert_logs_converged(servers, up_to=None):
 
 
 # ---------------------------------------------------------------------------
-# divergence -> truncate -> hint rewind -> reconverge (both lanes)
+# divergence -> truncate -> hint rewind -> reconverge (both depths)
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("lane", LANES)
-def test_follower_divergence_truncates_and_reconverges(lane, monkeypatch):
-    monkeypatch.setenv("COPYCAT_REPL_PIPELINE", lane)
+@REPL_DEPTHS
+def test_follower_divergence_truncates_and_reconverges(depth, monkeypatch):
+    monkeypatch.setenv("COPYCAT_REPL_DEPTH", depth)
 
     @async_test(timeout=120)
     async def run():
@@ -116,13 +115,13 @@ def test_follower_divergence_truncates_and_reconverges(lane, monkeypatch):
     run()
 
 
-@pytest.mark.parametrize("lane", LANES)
-def test_lagging_follower_last_index_hint_rewind(lane, monkeypatch):
+@REPL_DEPTHS
+def test_lagging_follower_last_index_hint_rewind(depth, monkeypatch):
     """A fresh leader starts every peer at next_index = last+1; a
     follower that missed a burst refuses the first append (prev past its
     tail) with its last_index as the hint, and the stream must rewind to
     it in ONE step and re-stream the gap (repl.rewinds counts it)."""
-    monkeypatch.setenv("COPYCAT_REPL_PIPELINE", lane)
+    monkeypatch.setenv("COPYCAT_REPL_DEPTH", depth)
 
     @async_test(timeout=120)
     async def run():
@@ -162,13 +161,13 @@ def test_lagging_follower_last_index_hint_rewind(lane, monkeypatch):
     run()
 
 
-@pytest.mark.parametrize("lane", LANES)
-def test_no_progress_backoff_branch(lane, monkeypatch):
+@REPL_DEPTHS
+def test_no_progress_backoff_branch(depth, monkeypatch):
     """A follower that refuses every append without a usable hint drives
     the leader's rewind to the log base; the leader must back off (stall
     counter) instead of hot-spinning, stay leader via the healthy
     follower, and reconverge once the refusal clears."""
-    monkeypatch.setenv("COPYCAT_REPL_PIPELINE", lane)
+    monkeypatch.setenv("COPYCAT_REPL_DEPTH", depth)
 
     @async_test(timeout=120)
     async def run():
@@ -222,7 +221,7 @@ def test_no_progress_backoff_branch(lane, monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-def test_repl_window_knob_reaches_both_lanes(monkeypatch):
+def test_repl_window_knob_reaches_the_stream(monkeypatch):
     monkeypatch.setenv("COPYCAT_REPL_WINDOW", "16")
 
     @async_test(timeout=60)
@@ -248,7 +247,6 @@ def test_backpressure_caps_inflight_entries(monkeypatch):
     """A tiny in-flight budget + wire latency: the pump must hold the
     stream at the cap (backpressure counter moves) and still commit
     everything; the gauges return to zero once the stream drains."""
-    monkeypatch.setenv("COPYCAT_REPL_PIPELINE", "1")
     monkeypatch.setenv("COPYCAT_REPL_WINDOW", "8")
     monkeypatch.setenv("COPYCAT_REPL_DEPTH", "1")
     monkeypatch.setenv("COPYCAT_REPL_MAX_INFLIGHT", "8")

@@ -282,14 +282,21 @@ async def test_spi_lock_histories_linearizable_under_partition():
            model=LockModel)
 
 
-async def _stale_leader_refuses(read_pump: bool) -> None:
+@pytest.mark.parametrize("empty_batch", (False, True),
+                         ids=("read", "empty-batch"))
+@async_test(timeout=420)
+async def test_stale_leader_refuses_reads_with_read_pump(empty_batch):
     """Round-9 stale-read nemesis (read-pump extension): after a
     partition deposes the leader, the OLD leader's lease expires and a
     new leader commits fresh writes on the majority side. A
     linearizable/bounded read sent straight at the deposed leader must
     REFUSE (its leadership confirm cannot reach a quorum) rather than
-    serve state that misses the committed write — with the batched read
-    window and with the per-op lane alike."""
+    serve state that misses the committed write.
+
+    ``empty-batch``: the probe is a ``QueryBatchRequest`` with no
+    operations, which stages nothing in a read window. It still pays
+    the gate: the leader confirms once and answers its ``last_applied``
+    with an empty list; the deposed leader refuses."""
     from copycat_tpu.protocol import messages as msg
     from copycat_tpu.atomic import commands as vc
     from copycat_tpu.manager.operations import InstanceQuery
@@ -305,8 +312,6 @@ async def _stale_leader_refuses(read_pump: bool) -> None:
     ]
     nem = registry.attach_nemesis()
     await asyncio.gather(*(s.open() for s in servers))
-    for s in servers:
-        s.server._read_pump = read_pump
     client = AtomixClient(addrs, LocalTransport(registry),
                           session_timeout=SESSION_TIMEOUT)
     await client.open()
@@ -318,6 +323,39 @@ async def _stale_leader_refuses(read_pump: bool) -> None:
         old = next(s for s in servers if s.server.role == LEADER)
         old_term = old.server.term
         lead_addr = old.server.address
+
+        def probe_request(consistency: str):
+            if empty_batch:
+                return msg.QueryBatchRequest(
+                    session_id=0, index=0, consistency=consistency,
+                    operations=[])
+            return msg.QueryRequest(
+                session_id=0, index=0, consistency=consistency,
+                operation=InstanceQuery(
+                    instance_id, ResourceQuery(vc.Get(), consistency)))
+
+        # anonymous connection — it reaches both sides of the partition
+        # later (the Jepsen client model)
+        probe = LocalTransport(registry).client()
+        conn = await probe.connect(lead_addr)
+        if empty_batch:
+            confirms = [0]
+            real_confirm = old.server._confirm_leadership
+
+            async def counting_confirm():
+                confirms[0] += 1
+                return await real_confirm()
+
+            old.server._confirm_leadership = counting_confirm
+            applied = old.server.last_applied
+            response = await asyncio.wait_for(
+                conn.send(probe_request("linearizable")), 30)
+            old.server._confirm_leadership = real_confirm
+            assert response.error is None, response.error
+            assert response.entries == []
+            # (a keep-alive may have applied while the gate confirmed)
+            assert 0 < applied <= response.index <= old.server.last_applied
+            assert confirms[0] == 1, "the empty batch skipped the gate"
         nem.partition([lead_addr], [a for a in addrs if a != lead_addr])
         # wait until the majority side elected a successor
         successor = None
@@ -342,20 +380,13 @@ async def _stale_leader_refuses(read_pump: bool) -> None:
         client.client._leader_hint = successor.server.address
         client.client._drop_connection()
         await asyncio.wait_for(reg.set(2), 120)
-        # direct reads at the DEPOSED leader (anonymous connection — it
-        # reaches both sides of the partition, the Jepsen client model)
-        probe = LocalTransport(registry).client()
-        conn = await probe.connect(lead_addr)
+        # direct reads at the DEPOSED leader
         for consistency in ("linearizable", "bounded_linearizable"):
-            response = await asyncio.wait_for(conn.send(msg.QueryRequest(
-                session_id=0, index=0, consistency=consistency,
-                operation=InstanceQuery(
-                    instance_id, ResourceQuery(vc.Get(), consistency)))),
-                30)
+            response = await asyncio.wait_for(
+                conn.send(probe_request(consistency)), 30)
             assert response.error in (msg.NOT_LEADER, msg.NO_LEADER), (
                 f"deposed leader served a {consistency} read "
-                f"(result={response.result!r}) that misses the committed "
-                f"write")
+                f"({response!r}) that misses the committed write")
         # the healed cluster serves the committed value linearizably
         nem.heal()
         reg._read_cl = "linearizable"
@@ -376,16 +407,6 @@ async def _stale_leader_refuses(read_pump: bool) -> None:
                 await asyncio.wait_for(s.close(), 10)
             except (Exception, asyncio.TimeoutError):
                 pass
-
-
-@async_test(timeout=420)
-async def test_stale_leader_refuses_reads_with_read_pump():
-    await _stale_leader_refuses(read_pump=True)
-
-
-@async_test(timeout=420)
-async def test_stale_leader_refuses_reads_per_op_lane():
-    await _stale_leader_refuses(read_pump=False)
 
 
 @async_test(timeout=420)

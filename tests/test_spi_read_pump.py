@@ -5,9 +5,10 @@ drive_query_vector``) coalesces reads arriving across sessions into
 per-consistency windows, pays each window's consistency gate ONCE, and
 evaluates device-eligible reads as tensors through one ``query_step``
 engine round. Its contract is BIT-IDENTICAL observable behavior to the
-per-op query lane (``COPYCAT_SERVER_READ_PUMP=0``): same results, same
-observed indices, same error surfaces — proven here by running the same
-seeded mixed read/write script through both lanes and comparing
+host state machines (``executor="cpu"``, whose every read runs its
+query handler): same results, same observed indices, same error
+surfaces — proven here by running the same seeded mixed read/write
+script through a device server and a CPU server and comparing
 everything the client can see, plus gate-amortization accounting
 (≤1 leadership-confirm round per linearizable window, witnessed by the
 ``query_gate_rounds_saved`` counter) and the engine-level vector read
@@ -39,14 +40,13 @@ from raft_fixtures import next_ports  # noqa: E402
 ENGINE = DeviceEngineConfig(capacity=16, num_peers=3, log_slots=32)
 
 
-async def _spi_cluster(registry, read_pump: bool):
-    """One standalone server + client; the read pump forced on or off."""
+async def _spi_cluster(registry, executor: str = "tpu"):
+    """One standalone server + client on the given executor."""
     (addr,) = next_ports(1)
     server = AtomixServer(addr, [addr], LocalTransport(registry),
                           election_timeout=0.5, heartbeat_interval=0.1,
-                          session_timeout=20.0, executor="tpu",
+                          session_timeout=20.0, executor=executor,
                           engine_config=ENGINE)
-    server.server._read_pump = read_pump
     await server.open()
     client = AtomixClient([addr], LocalTransport(registry),
                           session_timeout=20.0)
@@ -58,9 +58,9 @@ def _script(seed: int, n_rounds: int, wave: int):
     """Seeded read-dominated script over 4 values: each round is a
     write phase (set/cas/gas bursts) followed by a read phase of
     ``wave`` gets. Phases are awaited separately so every read phase
-    observes a settled state — the histories of both lanes are then
+    observes a settled state — the histories of both servers are then
     comparable value-for-value (concurrent read/write races have many
-    valid linearizations and would compare noise, not the lanes).
+    valid linearizations and would compare noise, not the executors).
     Value 3 carries a change listener (its writes take the generator
     path — the read window still serves its gets from the device)."""
     rng = random.Random(seed)
@@ -121,14 +121,15 @@ async def _run_script(client, rounds):
 
 @async_test(timeout=300)
 async def test_read_pump_bit_identical_to_per_op_path():
-    """Same seeded script, two servers (read pump on / off): results,
-    observed indices, event order and final state must be identical."""
+    """Same seeded script, the device server against the host state
+    machines: results, observed indices, event order and final state
+    must be identical."""
     waves = _script(seed=7, n_rounds=5, wave=32)
     histories = []
     metrics = []
-    for pump in (True, False):
+    for executor in ("tpu", "cpu"):
         registry = LocalServerRegistry()
-        server, client = await _spi_cluster(registry, read_pump=pump)
+        server, client = await _spi_cluster(registry, executor)
         try:
             histories.append(await _run_script(client, waves))
             snap = server.server.metrics.snapshot()
@@ -136,17 +137,19 @@ async def test_read_pump_bit_identical_to_per_op_path():
         finally:
             await asyncio.wait_for(client.close(), 5)
             await asyncio.wait_for(server.close(), 5)
-    on, off = histories
-    assert on[0] == off[0], "read pump diverged from per-op results"
-    assert on[1] == off[1], "read pump diverged in observed indices"
-    assert on[2] == off[2], "read pump diverged in final state"
-    assert on[3] == off[3], "read pump diverged in event order"
-    # the script genuinely exercised the batched lane: windows flushed,
-    # device rows evaluated, and the per-op lane stayed dark on writes
-    snap_on, snap_off = metrics
-    assert snap_on["query_windows"] > 0
-    assert snap_on["query_ops_device_lane"] > 0
-    assert snap_off["query_windows"] == 0, "pump-off must not window"
+    device, host = histories
+    assert device[0] == host[0], "read pump diverged from host results"
+    assert device[1] == host[1], "read pump diverged in observed indices"
+    assert device[2] == host[2], "read pump diverged in final state"
+    assert device[3] == host[3], "read pump diverged in event order"
+    # the script genuinely exercised the batched lane: windows flushed
+    # and device rows evaluated, while the reference answered every read
+    # from its query handlers
+    snap_dev, snap_host = metrics
+    assert snap_dev["query_windows"] > 0
+    assert snap_dev["query_ops_device_lane"] > 0
+    assert snap_host.get("query_ops_device_lane", 0) == 0
+    assert snap_host["query_ops_per_op_lane"] > 0
 
 
 @async_test(timeout=300)
@@ -155,7 +158,7 @@ async def test_linearizable_window_pays_one_confirm_round():
     exactly one leadership-confirm round runs, and the
     query_gate_rounds_saved counter records the N-1 amortized rounds."""
     registry = LocalServerRegistry()
-    server, client = await _spi_cluster(registry, read_pump=True)
+    server, client = await _spi_cluster(registry)
     try:
         raft = server.server
         values = [await client.get(f"v{i}", DistributedAtomicValue)
@@ -194,7 +197,7 @@ async def test_cross_session_reads_share_one_window():
     turn share a single read window (the pump's advantage over the
     per-request QueryBatch gate)."""
     registry = LocalServerRegistry()
-    server, client_a = await _spi_cluster(registry, read_pump=True)
+    server, client_a = await _spi_cluster(registry)
     client_b = AtomixClient([server.server.address],
                             LocalTransport(registry), session_timeout=20.0)
     await client_b.open()
@@ -214,22 +217,6 @@ async def test_cross_session_reads_share_one_window():
         await asyncio.wait_for(client_b.close(), 5)
         await asyncio.wait_for(client_a.close(), 5)
         await asyncio.wait_for(server.close(), 5)
-
-
-@async_test(timeout=120)
-async def test_read_pump_env_knob(monkeypatch):
-    """COPYCAT_SERVER_READ_PUMP=0 keeps the per-op lane; default is on."""
-    registry = LocalServerRegistry()
-    monkeypatch.setenv("COPYCAT_SERVER_READ_PUMP", "0")
-    (addr,) = next_ports(1)
-    server = AtomixServer(addr, [addr], LocalTransport(registry),
-                          session_timeout=20.0)
-    assert server.server._read_pump is False
-    monkeypatch.delenv("COPYCAT_SERVER_READ_PUMP")
-    (addr2,) = next_ports(1)
-    server2 = AtomixServer(addr2, [addr2], LocalTransport(registry),
-                           session_timeout=20.0)
-    assert server2.server._read_pump is True
 
 
 def test_drive_query_vector_matches_per_op_serve():
@@ -318,11 +305,12 @@ async def test_follower_reads_env_knob(monkeypatch):
 @async_test(timeout=300)
 async def test_read_pump_error_surfaces_match():
     """A read against a deleted resource raises the same ApplicationError
-    through both lanes (the window's per-row error path)."""
+    on the device server and on the host state machines (the window's
+    per-row error path)."""
     outcomes = []
-    for pump in (True, False):
+    for executor in ("tpu", "cpu"):
         registry = LocalServerRegistry()
-        server, client = await _spi_cluster(registry, read_pump=pump)
+        server, client = await _spi_cluster(registry, executor)
         try:
             v = await client.get("doomed", DistributedAtomicValue)
             await v.set(1)

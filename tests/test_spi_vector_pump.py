@@ -1,14 +1,15 @@
 """Differential proof for the batched server-side session pump.
 
-The vector lane (``RaftServer._apply_vector_run`` + ``DeviceEngine.
+The vector lane (``RaftServer.flush_fused`` + ``DeviceEngine.
 run_vector``) commits whole runs of device-eligible commands as tensors
 through one shared engine round instead of per-op generator chains. Its
-contract is BIT-IDENTICAL observable behavior to the per-op windowed
-apply: same results, same per-session event order, same exactly-once
-dedup under duplicate delivery and faults. These tests prove it by
-running the same seeded op script through both engines and comparing
-everything the client can see, then racing the batched path against a
-response-dropping / lossy-partition nemesis.
+contract is BIT-IDENTICAL observable behavior to the host state
+machines (``executor="cpu"``, the independent reference): same results,
+same per-session event order, same exactly-once dedup under duplicate
+delivery and faults. These tests prove it by running the same seeded op
+script through both executors and comparing everything the client can
+see, then racing the batched path against a response-dropping /
+lossy-partition nemesis.
 
 The flush-error split (ADVICE r5 #1: pre-dispatch failures restore
 ``_pending`` and re-raise, only abandoned drives mark INDETERMINATE)
@@ -40,14 +41,13 @@ from raft_fixtures import next_ports  # noqa: E402
 ENGINE = DeviceEngineConfig(capacity=16, num_peers=3, log_slots=32)
 
 
-async def _spi_cluster(registry, vector_pump: bool):
-    """One standalone server + client; the pump lane forced on or off."""
+async def _spi_cluster(registry, executor: str = "tpu"):
+    """One standalone server + client on the given executor."""
     (addr,) = next_ports(1)
     server = AtomixServer(addr, [addr], LocalTransport(registry),
                           election_timeout=0.5, heartbeat_interval=0.1,
-                          session_timeout=20.0, executor="tpu",
+                          session_timeout=20.0, executor=executor,
                           engine_config=ENGINE)
-    server.server._vector_pump = vector_pump
     await server.open()
     client = AtomixClient([addr], LocalTransport(registry),
                           session_timeout=20.0)
@@ -102,28 +102,51 @@ async def _run_script(client, waves):
     return results, events, finals
 
 
+async def _history(executor: str, waves):
+    """The script's client-observable history on one executor, plus the
+    server's vector-lane counters."""
+    registry = LocalServerRegistry()
+    server, client = await _spi_cluster(registry, executor)
+    try:
+        history = await _run_script(client, waves)
+        snap = server.server.metrics.snapshot()
+        return history, (snap.get("vector_runs", 0), snap.get("vector_ops", 0))
+    finally:
+        await asyncio.wait_for(client.close(), 5)
+        await asyncio.wait_for(server.close(), 5)
+
+
 @async_test(timeout=300)
 async def test_vector_pump_bit_identical_to_per_op_path():
-    """Same seeded script, two engines (pump on / pump off): results,
-    per-session event order, and final state must be identical."""
+    """Same seeded script, the device server against the host state
+    machines: results, per-session event order, and final state must be
+    identical."""
     waves = _script(seed=42, n_waves=6, wave=32)
-    histories = []
-    for pump in (True, False):
-        registry = LocalServerRegistry()
-        server, client = await _spi_cluster(registry, vector_pump=pump)
-        try:
-            histories.append(await _run_script(client, waves))
-        finally:
-            await asyncio.wait_for(client.close(), 5)
-            await asyncio.wait_for(server.close(), 5)
-    (res_on, ev_on, fin_on), (res_off, ev_off, fin_off) = histories
-    assert res_on == res_off, "vector pump diverged from per-op results"
-    assert ev_on == ev_off, "vector pump diverged in event order"
-    assert fin_on == fin_off, "vector pump diverged in final state"
-    # the script genuinely exercised both lanes: CAS outcomes of both
-    # kinds appeared (device CAS success + failure finalize arms)
-    cas = [r[1] for wave in res_on for r in wave if r[0] == "cas"]
+    (res_dev, ev_dev, fin_dev), (_, vops) = await _history("tpu", waves)
+    (res_cpu, ev_cpu, fin_cpu), cpu_lane = await _history("cpu", waves)
+    assert res_dev == res_cpu, "vector pump diverged from host results"
+    assert ev_dev == ev_cpu, "vector pump diverged in event order"
+    assert fin_dev == fin_cpu, "vector pump diverged in final state"
+    # the script genuinely exercised the vector lane on the device server
+    # and never on the reference, and CAS outcomes of both kinds appeared
+    # (device CAS success + failure finalize arms)
+    assert vops > 0 and cpu_lane == (0, 0)
+    cas = [r[1] for wave in res_dev for r in wave if r[0] == "cas"]
     assert True in cas and False in cas
+
+
+@async_test(timeout=300)
+async def test_commit_window_of_one_eligible_command_matches_cpu():
+    """The vector lane's smallest shape: each commit window holds ONE
+    eligible command (a client that waits for every reply), so each run
+    is one row and the fused flush carries one group. It must finalize
+    as the host state machines do."""
+    waves = _script(seed=7, n_waves=24, wave=1)
+    (res_dev, ev_dev, fin_dev), (runs, vops) = await _history("tpu", waves)
+    (res_cpu, ev_cpu, fin_cpu), _ = await _history("cpu", waves)
+    assert (res_dev, ev_dev, fin_dev) == (res_cpu, ev_cpu, fin_cpu)
+    # single-row runs: as many runs as rows
+    assert runs == vops > 0
 
 
 @async_test(timeout=300)
@@ -134,7 +157,7 @@ async def test_vector_pump_exactly_once_under_duplicate_delivery():
     equals the exact number of acked increments."""
     registry = LocalServerRegistry()
     nemesis = registry.attach_nemesis(NetworkNemesis(seed=7))
-    server, client = await _spi_cluster(registry, vector_pump=True)
+    server, client = await _spi_cluster(registry)
     try:
         counter = await client.get("c", DistributedAtomicLong)
         await counter.increment_and_get()  # settle to steady state
@@ -161,7 +184,7 @@ async def test_vector_pump_partition_mid_batch_no_duplicate_applies():
     applied, a dropped response applied once and deduped on resend."""
     registry = LocalServerRegistry()
     nemesis = registry.attach_nemesis(NetworkNemesis(seed=11))
-    server, client = await _spi_cluster(registry, vector_pump=True)
+    server, client = await _spi_cluster(registry)
     try:
         counter = await client.get("c", DistributedAtomicLong)
         await counter.increment_and_get()
