@@ -48,12 +48,26 @@ class CatalystSerializable(Protocol):
 
 _TYPE_REGISTRY: dict[int, type] = {}
 _ID_BY_TYPE: dict[type, int] = {}
-#: type_id -> tuple of field names for classes whose write/read is the
-#: GENERIC field-list form (both methods carry the ``_generic_fields``
-#: marker set by protocol.messages.Message), else None. The native codec
-#: (io/codec.py) serializes generic classes entirely in C; None means it
-#: calls back into the class's custom write_object/read_object.
+#: What the native codec (io/codec.py) needs to know of each class, read
+#: off the class at registration. A class has one of three shapes:
+#:
+#: - GENERIC: write/read is the field-list form (both methods carry the
+#:   ``_generic_fields`` marker set by protocol.messages.Message). The
+#:   entry is the tuple of field names and the C walk does all of it.
+#: - FIXED HEAD, then generic fields: the class (or a base) declares
+#:   ``_codec_head``, a tuple of ``(attribute, "i64" | "f64")`` written
+#:   raw (``BufferOutput.write_i64``/``write_f64``) before the
+#:   ``_fields``, and keeps the write/read methods of the class that
+#:   declared it (server/log.py's ``Entry``). The entry is the tuple of
+#:   field names, the head is in ``_CODEC_HEAD``, and the C walk does
+#:   all of it too; a subclass that overrides either method is custom.
+#: - CUSTOM: anything else. The entry is None and the C walk calls back
+#:   into the class's write_object/read_object (``codec.python_bodies``
+#:   counts those calls).
 _CODEC_FIELDS: dict[int, tuple | None] = {}
+#: type_id -> the fixed head of a class of the second shape (absent for
+#: the other two).
+_CODEC_HEAD: dict[int, tuple] = {}
 #: type_id -> count of TRAILING fields that are wire-optional (a
 #: trailing None run is omitted when writing; a reader at end-of-buffer
 #: fills them with None). Mirrors ``Message._optional`` so the C walk
@@ -73,6 +87,19 @@ def _generic_fields(cls: type) -> tuple | None:
     return None
 
 
+def _fixed_head(cls: type) -> tuple | None:
+    """The ``_codec_head`` of a class that still writes and reads itself
+    with the methods of the class that declared the head."""
+    owner = next((base for base in cls.__mro__
+                  if "_codec_head" in base.__dict__), None)
+    if owner is None or getattr(cls, "_fields", None) is None:
+        return None
+    for method in ("write_object", "read_object"):
+        if getattr(cls, method, None) is not owner.__dict__.get(method):
+            return None
+    return tuple(owner._codec_head)
+
+
 def serialize_with(type_id: int) -> Callable[[type], type]:
     """Class decorator registering a serializable type under a stable id.
 
@@ -86,9 +113,14 @@ def serialize_with(type_id: int) -> Callable[[type], type]:
         _TYPE_REGISTRY[type_id] = cls
         _ID_BY_TYPE[cls] = type_id
         fields = _generic_fields(cls)
-        _CODEC_FIELDS[type_id] = fields
         _CODEC_OPTIONAL[type_id] = (
             int(getattr(cls, "_optional", 0)) if fields is not None else 0)
+        _CODEC_HEAD.pop(type_id, None)
+        if fields is None and (head := _fixed_head(cls)) is not None:
+            # the headed walk never omits a field: optional stays 0
+            _CODEC_HEAD[type_id] = head
+            fields = tuple(cls._fields)
+        _CODEC_FIELDS[type_id] = fields
         return cls
 
     return register
@@ -113,10 +145,12 @@ class Serializer:
 
     ``write``/``read`` prefer the native codec (io/codec.py, a
     byte-identical C walk of the same format) and fall back to the pure
-    Python below on ``Fallback`` (>64-bit ints) or when the extension
-    is unavailable. ``write_object``/``read_object`` ARE the format's
+    Python below on ``Fallback`` (>64-bit ints, a log entry whose head
+    does not fit raw 64-bit fields) or when the extension is
+    unavailable. ``write_object``/``read_object`` ARE the format's
     reference implementation — custom-serialized classes re-enter
-    through them from the native side too.
+    through them from the native side too; generic and fixed-head
+    classes (``_CODEC_FIELDS`` above) do not.
     """
 
     def write(self, obj: Any) -> bytes:

@@ -220,6 +220,10 @@ class Entry(object):
     leader's clock at append time and drives all deterministic timers."""
 
     _fields: tuple[str, ...] = ()
+    #: what ``write_object``/``read_object`` below put before the fields,
+    #: for the registry of io/serializer.py: the native codec writes and
+    #: reads this head, then ``_fields``, itself
+    _codec_head = (("index", "i64"), ("term", "i64"), ("timestamp", "f64"))
 
     def __init__(self, term: int = 0, timestamp: float = 0.0, **kwargs: Any) -> None:
         self.index = 0
@@ -242,6 +246,11 @@ class Entry(object):
                                      "    self.term = term\n"
                                      "    self.timestamp = timestamp\n")
 
+    # The REFERENCE of a format the native codec (native/copycat_codec.c)
+    # also writes and reads, byte for byte: these two methods run where
+    # there is no toolchain and when the C walk raises Fallback (a head
+    # that does not fit raw 64-bit fields). A subclass that overrides
+    # either is registered as custom and walked here, not in C.
     def write_object(self, buf: BufferOutput, serializer: Serializer) -> None:
         buf.write_i64(self.index)
         buf.write_i64(self.term)

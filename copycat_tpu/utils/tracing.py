@@ -319,9 +319,10 @@ class Tracer:
     def register(self, registry: Any, prefix: str) -> None:
         """Tell the tracer of a ``MetricsRegistry`` whose counters the
         report should account for, under ``prefix`` (``engine.``,
-        ``group.``, ``server.``, ``client.``). A weak reference; nothing
-        is read until :meth:`enable` (or now, if already enabled: the
-        registry's counters so far are not the window's)."""
+        ``group.``, ``server.``, ``client.``, ``codec.``). A weak
+        reference; nothing is read until :meth:`enable` (or now, if
+        already enabled: the registry's counters so far are not the
+        window's)."""
         self._registries.append([
             weakref.ref(registry), prefix,
             registry.counter_values() if self.enabled else {}])

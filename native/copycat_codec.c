@@ -3,10 +3,14 @@
  * Byte-identical to copycat_tpu/io/serializer.py (the pure-Python
  * reference implementation and fallback): zigzag-LEB128 varints,
  * big-endian f64, tagged primitives/containers, registered types as
- * tag 16+id. Generic field-list classes (protocol.messages.Message
- * subclasses — the whole session/RPC hot path) are walked entirely in
- * C; classes with hand-written write_object/read_object round-trip
- * through Python callbacks registered at configure() time.
+ * tag 16+id. Three class shapes (serializer.py's registries say which):
+ * generic field-list classes (protocol.messages.Message subclasses —
+ * the whole session/RPC hot path) are walked entirely in C; so are
+ * classes with a FIXED HEAD before their generic fields (the log
+ * entries of server/log.py: raw big-endian i64 index, i64 term, f64
+ * timestamp, as BufferOutput.write_i64/write_f64 write them, then each
+ * field); classes with any other hand-written write_object/read_object
+ * round-trip through Python callbacks registered at configure() time.
  *
  * Anything the C path cannot express raises Fallback, and
  * Serializer.write/read re-runs the pure-Python codec — the native
@@ -34,11 +38,23 @@
 #define T_SET 10
 #define T_CLASS 11
 
+/* What this binary can do, checked by io/codec.py before it is loaded
+ * (the marker is searched for in the file's bytes) and after (ABI): a
+ * binary built from an older source beside newer Python would walk a
+ * class shape it does not know as plain fields, i.e. write other bytes.
+ * 2: the fixed head (configure's 7th argument). */
+#define CODEC_ABI 2
+#define CODEC_STR2(x) #x
+#define CODEC_STR(x) CODEC_STR2(x)
+static const char codec_abi_marker[] =
+    "copycat_codec_abi=" CODEC_STR(CODEC_ABI);
+
 /* module state: live dicts owned by serializer.py + callbacks */
 static PyObject *g_id_by_type;   /* dict: type -> int */
 static PyObject *g_type_by_id;   /* dict: int -> type */
 static PyObject *g_fields_by_id; /* dict: int -> tuple[str] | None */
 static PyObject *g_optional_by_id; /* dict: int -> int (trailing optional) */
+static PyObject *g_head_by_id;   /* dict: int -> ((name, "i64"|"f64"), ...) */
 static PyObject *g_encode_body;  /* callable(obj) -> bytes (custom types) */
 static PyObject *g_decode_body;  /* callable(cls, bytes, pos) -> (obj, pos) */
 static PyObject *g_fallback;     /* exception type */
@@ -85,6 +101,13 @@ static int w_varint(Writer *w, long long v) {
     unsigned long long zz =
         ((unsigned long long)v << 1) ^ (unsigned long long)(v >> 63);
     return w_uvarint(w, zz);
+}
+
+static int w_i64(Writer *w, long long v) {
+    unsigned long long u = (unsigned long long)v;
+    unsigned char be[8];
+    for (int i = 0; i < 8; i++) be[i] = (unsigned char)(u >> (56 - 8 * i));
+    return w_raw(w, be, 8);
 }
 
 static int w_f64(Writer *w, double d) {
@@ -138,6 +161,14 @@ static int r_varint(Reader *r, long long *out) {
     return 0;
 }
 
+static int r_i64(Reader *r, long long *out) {
+    if (r_need(r, 8) < 0) return -1;
+    unsigned long long u = 0;
+    for (int i = 0; i < 8; i++) u = (u << 8) | r->data[r->pos++];
+    *out = (long long)u;
+    return 0;
+}
+
 static int r_f64(Reader *r, double *out) {
     if (r_need(r, 8) < 0) return -1;
     unsigned long long u = 0;
@@ -146,6 +177,92 @@ static int r_f64(Reader *r, double *out) {
     x.u = u;
     *out = x.d;
     return 0;
+}
+
+/* ------------------------------------------------------------------ */
+/* fixed head: ((attribute name, "i64" | "f64"), ...) before the fields */
+
+/* the table is a live dict Python owns: never trust its shape */
+static int bad_head(void) {
+    PyErr_SetString(g_fallback, "fixed head the C walker cannot read");
+    return 0;
+}
+
+/* 'i' / 'f' for a well-formed (name, kind) pair, else 0 with Fallback */
+static int head_item(PyObject *item, PyObject **name) {
+    if (PyTuple_Check(item) && PyTuple_GET_SIZE(item) == 2) {
+        PyObject *kind = PyTuple_GET_ITEM(item, 1);
+        *name = PyTuple_GET_ITEM(item, 0);
+        if (PyUnicode_Check(*name) && PyUnicode_Check(kind)) {
+            if (PyUnicode_CompareWithASCIIString(kind, "i64") == 0)
+                return 'i';
+            if (PyUnicode_CompareWithASCIIString(kind, "f64") == 0)
+                return 'f';
+        }
+    }
+    return bad_head();
+}
+
+/* What struct's ">q" / ">d" would refuse or convert (an int beyond 64
+ * bits, a timestamp that is not a float) raises Fallback: the Python
+ * walk then answers for it, with its own error where it has one. */
+static int enc_head(PyObject *obj, PyObject *head, Writer *w) {
+    if (!PyTuple_Check(head)) { bad_head(); return -1; }
+    Py_ssize_t n = PyTuple_GET_SIZE(head);
+    for (Py_ssize_t i = 0; i < n; i++) {
+        PyObject *name;
+        int kind = head_item(PyTuple_GET_ITEM(head, i), &name);
+        if (!kind) return -1;
+        PyObject *val = PyObject_GetAttr(obj, name);
+        if (!val) return -1;
+        int rc = -1;
+        if (kind == 'i') {
+            int overflow = 1; /* not an int at all: Python's to answer */
+            long long v = 0;
+            if (PyLong_Check(val))
+                v = PyLong_AsLongLongAndOverflow(val, &overflow);
+            if (overflow)
+                PyErr_SetString(g_fallback, "head int beyond a raw i64");
+            else
+                rc = w_i64(w, v);
+        } else if (PyFloat_Check(val)) {
+            rc = w_f64(w, PyFloat_AS_DOUBLE(val));
+        } else {
+            PyErr_SetString(g_fallback, "head float is not a float");
+        }
+        Py_DECREF(val);
+        if (rc < 0) return -1;
+    }
+    return 0;
+}
+
+static int dec_head(Reader *r, PyObject *obj, PyObject *head) {
+    if (!PyTuple_Check(head)) { bad_head(); return -1; }
+    Py_ssize_t n = PyTuple_GET_SIZE(head);
+    for (Py_ssize_t i = 0; i < n; i++) {
+        PyObject *name, *val;
+        int kind = head_item(PyTuple_GET_ITEM(head, i), &name);
+        if (!kind) return -1;
+        if (kind == 'i') {
+            long long v;
+            if (r_i64(r, &v) < 0) return -1;
+            val = PyLong_FromLongLong(v);
+        } else {
+            double d;
+            if (r_f64(r, &d) < 0) return -1;
+            val = PyFloat_FromDouble(d);
+        }
+        if (!val) return -1;
+        int rc = PyObject_SetAttr(obj, name, val);
+        Py_DECREF(val);
+        if (rc < 0) return -1;
+    }
+    return 0;
+}
+
+/* the class's head, NULL without one (or with an error set) */
+static PyObject *head_of(PyObject *idobj) {
+    return g_head_by_id ? PyDict_GetItemWithError(g_head_by_id, idobj) : NULL;
 }
 
 /* ------------------------------------------------------------------ */
@@ -197,6 +314,9 @@ static int enc_registered(PyObject *obj, Writer *w, int depth) {
         Py_DECREF(body);
         return rc;
     }
+    PyObject *head = head_of(idobj);
+    if (!head && PyErr_Occurred()) return -1;
+    if (head && enc_head(obj, head, w) < 0) return -1;
     Py_ssize_t nf = PyTuple_GET_SIZE(fields);
     /* wire-optional trailing fields (Message._optional): a trailing
      * None run is omitted entirely, matching the Python reference walk
@@ -367,8 +487,10 @@ static PyObject *dec_registered(Reader *r, long long tid, int depth) {
     if (!fields && PyErr_Occurred()) { Py_DECREF(idobj); return NULL; }
     PyObject *optobj = (fields && g_optional_by_id)
         ? PyDict_GetItemWithError(g_optional_by_id, idobj) : NULL;
+    if (!optobj && PyErr_Occurred()) { Py_DECREF(idobj); return NULL; }
+    PyObject *head = head_of(idobj);
     Py_DECREF(idobj);
-    if (!optobj && PyErr_Occurred()) return NULL;
+    if (!head && PyErr_Occurred()) return NULL;
     if (!fields) {
         PyErr_Format(g_fallback, "no codec meta for id %lld", tid);
         return NULL;
@@ -404,6 +526,7 @@ static PyObject *dec_registered(Reader *r, long long tid, int depth) {
         Py_DECREF(newf);
     }
     if (!obj) return NULL;
+    if (head && dec_head(r, obj, head) < 0) { Py_DECREF(obj); return NULL; }
     Py_ssize_t nf = PyTuple_GET_SIZE(fields);
     long long nopt = 0;
     if (optobj) {
@@ -683,9 +806,9 @@ static PyObject *codec_encode_frames(PyObject *self, PyObject *frames) {
 
 static PyObject *codec_configure(PyObject *self, PyObject *args) {
     (void)self;
-    PyObject *ibt, *tbi, *fbi, *eb, *db, *obi = NULL;
-    if (!PyArg_ParseTuple(args, "OOOOO|O", &ibt, &tbi, &fbi, &eb, &db,
-                          &obi))
+    PyObject *ibt, *tbi, *fbi, *eb, *db, *obi = NULL, *hbi = NULL;
+    if (!PyArg_ParseTuple(args, "OOOOO|OO", &ibt, &tbi, &fbi, &eb, &db,
+                          &obi, &hbi))
         return NULL;
     Py_XDECREF(g_id_by_type); Py_INCREF(ibt); g_id_by_type = ibt;
     Py_XDECREF(g_type_by_id); Py_INCREF(tbi); g_type_by_id = tbi;
@@ -693,14 +816,15 @@ static PyObject *codec_configure(PyObject *self, PyObject *args) {
     Py_XDECREF(g_encode_body); Py_INCREF(eb); g_encode_body = eb;
     Py_XDECREF(g_decode_body); Py_INCREF(db); g_decode_body = db;
     Py_XDECREF(g_optional_by_id); Py_XINCREF(obi); g_optional_by_id = obi;
+    Py_XDECREF(g_head_by_id); Py_XINCREF(hbi); g_head_by_id = hbi;
     Py_RETURN_NONE;
 }
 
 static PyMethodDef codec_methods[] = {
     {"configure", codec_configure, METH_VARARGS,
      "configure(id_by_type, type_by_id, fields_by_id, encode_body, "
-     "decode_body[, optional_by_id]) — bind the live registries + "
-     "fallback hooks."},
+     "decode_body[, optional_by_id[, head_by_id]]) — bind the live "
+     "registries + fallback hooks."},
     {"encode", codec_encode, METH_O, "encode(obj) -> bytes"},
     {"decode", codec_decode, METH_O, "decode(bytes) -> obj"},
     {"decode_frames", codec_decode_frames, METH_O,
@@ -731,5 +855,10 @@ PyMODINIT_FUNC PyInit_copycat_codec(void) {
         return NULL;
     }
     Py_INCREF(g_fallback); /* module owns one ref; we keep the global */
+    if (PyModule_AddIntConstant(m, "ABI", CODEC_ABI) < 0 ||
+        PyModule_AddStringConstant(m, "ABI_MARKER", codec_abi_marker) < 0) {
+        Py_DECREF(m);
+        return NULL;
+    }
     return m;
 }
