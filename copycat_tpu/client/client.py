@@ -165,6 +165,7 @@ class RaftClient(Managed):
         self._connection: Connection | None = None
         self._connected_to: Address | None = None
         self._leader_hint: Address | None = None
+        self._failed_last: Address | None = None  # dialed last by _connect
         self._session = ClientSession(self)
         self._command_seq = 0
         # Exactly-once bookkeeping: the server may prune its response cache
@@ -279,7 +280,15 @@ class RaftClient(Managed):
         candidates: list[Address] = []
         if self._leader_hint is not None:
             candidates.append(self._leader_hint)
-        candidates += [a for a in self.strategy.order(self.members) if a not in candidates]
+        order = self.strategy.order(self.members)
+        if isinstance(self.strategy, AnyConnectionStrategy):
+            # a shuffle states no preference, so the member whose attempt
+            # just failed goes to its back (a hint still goes first):
+            # redialed at random, a partitioned leader that still takes
+            # commands and never answers caught the retry one time in
+            # three for another whole per-try timeout
+            order.sort(key=lambda a: a == self._failed_last)
+        candidates += [a for a in order if a not in candidates]
         last_error: Exception | None = None
         for address in candidates:
             try:
@@ -317,11 +326,19 @@ class RaftClient(Managed):
         tmo = per_try_timeout if per_try_timeout is not None \
             else self.session_timeout
         for _ in range(attempts):
+            conn = None
             try:
                 conn = await self._connect()
                 response = await asyncio.wait_for(conn.send(request), tmo)
             except (TransportError, OSError, asyncio.TimeoutError) as e:
                 last = e
+                if conn is not None and conn is not self._connection:
+                    # another request's failure already replaced the
+                    # connection this attempt rode (a keep-alive gives up
+                    # sooner than a command): retry on the new one, do
+                    # not drop it and dial again
+                    continue
+                self._failed_last = self._connected_to
                 # A hinted leader that failed the attempt gets no second
                 # pin: _connect prefers the hint, so keeping it after a
                 # timeout re-dialed the SAME stuck server every retry —

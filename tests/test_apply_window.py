@@ -13,13 +13,14 @@ from copycat_tpu.models.raft_groups import RaftGroups
 from copycat_tpu.ops import apply as ap
 from copycat_tpu.ops.consensus import Config
 
+from engines import wide_window
+
 
 def _drive(config: Config, seed: int) -> RaftGroups:
     """FIXED step schedule (not run_until): both executions see identical
     round counts, hence identical logical clocks — so even TTL deadlines
     (now + c) must come out bit-equal between the two paths."""
-    rg = RaftGroups(8, 3, log_slots=32, submit_slots=8, config=config,
-                    seed=3)
+    rg = wide_window(config, seed=3)
     rg.wait_for_leaders(max_rounds=60)
     extra = 60 - rg.rounds
     for _ in range(extra):  # normalize the election warm-up length
@@ -88,7 +89,7 @@ def test_tight_budgets_still_apply_everything():
     """Budgets of 1 defer heavily but must never drop or reorder."""
     config = Config(applies_per_round=8,
                     pool_budgets=(1,) * 8)
-    rg = RaftGroups(4, 3, log_slots=32, submit_slots=8, config=config)
+    rg = wide_window(config)
     rg.wait_for_leaders()
     tags = [rg.submit(0, ap.OP_LONG_ADD, 1) for _ in range(24)]
     tags += [rg.submit(0, ap.OP_MAP_PUT, k, k * 2) for k in range(6)]

@@ -11,13 +11,15 @@ import pytest
 
 jax = pytest.importorskip("jax")
 
-from copycat_tpu.models import BulkDriver, RaftGroups  # noqa: E402
+from copycat_tpu.models import BulkDriver  # noqa: E402
 from copycat_tpu.ops import apply as ap  # noqa: E402
+
+from engines import MONOTONE, device_plane  # noqa: E402
 
 
 @pytest.fixture(scope="module")
 def rg():
-    groups = RaftGroups(8, 3, log_slots=32, submit_slots=4, seed=11)
+    groups = device_plane(seed=11)
     groups.wait_for_leaders()
     return groups
 
@@ -98,11 +100,8 @@ def test_deep_scan_mode_matches_dispatch_mode():
     """``BulkDriver(deep_scan=True)`` — the whole blind phase as ONE
     lax.scan program — produces identical results, stream cursors, and
     session events to the per-window dispatch mode (same seeds)."""
-    from copycat_tpu.ops.consensus import Config
-
     def build():
-        rg = RaftGroups(8, 3, log_slots=32, submit_slots=4, seed=9,
-                        config=Config(monotone_tag_accept=True))
+        rg = device_plane(MONOTONE, seed=9)
         rg.wait_for_leaders()
         return rg
 
@@ -125,9 +124,12 @@ def test_deep_scan_mode_matches_dispatch_mode():
 
     # session events (lock grant) surface identically through the
     # stacked [W, ...] event path
+    # (acquire(7) is granted, acquire(8) queues, release(7) hands over:
+    # one drive, the shape of test_monotone_deep's, so one program)
     for rg, d in ((rg1, d1), (rg2, d2)):
-        d.drive([0, 0], ap.OP_LOCK_ACQUIRE, [7, 8], -1)
-        d.drive([0], ap.OP_LOCK_RELEASE, 7)
+        d.drive([0, 0, 0],
+                [ap.OP_LOCK_ACQUIRE, ap.OP_LOCK_ACQUIRE, ap.OP_LOCK_RELEASE],
+                [7, 8, 7], [0, -1, 0])
     assert rg1.events.get(0) == rg2.events.get(0)
     assert any(code == ap.EV_LOCK_GRANT and target == 8
                for _, code, target, _a in rg2.events.get(0, []))

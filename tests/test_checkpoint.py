@@ -10,12 +10,14 @@ import pytest
 
 jax = pytest.importorskip("jax")
 
-from copycat_tpu.models import RaftGroups, checkpoint  # noqa: E402
+from copycat_tpu.models import checkpoint  # noqa: E402
 from copycat_tpu.ops import apply as ap  # noqa: E402
+
+from engines import G, device_plane  # noqa: E402
 
 
 def test_save_load_roundtrip(tmp_path):
-    rg = RaftGroups(2, 3, log_slots=32)
+    rg = device_plane()
     rg.wait_for_leaders()
     tags = [rg.submit(0, ap.OP_LONG_ADD, 2) for _ in range(5)]
     tags += [rg.submit(1, ap.OP_MAP_PUT, 7, 70)]
@@ -47,7 +49,7 @@ def test_save_load_roundtrip(tmp_path):
 
 
 def test_restore_preserves_event_dedup(tmp_path):
-    rg = RaftGroups(1, 3, log_slots=32)
+    rg = device_plane()
     rg.wait_for_leaders()
     tags = [rg.submit(0, ap.OP_LOCK_ACQUIRE, 1, -1),
             rg.submit(0, ap.OP_LOCK_ACQUIRE, 2, -1),
@@ -87,7 +89,7 @@ def test_load_snapshot_missing_newer_pool_leaves(tmp_path):
 
     import jax
 
-    rg = RaftGroups(2, 3, log_slots=16)
+    rg = device_plane()
     rg.wait_for_leaders()
     tag = rg.submit(0, ap.OP_LONG_ADD, 7)
     rg.run_until([tag])
@@ -143,9 +145,9 @@ def test_restore_onto_different_device_layout(tmp_path):
     if len(jax.devices()) < 8:
         pytest.skip("needs the 8-device virtual CPU mesh (conftest)")
 
-    rg = RaftGroups(16, 3, log_slots=32)
+    rg = device_plane()
     rg.wait_for_leaders()
-    tags = [rg.submit(g, ap.OP_LONG_ADD, g + 1) for g in range(16)]
+    tags = [rg.submit(g, ap.OP_LONG_ADD, g + 1) for g in range(G)]
     rg.run_until(tags)
     rg.run(3)
     path = tmp_path / "snap.npz"
@@ -164,7 +166,7 @@ def test_restore_onto_different_device_layout(tmp_path):
 
     # both resume and agree on new work
     for drv in (rg, onto_mesh):
-        t2 = [drv.submit(g, ap.OP_LONG_ADD, 10) for g in range(16)]
+        t2 = [drv.submit(g, ap.OP_LONG_ADD, 10) for g in range(G)]
         drv.run_until(t2)
     assert_states_equal(rg.state, onto_mesh.state)
 
@@ -183,7 +185,7 @@ def test_cut_is_the_state_at_its_instant_whatever_runs_after():
     with the host fields and the key as they were."""
     import threading
 
-    rg = RaftGroups(4, 3, log_slots=32)
+    rg = device_plane()
     rg.wait_for_leaders()
     rg.run_until([rg.submit(g, ap.OP_LONG_ADD, g + 1) for g in range(4)])
     names = [checkpoint._leaf_name(p) for p, _ in

@@ -33,8 +33,7 @@ from copycat_tpu.coordination import (  # noqa: E402
 from copycat_tpu.io.local import LocalServerRegistry, LocalTransport  # noqa: E402
 from copycat_tpu.manager.atomix import AtomixClient, AtomixServer  # noqa: E402
 from copycat_tpu.manager.device_executor import (  # noqa: E402
-    DeviceEngineConfig, DeviceLeaderElectionState, DeviceLockState,
-    _UnboundSession)
+    DeviceLeaderElectionState, DeviceLockState, _UnboundSession)
 from copycat_tpu.ops import apply as ops  # noqa: E402
 from copycat_tpu.resource.consistency import Consistency  # noqa: E402
 from copycat_tpu.server.log import Storage, StorageLevel  # noqa: E402
@@ -42,6 +41,8 @@ from copycat_tpu.testing.nemesis import crash_server  # noqa: E402
 
 from helpers import async_test  # noqa: E402
 from raft_fixtures import next_ports  # noqa: E402
+
+from engines import SERVED, SERVED_WIDE  # noqa: E402
 
 
 def cadence(monkeypatch, entries):
@@ -77,13 +78,14 @@ async def test_lock_and_election_state_round_trip_a_snapshot(
             storage=Storage(StorageLevel.DISK, str(tmp_path / "m0")),
             election_timeout=0.2, heartbeat_interval=0.04,
             session_timeout=60.0, executor="tpu",
-            engine_config=DeviceEngineConfig(capacity=16, num_peers=3,
-                                             log_slots=32))
+            engine_config=SERVED)
 
     server = build()
     await server.open()
-    a = AtomixClient([addr], LocalTransport(registry), session_timeout=60.0)
-    b = AtomixClient([addr], LocalTransport(registry), session_timeout=60.0)
+    # 20 s, so a keep-alive every 5: after the crash the waiter's grant
+    # reaches b when b's next keep-alive has found the reborn member
+    a = AtomixClient([addr], LocalTransport(registry), session_timeout=20.0)
+    b = AtomixClient([addr], LocalTransport(registry), session_timeout=20.0)
     await a.open()
     await b.open()
     reborn = None
@@ -170,8 +172,7 @@ async def test_a_lock_with_an_armed_timeout_opts_out(tmp_path, monkeypatch):
         addr, [addr], LocalTransport(registry, local_address=addr),
         storage=Storage(StorageLevel.DISK, str(tmp_path / "m0")),
         election_timeout=0.2, heartbeat_interval=0.04, session_timeout=60.0,
-        executor="tpu", engine_config=DeviceEngineConfig(
-            capacity=16, num_peers=3, log_slots=32))
+        executor="tpu", engine_config=SERVED)
     await server.open()
     a = AtomixClient([addr], LocalTransport(registry), session_timeout=60.0)
     b = AtomixClient([addr], LocalTransport(registry), session_timeout=60.0)
@@ -206,7 +207,6 @@ async def test_a_lock_with_an_armed_timeout_opts_out(tmp_path, monkeypatch):
 
 # -- the deployment against the plain reference ------------------------------
 
-ENGINE = DeviceEngineConfig(capacity=32, num_peers=3, log_slots=32)
 COUNTERS, ROUNDS = 24, 12
 
 
@@ -219,7 +219,7 @@ class Cluster:
             addr, addrs, LocalTransport(self.registry),
             storage=Storage(StorageLevel.DISK, str(root / f"m{i}")),
             election_timeout=0.5, heartbeat_interval=0.1,
-            session_timeout=60.0, executor="tpu", engine_config=ENGINE)
+            session_timeout=60.0, executor="tpu", engine_config=SERVED_WIDE)
             for i, addr in enumerate(addrs)]
 
     @property

@@ -59,7 +59,7 @@ async def test_three_process_cluster_survives_server_kill():
                     pytest.fail("all servers died: "
                                 + logs[0].read().decode(
                                     errors="replace")[-600:])
-                await asyncio.sleep(2)
+                await asyncio.sleep(0.25)
         else:
             pytest.fail("client never connected to the cluster")
 

@@ -13,6 +13,8 @@ import subprocess
 import sys
 import textwrap
 
+import pytest
+
 from copycat_tpu.analysis import ALL_RULES
 from copycat_tpu.analysis.engine import (
     LintContext,
@@ -50,6 +52,13 @@ from copycat_tpu.analysis.rules_registries import (
 from copycat_tpu.analysis.rules_wire import check_wire_schema, render_golden
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def live_lint():
+    """The whole-tree lint, once a module (6-7 s a walk): nine tests read
+    it, each for its own rule."""
+    return run_lint(root=REPO, use_cache=False)
 
 
 def _tree(code: str) -> ast.Module:
@@ -195,9 +204,8 @@ def test_loop_blocking_awaited_wait_is_the_asyncio_form():
     assert len(found) == 1 and found[0].symbol == "bad"
 
 
-def test_loop_blocking_live_tree_is_clean():
-    result = run_lint(root=REPO, use_cache=False)
-    assert [f for f in result.findings if f.rule == "loop-blocking"] == []
+def test_loop_blocking_live_tree_is_clean(live_lint):
+    assert [f for f in live_lint.findings if f.rule == "loop-blocking"] == []
 
 
 # ---------------------------------------------------------------------------
@@ -231,11 +239,10 @@ def test_orphan_task_exempts_tasks_module_and_spawn_calls():
     assert check_orphan_task(raw, "copycat_tpu/utils/tasks.py") == []
 
 
-def test_live_tree_has_no_raw_spawns():
+def test_live_tree_has_no_raw_spawns(live_lint):
     # the satellite fix: every create_task/ensure_future routed through
     # utils/tasks.spawn — keep it that way
-    result = run_lint(root=REPO, use_cache=False)
-    assert [f for f in result.findings if f.rule == "orphan-task"] == []
+    assert [f for f in live_lint.findings if f.rule == "orphan-task"] == []
 
 
 # ---------------------------------------------------------------------------
@@ -369,9 +376,8 @@ def test_await_tear_ignores_pre_await_writes_and_other_files():
     assert check_await_tear(_tree(TEAR), "client/client.py") == []
 
 
-def test_await_tear_live_tree_is_clean():
-    result = run_lint(root=REPO, use_cache=False)
-    assert [f for f in result.findings if f.rule == "await-tear"] == []
+def test_await_tear_live_tree_is_clean(live_lint):
+    assert [f for f in live_lint.findings if f.rule == "await-tear"] == []
 
 
 # --- interprocedural (copycheck v2): the call graph closes the two
@@ -639,12 +645,11 @@ def test_durability_order_flags_undominated_success_append_ack():
     assert check_durability_order(synced, "server/raft_group.py") == []
 
 
-def test_durability_order_live_tree_carries_only_justified_baselines():
-    result = run_lint(root=REPO, use_cache=False)
-    assert [f for f in result.findings if f.rule == "durability-order"] == []
+def test_durability_order_live_tree_carries_only_justified_baselines(live_lint):
+    assert [f for f in live_lint.findings if f.rule == "durability-order"] == []
     # the fused-dispatch seam findings ride the baseline, each with a
     # written dominance argument (no TODO placeholders — CI's contract)
-    carried = [f for f in result.baselined if f.rule == "durability-order"]
+    carried = [f for f in live_lint.baselined if f.rule == "durability-order"]
     assert carried, "the fused-seam findings should be baselined, not gone"
     baseline = json.load(open(os.path.join(REPO, ".copycheck-baseline.json")))
     for entry in baseline["findings"]:
@@ -750,12 +755,11 @@ def test_durability_order_error_exemption_is_msg_scoped():
     assert len(found) == 1
 
 
-def test_span_pairing_live_tree_names_all_in_vocabulary():
+def test_span_pairing_live_tree_names_all_in_vocabulary(live_lint):
     catalog = parse_span_catalog(
         open(os.path.join(REPO, "docs", "OBSERVABILITY.md")).read())
     assert catalog and "quorum.wait" in catalog
-    result = run_lint(root=REPO, use_cache=False)
-    assert [f for f in result.findings if f.rule == "span-pairing"] == []
+    assert [f for f in live_lint.findings if f.rule == "span-pairing"] == []
 
 
 def test_span_pairing_admits_edge_spans_and_still_fires_uncataloged():
@@ -825,9 +829,8 @@ def test_exit_code_contract_sees_negative_literals():
                                {0, 1, 2}) == []
 
 
-def test_exit_code_contract_live_tree_is_clean():
-    result = run_lint(root=REPO, use_cache=False)
-    assert [f for f in result.findings if f.rule == "exit-code"] == []
+def test_exit_code_contract_live_tree_is_clean(live_lint):
+    assert [f for f in live_lint.findings if f.rule == "exit-code"] == []
 
 
 # ---------------------------------------------------------------------------
@@ -871,9 +874,8 @@ def test_knob_registry_allows_writes_typed_getters_and_knobs_module():
                                registered) == []
 
 
-def test_live_tree_knob_reads_all_routed():
-    result = run_lint(root=REPO, use_cache=False)
-    assert [f for f in result.findings if f.rule == "knob-registry"] == []
+def test_live_tree_knob_reads_all_routed(live_lint):
+    assert [f for f in live_lint.findings if f.rule == "knob-registry"] == []
 
 
 # ---------------------------------------------------------------------------
@@ -917,9 +919,8 @@ def test_metric_registry_checks_both_branches_of_a_ternary():
     assert len(found) == 1 and "nope" in found[0].message
 
 
-def test_live_tree_metric_names_all_cataloged():
-    result = run_lint(root=REPO, use_cache=False)
-    assert [f for f in result.findings if f.rule == "metric-registry"] == []
+def test_live_tree_metric_names_all_cataloged(live_lint):
+    assert [f for f in live_lint.findings if f.rule == "metric-registry"] == []
 
 
 def test_catalog_has_no_orphan_entries():
@@ -1078,9 +1079,8 @@ def test_jit_purity_decorated_roots_and_callbacks():
     assert len(found) == 1 and "callback" in found[0].message
 
 
-def test_live_ops_tree_is_pure():
-    result = run_lint(root=REPO, use_cache=False)
-    assert [f for f in result.findings if f.rule == "jit-purity"] == []
+def test_live_ops_tree_is_pure(live_lint):
+    assert [f for f in live_lint.findings if f.rule == "jit-purity"] == []
 
 
 # ---------------------------------------------------------------------------
@@ -1289,7 +1289,6 @@ def test_changed_mode_uses_merge_base_not_two_dot(tmp_path):
 def test_write_baseline_refuses_changed_scope(tmp_path, capsys):
     from copycat_tpu.analysis.engine import main as lint_main
 
-    import pytest
     with pytest.raises(SystemExit) as exc:
         lint_main(["--write-baseline", "--changed", "HEAD"])
     assert exc.value.code == 2

@@ -17,13 +17,14 @@ from copycat_tpu.models import (  # noqa: E402
     DeviceQueue,
     DeviceSet,
     DeviceValue,
-    RaftGroups,
 )
+
+from engines import device_plane  # noqa: E402
 
 
 @pytest.fixture(scope="module")
 def rg():
-    groups = RaftGroups(4, 3, log_slots=64)
+    groups = device_plane()
     groups.wait_for_leaders()
     return groups
 
@@ -77,7 +78,7 @@ def test_set_queue_facades(rg):
 
 
 def test_lock_facade_two_clients():
-    rg = RaftGroups(1, 3, log_slots=64)
+    rg = device_plane()
     rg.wait_for_leaders()
     a = DeviceLock(rg, 0, holder_id=101)
     b = DeviceLock(rg, 0, holder_id=102)
@@ -90,7 +91,7 @@ def test_lock_facade_two_clients():
 
 
 def test_lock_blocking_handoff():
-    rg = RaftGroups(1, 3, log_slots=64)
+    rg = device_plane()
     rg.wait_for_leaders()
     a = DeviceLock(rg, 0, holder_id=1)
     b = DeviceLock(rg, 0, holder_id=2)
@@ -107,7 +108,7 @@ def test_lock_blocking_handoff():
 def test_no_stale_grant_after_immediate_grant():
     """An immediate grant is synchronous-only; a later queued try_lock must
     not be satisfied by any stale event (mutual exclusion regression)."""
-    rg = RaftGroups(1, 3, log_slots=64)
+    rg = device_plane()
     rg.wait_for_leaders()
     a = DeviceLock(rg, 0, holder_id=1)
     b = DeviceLock(rg, 0, holder_id=2)
@@ -119,7 +120,7 @@ def test_no_stale_grant_after_immediate_grant():
 
 
 def test_election_facade():
-    rg = RaftGroups(1, 3, log_slots=64)
+    rg = device_plane()
     rg.wait_for_leaders()
     e1 = DeviceElection(rg, 0, candidate_id=11)
     e2 = DeviceElection(rg, 0, candidate_id=22)
@@ -138,7 +139,7 @@ def test_sequential_reads_via_query_lane():
     """SEQUENTIAL reads are served from the leader's applied state (no log
     append): committed writes are visible and the log does not grow."""
     import numpy as np
-    groups = RaftGroups(2, 3, log_slots=64)
+    groups = device_plane()
     groups.wait_for_leaders()
     m = DeviceMap(groups, 0).with_consistency("sequential")
     v = DeviceValue(groups, 1).with_consistency("sequential")
@@ -160,7 +161,7 @@ def test_query_lane_escalates_without_leader():
     applied state; it falls back to the command path and resolves through
     the log once a leader is elected (queries are never silently
     dropped — reference routes every query to a leader)."""
-    groups = RaftGroups(1, 3, log_slots=64)
+    groups = device_plane()
     assert groups.leader(0) == -1  # pre-election: genuinely leaderless
     tag = groups.submit_query(0, ap.OP_VALUE_GET)
     groups.step_round()  # query lane attempts + escalates
@@ -172,7 +173,7 @@ def test_query_lane_escalates_without_leader():
 def test_sequential_reads_are_monotone():
     """Mixed read/write history: query-lane reads of a counter never go
     backwards (sequential consistency on one session)."""
-    groups = RaftGroups(1, 3, log_slots=64)
+    groups = device_plane()
     groups.wait_for_leaders()
     counter = DeviceLong(groups, 0)
     reader = DeviceLong(groups, 0).with_consistency("sequential")
@@ -188,6 +189,6 @@ def test_sequential_reads_are_monotone():
 def test_query_lane_rejects_write_opcodes():
     """The query lane discards state, so writes must be rejected up front
     (a put 'served' there would be silently dropped with a success ack)."""
-    groups = RaftGroups(1, 3, log_slots=64)
+    groups = device_plane()
     with pytest.raises(ValueError, match="not read-only"):
         groups.submit_query(0, ap.OP_MAP_PUT, 1, 2)

@@ -31,6 +31,8 @@ from copycat_tpu.parallel import make_mesh  # noqa: E402
 from helpers import async_test  # noqa: E402
 from raft_fixtures import next_ports  # noqa: E402
 
+from engines import SERVED  # noqa: E402
+
 
 def _mesh_or_skip():
     if len(jax.devices()) < 8:
@@ -47,13 +49,12 @@ def test_capacity_must_divide_mesh():
 
 def test_engine_state_sharded_over_mesh():
     mesh = _mesh_or_skip()
-    engine = DeviceEngine(DeviceEngineConfig(
-        capacity=16, num_peers=3, log_slots=32, mesh=mesh))
+    engine = DeviceEngine(SERVED._replace(mesh=mesh))
     rg = engine._ensure()
     shardings = {str(rg.state.term.sharding.spec),
                  str(rg.state.log_term.sharding.spec)}
     assert all("groups" in s for s in shardings), shardings
-    # 16 groups over 8 devices: each device holds a [2, ...] slice
+    # 8 groups over 8 devices: each device holds a [1, ...] slice
     assert len(rg.state.term.devices()) == 8
 
 
@@ -62,8 +63,7 @@ async def test_public_api_through_sharded_engine():
     mesh = _mesh_or_skip()
     registry = LocalServerRegistry()
     addrs = next_ports(3)
-    cfg = DeviceEngineConfig(capacity=16, num_peers=3, log_slots=32,
-                             mesh=mesh)
+    cfg = SERVED._replace(mesh=mesh)
     servers = [
         AtomixServer(a, addrs, LocalTransport(registry),
                      election_timeout=0.2, heartbeat_interval=0.04,
@@ -95,10 +95,7 @@ async def test_mixed_mesh_cluster_replicates_identically():
     mesh = _mesh_or_skip()
     registry = LocalServerRegistry()
     addrs = next_ports(3)
-    base = dict(capacity=16, num_peers=3, log_slots=32)
-    configs = [DeviceEngineConfig(mesh=mesh, **base),
-               DeviceEngineConfig(**base),
-               DeviceEngineConfig(**base)]
+    configs = [SERVED._replace(mesh=mesh), SERVED, SERVED]
     servers = [
         AtomixServer(a, addrs, LocalTransport(registry),
                      election_timeout=0.2, heartbeat_interval=0.04,

@@ -12,9 +12,11 @@ from copycat_tpu.models.device_resources import DeviceMultiMap, DeviceTopic
 from copycat_tpu.models.raft_groups import RaftGroups
 from copycat_tpu.ops import apply as ap
 
+from engines import device_plane, wide_window
 
-def _groups(G: int = 2) -> RaftGroups:
-    rg = RaftGroups(G, 3, log_slots=32, submit_slots=4, seed=5)
+
+def _groups() -> RaftGroups:
+    rg = device_plane(seed=5)
     rg.wait_for_leaders()
     return rg
 
@@ -108,7 +110,7 @@ def test_multimap_topic_independent_of_other_pools():
     from copycat_tpu.ops.consensus import Config
     config = Config(applies_per_round=8,
                     pool_budgets=(2, 2, 2, 2, 2, 2, 2, 2))
-    rg = RaftGroups(2, 3, log_slots=32, submit_slots=8, config=config)
+    rg = wide_window(config)
     rg.wait_for_leaders()
     tags = {}
     tags["add"] = rg.submit(0, ap.OP_LONG_ADD, 5)

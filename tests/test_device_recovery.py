@@ -18,13 +18,13 @@ from copycat_tpu.atomic import DistributedAtomicLong, DistributedAtomicValue  # 
 from copycat_tpu.collections import DistributedMap  # noqa: E402
 from copycat_tpu.io.local import LocalServerRegistry, LocalTransport  # noqa: E402
 from copycat_tpu.manager.atomix import AtomixClient, AtomixServer  # noqa: E402
-from copycat_tpu.manager.device_executor import DeviceEngineConfig  # noqa: E402
 from copycat_tpu.server.log import Storage, StorageLevel  # noqa: E402
 
 from helpers import async_test  # noqa: E402
 from raft_fixtures import next_ports  # noqa: E402
 
-ENGINE = DeviceEngineConfig(capacity=16, num_peers=3, log_slots=32)
+from engines import SERVED  # noqa: E402
+
 
 
 @pytest.mark.parametrize("level", [StorageLevel.DISK, StorageLevel.MAPPED])
@@ -37,7 +37,7 @@ async def test_restart_replays_log_into_fresh_device_engine(tmp_path, level):
     server = AtomixServer(addrs[0], addrs, LocalTransport(registry),
                           election_timeout=0.2, heartbeat_interval=0.04,
                           session_timeout=10.0, executor="tpu",
-                          engine_config=ENGINE, storage=storage)
+                          engine_config=SERVED, storage=storage)
     await server.open()
     client = AtomixClient(addrs, LocalTransport(registry),
                           session_timeout=10.0)
@@ -65,7 +65,7 @@ async def test_restart_replays_log_into_fresh_device_engine(tmp_path, level):
     server2 = AtomixServer(addrs[0], addrs, LocalTransport(registry2),
                            election_timeout=0.2, heartbeat_interval=0.04,
                            session_timeout=10.0, executor="tpu",
-                           engine_config=ENGINE, storage=storage2)
+                           engine_config=SERVED, storage=storage2)
     await server2.open()
     client2 = AtomixClient(addrs, LocalTransport(registry2),
                            session_timeout=10.0)

@@ -12,14 +12,14 @@ import pytest
 
 jax = pytest.importorskip("jax")
 
-from copycat_tpu.models import RaftGroups  # noqa: E402
 from copycat_tpu.ops import apply as ap  # noqa: E402
 from copycat_tpu.ops.apply import FAIL  # noqa: E402
 
+from engines import G, device_plane  # noqa: E402
 
-def make(groups=1, peers=3, **kw):
-    kw.setdefault("log_slots", 64)
-    rg = RaftGroups(groups, peers, **kw)
+
+def make(config=None):
+    rg = device_plane(config)
     rg.wait_for_leaders()
     return rg
 
@@ -101,7 +101,7 @@ def test_map_clear_and_overflow():
 
 
 def test_map_groups_are_isolated():
-    rg = make(groups=3)
+    rg = make()
     t1 = rg.submit(0, ap.OP_MAP_PUT, 1, 111)
     t2 = rg.submit(1, ap.OP_MAP_PUT, 1, 222)
     rg.run_until([t1, t2])
@@ -330,9 +330,7 @@ def test_counters_only_config():
     from copycat_tpu.ops.apply import ResourceConfig
     from copycat_tpu.ops.consensus import Config
 
-    rg = RaftGroups(1, 3, log_slots=32,
-                    config=Config(resource=ResourceConfig.counters_only()))
-    rg.wait_for_leaders()
+    rg = make(Config(resource=ResourceConfig.counters_only()))
     # counters fully work
     res = run_ops(rg, [(ap.OP_LONG_ADD, 5), (ap.OP_LONG_ADD, 5),
                        (ap.OP_VALUE_GET,)])
@@ -368,9 +366,8 @@ def test_counters_only_config():
 # ---------------------------------------------------------------------------
 
 def test_all_pools_converge_under_partitions():
-    G, P = 2, 3
-    rg = RaftGroups(G, P, log_slots=64)
-    rg.wait_for_leaders()
+    rg = make()
+    P = rg.num_peers
     rng = np.random.default_rng(3)
     import jax.numpy as jnp
     ops = [
@@ -420,8 +417,7 @@ def _overflow_groups():
     cfg = Config(resource=ResourceConfig(
         map_slots=0, set_slots=0, queue_slots=0,
         wait_slots=4, listener_slots=4, event_slots=1))
-    rg = RaftGroups(1, 3, log_slots=64, config=cfg)
-    rg.wait_for_leaders()
+    rg = make(cfg)
     a, b = DeviceLock(rg, 0, 1), DeviceLock(rg, 0, 2)
     e1, e2 = DeviceElection(rg, 0, 11), DeviceElection(rg, 0, 12)
     a.lock()

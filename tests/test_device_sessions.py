@@ -14,9 +14,11 @@ from copycat_tpu.models.raft_groups import RaftGroups
 from copycat_tpu.models.sessions import SessionExpiredError
 from copycat_tpu.ops.apply import OP_LOCK_ACQUIRE
 
+from engines import device_plane
+
 
 def _groups(timeout_rounds: int = 25) -> RaftGroups:
-    groups = RaftGroups(4, 3, log_slots=32, submit_slots=4, seed=7)
+    groups = device_plane(seed=7)
     groups.sessions.timeout_rounds = timeout_rounds
     groups.wait_for_leaders()
     return groups

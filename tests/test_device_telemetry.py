@@ -13,9 +13,7 @@ import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
-import jax.numpy as jnp  # noqa: E402
 
-from copycat_tpu.models import RaftGroups  # noqa: E402
 from copycat_tpu.models.telemetry import (  # noqa: E402
     DeviceTelemetryHub,
     InvariantViolation,
@@ -25,21 +23,17 @@ from copycat_tpu.ops import apply as ap  # noqa: E402
 from copycat_tpu.ops.consensus import (  # noqa: E402
     Config,
     DeviceTelemetry,
-    full_delivery,
-    init_state,
-    make_submits,
-    step,
 )
 from copycat_tpu.testing.nemesis import Nemesis  # noqa: E402
 from copycat_tpu.utils.metrics import merge_snapshots  # noqa: E402
 
+from engines import MONOTONE, device_plane  # noqa: E402
+
 TEL_CFG = Config(telemetry=True)
 
 
-def make(groups=8, **kw):
-    kw.setdefault("log_slots", 32)
-    kw.setdefault("config", TEL_CFG)
-    return RaftGroups(groups, 3, **kw)
+def make():
+    return device_plane(TEL_CFG)
 
 
 def counter_value(rg, name, **labels):
@@ -55,33 +49,22 @@ def test_telemetry_off_state_bit_identical():
     """Same seeds, same submits: the telemetry-on and telemetry-off
     programs must produce bit-identical STATE every round (the block
     derives from existing intermediates — no extra RNG, no writes)."""
-    from functools import partial
-
-    G, P, L = 4, 3, 16
-    key = jax.random.PRNGKey(7)
-    key, ik = jax.random.split(key)
-    on, off = Config(telemetry=True), Config()
-    s_on = init_state(G, P, L, ik, on)
-    s_off = init_state(G, P, L, ik, off)
-    sub = make_submits(G, 4)
-    ones = jnp.ones((G, 4), jnp.int32)
-    sub = sub._replace(opcode=ones * ap.OP_LONG_ADD, a=ones, tag=ones,
-                       valid=ones.astype(bool))
-    dl = full_delivery(G, P)
-    f_on = jax.jit(partial(step, config=on))
-    f_off = jax.jit(partial(step, config=off))
+    on, off = device_plane(TEL_CFG, seed=7), device_plane(seed=7)
+    assert off.telemetry is None
+    assert on.telemetry is not None
     for _ in range(15):
-        key, k = jax.random.split(key)
-        s_on, out_on = f_on(s_on, sub, dl, k)
-        s_off, out_off = f_off(s_off, sub, dl, k)
-    assert out_off.telemetry is None
-    assert out_on.telemetry is not None
+        for rg in (on, off):
+            for g in range(rg.num_groups):
+                rg.submit(g, ap.OP_LONG_ADD, 1)
+            rg.step_round()
+    s_on, s_off = on.state, off.state
     for name, a, b in zip(s_on._fields, s_on, s_off):
         if name == "resources":
             for rn, ra, rb in zip(a._fields, a, b):
                 assert (np.asarray(ra) == np.asarray(rb)).all(), rn
         else:
             assert (np.asarray(a) == np.asarray(b)).all(), name
+    assert on.rounds == off.rounds == 15 and int(on.value(0)) > 0
 
 
 # ---------------------------------------------------------------------------
@@ -90,7 +73,7 @@ def test_telemetry_off_state_bit_identical():
 
 
 def test_steady_state_zero_elections_after_warmup():
-    rg = make(groups=8)
+    rg = make()
     rg.wait_for_leaders()
     rg.run(5)  # settle any residual churn
     e0 = counter_value(rg, "device.elections_started")
@@ -110,7 +93,7 @@ def test_steady_state_zero_elections_after_warmup():
 
 
 def test_nemesis_partition_shows_elections_then_heals():
-    rg = make(groups=16)
+    rg = make()
     rg.wait_for_leaders()
     rg.run(5)
     nem = Nemesis(rg, seed=3, period=10, faults=("partition",))
@@ -149,7 +132,7 @@ def test_nemesis_partition_shows_elections_then_heals():
 def test_events_drained_counted():
     """A queued-lock grant pushes a session event through the outbox;
     the drain shows up in device.events_drained."""
-    rg = make(groups=2)
+    rg = make()
     rg.wait_for_leaders()
     t1 = rg.submit(0, ap.OP_LOCK_ACQUIRE, 1, -1)
     t2 = rg.submit(0, ap.OP_LOCK_ACQUIRE, 2, -1)
@@ -167,7 +150,7 @@ def test_events_drained_counted():
 
 
 def test_step_rounds_fused_ingests_every_round():
-    rg = make(groups=4)
+    rg = make()
     rg.wait_for_leaders()
     r0 = counter_value(rg, "device.rounds")
     rg.step_rounds(5)
@@ -178,8 +161,7 @@ def test_step_rounds_fused_ingests_every_round():
 def test_deep_drive_telemetry_one_fetch():
     from copycat_tpu.models.bulk import BulkDriver
 
-    rg = RaftGroups(4, 3, log_slots=32, submit_slots=4,
-                    config=Config(monotone_tag_accept=True, telemetry=True))
+    rg = device_plane(MONOTONE._replace(telemetry=True))
     rg.wait_for_leaders()
     r0 = counter_value(rg, "device.rounds")
     drv = BulkDriver(rg)
@@ -269,7 +251,7 @@ def test_monitor_leaderless_bound():
 
 
 def test_strict_mode_raises_through_the_engine_path():
-    rg = make(groups=4)
+    rg = make()
     rg.wait_for_leaders()
     rg.telemetry.monitor.mode = "strict"
     # fabricate a corruption baseline: pretend we saw commits far ahead
@@ -281,12 +263,12 @@ def test_strict_mode_raises_through_the_engine_path():
 
 def test_env_opt_in_enables_telemetry(monkeypatch):
     monkeypatch.setenv("COPYCAT_INVARIANTS", "strict")
-    rg = RaftGroups(2, 3, log_slots=32)
+    rg = device_plane()
     assert rg.config.telemetry
     assert rg.telemetry is not None
     assert rg.telemetry.monitor.mode == "strict"
     monkeypatch.setenv("COPYCAT_INVARIANTS", "off")
-    rg2 = RaftGroups(2, 3, log_slots=32)
+    rg2 = device_plane()
     assert not rg2.config.telemetry and rg2.telemetry is None
 
 
@@ -296,7 +278,7 @@ def test_env_opt_in_enables_telemetry(monkeypatch):
 
 
 def test_device_snapshot_and_shard_merge():
-    rg = make(groups=8)
+    rg = make()
     rg.wait_for_leaders()
     rg.run(5)
     snap = rg.device_snapshot()

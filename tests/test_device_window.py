@@ -22,7 +22,6 @@ from copycat_tpu.io.local import LocalServerRegistry, LocalTransport  # noqa: E4
 from copycat_tpu.manager.atomix import AtomixClient, AtomixServer  # noqa: E402
 from copycat_tpu.manager.device_executor import (  # noqa: E402
     DeviceEngine,
-    DeviceEngineConfig,
     DeviceJob,
 )
 from copycat_tpu.ops.apply import OP_LONG_ADD  # noqa: E402
@@ -30,7 +29,8 @@ from copycat_tpu.ops.apply import OP_LONG_ADD  # noqa: E402
 from helpers import async_test  # noqa: E402
 from raft_fixtures import next_ports  # noqa: E402
 
-ENGINE = DeviceEngineConfig(capacity=64, num_peers=3, log_slots=32)
+from engines import SERVED_WIDE  # noqa: E402
+
 
 
 def _one_add(engine: DeviceEngine, group: int, amount: int) -> DeviceJob:
@@ -42,7 +42,7 @@ def _one_add(engine: DeviceEngine, group: int, amount: int) -> DeviceJob:
 
 
 def test_window_shares_rounds_across_groups():
-    engine = DeviceEngine(ENGINE)
+    engine = DeviceEngine(SERVED_WIDE)
     warm_groups = engine._ensure()
     r0 = warm_groups.rounds
 
@@ -61,7 +61,7 @@ def test_window_shares_rounds_across_groups():
 
 
 def test_window_serializes_same_group_chains_in_order():
-    engine = DeviceEngine(ENGINE)
+    engine = DeviceEngine(SERVED_WIDE)
     engine._ensure()
 
     window = engine.begin_window()
@@ -75,7 +75,7 @@ def test_window_serializes_same_group_chains_in_order():
 
 
 def test_window_finalizes_in_add_order():
-    engine = DeviceEngine(ENGINE)
+    engine = DeviceEngine(SERVED_WIDE)
     engine._ensure()
     window = engine.begin_window()
     done = []
@@ -87,7 +87,7 @@ def test_window_finalizes_in_add_order():
 
 
 def test_window_surfaces_chain_exceptions_to_on_done():
-    engine = DeviceEngine(ENGINE)
+    engine = DeviceEngine(SERVED_WIDE)
     engine._ensure()
 
     def boom():
@@ -112,7 +112,7 @@ async def test_spi_batching_end_to_end():
     server = AtomixServer(addrs[0], addrs, LocalTransport(registry),
                           election_timeout=0.2, heartbeat_interval=0.04,
                           session_timeout=10.0, executor="tpu",
-                          engine_config=ENGINE)
+                          engine_config=SERVED_WIDE)
     await server.open()
     client = AtomixClient(addrs, LocalTransport(registry),
                           session_timeout=10.0)
@@ -151,7 +151,7 @@ async def test_ttl_under_window_still_fires(monkeypatch):
     server = AtomixServer(addrs[0], addrs, LocalTransport(registry),
                           election_timeout=0.2, heartbeat_interval=0.04,
                           session_timeout=10.0, executor="tpu",
-                          engine_config=ENGINE)
+                          engine_config=SERVED_WIDE)
     await server.open()
     client = AtomixClient(addrs, LocalTransport(registry),
                           session_timeout=10.0)

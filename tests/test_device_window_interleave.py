@@ -19,12 +19,12 @@ from copycat_tpu.collections import DistributedMap  # noqa: E402
 from copycat_tpu.coordination import DistributedLock  # noqa: E402
 from copycat_tpu.io.local import LocalServerRegistry, LocalTransport  # noqa: E402
 from copycat_tpu.manager.atomix import AtomixClient, AtomixServer  # noqa: E402
-from copycat_tpu.manager.device_executor import DeviceEngineConfig  # noqa: E402
 
 from helpers import async_test  # noqa: E402
 from raft_fixtures import next_ports  # noqa: E402
 
-ENGINE = DeviceEngineConfig(capacity=32, num_peers=3, log_slots=32)
+from engines import SERVED_WIDE  # noqa: E402
+
 
 
 async def _node(n_clients: int = 1):
@@ -33,7 +33,7 @@ async def _node(n_clients: int = 1):
     server = AtomixServer(addrs[0], addrs, LocalTransport(registry),
                           election_timeout=0.2, heartbeat_interval=0.04,
                           session_timeout=10.0, executor="tpu",
-                          engine_config=ENGINE)
+                          engine_config=SERVED_WIDE)
     await server.open()
     clients = []
     for _ in range(n_clients):

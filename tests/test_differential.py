@@ -37,11 +37,12 @@ from copycat_tpu.models import (
     DeviceQueue,
     DeviceSet,
     DeviceValue,
-    RaftGroups,
 )
 
 from atomix_fixtures import Stack
 from helpers import async_test
+
+from engines import device_plane
 
 SEED = 20260729
 NUM_OPS = 1000
@@ -261,7 +262,7 @@ class DevicePath:
     def __init__(self):
         # one group per resource type: value/long share an opcode register,
         # so they must live in separate groups
-        self.rg = RaftGroups(7, 3, log_slots=64)
+        self.rg = device_plane()
         self.rg.wait_for_leaders()
         self.value = DeviceValue(self.rg, 0)
         self.long = DeviceLong(self.rg, 1)

@@ -448,8 +448,13 @@ async def test_nemesis_monotone_and_ryw_against_linearizable_witness(
     # 8 s flaked as SessionExpiredError deep in the full suite
     servers = await _stack(registry, members=3, session_timeout=20.0)
     addrs = [s.server.address for s in servers]
+    # the writer's is also its commands' per-try timeout: the first write
+    # after the partition is taken by the old leader, which keeps its role
+    # and never answers, and LocalConnection fails no send in flight, so
+    # that write waits these 10 s before it rides the connection the
+    # keep-alive has found by then (ROADMAP Queue 3 item 1)
     writer = AtomixClient(addrs, LocalTransport(registry),
-                          session_timeout=20.0)
+                          session_timeout=10.0)
     reader = AtomixClient(addrs, LocalTransport(registry),
                           session_timeout=20.0)
     await writer.open()

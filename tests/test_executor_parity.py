@@ -27,15 +27,15 @@ from copycat_tpu.collections import (
 from copycat_tpu.coordination import DistributedLeaderElection, DistributedLock
 from copycat_tpu.io.local import LocalServerRegistry, LocalTransport
 from copycat_tpu.manager.atomix import AtomixClient, AtomixServer
-from copycat_tpu.manager.device_executor import DeviceEngineConfig
 
 from helpers import async_test
 from raft_fixtures import next_ports
 
+from engines import SERVED
+
 EXECUTORS = ("cpu", "tpu")
 
 # one small engine shape for every parity test → one jit compile per process
-ENGINE = DeviceEngineConfig(capacity=8, num_peers=3, log_slots=32)
 
 
 async def _cluster(executor: str, n: int = 3, n_clients: int = 1):
@@ -45,7 +45,7 @@ async def _cluster(executor: str, n: int = 3, n_clients: int = 1):
         AtomixServer(a, addrs, LocalTransport(registry),
                      election_timeout=0.2, heartbeat_interval=0.04,
                      session_timeout=10.0, executor=executor,
-                     engine_config=ENGINE)
+                     engine_config=SERVED)
         for a in addrs
     ]
     await asyncio.gather(*(s.open() for s in servers))

@@ -27,9 +27,10 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
-from copycat_tpu.models.raft_groups import RaftGroups  # noqa: E402
 from copycat_tpu.ops import apply as ap  # noqa: E402
 from copycat_tpu.ops.consensus import Config  # noqa: E402
+
+from engines import five_peer  # noqa: E402
 
 
 def _island_deliver(G: int, P: int, island: set[int]) -> jnp.ndarray:
@@ -43,8 +44,7 @@ def _island_deliver(G: int, P: int, island: set[int]) -> jnp.ndarray:
 
 
 def test_partitioned_ex_leader_lease_drops_under_grown_config():
-    rg = RaftGroups(2, 5, log_slots=32, submit_slots=4, seed=3,
-                    config=Config(dynamic_membership=True), voters=3)
+    rg = five_peer(Config(dynamic_membership=True), seed=3, voters=3)
     rg.wait_for_leaders()
 
     # grow the voter set to all 5 lanes (single-server steps)
@@ -65,7 +65,7 @@ def test_partitioned_ex_leader_lease_drops_under_grown_config():
     # 3-voter config (2 of {0,1,2}) but not of the active 5-voter one
     companion = next(p for p in (0, 1, 2) if p != leader)
     island = {leader, companion}
-    rg.deliver = _island_deliver(2, 5, island)
+    rg.deliver = _island_deliver(rg.num_groups, 5, island)
 
     for _ in range(3):
         rg.step_round()
@@ -111,7 +111,7 @@ def test_partitioned_ex_leader_lease_drops_under_grown_config():
 
     # heal: the ex-leader steps down; no stale value resurfaces
     from copycat_tpu.ops.consensus import full_delivery
-    rg.deliver = full_delivery(2, 5)
+    rg.deliver = full_delivery(rg.num_groups, 5)
     rg.run(10)
     q = rg.submit_query(0, ap.OP_VALUE_GET, consistency="atomic")
     rg.run_until([q], max_rounds=120)
@@ -123,8 +123,7 @@ def test_lease_read_never_serves_during_config_island():
     query routed at it must escalate to the command path (and therefore
     only complete on the true leader's line) — never serve locally from
     the stale lane."""
-    rg = RaftGroups(1, 5, log_slots=32, submit_slots=4, seed=5,
-                    config=Config(dynamic_membership=True), voters=3)
+    rg = five_peer(Config(dynamic_membership=True), seed=5, voters=3)
     rg.wait_for_leaders()
     for lane in (3, 4):
         rg.run_until([rg.add_peer(0, lane)])
@@ -133,7 +132,7 @@ def test_lease_read_never_serves_during_config_island():
 
     leader = rg.leader(0)
     companion = next(p for p in (0, 1, 2) if p != leader)
-    rg.deliver = _island_deliver(1, 5, {leader, companion})
+    rg.deliver = _island_deliver(rg.num_groups, 5, {leader, companion})
 
     # atomic read during the partition: it must reflect the majority
     # line's state (the islanded lanes cannot serve it via lease)

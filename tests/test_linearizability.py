@@ -13,7 +13,6 @@ import pytest
 
 jax = pytest.importorskip("jax")
 
-from copycat_tpu.models import RaftGroups  # noqa: E402
 from copycat_tpu.ops import apply as ap  # noqa: E402
 from copycat_tpu.testing import (  # noqa: E402
     HOp,
@@ -24,6 +23,8 @@ from copycat_tpu.testing import (  # noqa: E402
     RegisterModel,
     check_linearizable,
 )
+
+from engines import device_plane  # noqa: E402
 
 
 # ---------------------------------------------------------------------------
@@ -102,7 +103,7 @@ REGISTER_OPS = [
 def test_register_histories_linearizable_under_nemesis():
     import numpy as np
     G = 4
-    rg = RaftGroups(G, 3, log_slots=64)
+    rg = device_plane()
     rg.wait_for_leaders()
     rec = HistoryRecorder(rg)
     nemesis = Nemesis(rg, seed=11, period=12)
@@ -139,7 +140,7 @@ def test_register_histories_linearizable_under_nemesis():
 def test_map_histories_linearizable_under_nemesis():
     import numpy as np
     G = 2
-    rg = RaftGroups(G, 3, log_slots=64)
+    rg = device_plane()
     rg.wait_for_leaders()
     rec = HistoryRecorder(rg)
     nemesis = Nemesis(rg, seed=3, period=15)
@@ -170,7 +171,7 @@ def test_map_histories_linearizable_under_nemesis():
 
 def test_trylock_histories_linearizable_under_nemesis():
     import numpy as np
-    rg = RaftGroups(1, 3, log_slots=64)
+    rg = device_plane()
     rg.wait_for_leaders()
     rec = HistoryRecorder(rg)
     nemesis = Nemesis(rg, seed=7, period=10, faults=("heal", "loss"))
@@ -206,7 +207,7 @@ def test_atomic_lease_reads_linearizable_under_nemesis():
     reference Consistency.java:157-176 BOUNDED_LINEARIZABLE)."""
     import numpy as np
     G = 4
-    rg = RaftGroups(G, 3, log_slots=64)
+    rg = device_plane()
     rg.wait_for_leaders()
     rec = HistoryRecorder(rg)
     nemesis = Nemesis(rg, seed=21, period=12)

@@ -13,17 +13,18 @@ import pytest
 
 jax = pytest.importorskip("jax")
 
-from copycat_tpu.models import BulkDriver, RaftGroups  # noqa: E402
+from copycat_tpu.models import BulkDriver  # noqa: E402
 from copycat_tpu.ops import apply as ap  # noqa: E402
 from copycat_tpu.ops.consensus import Config  # noqa: E402
 from copycat_tpu.parallel.mesh import make_mesh  # noqa: E402
 from copycat_tpu.parallel.scaling import census_text, _deep_census  # noqa: E402
 
+from engines import G, MONOTONE, device_plane  # noqa: E402
 
-def _mesh_engine(n_groups=48, seed=51):
+
+def _mesh_engine(seed=51):
     mesh = make_mesh()  # all 8 virtual devices, 1D groups axis
-    rg = RaftGroups(n_groups, 3, log_slots=32, submit_slots=4, seed=seed,
-                    mesh=mesh, config=Config(monotone_tag_accept=True))
+    rg = device_plane(MONOTONE, seed=seed, mesh=mesh)
     rg.wait_for_leaders()
     return rg
 
@@ -32,20 +33,20 @@ def test_deep_drive_on_sharded_mesh_fifo_and_reads():
     rg = _mesh_engine()
     driver = BulkDriver(rg)
     # uneven per-group counts exercise the padded [G,B] accumulators
-    g = np.concatenate([np.full((i % 7) + 1, i) for i in range(48)])
+    g = np.concatenate([np.full((i % 7) + 1, i) for i in range(G)])
     res = driver.drive(g, ap.OP_LONG_ADD, 1)
     off = 0
-    for i in range(48):
+    for i in range(G):
         cnt = (i % 7) + 1
         assert (res.results[off:off + cnt] == np.arange(1, cnt + 1)).all()
         off += cnt
     # second drive continues streams across the mesh
-    res2 = driver.drive(np.arange(48), ap.OP_LONG_ADD, 1)
-    assert (res2.results == (np.arange(48) % 7) + 2).all()
+    res2 = driver.drive(np.arange(G), ap.OP_LONG_ADD, 1)
+    assert (res2.results == (np.arange(G) % 7) + 2).all()
     # and the query lane serves ATOMIC lease reads over the mesh
-    got = driver.drive_queries(np.arange(48), ap.OP_VALUE_GET,
+    got = driver.drive_queries(np.arange(G), ap.OP_VALUE_GET,
                                consistency="atomic")
-    assert (got == (np.arange(48) % 7) + 2).all()
+    assert (got == (np.arange(G) % 7) + 2).all()
 
 
 def test_deep_step_census_zero_collectives_on_mesh():

@@ -179,10 +179,11 @@ def test_merge_snapshots_keeps_gauges_point_in_time():
 
 def test_driver_records_commit_latency():
     jax = pytest.importorskip("jax")  # noqa: F841
-    from copycat_tpu.models import RaftGroups
     from copycat_tpu.ops import apply as ap
 
-    rg = RaftGroups(2, 3, log_slots=32)
+    from engines import device_plane
+
+    rg = device_plane()
     rg.wait_for_leaders()
     tags = [rg.submit(0, ap.OP_LONG_ADD, 1) for _ in range(8)]
     rg.run_until(tags)

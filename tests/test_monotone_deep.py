@@ -18,20 +18,23 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
-from copycat_tpu.models import BulkDriver, RaftGroups  # noqa: E402
+from copycat_tpu.models import BulkDriver  # noqa: E402
 from copycat_tpu.ops import apply as ap  # noqa: E402
 from copycat_tpu.ops.consensus import (  # noqa: E402
-    Config,
     Submits,
     full_delivery,
     step,
 )
 
+from engines import G, MONOTONE, device_plane  # noqa: E402
+
+#: elections within a few rounds of a cut, for the failover tests
+QUICK = MONOTONE._replace(timer_min=2, timer_max=4)
+
 
 @pytest.fixture(scope="module")
 def rg():
-    groups = RaftGroups(8, 3, log_slots=32, submit_slots=4, seed=7,
-                        config=Config(monotone_tag_accept=True))
+    groups = device_plane(MONOTONE, seed=7)
     groups.wait_for_leaders()
     return groups
 
@@ -73,13 +76,11 @@ def test_gate_accepts_dense_stream_rejects_duplicates_and_gaps(rg):
 
 
 def test_gate_election_noop_does_not_break_the_chain():
-    groups = RaftGroups(4, 3, log_slots=16, submit_slots=2, seed=3,
-                        config=Config(monotone_tag_accept=True,
-                                      timer_min=2, timer_max=4))
+    groups = device_plane(QUICK, seed=3)
     groups.wait_for_leaders()
     for _ in range(10):  # lease-gated accept needs a warm leader: retry
         out = _step_raw(groups, _submit_window(groups, 1, [1, 2]))
-        if np.asarray(out.accepted)[1].all():
+        if np.asarray(out.accepted)[1, :2].all():
             break
     else:
         pytest.fail("initial window never accepted")
@@ -88,11 +89,8 @@ def test_gate_election_noop_does_not_break_the_chain():
     # force a re-election in group 1: isolate the leader for a while
     lead = int(np.asarray(jax.device_get(
         groups.state.leader_hint)).max(axis=1)[1])
-    deliver = np.ones((4, 3, 3), bool)
-    deliver[1, lead, :] = False
-    deliver[1, :, lead] = False
     saved = groups.deliver
-    groups.deliver = jnp.asarray(deliver)
+    groups.deliver = _isolate(groups, 1, lead)
     for _ in range(12):
         _step_raw(groups, groups._empty_submits())
     groups.deliver = saved
@@ -113,10 +111,9 @@ def test_gate_election_noop_does_not_break_the_chain():
 def test_compact_leaves_match_full_arrays():
     """Scalar opcode/payload leaves and the [G,1] consecutive-tag leaf
     must behave exactly like full [G,S] arrays."""
-    groups = RaftGroups(4, 3, log_slots=16, submit_slots=4, seed=5,
-                        config=Config(monotone_tag_accept=True))
+    groups = device_plane(MONOTONE, seed=5)
     groups.wait_for_leaders()
-    G, S = 4, 4
+    S = groups.submit_slots
     # compact: every group submits tags 1..4, op/a scalar
     sub = Submits(opcode=np.int32(ap.OP_LONG_ADD), a=np.int32(1),
                   b=np.int32(0), c=np.int32(0),
@@ -147,8 +144,7 @@ def test_compact_leaves_match_full_arrays():
 
 
 def test_deep_drive_fifo_across_drives(rg_deep=None):
-    groups = RaftGroups(8, 3, log_slots=32, submit_slots=4, seed=11,
-                        config=Config(monotone_tag_accept=True))
+    groups = device_plane(MONOTONE, seed=11)
     groups.wait_for_leaders()
     driver = BulkDriver(groups)
     g = np.repeat(np.arange(8), 10)
@@ -165,8 +161,7 @@ def test_deep_drive_fifo_across_drives(rg_deep=None):
 
 
 def test_deep_drive_mixed_payloads_map_roundtrip():
-    groups = RaftGroups(8, 3, log_slots=32, submit_slots=4, seed=13,
-                        config=Config(monotone_tag_accept=True))
+    groups = device_plane(MONOTONE, seed=13)
     groups.wait_for_leaders()
     driver = BulkDriver(groups)
     n = 8 * 10
@@ -180,8 +175,7 @@ def test_deep_drive_mixed_payloads_map_roundtrip():
 
 
 def test_deep_drive_uneven_group_counts():
-    groups = RaftGroups(8, 3, log_slots=32, submit_slots=4, seed=17,
-                        config=Config(monotone_tag_accept=True))
+    groups = device_plane(MONOTONE, seed=17)
     groups.wait_for_leaders()
     driver = BulkDriver(groups)
     # ragged: group i gets i+1 ops
@@ -205,8 +199,7 @@ def test_query_lane_allowed_and_never_escalates_on_monotone_engine():
     """Queries don't append, so they stay allowed — and an unservable
     query must RETRY on the query lane, never escalate to the (closed)
     command path where the gate would reject its tag forever."""
-    groups = RaftGroups(4, 3, log_slots=16, submit_slots=4, seed=23,
-                        config=Config(monotone_tag_accept=True))
+    groups = device_plane(MONOTONE, seed=23)
     groups.wait_for_leaders()
     driver = BulkDriver(groups)
     driver.drive(np.array([0]), ap.OP_LONG_ADD, 7)
@@ -235,10 +228,7 @@ def test_gate_exactly_once_across_leader_change_uncommitted_tail():
     replicated are lost with that leader; the gate must accept the
     re-dispatch at the new leader (tags > its ring max) and each op
     applies EXACTLY once."""
-    groups = RaftGroups(2, 3, log_slots=16, submit_slots=2, seed=31,
-                        config=Config(monotone_tag_accept=True,
-                                      timer_min=2, timer_max=4,
-                                      lease_gated_accept=False))
+    groups = device_plane(QUICK._replace(lease_gated_accept=False), seed=31)
     groups.wait_for_leaders()
     out = _step_raw(groups, groups._empty_submits())
     lead = int(np.asarray(out.leader)[0])
@@ -248,7 +238,7 @@ def test_gate_exactly_once_across_leader_change_uncommitted_tail():
     groups.deliver = _isolate(groups, 0, lead)
     for _ in range(3):
         out = _step_raw(groups, _submit_window(groups, 0, [1, 2]))
-        if np.asarray(out.accepted)[0].all():
+        if np.asarray(out.accepted)[0, :2].all():
             break
     else:
         pytest.fail("doomed leader never accepted the window")
@@ -264,7 +254,7 @@ def test_gate_exactly_once_across_leader_change_uncommitted_tail():
     # so [1,2] must be accepted again
     for _ in range(10):
         out = _step_raw(groups, _submit_window(groups, 0, [1, 2]))
-        if np.asarray(out.accepted)[0].all():
+        if np.asarray(out.accepted)[0, :2].all():
             break
     else:
         pytest.fail("re-dispatch never accepted at the new leader")
@@ -281,13 +271,11 @@ def test_gate_exactly_once_across_leader_change_uncommitted_tail():
 def test_gate_dedups_committed_ops_across_leader_change():
     """Committed entries survive elections (leader completeness), so a
     duplicate re-send after failover must be rejected."""
-    groups = RaftGroups(2, 3, log_slots=16, submit_slots=2, seed=37,
-                        config=Config(monotone_tag_accept=True,
-                                      timer_min=2, timer_max=4))
+    groups = device_plane(QUICK, seed=37)
     groups.wait_for_leaders()
     for _ in range(10):
         out = _step_raw(groups, _submit_window(groups, 0, [1, 2]))
-        if np.asarray(out.accepted)[0].all():
+        if np.asarray(out.accepted)[0, :2].all():
             break
     for _ in range(4):  # commit + apply on a quorum
         out = _step_raw(groups, groups._empty_submits())
@@ -315,11 +303,10 @@ def test_timeout_resyncs_stream_cursor_engine_not_wedged():
     the device consumed tags the host never saw resolve, so the cursor
     resyncs from the device ring and the NEXT drive's tags are accepted
     (round-4 review: the stale cursor wedged every later drive)."""
-    groups = RaftGroups(4, 3, log_slots=32, submit_slots=4, seed=29,
-                        config=Config(monotone_tag_accept=True))
+    groups = device_plane(MONOTONE, seed=29)
     groups.wait_for_leaders()
     driver = BulkDriver(groups)
-    g = np.repeat(np.arange(4), 8)
+    g = np.repeat(np.arange(G), 8)
     # max_rounds too small to even finish phase 1 + settle + harvest
     with pytest.raises(TimeoutError):
         driver.drive(g, ap.OP_LONG_ADD, 1, max_rounds=1)
@@ -328,7 +315,7 @@ def test_timeout_resyncs_stream_cursor_engine_not_wedged():
     # for abandoned ops — each group's counter is monotone and the new
     # ops' deltas all land exactly once)
     res = driver.drive(g, ap.OP_LONG_ADD, 1)
-    vals = res.results.reshape(4, 8)
+    vals = res.results.reshape(G, 8)
     assert (np.diff(vals, axis=1) == 1).all()  # FIFO, each delta once
 
 
@@ -336,8 +323,7 @@ def test_bulk_query_drive_all_levels():
     """Client-visible bulk READS through the no-append query lane: each
     level serves the applied value; ATOMIC additionally rides the leader
     lease (linearizable with zero log entries)."""
-    groups = RaftGroups(8, 3, log_slots=32, submit_slots=4, seed=41,
-                        config=Config(monotone_tag_accept=True))
+    groups = device_plane(MONOTONE, seed=41)
     groups.wait_for_leaders()
     driver = BulkDriver(groups)
     g = np.repeat(np.arange(8), 5)
@@ -350,8 +336,7 @@ def test_bulk_query_drive_all_levels():
 
 
 def test_bulk_query_drive_map_and_errors():
-    groups = RaftGroups(4, 3, log_slots=32, submit_slots=4, seed=43,
-                        config=Config(monotone_tag_accept=True))
+    groups = device_plane(MONOTONE, seed=43)
     groups.wait_for_leaders()
     driver = BulkDriver(groups)
     n = 4 * 6
@@ -369,8 +354,7 @@ def test_bulk_query_drive_map_and_errors():
 def test_deep_drive_session_events_ingested():
     """Lock grants ride the event ring; the deep drive's rare ev path
     must still deliver them to the host buffer."""
-    groups = RaftGroups(4, 3, log_slots=32, submit_slots=4, seed=19,
-                        config=Config(monotone_tag_accept=True))
+    groups = device_plane(MONOTONE, seed=19)
     groups.wait_for_leaders()
     driver = BulkDriver(groups)
     # acquire(1) grants synchronously; acquire(2) queues; release(1)
@@ -392,11 +376,10 @@ def test_checkpoint_restore_rebuilds_stream_cursor(tmp_path):
     snapshot does not carry)."""
     from copycat_tpu.models import checkpoint
 
-    groups = RaftGroups(6, 3, log_slots=32, submit_slots=4, seed=61,
-                        config=Config(monotone_tag_accept=True))
+    groups = device_plane(MONOTONE, seed=61)
     groups.wait_for_leaders()
     driver = BulkDriver(groups)
-    g = np.repeat(np.arange(6), 9)
+    g = np.repeat(np.arange(G), 9)
     driver.drive(g, ap.OP_LONG_ADD, 1)
 
     path = tmp_path / "snap.npz"
@@ -405,4 +388,4 @@ def test_checkpoint_restore_rebuilds_stream_cursor(tmp_path):
     assert (restored._stream_count == 9).all(), restored._stream_count
     drv2 = BulkDriver(restored)
     res = drv2.drive(g, ap.OP_LONG_ADD, 1)
-    assert (res.results.reshape(6, 9) == 9 + np.arange(1, 10)).all()
+    assert (res.results.reshape(G, 9) == 9 + np.arange(1, 10)).all()

@@ -190,7 +190,15 @@ async def test_majority_progress_and_stale_leader_refusal_symmetric():
         majority = [s.address for s in cluster.servers if s is not old]
         nem.partition(minority, majority)
 
-        # majority side elects and commits a NEWER value
+        # majority side elects (awaited, its follower's word of it too: a
+        # client that opens sooner is hinted to the old leader, which
+        # takes the register, never answers, and holds it for a whole
+        # SESSION_T) and commits a NEWER value
+        for _ in range(500):
+            if all(s.leader_address in majority
+                   for s in cluster.servers if s is not old):
+                break
+            await asyncio.sleep(0.02)
         maj_client = RaftClient(majority, LocalTransport(cluster.registry),
                                 session_timeout=SESSION_T)
         await maj_client.open()
@@ -225,17 +233,65 @@ async def test_majority_progress_and_stale_leader_refusal_symmetric():
         await cluster.close()
 
 
+@async_test(timeout=120)
+async def test_command_at_a_partitioned_leader_reroutes_after_one_timeout(
+        monkeypatch):
+    """A partitioned leader keeps its role and its connections: it
+    takes the command, cannot commit it and never answers, and
+    ``LocalConnection.close`` fails no send in flight, so the command
+    waits out one per-try timeout (the session timeout). The keep-alive
+    gives up sooner and finds the new leader. The command's retry must
+    ride that connection, neither drop it nor dial the dead member
+    first: it did both, one time in three (here every time: the shuffle
+    is held still with the old leader in front), and stalled a second
+    timeout (ROADMAP Queue 3 item 1, the edge-reads nemesis test's red)."""
+    import random
+
+    T = 4.0
+    cluster, nem = await _nemesis_cluster()
+    try:
+        old = await cluster.await_leader()
+        others = [s.address for s in cluster.servers if s is not old]
+        client = RaftClient([old.address, *others],
+                            LocalTransport(cluster.registry),
+                            session_timeout=T)
+        monkeypatch.setattr(random, "shuffle", lambda members: None)
+        await client.open()
+        cluster.clients.append(client)
+        assert await client.submit(Put(key="k", value=1)) is None
+        assert client._connected_to == old.address
+        nem.partition([old.address], others)
+        loop = asyncio.get_running_loop()
+        t0 = loop.time()
+        assert await asyncio.wait_for(
+            client.submit(Put(key="k", value=2)), 3 * T) == 1
+        assert loop.time() - t0 < T + 2.0, "more than one per-try timeout"
+        assert client._connected_to not in (None, old.address)
+        assert client._failed_last == old.address
+    finally:
+        nem.heal()
+        await cluster.close()
+
+
 # ---------------------------------------------------------------------------
 # partition + loss soak: convergence and exactly-once apply
 # ---------------------------------------------------------------------------
 
 
+# A command in flight at a leader that is cut off or deposed is never
+# answered (ROADMAP Queue 3 item 1) and waits out SESSION_T, so the soak
+# takes 30 s a disturbance that catches one: 5 to 278 s over thirty runs
+# of the 30 writes (three partitions), at the parent as now. Tier-1 runs
+# one partition's worth, the same schedule cut at its first heal; the
+# three rotations stay behind ``-m slow``.
+@pytest.mark.parametrize(
+    "n_puts", [10, pytest.param(30, marks=pytest.mark.slow)])
 @async_test(timeout=480)
-async def test_soak_partitions_and_loss_exactly_once():
-    """30 acked writes through rolling partitions + 15%/10% message loss
-    + 0-3ms delays. After heal: every server applied each committed
-    command EXACTLY once (the session dedup surviving lost responses)
-    and all logs converge to the same final state."""
+async def test_soak_partitions_and_loss_exactly_once(n_puts):
+    """``n_puts`` acked writes through rolling partitions + 15%/10%
+    message loss + 0-3ms delays. After heal: every server applied each
+    committed command EXACTLY once (the session dedup surviving lost
+    responses) and all logs converge to the same final state."""
     # generous session timeout: under full-suite load the event loop can
     # starve keep-alives for seconds, and an expiry mid-soak fails the
     # run with SessionExpiredError — a timing artifact, not a finding
@@ -248,7 +304,6 @@ async def test_soak_partitions_and_loss_exactly_once():
         nem.set_delay(0.0, 0.003)
 
         addrs = [s.address for s in cluster.servers]
-        n_puts = 30
         for i in range(n_puts):
             if i % 10 == 3:
                 # rotate a symmetric minority partition mid-stream

@@ -17,6 +17,8 @@ from copycat_tpu.ops.pallas_kernels import (  # noqa: E402
     kth_largest_pallas,
 )
 
+from engines import device_plane  # noqa: E402
+
 
 @pytest.mark.parametrize("P,k", [(3, 2), (5, 3), (7, 4), (4, 1), (3, 3)])
 def test_kth_largest_matches_numpy(P, k):
@@ -44,12 +46,10 @@ def test_pallas_with_duplicates():
 
 
 def test_consensus_with_pallas_quorum():
-    from copycat_tpu.models import RaftGroups
     from copycat_tpu.ops import apply as ap
     from copycat_tpu.ops.consensus import Config
 
-    rg = RaftGroups(4, 3, log_slots=32, config=Config(use_pallas=True,
-                                  pallas_interpret=True))
+    rg = device_plane(Config(use_pallas=True, pallas_interpret=True))
     rg.wait_for_leaders()
     tags = [rg.submit(g, ap.OP_LONG_ADD, g + 1) for g in range(4)
             for _ in range(3)]

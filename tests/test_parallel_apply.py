@@ -33,14 +33,14 @@ from copycat_tpu.atomic import (  # noqa: E402
 from copycat_tpu.io.local import LocalServerRegistry, LocalTransport  # noqa: E402
 from copycat_tpu.io.serializer import Serializer  # noqa: E402
 from copycat_tpu.manager.atomix import AtomixClient, AtomixServer  # noqa: E402
-from copycat_tpu.manager.device_executor import DeviceEngineConfig  # noqa: E402
 from copycat_tpu.server.log import CommandEntry  # noqa: E402
 from copycat_tpu.server.raft import LEADER  # noqa: E402
 
 from helpers import async_test  # noqa: E402
 from raft_fixtures import next_ports  # noqa: E402
 
-ENGINE = DeviceEngineConfig(capacity=32, num_peers=3, log_slots=32)
+from engines import SERVED_WIDE  # noqa: E402
+
 
 
 async def _cluster(registry, *, executor: str = "tpu",
@@ -51,7 +51,7 @@ async def _cluster(registry, *, executor: str = "tpu",
                             election_timeout=election_timeout,
                             heartbeat_interval=election_timeout / 5,
                             session_timeout=30.0, executor=executor,
-                            engine_config=ENGINE, groups=groups)
+                            engine_config=SERVED_WIDE, groups=groups)
                for a in addrs]
     await asyncio.gather(*(s.open() for s in servers))
     cs = [AtomixClient(addrs, LocalTransport(registry),

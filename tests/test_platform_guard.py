@@ -104,3 +104,42 @@ class TestCompilationCache:
         # least-recently-used cache entries dropped; user file untouched
         assert left == [f"jit_f3-{h}-cache", f"jit_f4-{h}-cache",
                         f"jit_f5-{h}-cache", "precious.txt"], left
+
+
+def test_a_test_engine_takes_a_shared_shape_or_says_why():
+    """Tier-1 runs from an empty compile cache on the driver, where every
+    engine shape of its own costs seconds (``tests/engines.py``). Outside
+    ``tests/benchmark``, a test builds its engine through that module, or
+    constructs ``RaftGroups`` under a ``# shape:`` comment (on the call's
+    first line or the line above) that says why the shape is its subject."""
+    import ast
+    import glob
+
+    tests = os.path.join(REPO, "tests")
+    engines = os.path.join(tests, "engines.py")
+    with open(engines) as f:
+        shared = [n.name for n in ast.parse(f.read()).body
+                  if isinstance(n, ast.FunctionDef)]
+    assert "device_plane" in shared
+    unexplained = []
+    for path in sorted(glob.glob(os.path.join(tests, "*.py"))):
+        if path == engines:
+            continue
+        with open(path) as f:
+            source = f.read()
+        lines = source.splitlines()
+        for node in ast.walk(ast.parse(source)):
+            if not (isinstance(node, ast.Call)
+                    and getattr(node.func, "id",
+                                getattr(node.func, "attr", "")) == "RaftGroups"
+                    and any(isinstance(a, ast.Constant) for a in
+                            [*node.args, *(k.value for k in node.keywords
+                                           if k.arg != "seed")])):
+                continue
+            near = lines[max(0, node.lineno - 2):node.lineno]
+            if not any("# shape:" in line for line in near):
+                unexplained.append(
+                    f"{os.path.relpath(path, REPO)}:{node.lineno}")
+    assert not unexplained, (
+        "RaftGroups(<literal sizes>) with no '# shape:' reason; take a "
+        f"shape of tests/engines.py ({', '.join(shared)}): {unexplained}")

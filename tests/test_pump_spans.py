@@ -19,7 +19,6 @@ from copycat_tpu.io.local import (  # noqa: E402
     LocalServerRegistry, LocalTransport)
 from copycat_tpu.io.serializer import Serializer  # noqa: E402
 from copycat_tpu.manager.atomix import AtomixClient, AtomixServer  # noqa: E402
-from copycat_tpu.manager.device_executor import DeviceEngineConfig  # noqa: E402
 from copycat_tpu.models import RaftGroups  # noqa: E402
 from copycat_tpu.ops import apply as ap  # noqa: E402
 from copycat_tpu.ops.consensus import Config  # noqa: E402
@@ -31,6 +30,8 @@ from copycat_tpu.utils.tracing import TRACER, Tracer  # noqa: E402
 from helpers import arun  # noqa: E402
 from raft_fixtures import next_ports  # noqa: E402
 from test_trace_plane import GOLDEN, _golden_samples  # noqa: E402
+
+from engines import SERVED, device_plane  # noqa: E402
 
 COUNTERS, BURSTS = 8, 3
 WRITE_PUMP = ("apply.classify", "apply.park", "apply.marshal",
@@ -58,7 +59,7 @@ async def _deployment():
     server = AtomixServer(
         addr, [addr], LocalTransport(registry), election_timeout=0.5,
         heartbeat_interval=0.1, session_timeout=60.0, executor="tpu",
-        engine_config=DeviceEngineConfig(capacity=16, num_peers=3))
+        engine_config=SERVED)
     await server.open()
     client = AtomixClient([addr], LocalTransport(registry),
                           session_timeout=60.0)
@@ -262,7 +263,7 @@ def test_one_write_round_is_five_buffers_and_one_query_three():
     """Exactly: a round puts the six submit planes as 1 buffer, gets 2
     fresh slabs (the state and the key alias their donated inputs) and
     fetches those 2; a query evaluation puts 1, gets 1, fetches 1."""
-    rg = RaftGroups(num_groups=4, num_peers=3, log_slots=16, submit_slots=4)
+    rg = device_plane()
     rg.wait_for_leaders()
     leaves = rg.metrics.counter("dispatch_leaves")
     tag = rg.submit(0, ap.OP_LONG_ADD, 5)
@@ -328,8 +329,7 @@ def test_a_read_behind_a_committed_unapplied_write_counts_settle_rounds():
     round, three are still unapplied when the read comes, so the query
     drive settles with engine rounds, counts them, and records their
     stages as children of ``engine.query``."""
-    rg = RaftGroups(num_groups=2, num_peers=3, log_slots=16, submit_slots=4,
-                    config=Config(applies_per_round=1))
+    rg = device_plane(Config(applies_per_round=1))
     rg.wait_for_leaders()
     rg.run(3)
     for _ in range(4):
@@ -360,7 +360,7 @@ def test_a_read_behind_a_committed_unapplied_write_counts_settle_rounds():
 
 
 def test_fused_rounds_record_the_four_stages_once_with_their_rounds():
-    rg = RaftGroups(num_groups=2, num_peers=3, log_slots=16)
+    rg = device_plane()
     rg.wait_for_leaders()
     rg.step_rounds(3)                            # compiles, untraced
     walls = rg.metrics.histogram("step_wall_ms").count

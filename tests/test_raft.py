@@ -213,8 +213,13 @@ async def test_session_expiry_fans_out():
         # Kill keep-alives without a graceful unregister.
         client._keepalive.cancel()
         client._session.state = "expired"  # stop client-side submissions
-        await asyncio.sleep(3.0)
-        leader = cluster.leader
+        # the 0.8 s expiry is what is under test; the wait for it is not
+        for _ in range(100):
+            leader = cluster.leader
+            if leader is not None and \
+                    session_id in leader.state_machine.closed_sessions:
+                break
+            await asyncio.sleep(0.05)
         assert session_id in leader.state_machine.expired_sessions
         assert session_id in leader.state_machine.closed_sessions
         assert session_id not in leader.sessions

@@ -30,6 +30,8 @@ from raft_fixtures import (
     server_fingerprint,
 )
 
+from engines import SERVED
+
 LEVELS = [StorageLevel.MAPPED, StorageLevel.DISK]
 
 
@@ -444,7 +446,6 @@ def test_manager_tpu_snapshot_restores_device_values(tmp_path, monkeypatch):
     from copycat_tpu.atomic import DistributedAtomicValue
     from copycat_tpu.io.local import LocalServerRegistry
     from copycat_tpu.manager.atomix import AtomixClient, AtomixServer
-    from copycat_tpu.manager.device_executor import DeviceEngineConfig
 
     from raft_fixtures import next_ports
 
@@ -460,7 +461,7 @@ def test_manager_tpu_snapshot_restores_device_values(tmp_path, monkeypatch):
                 storage=_storage(StorageLevel.DISK, d),
                 election_timeout=0.2, heartbeat_interval=0.04,
                 session_timeout=10.0, executor="tpu",
-                engine_config=DeviceEngineConfig(capacity=4))
+                engine_config=SERVED)
 
         server = build_server()
         await server.open()
@@ -520,7 +521,6 @@ def test_manager_tpu_snapshot_restores_device_map_and_set(tmp_path,
     from copycat_tpu.collections import DistributedMap, DistributedSet
     from copycat_tpu.io.local import LocalServerRegistry
     from copycat_tpu.manager.atomix import AtomixClient, AtomixServer
-    from copycat_tpu.manager.device_executor import DeviceEngineConfig
 
     from raft_fixtures import next_ports
 
@@ -548,7 +548,7 @@ def test_manager_tpu_snapshot_restores_device_map_and_set(tmp_path,
                 storage=_storage(StorageLevel.DISK, d),
                 election_timeout=0.2, heartbeat_interval=0.04,
                 session_timeout=10.0, executor="tpu",
-                engine_config=DeviceEngineConfig(capacity=4))
+                engine_config=SERVED)
 
         server = build_server()
         await server.open()

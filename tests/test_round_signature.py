@@ -8,13 +8,12 @@ state equal, leaf for leaf, what the plain ``jax.jit(step)`` /
 ``query_step`` / ``install_snapshots`` give on the same inputs and keys.
 """
 
-from functools import partial
+from functools import lru_cache, partial
 
 import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
-from jax.sharding import Mesh  # noqa: E402
 
 from copycat_tpu.models import checkpoint  # noqa: E402
 from copycat_tpu.models.bulk import BulkDriver  # noqa: E402
@@ -28,8 +27,19 @@ from copycat_tpu.ops.consensus import (  # noqa: E402
     query_step,
     step,
 )
+from copycat_tpu.parallel import make_mesh  # noqa: E402
 
-G, P, L, S = 16, 3, 16, 4
+from engines import G, five_peer, short_ring  # noqa: E402
+
+P, L, S = 3, 16, 4  # engines.short_ring's: the cut of schedule() wraps it
+
+
+@lru_cache(maxsize=None)
+def plain_programs(config: Config) -> tuple:
+    """Traced once a module: a fresh ``jax.jit`` a test lowers again."""
+    return (jax.jit(partial(step, config=config)),
+            jax.jit(partial(query_step, config=config)),
+            jax.jit(partial(install_snapshots, config=config)))
 
 
 class Plain:
@@ -39,9 +49,7 @@ class Plain:
     def __init__(self, seed: int, config: Config = Config()) -> None:
         self.key, init_key = jax.random.split(jax.random.PRNGKey(seed))
         self.state = init_state(G, P, L, init_key, config)
-        self.step = jax.jit(partial(step, config=config))
-        self.query = jax.jit(partial(query_step, config=config))
-        self.install = jax.jit(partial(install_snapshots, config=config))
+        self.step, self.query, self.install = plain_programs(config)
         self.installs = 0
 
     def round(self, submits: Submits, deliver, key=None):
@@ -122,7 +130,7 @@ def drive(rg: RaftGroups, ref: Plain, seed: int, rounds: int) -> None:
 
 @pytest.mark.parametrize("seed", [0, 1, 2147483725])
 def test_step_round_equals_the_plain_step(seed):
-    rg, ref = RaftGroups(G, P, L, S, seed=seed), Plain(seed)
+    rg, ref = short_ring(seed=seed), Plain(seed)
     drive(rg, ref, seed, rounds=48)
     # the schedule reached the snapshot install, which donates too
     assert ref.installs > 0
@@ -144,12 +152,12 @@ def test_step_round_equals_the_plain_step(seed):
 
 
 def test_step_round_equals_the_plain_step_on_a_mesh():
-    if len(jax.devices()) < 4:
-        pytest.skip("needs 4 virtual CPU devices (conftest)")
-    mesh = Mesh(np.asarray(jax.devices()[:4]), ("groups",))
-    rg, ref = RaftGroups(G, P, L, S, seed=5, mesh=mesh), Plain(5)
+    if len(jax.devices()) < 8:
+        pytest.skip("needs the 8 virtual CPU devices (conftest)")
+    mesh = make_mesh(groups=8)
+    rg, ref = short_ring(seed=5, mesh=mesh), Plain(5)
     drive(rg, ref, 5, rounds=24)
-    assert len(rg.state.term.devices()) == 4
+    assert len(rg.state.term.devices()) == 8
     assert "groups" in str(rg.state.log_term.sharding.spec)
 
 
@@ -158,7 +166,7 @@ def test_step_rounds_equals_n_plain_steps(n):
     """The fused program: round 0 carries the queue's submits, the rest
     run empty, under the keys ``split(k, n)`` of one carried split."""
     seed = 7
-    rg, ref = RaftGroups(G, P, L, S, seed=seed), Plain(seed)
+    rg, ref = short_ring(seed=seed), Plain(seed)
     full = np.ones((G, P, P), bool)
     empty = rg._empty_submits()
     for _ in range(12):  # elect
@@ -193,7 +201,7 @@ def live(tree) -> list[bool]:
 
 @pytest.fixture
 def rg():
-    groups = RaftGroups(G, P, L, S, seed=11)
+    groups = short_ring(seed=11)
     groups.wait_for_leaders()
     return groups
 
@@ -211,7 +219,7 @@ def test_a_round_donates_the_state_and_the_key(rg):
 
 
 def test_a_fresh_state_holds_no_buffer_twice():
-    state = RaftGroups(G, P, L, S).state
+    state = short_ring().state
     pointers = [x.unsafe_buffer_pointer() for x in jax.tree.leaves(state)
                 if x.size]
     assert len(set(pointers)) == len(pointers)
@@ -231,8 +239,7 @@ def test_checkpoint_restore_then_a_round(rg, tmp_path):
 
 
 def test_voting_members_between_rounds():
-    rg = RaftGroups(G, 5, L, S, seed=3, voters=3,
-                    config=Config(dynamic_membership=True))
+    rg = five_peer(Config(dynamic_membership=True), seed=3, voters=3)
     rg.wait_for_leaders()
     assert rg.voting_members(0) == [0, 1, 2]
     rg.run_until([rg.add_peer(0, 3)])

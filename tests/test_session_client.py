@@ -13,16 +13,16 @@ import pytest
 
 jax = pytest.importorskip("jax")
 
-from copycat_tpu.models import BulkSessionClient, RaftGroups  # noqa: E402
+from copycat_tpu.models import BulkSessionClient  # noqa: E402
 from copycat_tpu.models.sessions import SessionExpiredError  # noqa: E402
 from copycat_tpu.ops import apply as ap  # noqa: E402
-from copycat_tpu.ops.consensus import Config  # noqa: E402
+
+from engines import MONOTONE, device_plane  # noqa: E402
 
 
 @pytest.fixture(scope="module")
 def deep_rg():
-    rg = RaftGroups(8, 3, log_slots=32, submit_slots=4, seed=11,
-                    config=Config(monotone_tag_accept=True))
+    rg = device_plane(MONOTONE, seed=11)
     rg.wait_for_leaders()
     return rg
 
@@ -173,8 +173,7 @@ def test_edge_cache_purged_on_abandoned_flush(monkeypatch):
 
 def test_edge_cache_knob_off(monkeypatch):
     monkeypatch.setenv("COPYCAT_EDGE_READS", "0")
-    rg = RaftGroups(4, 3, log_slots=32, submit_slots=4, seed=12,
-                    config=Config(monotone_tag_accept=True))
+    rg = device_plane(MONOTONE, seed=12)
     rg.wait_for_leaders()
     c = BulkSessionClient(rg)
     assert c._edge is None
@@ -240,7 +239,7 @@ def test_graceful_close_releases_lock(deep_rg, client):
 def test_classic_engine_compat():
     """The same client contract runs on a CLASSIC engine (no monotone
     gate): drive is the classic bulk path, cleanup rides the queue."""
-    rg = RaftGroups(4, 3, log_slots=32, submit_slots=4, seed=3)
+    rg = device_plane(seed=3)
     rg.wait_for_leaders()
     client = BulkSessionClient(rg)
     s = client.open_session()
@@ -286,8 +285,7 @@ def test_abandoned_flush_indeterminate_then_recover():
 
     from copycat_tpu.models.session_client import CommandIndeterminateError
 
-    rg = RaftGroups(4, 3, log_slots=32, submit_slots=4, seed=21,
-                    config=Config(monotone_tag_accept=True))
+    rg = device_plane(MONOTONE, seed=21)
     rg.wait_for_leaders()
     client = BulkSessionClient(rg)
     s = client.open_session()
@@ -296,7 +294,7 @@ def test_abandoned_flush_indeterminate_then_recover():
     assert s.result(base) == 1
 
     # cut ALL delivery: nothing can commit; the drive must lose liveness
-    rg.deliver = jnp.zeros((4, 3, 3), dtype=bool)
+    rg.deliver = jnp.zeros_like(rg.deliver)
     seqs = s.submit_batch([0] * 4, ap.OP_LONG_ADD, 1)
     with pytest.raises(TimeoutError):
         client.flush(max_rounds=40)
@@ -304,7 +302,7 @@ def test_abandoned_flush_indeterminate_then_recover():
         s.result(int(seqs[0]))
 
     # heal + recover, then the session keeps working with fresh seqs
-    rg.deliver = jnp.ones((4, 3, 3), dtype=bool)
+    rg.deliver = jnp.ones_like(rg.deliver)
     client.recover()
     q = s.submit(0, ap.OP_VALUE_GET)
     client.flush()

@@ -443,14 +443,16 @@ def test_single_plane_differential_under_nemesis_strict(monkeypatch):
             old_leader = next(s for s in servers if s.role == LEADER)
             majority = [s.address for s in servers if s is not old_leader]
             nem.partition([old_leader.address], majority)
-            # wait for the majority to elect before registering: a
-            # follower still hinting the OLD leader would route the
-            # register to an uncommittable append (clients bypass the
-            # partition by design), burning a whole per-try timeout
+            # wait for the majority to elect, and for its follower to
+            # have heard of it, before registering: a follower still
+            # hinting the OLD leader would route the register to an
+            # uncommittable append (clients bypass the partition by
+            # design), burning a whole per-try timeout (60 s, one run
+            # in a few when only the election was awaited)
             deadline = asyncio.get_running_loop().time() + 15
             while asyncio.get_running_loop().time() < deadline:
-                if any(s.role == LEADER and s is not old_leader
-                       for s in servers):
+                if all(s.leader_address in majority
+                       for s in servers if s is not old_leader):
                     break
                 await asyncio.sleep(0.05)
             assert any(s.role == LEADER and s is not old_leader

@@ -33,6 +33,8 @@ from copycat_tpu.utils.tracing import TRACER
 from helpers import async_test
 from raft_fixtures import create_cluster, next_ports
 
+from engines import SERVED
+
 EVERY = 8
 
 
@@ -202,7 +204,6 @@ async def test_image_is_the_cut_device_machine(tmp_path):
     pytest.importorskip("jax")
     from copycat_tpu.atomic import DistributedAtomicLong
     from copycat_tpu.manager.atomix import AtomixClient, AtomixServer
-    from copycat_tpu.manager.device_executor import DeviceEngineConfig
     from copycat_tpu.resource.consistency import Consistency
 
     registry = LocalServerRegistry()
@@ -214,8 +215,7 @@ async def test_image_is_the_cut_device_machine(tmp_path):
             storage=Storage(StorageLevel.DISK, str(directory)),
             election_timeout=0.2, heartbeat_interval=0.04,
             session_timeout=60.0, executor="tpu",
-            engine_config=DeviceEngineConfig(capacity=16, num_peers=3,
-                                             log_slots=32))
+            engine_config=SERVED)
 
     server = build(tmp_path / "m")
     await server.open()

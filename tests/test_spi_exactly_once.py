@@ -17,12 +17,12 @@ jax = pytest.importorskip("jax")
 from copycat_tpu.atomic import DistributedAtomicLong  # noqa: E402
 from copycat_tpu.io.local import LocalServerRegistry, LocalTransport  # noqa: E402
 from copycat_tpu.manager.atomix import AtomixClient, AtomixServer  # noqa: E402
-from copycat_tpu.manager.device_executor import DeviceEngineConfig  # noqa: E402
 
 from helpers import async_test  # noqa: E402
 from raft_fixtures import next_ports  # noqa: E402
 
-ENGINE = DeviceEngineConfig(capacity=16, num_peers=3, log_slots=32)
+from engines import SERVED  # noqa: E402
+
 
 
 @async_test(timeout=300)
@@ -32,7 +32,7 @@ async def test_acked_increments_apply_exactly_once_across_leader_kills():
     servers = [AtomixServer(a, addrs, LocalTransport(registry),
                             election_timeout=0.2, heartbeat_interval=0.04,
                             session_timeout=20.0, executor="tpu",
-                            engine_config=ENGINE) for a in addrs]
+                            engine_config=SERVED) for a in addrs]
     await asyncio.gather(*(s.open() for s in servers))
     client = AtomixClient(addrs, LocalTransport(registry),
                           session_timeout=20.0)

@@ -47,6 +47,8 @@ from copycat_tpu.testing.linearize import (
 from helpers import async_test
 from raft_fixtures import next_ports
 
+from engines import SERVED
+
 OPS_PER_CLIENT = 24
 CLIENTS = 3
 VALUE_DOMAIN = 4     # small domain so cas sometimes succeeds
@@ -147,9 +149,7 @@ async def _run_stack(executor: str, loop_fn, fault: str = "kill"
     addrs = next_ports(3)
     kwargs = {}
     if executor == "tpu":
-        from copycat_tpu.manager.device_executor import DeviceEngineConfig
-        kwargs = dict(engine_config=DeviceEngineConfig(
-            capacity=8, num_peers=3, log_slots=32))
+        kwargs = dict(engine_config=SERVED)
     servers = [
         AtomixServer(a, addrs, LocalTransport(registry, local_address=a),
                      election_timeout=0.2, heartbeat_interval=0.04,

@@ -29,15 +29,14 @@ from copycat_tpu.atomic import DistributedAtomicValue  # noqa: E402
 from copycat_tpu.io.local import (  # noqa: E402
     LocalServerRegistry, LocalTransport)
 from copycat_tpu.manager.atomix import AtomixClient, AtomixServer  # noqa: E402
-from copycat_tpu.manager.device_executor import DeviceEngineConfig  # noqa: E402
-from copycat_tpu.models import RaftGroups  # noqa: E402
 from copycat_tpu.ops import apply as ap  # noqa: E402
 from copycat_tpu.resource.consistency import Consistency  # noqa: E402
 
 from helpers import async_test  # noqa: E402
 from raft_fixtures import next_ports  # noqa: E402
 
-ENGINE = DeviceEngineConfig(capacity=16, num_peers=3, log_slots=32)
+from engines import SERVED, device_plane  # noqa: E402
+
 
 
 async def _spi_cluster(registry, executor: str = "tpu"):
@@ -46,7 +45,7 @@ async def _spi_cluster(registry, executor: str = "tpu"):
     server = AtomixServer(addr, [addr], LocalTransport(registry),
                           election_timeout=0.5, heartbeat_interval=0.1,
                           session_timeout=20.0, executor=executor,
-                          engine_config=ENGINE)
+                          engine_config=SERVED)
     await server.open()
     client = AtomixClient([addr], LocalTransport(registry),
                           session_timeout=20.0)
@@ -223,7 +222,7 @@ def test_drive_query_vector_matches_per_op_serve():
     """Engine level: one vectorized query_step round returns exactly what
     per-op serve_query returns, for mixed groups and uneven per-group
     read counts (slot packing + pow2 width padding)."""
-    rg = RaftGroups(8, 3, log_slots=32, submit_slots=4, seed=3)
+    rg = device_plane(seed=3)
     rg.wait_for_leaders()
     for g in range(8):
         rg.run_until([rg.submit(g, ap.OP_LONG_ADD, g + 1)])
@@ -240,7 +239,7 @@ def test_drive_query_vector_matches_per_op_serve():
 
 
 def test_drive_query_vector_refuses_writes():
-    rg = RaftGroups(2, 3, log_slots=32, submit_slots=4, seed=4)
+    rg = device_plane(seed=4)
     rg.wait_for_leaders()
     with pytest.raises(ValueError, match="not read-only"):
         rg.drive_query_vector([0], ap.OP_LONG_ADD, 1)

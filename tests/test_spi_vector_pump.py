@@ -28,17 +28,16 @@ from copycat_tpu.atomic import DistributedAtomicLong, DistributedAtomicValue  # 
 from copycat_tpu.io.local import (  # noqa: E402
     LocalServerRegistry, LocalTransport, NetworkNemesis)
 from copycat_tpu.manager.atomix import AtomixClient, AtomixServer  # noqa: E402
-from copycat_tpu.manager.device_executor import DeviceEngineConfig  # noqa: E402
-from copycat_tpu.models import BulkSessionClient, RaftGroups  # noqa: E402
+from copycat_tpu.models import BulkSessionClient  # noqa: E402
 from copycat_tpu.models.session_client import (  # noqa: E402
     CommandIndeterminateError)
 from copycat_tpu.ops import apply as ap  # noqa: E402
-from copycat_tpu.ops.consensus import Config  # noqa: E402
 
 from helpers import async_test  # noqa: E402
 from raft_fixtures import next_ports  # noqa: E402
 
-ENGINE = DeviceEngineConfig(capacity=16, num_peers=3, log_slots=32)
+from engines import MONOTONE, SERVED, device_plane  # noqa: E402
+
 
 
 async def _spi_cluster(registry, executor: str = "tpu"):
@@ -47,7 +46,7 @@ async def _spi_cluster(registry, executor: str = "tpu"):
     server = AtomixServer(addr, [addr], LocalTransport(registry),
                           election_timeout=0.5, heartbeat_interval=0.1,
                           session_timeout=20.0, executor=executor,
-                          engine_config=ENGINE)
+                          engine_config=SERVED)
     await server.open()
     client = AtomixClient([addr], LocalTransport(registry),
                           session_timeout=20.0)
@@ -218,8 +217,7 @@ async def test_vector_pump_partition_mid_batch_no_duplicate_applies():
 
 @pytest.fixture()
 def deep_rg():
-    rg = RaftGroups(8, 3, log_slots=32, submit_slots=4, seed=13,
-                    config=Config(monotone_tag_accept=True))
+    rg = device_plane(MONOTONE, seed=13)
     rg.wait_for_leaders()
     return rg
 

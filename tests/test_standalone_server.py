@@ -56,7 +56,7 @@ async def test_packaged_server_serves_remote_client():
                     out = logf.read().decode(errors="replace")
                     pytest.fail(f"server died rc={proc.returncode}: "
                                 f"{out[-800:]}")
-                await asyncio.sleep(2)
+                await asyncio.sleep(0.25)
         else:
             pytest.fail("client never connected")
 

@@ -16,10 +16,7 @@ from copycat_tpu.models import RaftGroups  # noqa: E402
 from copycat_tpu.ops import apply as ap  # noqa: E402
 from copycat_tpu.ops.consensus import LEADER, Config  # noqa: E402
 
-
-def make(groups=4, peers=3, **kw):
-    kw.setdefault("log_slots", 32)
-    return RaftGroups(groups, peers, **kw)
+from engines import device_plane, short_ring  # noqa: E402
 
 
 class LeaderLedger:
@@ -38,7 +35,7 @@ class LeaderLedger:
 
 
 def test_every_group_elects_one_leader():
-    rg = make(groups=8, peers=3)
+    rg = device_plane()
     ledger = LeaderLedger()
     leaders = None
     for _ in range(100):
@@ -54,7 +51,7 @@ def test_every_group_elects_one_leader():
 
 
 def test_counter_ops_commit_and_replicate():
-    rg = make(groups=2, peers=3)
+    rg = device_plane()
     rg.wait_for_leaders()
     tags = [rg.submit(0, ap.OP_LONG_ADD, 1) for _ in range(10)]
     tags += [rg.submit(1, ap.OP_LONG_ADD, 5) for _ in range(4)]
@@ -72,7 +69,7 @@ def test_counter_ops_commit_and_replicate():
 
 
 def test_value_set_cas_get_semantics():
-    rg = make(groups=1, peers=3)
+    rg = device_plane()
     rg.wait_for_leaders()
     t_set = rg.submit(0, ap.OP_VALUE_SET, 5)
     t_cas_hit = rg.submit(0, ap.OP_VALUE_CAS, 5, 7)
@@ -87,7 +84,7 @@ def test_value_set_cas_get_semantics():
 
 
 def test_leader_partition_failover_preserves_committed_writes():
-    rg = make(groups=1, peers=3, log_slots=32)
+    rg = device_plane()
     ledger = LeaderLedger()
     rg.wait_for_leaders()
     t1 = rg.submit(0, ap.OP_LONG_ADD, 7)
@@ -96,7 +93,7 @@ def test_leader_partition_failover_preserves_committed_writes():
     assert old_leader >= 0
 
     # Partition the leader from both followers.
-    deliver = np.ones((1, 3, 3), bool)
+    deliver = np.ones((rg.num_groups, 3, 3), bool)
     deliver[0, old_leader, :] = False
     deliver[0, :, old_leader] = False
     rg.deliver = jnp.asarray(deliver)
@@ -114,7 +111,7 @@ def test_leader_partition_failover_preserves_committed_writes():
     assert rg.results[t2] == 10
 
     # Heal; the deposed leader catches up and converges.
-    rg.deliver = jnp.ones((1, 3, 3), bool)
+    rg.deliver = jnp.ones_like(rg.deliver)
     rg.run(20)
     ledger.observe(rg)
     val = np.asarray(rg.state.resources.value)
@@ -128,7 +125,7 @@ def test_exactly_once_under_partitions():
     (entries overwritten by new leaders get re-submitted) and nothing
     double-applied (re-submission only on proof of loss)."""
     rng = np.random.default_rng(11)
-    rg = make(groups=3, peers=3, log_slots=32)
+    rg = device_plane()
     rg.wait_for_leaders()
     tags = {g: [] for g in range(3)}
     for r in range(240):
@@ -137,7 +134,8 @@ def test_exactly_once_under_partitions():
             tags[g].append(rg.submit(g, ap.OP_LONG_ADD, 1))
         deliver = None
         if 0 < (r % 24) < 10:  # partition window
-            deliver = jnp.asarray(rng.random((3, 3, 3)) > 0.3)
+            deliver = jnp.asarray(
+                rng.random((rg.num_groups, 3, 3)) > 0.3)
         rg.step_round(deliver=deliver)
     all_tags = [t for ts in tags.values() for t in ts]
     rg.run_until(all_tags, max_rounds=300)
@@ -152,7 +150,7 @@ def test_submit_batch_matches_scalar_submits():
     """The vectorized bulk-submit path must be behaviorally identical to
     per-op submits: same per-group FIFO order, same results, tags
     aligned with the input."""
-    rg = make(groups=4, peers=3)
+    rg = device_plane()
     rg.wait_for_leaders()
     groups = np.array([0, 0, 1, 2, 3, 3, 3])
     deltas = np.array([1, 2, 10, 5, 7, 1, 2])
@@ -178,7 +176,8 @@ def test_checkquorum_releases_asymmetric_partition():
     forever at 2 < 3 acks. CheckQuorum steps the quorumless leader down
     after an election timeout, heartbeats stop, and the fully-connected
     majority elects a working leader."""
-    rg = make(groups=1, peers=4, log_slots=32)
+    # shape: four peers, a leader that reaches one of its three followers
+    rg = RaftGroups(1, 4, log_slots=32)
     rg.wait_for_leaders()
     lead = rg.leader(0)
     others = [p for p in range(4) if p != lead]
@@ -195,10 +194,9 @@ def test_checkquorum_releases_asymmetric_partition():
 
 
 def test_safety_under_random_partitions():
-    G, P = 4, 3
-    rg = make(groups=G, peers=P, log_slots=64,
-              config=Config(append_window=4, applies_per_round=4,
+    rg = device_plane(config=Config(append_window=4, applies_per_round=4,
                             timer_min=4, timer_max=9))
+    G, P = rg.num_groups, rg.num_peers
     ledger = LeaderLedger()
     rng = np.random.default_rng(7)
     submitted = {g: [] for g in range(G)}
@@ -248,14 +246,14 @@ def test_safety_under_random_partitions():
 def test_stale_follower_caught_up_by_snapshot_install():
     """A follower partitioned past the ring window reconverges via
     host-side snapshot install (``install_snapshots``)."""
-    L = 8
-    rg = make(groups=1, peers=3, log_slots=L)
+    rg = short_ring()
+    L = rg.log_slots
     rg.wait_for_leaders()
     leader = rg.leader(0)
     follower = next(p for p in range(3) if p != leader)
 
     # Fully isolate one follower; quorum of 2 keeps committing far past L.
-    deliver = np.ones((1, 3, 3), bool)
+    deliver = np.ones((rg.num_groups, 3, 3), bool)
     deliver[0, :, follower] = False
     deliver[0, follower, :] = False
     rg.deliver = jnp.asarray(deliver)
@@ -268,7 +266,7 @@ def test_stale_follower_caught_up_by_snapshot_install():
 
     # Heal: AppendEntries can no longer serve the follower (beyond the ring);
     # the stale flag must trigger snapshot install and full reconvergence.
-    rg.deliver = jnp.ones((1, 3, 3), bool)
+    rg.deliver = jnp.ones_like(rg.deliver)
     rg.run(30)
     val = np.asarray(rg.state.resources.value)
     applied = np.asarray(rg.state.applied_index)
@@ -277,7 +275,7 @@ def test_stale_follower_caught_up_by_snapshot_install():
 
 
 def test_single_peer_group_commits_immediately():
-    rg = make(groups=1, peers=1)
+    rg = RaftGroups(1, 1, log_slots=32)  # shape: a group of one peer
     rg.wait_for_leaders()
     t = rg.submit(0, ap.OP_LONG_ADD, 9)
     rg.run_until([t], max_rounds=20)
@@ -290,9 +288,10 @@ def test_sharded_over_mesh(mesh_kind):
 
     if mesh_kind == "groups":
         mesh = make_mesh(groups=8)
-        rg = RaftGroups(16, 3, log_slots=16, mesh=mesh)
+        rg = short_ring(mesh=mesh)
     else:
         mesh = make_mesh(groups=2, peers=4)
+        # shape: four peer lanes, a multiple of the mesh's peers axis
         rg = RaftGroups(8, 4, log_slots=16, mesh=mesh)
     rg.wait_for_leaders()
     tags = [rg.submit(g, ap.OP_LONG_ADD, g + 1) for g in range(4)]
@@ -304,7 +303,7 @@ def test_sharded_over_mesh(mesh_kind):
 def test_out_latency_tracks_append_to_apply_lag():
     """out_latency = rounds an entry waited in the log before apply (0 when
     the synchronous round replicates+commits+applies it immediately)."""
-    rg = make(groups=2, peers=3)
+    rg = device_plane()
     rg.wait_for_leaders()
     tags = [rg.submit(0, ap.OP_LONG_ADD, 1) for _ in range(3)]
     lats = []
@@ -325,11 +324,7 @@ def test_leader_lease_tracks_quorum_contact():
     one round of the leader losing contact with a quorum — the
     falsifiable core of the BOUNDED_LINEARIZABLE read gate (a served
     atomic read relies on exactly this bit)."""
-    import numpy as np
-
-    from copycat_tpu.models.raft_groups import RaftGroups
-
-    rg = RaftGroups(4, 3, log_slots=32, seed=2)
+    rg = device_plane(seed=2)
     leaders = rg.wait_for_leaders()
     rg.run(2)
     assert bool(np.asarray(rg.state.lease).any(axis=1).all()), \
@@ -338,19 +333,19 @@ def test_leader_lease_tracks_quorum_contact():
     # isolate group 0's leader from BOTH followers: next round it cannot
     # assemble a quorum of acks, so its lease must drop (groups 1..3 keep
     # theirs)
-    deliver = np.ones((4, 3, 3), bool)
+    deliver = np.ones((rg.num_groups, 3, 3), bool)
     lead0 = int(leaders[0])
     deliver[0, lead0, :] = False
     deliver[0, :, lead0] = False
     deliver[0, lead0, lead0] = True
-    rg.deliver = __import__("jax").numpy.asarray(deliver)
+    rg.deliver = jnp.asarray(deliver)
     rg.run(1)
     lease = np.asarray(rg.state.lease).any(axis=1)
     assert not lease[0], "isolated leader must lose the lease immediately"
     assert lease[1:].all(), "connected groups keep their leases"
 
     # heal: the lease returns once a quorum acks again
-    rg.deliver = __import__("jax").numpy.asarray(np.ones((4, 3, 3), bool))
+    rg.deliver = jnp.ones_like(rg.deliver)
     rg.run(3)
     assert np.asarray(rg.state.lease).any(axis=1).all()
 
@@ -362,13 +357,13 @@ def test_step_rounds_fused_matches_single_steps_and_installs_stale():
     reconverge the same way it does under single-round stepping
     (round-5 review finding: the stale slice-and-install path had no
     coverage)."""
-    L = 8
-    rg = make(groups=2, peers=3, log_slots=L)
+    rg = short_ring()
+    L = rg.log_slots
     rg.wait_for_leaders()
     leader = rg.leader(0)
     follower = next(p for p in range(3) if p != leader)
 
-    deliver = np.ones((2, 3, 3), bool)
+    deliver = np.ones((rg.num_groups, 3, 3), bool)
     deliver[0, :, follower] = False
     deliver[0, follower, :] = False
     rg.deliver = jnp.asarray(deliver)
@@ -382,7 +377,7 @@ def test_step_rounds_fused_matches_single_steps_and_installs_stale():
 
     # heal; the isolated follower is beyond AppendEntries range, so the
     # fused path's stale branch must snapshot-install it
-    rg.deliver = jnp.ones((2, 3, 3), bool)
+    rg.deliver = jnp.ones_like(rg.deliver)
     for _ in range(8):
         rg.step_rounds(4)
     val = np.asarray(rg.state.resources.value)
