@@ -19,7 +19,7 @@ import logging
 import mmap
 import os
 import zlib
-from typing import Any, Iterator
+from typing import Any, Iterable, Iterator
 
 from ..io.buffer import BufferInput, BufferOutput
 from ..io.serializer import Serializer, serialize_with
@@ -30,7 +30,7 @@ from ..utils.metrics import Counter
 class StorageLevel(enum.Enum):
     MEMORY = "memory"
     MAPPED = "mapped"  # mmap-backed segments (page-cache writes, no syscalls)
-    DISK = "disk"      # buffered files, flushed (not fsynced) per append
+    DISK = "disk"      # buffered files, flushed (not fsynced) per append call
 
 
 #: Valid ``Storage.fsync`` policies (docs/DURABILITY.md):
@@ -345,10 +345,12 @@ class Log:
         self._mapped: _MappedSegment | None = None  # MAPPED: mmap segment
         self._segment_count = 0
         # what the deployment's accounting reads (docs/OBSERVABILITY.md):
-        # calls of sync() and frame bytes written since open (recovery's
+        # calls of sync(), calls of write on a segment file (frame copies
+        # into a mapping) and frame bytes written since open (recovery's
         # replay appends nothing). The owning group swaps in counters of
         # its registry, so the tracer's window report sees them.
         self.syncs = Counter()
+        self.writes = Counter()
         self.bytes_appended = Counter()
         # DISK: (path, length) of the newest segment as of the last
         # fsync — what a power loss is promised to leave of it
@@ -402,7 +404,7 @@ class Log:
         self._entries.append(entry)
         self._note_term(entry.index, entry.term)
         if self._segment_dir is not None:
-            self._persist(entry)
+            self._persist_block((entry,))
         return entry.index
 
     def append_block(self, entries: list[Entry]) -> int:
@@ -418,8 +420,7 @@ class Log:
             store.append(entry)
         self._note_term(entries[0].index, entries[0].term)
         if self._segment_dir is not None:
-            for entry in entries:
-                self._persist(entry)
+            self._persist_block(entries)
         return index
 
     def append_replicated(self, entry: Entry) -> None:
@@ -432,7 +433,7 @@ class Log:
         self._entries.append(entry)
         self._note_term(entry.index, entry.term)
         if self._segment_dir is not None:
-            self._persist(entry)
+            self._persist_block((entry,))
 
     def append_replicated_block(self, entries: list[Entry]) -> None:
         """Append a run of replicated entries past ``last_index`` in one
@@ -461,8 +462,7 @@ class Log:
                 self._note_term(entry.index, entry.term)
                 term = entry.term
         if self._segment_dir is not None:
-            for entry in entries:
-                self._persist(entry)
+            self._persist_block(entries)
 
     def fill_gap(self, to_index: int) -> None:
         """Extend the log with empty (compacted-elsewhere) slots up to to_index."""
@@ -475,7 +475,7 @@ class Log:
         if 0 <= slot < len(self._entries) and self._entries[slot] is None:
             self._entries[slot] = entry
             if self._segment_dir is not None:
-                self._persist(entry)
+                self._persist_block((entry,))
 
     def get(self, index: int) -> Entry | None:
         if index < self._offset or index > self.last_index:
@@ -655,53 +655,99 @@ class Log:
         ext = "mseg" if self._storage.level is StorageLevel.MAPPED else "seg"
         return os.path.join(self._segment_dir, f"{self._name}-{index}.{ext}")
 
-    def _persist(self, entry: Entry) -> None:
-        data = self._serializer.write(entry)
+    def _persist_block(self, entries: Iterable[Entry]) -> None:
+        """The one segment writer: frame a run of entries and hand the
+        segment file each stretch of it that lands in one segment ONCE —
+        one ``write`` and one ``flush`` a stretch, where an entry at a
+        time paid a system call for 48 bytes. The files are byte for byte
+        what entry-by-entry appends leave: a roll still happens at the
+        entry that finds the segment full, the closed segment is fsynced
+        and the new one named by that entry's index, and everything is
+        flushed to the kernel before this returns, so ``sync()`` (which
+        no caller moved) finds the bytes it always found.
+
+        What it reads instead of a knob: ``fsync="always"`` promises an
+        fsync an entry, so there a stretch is one entry; MAPPED copies a
+        frame into the mapping with no system call and keeps its
+        frame-by-frame copy."""
+        write = self._serializer.write
         if self._storage.level is StorageLevel.MAPPED:
-            roll = (self._mapped is None
-                    or self._segment_count >= self._storage.max_entries_per_segment)
-            if not roll and not self._mapped.append(data):
-                roll = True  # full: close and start a segment that fits
-            if roll:
-                if self._mapped is not None:
-                    self._mapped.close()  # close() msyncs: rolls are durable
-                self._mapped = _MappedSegment(
-                    self._segment_path(entry.index),
-                    max(self.MAPPED_SEGMENT_BYTES,
-                        _MappedSegment.FRAME_HEADER + len(data)))
-                self._segment_count = 0
-                if not self._mapped.append(data):
-                    raise AssertionError("fresh mapped segment rejected frame")
-            self._segment_count += 1
-            self.bytes_appended.inc(_MappedSegment.FRAME_HEADER + len(data))
-            if self._storage.fsync == "always":
-                self._mapped.flush()
+            for entry in entries:
+                self._map_frame(entry.index, write(entry))
             return
-        # [varint len][payload][varint crc32(payload, seed)]: the trailing
-        # seeded CRC catches torn frames whose LENGTH survived — without
-        # it, a zeroed/garbled payload tail can deserialize into a
-        # plausible-but-wrong entry and silently corrupt the state
-        # machine on replay (found by the partial_frame nemesis).
-        frame = (BufferOutput().write_bytes(data)
-                 .write_varint(zlib.crc32(data, _MappedSegment.CRC_SEED))
-                 .to_bytes())
-        if self._segment_file is None or self._segment_count >= self._storage.max_entries_per_segment:
-            if self._segment_file is not None:
-                if self._storage.fsync != "never":
-                    # segment-roll boundary: the closed segment is durable
-                    self._segment_file.flush()
-                    os.fsync(self._segment_file.fileno())
-                self._segment_file.close()
-            self._segment_file = open(self._segment_path(entry.index), "ab")
-            self._segment_count = 0
-            if self._storage.fsync != "never":
-                self._synced = (self._segment_file.name, 0)
-        self._segment_file.write(frame)
+        limit = self._storage.max_entries_per_segment
+        each = self._storage.fsync == "always"
+        stretch, count = BufferOutput(), 0
+        for entry in entries:
+            data = write(entry)
+            if (self._segment_file is None
+                    or self._segment_count + count >= limit):
+                self._write_stretch(stretch, count)
+                stretch, count = BufferOutput(), 0
+                self._roll_segment(entry.index)
+            # [varint len][payload][varint crc32(payload, seed)]: the trailing
+            # seeded CRC catches torn frames whose LENGTH survived — without
+            # it, a zeroed/garbled payload tail can deserialize into a
+            # plausible-but-wrong entry and silently corrupt the state
+            # machine on replay (found by the partial_frame nemesis).
+            stretch.write_bytes(data).write_varint(
+                zlib.crc32(data, _MappedSegment.CRC_SEED))
+            count += 1
+            if each:
+                self._write_stretch(stretch, count)
+                stretch, count = BufferOutput(), 0
+        self._write_stretch(stretch, count)
+
+    def _write_stretch(self, stretch: BufferOutput, count: int) -> None:
+        """``count`` frames to the open DISK segment in one write, flushed
+        to the kernel; fsynced only where ``fsync="always"`` promises it."""
+        if not count:
+            return
+        frames = stretch.to_bytes()
+        self._segment_file.write(frames)
         self._segment_file.flush()
-        self.bytes_appended.inc(len(frame))
+        self.writes.inc()
+        self.bytes_appended.inc(len(frames))
         if self._storage.fsync == "always":
             self._fsync_segment()
+        self._segment_count += count
+
+    def _roll_segment(self, index: int) -> None:
+        """Close the DISK segment (durably, unless ``fsync="never"``) and
+        open the one that the entry at ``index`` starts."""
+        if self._segment_file is not None:
+            if self._storage.fsync != "never":
+                # segment-roll boundary: the closed segment is durable
+                self._segment_file.flush()
+                os.fsync(self._segment_file.fileno())
+            self._segment_file.close()
+        self._segment_file = open(self._segment_path(index), "ab")
+        self._segment_count = 0
+        if self._storage.fsync != "never":
+            self._synced = (self._segment_file.name, 0)
+
+    def _map_frame(self, index: int, data: bytes) -> None:
+        """MAPPED: copy one frame into the mapping, rolling to a segment
+        named by ``index`` when this one is full."""
+        roll = (self._mapped is None
+                or self._segment_count >= self._storage.max_entries_per_segment)
+        if not roll and not self._mapped.append(data):
+            roll = True  # full: close and start a segment that fits
+        if roll:
+            if self._mapped is not None:
+                self._mapped.close()  # close() msyncs: rolls are durable
+            self._mapped = _MappedSegment(
+                self._segment_path(index),
+                max(self.MAPPED_SEGMENT_BYTES,
+                    _MappedSegment.FRAME_HEADER + len(data)))
+            self._segment_count = 0
+            if not self._mapped.append(data):
+                raise AssertionError("fresh mapped segment rejected frame")
         self._segment_count += 1
+        self.writes.inc()
+        self.bytes_appended.inc(_MappedSegment.FRAME_HEADER + len(data))
+        if self._storage.fsync == "always":
+            self._mapped.flush()
 
     def _persist_truncate(self, from_index: int) -> None:
         # Truncation is rare (follower conflict resolution): rewrite all
@@ -711,9 +757,7 @@ class Log:
             if fname.startswith(f"{self._name}-") and fname.endswith((".seg", ".mseg")):
                 os.remove(os.path.join(self._segment_dir, fname))
         self._segment_count = 0
-        for entry in self._entries:
-            if entry is not None:
-                self._persist(entry)
+        self._persist_block(e for e in self._entries if e is not None)
 
     @property
     def _prefix_path(self) -> str:

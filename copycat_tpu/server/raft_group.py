@@ -388,8 +388,10 @@ class RaftGroup:
         self._m_repl_inflight_windows = m.gauge("repl.windows_inflight")
         self._m_repl_inflight_entries = m.gauge("repl.entries_inflight")
         # the log's own accounting, on this registry so the tracer's
-        # window report reads it (group.log.syncs, group.log.bytes_appended)
+        # window report reads it (group.log.syncs, group.log.writes,
+        # group.log.bytes_appended)
         self.log.syncs = m.counter("log.syncs")
+        self.log.writes = m.counter("log.writes")
         self.log.bytes_appended = m.counter("log.bytes_appended")
         self._m_snap_taken = m.counter("snap.snapshots_taken")
         self._m_snap_bytes = m.counter("snap.snapshot_bytes")
