@@ -7,6 +7,7 @@ the root ``BENCHMARK.json`` names for the plane resolves. Sizes come from
 from here is a device number.
 """
 
+import functools
 import importlib.util
 import json
 import os
@@ -19,12 +20,18 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 BENCH = os.path.join(REPO, "benchmarks")
 DATA = os.path.join(REPO, "tests", "benchmark", "data_cluster")
 TINY, CELL = "cluster-tiny.write-tiny", "cluster-3x1k.write"
+#: the cell's metrics that this file holds, in the root file's order: PR 27's
+#: twelve, and the four that read what PR 28, 30 and 32 record (PR 34). A
+#: metric on the cell that is not named here is a later PR's and brings a
+#: test and a tiny data directory of its own
 NEW = ["cluster.ack_p50_ms", "cluster.quorum_wait_ms", "cluster.fsync_ms",
        "cluster.follower_append_ms", "cluster.snapshot_ms",
        "cluster.fsyncs_per_kop", "cluster.log_bytes_per_op",
        "cluster.repl_windows_per_kop", "cluster.snapshots_per_kop",
        "cluster.apply_ms", "cluster.rounds_per_kop",
-       "device.idle_share.cluster"]
+       "device.idle_share.cluster", "cluster.snapshot_finish_ms",
+       "cluster.captures_deferred_per_kop",
+       "cluster.codec_python_bodies_per_kop", "cluster.log_writes_per_kop"]
 #: spans of the block lane only: a turn whose commands were staged one by
 #: one records the coarse ``group.commit`` instead, and eight clients fall
 #: into either lane from run to run
@@ -42,9 +49,14 @@ def load(path, name):
     return module
 
 
+@functools.cache
+def run_py():
+    return load(os.path.join(BENCH, "run.py"), "benchmark_run_cluster")
+
+
 @pytest.fixture(scope="module")
 def harness():
-    return load(os.path.join(BENCH, "run.py"), "benchmark_run_cluster")
+    return run_py()
 
 
 @pytest.fixture(scope="module")
@@ -106,7 +118,8 @@ def test_a_traced_run_prints_the_cells_metrics(harness, tiny, capsys):
     line, checks, out = drive(harness, capsys, trace=True)
     assert line["correct"] is True and set(checks.values()) == {0}, out
     wanted = {m["name"]: m for m in harness.metrics_of(tiny, "per_layer",
-                                                       TINY)}
+                                                       TINY)
+              if m["name"] in NEW}
     assert list(wanted) == NEW
     missing = set(wanted) - set(line["metrics"])
     assert missing <= BLOCK_LANE, missing
@@ -118,6 +131,13 @@ def test_a_traced_run_prints_the_cells_metrics(harness, tiny, capsys):
     assert got["cluster.fsyncs_per_kop"] > 0 < got["cluster.rounds_per_kop"]
     # an entry of this mix takes 60 to 70 bytes in each of three logs
     assert 150 < got["cluster.log_bytes_per_op"] < 300
+    # what PR 28, 30 and 32 record: a finish on the worker for every cut,
+    # none due in flight or some (0 is a reading), entries through the C
+    # walk and blocks in one write
+    assert got["cluster.snapshot_finish_ms"] > 0
+    assert "cluster.captures_deferred_per_kop" in got
+    assert got["cluster.codec_python_bodies_per_kop"] > 0
+    assert 0 < got["cluster.log_writes_per_kop"] < 3000
     assert "snapshot.fetch x" in out and "snapshot.write x" in out
 
 
@@ -252,7 +272,8 @@ def test_a_program_without_the_synced_length_fails_at_once(plane,
 
 # -- what the root BENCHMARK.json names for the plane -----------------------
 
-def test_the_cell_its_configuration_and_its_traffic_resolve(bench, harness):
+def holds_the_cell_its_configuration_and_its_traffic(bench, root):
+    here = os.path.join(root, "benchmarks")
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "cluster-3x1k", "cluster-write", 1)
@@ -261,9 +282,11 @@ def test_the_cell_its_configuration_and_its_traffic_resolve(bench, harness):
     # the source's own words
     assert baseline["configs"][0] in entry["source"]
     assert entry["reduced"] == ["hosts"]
-    _, config, traffic = harness.load_cell(bench, CELL, BENCH)
-    served = json.load(open(os.path.join(BENCH, "configs", "served-1k.json")))
-    write = json.load(open(os.path.join(BENCH, "traffic", "write.json")))
+    config = json.load(open(os.path.join(root, entry["file"])))
+    traffic = json.load(open(os.path.join(
+        here, "traffic", cell["traffic"] + ".json")))
+    served = json.load(open(os.path.join(here, "configs", "served-1k.json")))
+    write = json.load(open(os.path.join(here, "traffic", "write.json")))
     # served-1k's resources, engine and timeouts; three members, a disk, a wire
     same = ("resources", "capacity", "peers", "counters", "maps", "locks",
             "elections", "election_timeout_s", "heartbeat_interval_s",
@@ -283,19 +306,39 @@ def test_the_cell_its_configuration_and_its_traffic_resolve(bench, harness):
            "warmup_quiet_s", "generator")
     assert all(traffic[k] == write[k] for k in mix)
     assert traffic["plane"] == "cluster"
+    return cell, config, traffic
 
 
-def test_the_cells_metrics_are_the_tiny_cells_metrics(bench, tiny, harness):
-    assert {m["name"] for m in harness.metrics_of(
+def holds_the_cells_metrics_to_the_tiny_cells(bench, root):
+    """The entries named in ``NEW`` and nothing of the rest: what follows
+    them in the root file, and a metric on the cell that ``NEW`` does not
+    name, is a later PR's."""
+    tiny = json.load(open(os.path.join(
+        root, "tests", "benchmark", "data_cluster", "BENCHMARK.json")))
+    assert {m["name"] for m in run_py().metrics_of(
         bench, "end_to_end", CELL)} == {"served_ops_per_s", "ack_p99_ms",
                                         "setup_s"}
     keys = ("name", "unit", "better", "source", "layer", "moves")
-    real = [{k: m[k] for k in keys}
-            for m in harness.metrics_of(bench, "per_layer", CELL)]
-    rehearsed = [{k: m[k] for k in keys}
-                 for m in harness.metrics_of(tiny, "per_layer", TINY)]
-    assert real == rehearsed and [m["name"] for m in real] == NEW
-    # appended after the 40 that were there, read in this cell alone
-    assert [m["name"] for m in bench["per_layer"][40:]] == NEW
-    assert all(m["workloads"] == [CELL] for m in bench["per_layer"][40:])
+    real = [m for m in bench["per_layer"] if m["name"] in NEW]
+    rehearsed = {m["name"]: m for m in run_py().metrics_of(
+        tiny, "per_layer", TINY)}
+    assert [m["name"] for m in real] == NEW
+    for m in real:
+        assert m["workloads"] == [CELL], m["name"]     # this cell alone
+        assert all(m[k] == rehearsed[m["name"]][k] for k in keys), m["name"]
+    # the 40 that stood before the cell was added never read it
     assert all(CELL not in m["workloads"] for m in bench["per_layer"][:40])
+
+
+ROOT_FILE_RULES = [holds_the_cell_its_configuration_and_its_traffic,
+                   holds_the_cells_metrics_to_the_tiny_cells]
+
+
+def test_the_cell_its_configuration_and_its_traffic_resolve(bench, harness):
+    held = holds_the_cell_its_configuration_and_its_traffic(bench, REPO)
+    # and they are what a run of the cell loads
+    assert harness.load_cell(bench, CELL, BENCH) == held
+
+
+def test_the_cells_metrics_are_the_tiny_cells_metrics(bench):
+    holds_the_cells_metrics_to_the_tiny_cells(bench, REPO)

@@ -6,6 +6,8 @@ everything it names resolves. Tiny sizes come from ``tests/benchmark/data``,
 never from the cells' own files. No number from here is a device number.
 """
 
+import functools
+import glob
 import importlib.util
 import json
 import os
@@ -27,13 +29,21 @@ pytest.importorskip("jax")
 sys.path.insert(0, REPO)
 
 
-@pytest.fixture(scope="module")
-def harness():
-    spec = importlib.util.spec_from_file_location(
-        "benchmark_run", os.path.join(BENCH, "run.py"))
+def load(path, name):
+    spec = importlib.util.spec_from_file_location(name, path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+@functools.cache
+def run_py():
+    return load(os.path.join(BENCH, "run.py"), "benchmark_run")
+
+
+@pytest.fixture(scope="module")
+def harness():
+    return run_py()
 
 
 def drive(harness, cell, trace=False, fault=None, seed=2**31 + 77):
@@ -162,11 +172,19 @@ def test_run_py_exits_2_and_prints_no_line_without_a_tpu():
     assert "needs 1 TPU chip" in out.stderr
 
 
-def test_run_py_fails_in_a_directory_with_the_benchmark_alone(tmp_path):
-    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), tmp_path)
+def copy_the_benchmark(to, *more):
+    """``BENCHMARK.json`` and the directories under its ``paths``, as a
+    checkout that holds the benchmark alone has them."""
+    for path in ("BENCHMARK.json", *more):
+        os.makedirs(os.path.dirname(to / path), exist_ok=True)
+        shutil.copy(os.path.join(REPO, path), to / path)
     for path in ("benchmarks", os.path.join("tests", "benchmark")):
-        shutil.copytree(os.path.join(REPO, path), tmp_path / path,
+        shutil.copytree(os.path.join(REPO, path), to / path,
                         ignore=shutil.ignore_patterns("__pycache__"))
+
+
+def test_run_py_fails_in_a_directory_with_the_benchmark_alone(tmp_path):
+    copy_the_benchmark(tmp_path)
     out = run_cli(tmp_path, "--workload", "served-1k.write", "--seed", "1",
                   "--seconds", "1", "--trace", "0")
     assert out.returncode != 0 and '"correct"' not in out.stdout
@@ -183,14 +201,19 @@ def bench():
     return json.load(open(os.path.join(REPO, "BENCHMARK.json")))
 
 
-def test_benchmark_json_has_the_contracts_keys_and_limits(bench):
+# Every assertion of ``tests/benchmark/`` over the root file's lists is a
+# plain function of (bench, root) in its file's ROOT_FILE_RULES: the tests
+# call it with the repository, the rehearsal of an addition below with a
+# copy that a later PR's entries were appended to.
+
+def holds_the_contracts_keys_and_limits(bench, root):
     assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
     assert bench["command"] == ["python3", "benchmarks/run.py"]
     assert bench["paths"] == ["benchmarks", "tests/benchmark"]
     assert isinstance(bench["run_seconds"], int)
     assert 1 <= bench["run_seconds"] <= 51
-    assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) <= 64 << 10
+    assert os.path.getsize(os.path.join(root, "BENCHMARK.json")) <= 64 << 10
     fours = sum(w["chips"] == 4 for w in bench["workloads"])
     assert fours <= max(1, len(bench["workloads"]) // 2)
     for m in bench["end_to_end"]:
@@ -206,7 +229,7 @@ def test_benchmark_json_has_the_contracts_keys_and_limits(bench):
     assert "setup_s" in {m["name"] for m in bench["end_to_end"]}
 
 
-def test_every_name_and_unit_is_made_of_the_allowed_characters(bench):
+def holds_every_name_and_unit_to_the_allowed_characters(bench, root):
     names = [x["name"] for group in ("configs", "workloads", "end_to_end",
                                      "per_layer") for x in bench[group]]
     names += [w[k] for w in bench["workloads"] for k in ("config", "traffic")]
@@ -224,7 +247,8 @@ def test_every_name_and_unit_is_made_of_the_allowed_characters(bench):
     assert len(set(pairs)) == len(pairs)
 
 
-def test_everything_benchmark_json_names_resolves(bench, harness):
+def holds_that_everything_named_resolves(bench, root):
+    here = os.path.join(root, "benchmarks")
     configs = {c["name"]: c for c in bench["configs"]}
     cells = {w["name"] for w in bench["workloads"]}
     end_to_end = {m["name"]: m for m in bench["end_to_end"]}
@@ -233,16 +257,16 @@ def test_everything_benchmark_json_names_resolves(bench, harness):
     assert len(set(files)) == len(files)
     for c in configs.values():
         assert c["file"].startswith("benchmarks/configs/")
-        held = json.load(open(os.path.join(REPO, c["file"])))
+        held = json.load(open(os.path.join(root, c["file"])))
         assert all(key in held for key in c["reduced"])
     for w in bench["workloads"]:
         assert w["chips"] in (1, 4)
         mix = json.load(open(os.path.join(
-            BENCH, "traffic", w["traffic"] + ".json")))
+            here, "traffic", w["traffic"] + ".json")))
         assert os.path.exists(os.path.join(
-            BENCH, "planes", mix["plane"] + ".py"))
+            here, "planes", mix["plane"] + ".py"))
         reported = {m["name"] for g in ("end_to_end", "per_layer")
-                    for m in harness.metrics_of(bench, g, w["name"])}
+                    for m in run_py().metrics_of(bench, g, w["name"])}
         assert "setup_s" in reported
         assert len(reported & set(end_to_end)) >= 2
         assert reported - set(end_to_end)
@@ -250,16 +274,123 @@ def test_everything_benchmark_json_names_resolves(bench, harness):
         assert set(m.get("workloads", [])) <= cells
     for m in bench["per_layer"]:
         spec = json.load(open(os.path.join(
-            BENCH, "layer_metrics", m["name"] + ".json")))
+            here, "layer_metrics", m["name"] + ".json")))
         assert all(spec[k] == m[k] for k in ("name", "unit", "better",
                                              "layer", "moves", "source"))
         assert os.path.exists(os.path.join(
-            BENCH, "reducers", spec["reducer"] + ".py"))
+            here, "reducers", spec["reducer"] + ".py"))
         moved = end_to_end[m["moves"]]
         # every cell that reads the metric reports the metric it moves
         assert set(m["workloads"]) <= set(moved.get("workloads", cells))
-    peaks = json.load(open(os.path.join(BENCH, "peaks.json")))
+    peaks = json.load(open(os.path.join(here, "peaks.json")))
     assert peaks["devices"]["TPU v5 lite"]["hbm_bytes_per_s"] == 819e9
+
+
+ROOT_FILE_RULES = [holds_the_contracts_keys_and_limits,
+                   holds_every_name_and_unit_to_the_allowed_characters,
+                   holds_that_everything_named_resolves]
+
+
+def test_benchmark_json_has_the_contracts_keys_and_limits(bench):
+    holds_the_contracts_keys_and_limits(bench, REPO)
+
+
+def test_every_name_and_unit_is_made_of_the_allowed_characters(bench):
+    holds_every_name_and_unit_to_the_allowed_characters(bench, REPO)
+
+
+def test_everything_benchmark_json_names_resolves(bench):
+    holds_that_everything_named_resolves(bench, REPO)
+
+
+# -- an addition rehearsed ----------------------------------------------------
+
+def every_files_rules():
+    """ROOT_FILE_RULES of every test file here, found as a later PR's file
+    will be: by its name. A file that opens the root ``BENCHMARK.json`` has
+    to offer some."""
+    rules = []
+    for path in sorted(glob.glob(os.path.join(
+            REPO, "tests", "benchmark", "test_*.py"))):
+        name = os.path.basename(path)[:-3]
+        found = getattr(load(path, "rules_of_" + name), "ROOT_FILE_RULES", [])
+        if re.search(r'REPO,\s*"BENCHMARK\.json"', open(path).read()):
+            assert found, f"{name} reads the root file and offers no rules"
+        rules += [(name, rule) for rule in found]
+    return rules
+
+
+CLUSTER, BULK = "cluster-3x1k.write", "mixed-400kx5-4chip.bulk"
+
+
+def append_what_later_prs_add(root):
+    """What a ``model_config`` PR adds: a configuration, a mix on a plane
+    that is there, a one-chip cell under ``served_ops_per_s`` and two
+    per-layer metrics at the end of the list, one of them read in the cluster
+    cell too. And what a ``tracing`` PR adds: a metric on the bulk cell.
+    Files added, and no file that was there edited but ``BENCHMARK.json``."""
+    here = root / "benchmarks"
+    cell = "rehearsed-1k.rehearsed"
+    shutil.copy(here / "configs" / "served-1k.json",
+                here / "configs" / "rehearsed-1k.json")
+    shutil.copy(here / "traffic" / "write.json",
+                here / "traffic" / "rehearsed.json")
+    bench = json.load(open(root / "BENCHMARK.json"))
+    first = bench["configs"][0]
+    bench["configs"].append({
+        "name": "rehearsed-1k", "source": first["source"],
+        "file": "benchmarks/configs/rehearsed-1k.json",
+        "reduced": first["reduced"], "why": "a later deployment"})
+    bench["workloads"].append({
+        "name": cell, "config": "rehearsed-1k", "traffic": "rehearsed",
+        "chips": 1, "why": "a later cell on the served plane"})
+    next(m for m in bench["end_to_end"]
+         if m["name"] == "served_ops_per_s")["workloads"].append(cell)
+    for name, like, cells in (
+            ("rehearsed.alone_per_kop", "runtime.fetches_per_kop", [cell]),
+            ("rehearsed.shared_per_kop", "runtime.fetches_per_kop",
+             [cell, CLUSTER]),
+            ("rehearsed.bulk_per_drive", "bulk.fetches_per_drive", [BULK])):
+        spec = json.load(open(here / "layer_metrics" / (like + ".json")))
+        with open(here / "layer_metrics" / (name + ".json"), "w") as f:
+            json.dump({**spec, "name": name}, f)
+        bench["per_layer"].append({
+            **{k: spec[k] for k in ("unit", "better", "source", "layer",
+                                    "moves")}, "name": name,
+            "workloads": cells})
+    with open(root / "BENCHMARK.json", "w") as f:
+        json.dump(bench, f, indent=1)
+    return bench
+
+
+def test_a_later_prs_entries_pass_every_rule_of_every_test_file(tmp_path):
+    """The door stays open: what later PRs append passes every assertion
+    that ``tests/benchmark/`` makes over the root file's lists. A test that
+    pins the length, the tail or the remainder of a list fails here in the
+    PR that writes it."""
+    rules = every_files_rules()
+    assert {name for name, _ in rules} >= {
+        "test_benchmark_harness", "test_benchmark_pump_metrics",
+        "test_benchmark_bulk_plane", "test_benchmark_cluster_plane"}
+    # the layers' names and the spans' vocabulary, which a metric is held to
+    copy_the_benchmark(tmp_path, "PERF.md",
+                       os.path.join("docs", "OBSERVABILITY.md"))
+    was = json.load(open(tmp_path / "BENCHMARK.json"))
+    bench = append_what_later_prs_add(tmp_path)
+    for group in ("configs", "workloads", "per_layer"):
+        assert bench[group][:len(was[group])] == was[group]
+        assert len(bench[group]) > len(was[group])
+    for there, last in ((CLUSTER, "rehearsed.shared_per_kop"),
+                        (BULK, "rehearsed.bulk_per_drive")):
+        assert run_py().metrics_of(bench, "per_layer", there)[-1][
+            "name"] == last
+    for _, rule in rules:
+        rule(bench, str(tmp_path))
+    # and they were held to the copy: its new cell without its traffic
+    # file no longer resolves
+    os.remove(tmp_path / "benchmarks" / "traffic" / "rehearsed.json")
+    with pytest.raises(FileNotFoundError, match="rehearsed.json"):
+        holds_that_everything_named_resolves(bench, str(tmp_path))
 
 
 # -- the yardstick's own arithmetic ------------------------------------------

@@ -7,6 +7,7 @@ the real file's metric entries); traffic and configurations are
 number from here is a device number.
 """
 
+import functools
 import importlib.util
 import json
 import os
@@ -46,6 +47,9 @@ PUMPS = [
     ("engine.settle_rounds_per_kop", [R]), ("host.unspanned_share", [W, R]),
 ]
 PINNED = {name for name, _ in FIRST + PUMPS}
+#: the round's call signature on the record (PR 25's counter, PR 34's
+#: metric): the one served metric after the pinned ones that this file holds
+LEAVES = "runtime.leaves_per_kop"
 
 CELLS = {W: "served-tiny.write-tiny", R: "served-tiny.read90-tiny"}
 
@@ -60,9 +64,14 @@ def load(path, name):
     return module
 
 
+@functools.cache
+def run_py():
+    return load(os.path.join(BENCH, "run.py"), "benchmark_run_pump")
+
+
 @pytest.fixture(scope="module")
 def harness():
-    return load(os.path.join(BENCH, "run.py"), "benchmark_run_pump")
+    return run_py()
 
 
 @pytest.fixture(scope="module")
@@ -80,45 +89,45 @@ ROOT_METRICS = [m["name"] for m in json.load(open(os.path.join(
     REPO, "BENCHMARK.json")))["per_layer"]]
 
 
-def metric_file(name):
+def metric_file(name, root):
     return json.load(open(os.path.join(
-        BENCH, "layer_metrics", name + ".json")))
+        root, "benchmarks", "layer_metrics", name + ".json")))
 
 
-def test_the_accepted_metrics_stand_first_and_unchanged(bench):
+# -- the root file's lists: plain functions of (bench, root), see
+# -- ROOT_FILE_RULES in test_benchmark_harness.py
+
+def holds_the_accepted_metrics_first_and_unchanged(bench, root):
     pinned = bench["per_layer"][:len(FIRST) + len(PUMPS)]
     assert [(m["name"], m["workloads"]) for m in pinned] == FIRST + PUMPS
-    assert (metric_file("engine.apply_ms")["key"],
-            metric_file("server.append_ms")["key"],
-            metric_file("server.append_ms.read")["key"],
-            metric_file("engine.rounds_per_kop")["key"]) == (
+    assert (metric_file("engine.apply_ms", root)["key"],
+            metric_file("server.append_ms", root)["key"],
+            metric_file("server.append_ms.read", root)["key"],
+            metric_file("engine.rounds_per_kop", root)["key"]) == (
         "apply", "group.append", "group.append", "rounds")
 
 
-@pytest.fixture(scope="module")
-def layers():
+def layers_of(root):
     """The first column of PERF.md section 3's table."""
-    perf = open(os.path.join(REPO, "PERF.md")).read()
+    perf = open(os.path.join(root, "PERF.md")).read()
     section = perf[perf.index("## 3. Layers"):perf.index("## 4. Cells")]
     return set(re.findall(r"^\| ([^|`]+?) \| ", section, re.M))
 
 
-@pytest.fixture(scope="module")
-def vocabulary():
-    return open(os.path.join(REPO, "docs", "OBSERVABILITY.md")).read()
+def vocabulary_of(root):
+    return open(os.path.join(root, "docs", "OBSERVABILITY.md")).read()
 
 
-@pytest.mark.parametrize("name", ROOT_METRICS)
-def test_a_metric_file_loads_and_resolves(bench, layers, vocabulary, name):
+def holds_a_metric_file(bench, root, name, layers, vocabulary):
     """What holds for every per-layer metric, whoever added it."""
     m = next(m for m in bench["per_layer"] if m["name"] == name)
-    spec = metric_file(name)
+    spec = metric_file(name, root)
     assert set(spec) >= {"name", "unit", "better", "layer", "source",
                          "moves", "kind", "key", "reducer", "what"}
     assert all(spec[k] == m[k] for k in (
         "name", "unit", "better", "layer", "source", "moves"))
     assert os.path.exists(os.path.join(
-        BENCH, "reducers", spec["reducer"] + ".py"))
+        root, "benchmarks", "reducers", spec["reducer"] + ".py"))
     assert spec["layer"] in layers, (spec["layer"], layers)
     assert len(spec["unit"]) <= 16
     moved = next(e for e in bench["end_to_end"] if e["name"] == spec["moves"])
@@ -128,12 +137,16 @@ def test_a_metric_file_loads_and_resolves(bench, layers, vocabulary, name):
         assert f"| `{spec['key']}` |" in vocabulary, spec["key"]
 
 
-@pytest.mark.parametrize("name", [name for name, _ in PUMPS])
-def test_a_pump_metric_reads_the_served_cells_through_its_reducers(
-        bench, name):
+def holds_every_metric_file(bench, root):
+    layers, vocabulary = layers_of(root), vocabulary_of(root)
+    for m in bench["per_layer"]:
+        holds_a_metric_file(bench, root, m["name"], layers, vocabulary)
+
+
+def holds_a_pump_metric(bench, root, name):
     """What is PR 24's own, held over its twenty names only."""
     m = next(m for m in bench["per_layer"] if m["name"] == name)
-    spec = metric_file(name)
+    spec = metric_file(name, root)
     assert set(m["workloads"]) <= set(CELLS)      # never the raw cell
     if spec["reducer"] == "span_mean_ms":
         assert spec["source"] == "program_span"
@@ -142,6 +155,68 @@ def test_a_pump_metric_reads_the_served_cells_through_its_reducers(
         assert isinstance(spec["key"], list)
         assert spec["source"] == ("program_span" if spec["key"][0]
                                   == "timeline" else "program_counter")
+
+
+def holds_every_pump_metric(bench, root):
+    for name, _ in PUMPS:
+        holds_a_pump_metric(bench, root, name)
+
+
+def held_in(bench, real, names):
+    """The entries of ``names`` that ``real`` reads, by name; nothing of a
+    later metric on a served cell, which brings a test of its own."""
+    return {m["name"]: m for m in bench["per_layer"]
+            if real in m["workloads"] and m["name"] in names}
+
+
+def holds_the_twins_metrics_to_the_served_cells(bench, root):
+    """``data_pump``'s entries for the names this file holds are the root
+    file's, cell for tiny cell."""
+    keys = ("name", "unit", "better", "source", "layer", "moves")
+    pump = json.load(open(os.path.join(root, os.path.relpath(PUMP, REPO))))
+    for real, tiny in CELLS.items():
+        wanted = held_in(bench, real, PINNED | {LEAVES})
+        twins = held_in(pump, tiny, PINNED | {LEAVES})
+        assert set(twins) == set(wanted)
+        assert all(twins[n][k] == wanted[n][k] for n in wanted for k in keys)
+    leaves = next(m for m in bench["per_layer"] if m["name"] == LEAVES)
+    assert leaves["workloads"] == [W, R]
+    assert metric_file(LEAVES, root)["key"] == ["counters",
+                                                "engine.dispatch_leaves"]
+
+
+ROOT_FILE_RULES = [holds_the_accepted_metrics_first_and_unchanged,
+                   holds_every_metric_file, holds_every_pump_metric,
+                   holds_the_twins_metrics_to_the_served_cells]
+
+
+def test_the_accepted_metrics_stand_first_and_unchanged(bench):
+    holds_the_accepted_metrics_first_and_unchanged(bench, REPO)
+
+
+@pytest.fixture(scope="module")
+def layers():
+    return layers_of(REPO)
+
+
+@pytest.fixture(scope="module")
+def vocabulary():
+    return vocabulary_of(REPO)
+
+
+@pytest.mark.parametrize("name", ROOT_METRICS)
+def test_a_metric_file_loads_and_resolves(bench, layers, vocabulary, name):
+    holds_a_metric_file(bench, REPO, name, layers, vocabulary)
+
+
+@pytest.mark.parametrize("name", [name for name, _ in PUMPS])
+def test_a_pump_metric_reads_the_served_cells_through_its_reducers(
+        bench, name):
+    holds_a_pump_metric(bench, REPO, name)
+
+
+def test_the_twins_metrics_are_the_served_cells_metrics(bench):
+    holds_the_twins_metrics_to_the_served_cells(bench, REPO)
 
 
 RECORDED = {
@@ -195,36 +270,56 @@ def test_program_report_without_acknowledged_operations_or_a_report(
     assert program_report.reduce({"clock": {"acked_ops": 5}}, spec) is None
 
 
-@pytest.mark.parametrize("real", sorted(CELLS), ids=["read90", "write"])
-def test_a_traced_run_prints_every_metric_of_its_cell(harness, bench, real):
-    cell = CELLS[real]
-    rc, line = harness.run_cell(cell, 2**31 + 99, 0.6, True, None,
+@pytest.fixture(scope="module", params=sorted(CELLS), ids=["read90", "write"])
+def traced(request, harness):
+    """One traced run of a tiny served cell, and what it left in the tracer:
+    every test of the printed line reads this one run."""
+    from copycat_tpu.utils.tracing import TRACER
+
+    real = request.param
+    rc, line = harness.run_cell(CELLS[real], 2**31 + 99, 0.6, True, None,
                                 bench_file=PUMP, data_root=DATA,
                                 require_tpu=False)
     assert rc == 0 and line["correct"] is True and line["failed"] == 0
     json.dumps(line)
+    # what a run leaves in the tracer is the window's, frozen
+    report = TRACER.report()
+    assert not TRACER.enabled and report is TRACER.report()
+    return real, line, report
+
+
+def test_a_traced_run_prints_every_metric_of_its_cell(bench, traced):
+    real, line, report = traced
     # the pinned metrics only: a later metric on a served cell brings a
     # test of its own
-    wanted = {m["name"]: m for m in bench["per_layer"]
-              if real in m["workloads"] and m["name"] in PINNED}
-    pump = json.load(open(PUMP))
-    assert {m["name"] for m in harness.metrics_of(pump, "per_layer", cell)} \
-        == set(wanted)
+    wanted = held_in(bench, real, PINNED)
     missing = set(wanted) - set(line["metrics"])
     assert not missing, missing
     for name, got in line["metrics"].items():
-        assert got["unit"] == wanted[name]["unit"]
         assert isinstance(got["value"], float) and got["value"] >= 0, name
+        if name in wanted:
+            assert got["unit"] == wanted[name]["unit"]
     assert 0 <= line["metrics"]["host.unspanned_share"]["value"] < 100
     assert line["metrics"]["runtime.fetches_per_kop"]["value"] > 0
     assert line["metrics"]["runtime.wait_ms"]["value"] > 0
     if real.endswith("read90"):
         assert line["metrics"]["server.reads_per_window"]["value"] >= 1
         assert line["metrics"]["engine.query_drives_per_kop"]["value"] > 0
-    # what a run leaves in the tracer is the window's, frozen
-    from copycat_tpu.utils.tracing import TRACER
-
-    report = TRACER.report()
-    assert not TRACER.enabled and report is TRACER.report()
     assert sum(report["timeline"].values()) == pytest.approx(100, abs=0.01)
     assert report["window_s"] >= 0.6     # the profiler's holds lengthen it
+
+
+def test_a_traced_run_prints_the_dispatch_leaves(bench, traced):
+    """The served metric after the pinned ones, on the line the same run
+    printed: the counter's delta over the operations the window
+    acknowledged, a few leaves a round and never none."""
+    real, line, report = traced
+    wanted = held_in(bench, real, {LEAVES})[LEAVES]
+    got = line["metrics"][LEAVES]
+    assert got["unit"] == wanted["unit"] == "leaves/kop"
+    leaves = report["counters"]["engine.dispatch_leaves"]
+    rounds = line["metrics"]["engine.rounds_per_kop"]["value"]
+    assert leaves > 0 and got["value"] > 0
+    # both are per 1,000 acknowledged operations of the same window
+    assert got["value"] / rounds == pytest.approx(
+        leaves / report["counters"]["engine.rounds"])
