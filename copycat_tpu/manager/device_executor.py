@@ -64,11 +64,12 @@ def _devint(v: Any) -> bool:
     """True if ``v`` can live in a device int32 lane.
 
     ``bool`` is excluded (a device round-trip would turn ``True`` into
-    ``1`` — a visible type change vs the CPU path), as is the engine's
-    INT_MIN FAIL sentinel.
+    ``1`` — a visible type change vs the CPU path), as are the engine's
+    sentinels: INT_MIN (FAIL) and the two above it (the map's ABSENT and
+    FULL, ``ops/apply.py``).
     """
     return (isinstance(v, int) and not isinstance(v, bool)
-            and INT32_MIN < v <= INT32_MAX)
+            and INT32_MIN + 2 < v <= INT32_MAX)
 
 
 class DeviceEngineConfig(NamedTuple):
@@ -394,6 +395,8 @@ class DeviceEngine:
         self._next_group = 0
         self._free: list[int] = []   # released (reset) groups, lowest first
         self._window: DeviceWindow | None = None
+        self._map_shadow = 0         # map keys held on the host
+        self._map_ops = None         # the registry's two map counters
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -451,6 +454,41 @@ class DeviceEngine:
         from ..models import checkpoint
         checkpoint.cut(self._groups)
 
+    def count_map_op(self, chain: bool) -> None:
+        """One more map command finalised on the vector lane, or (``chain``)
+        run as a generator chain: ``engine.map_vector_ops`` and
+        ``engine.map_chain_ops`` in the tracer's report."""
+        counters = self._map_ops
+        if counters is None:
+            metrics = self._groups.metrics
+            counters = self._map_ops = (metrics.counter("map_vector_ops"),
+                                        metrics.counter("map_chain_ops"))
+        counters[chain].inc()
+
+    def count_shadow(self, delta: int) -> None:
+        """Map keys held on the host for want of room in their bucket or
+        of an int32 shape moved by ``delta``."""
+        self._map_shadow += delta
+        self._groups.metrics.gauge("map.host_shadow_keys").set(
+            self._map_shadow)
+
+    def map_keys(self) -> tuple[int, int]:
+        """(keys in the device's map tables, by the leader lanes' live
+        counts; map keys shadowed on the host), also set as the gauges
+        ``map.device_keys`` and ``map.host_shadow_keys``. Costs one
+        fetch; nothing on the served path calls it."""
+        import numpy as np
+        from ..ops.consensus import current_leader
+        groups = self._ensure()
+        res = groups.state.resources
+        count = res.map_count[..., 0] if res.map_count.shape[-1] \
+            else res.map_live.sum(-1)
+        lead = np.maximum(np.asarray(current_leader(groups.state)[0]), 0)
+        on_device = int(np.asarray(count)[np.arange(lead.size), lead].sum())
+        groups.metrics.gauge("map.device_keys").set(on_device)
+        self.count_shadow(0)
+        return on_device, self._map_shadow
+
     def allocate(self) -> int | None:
         """Lowest free device group, or ``None`` when all are live."""
         if self._free:
@@ -478,6 +516,8 @@ class DeviceEngine:
         the crash-recovery plane (docs/DURABILITY.md)."""
         from ..models import checkpoint
         self._groups = checkpoint.load_bytes(blob, mesh=self.config.mesh)
+        self._map_ops = None         # they were the replaced registry's
+        self._map_shadow = 0         # the machines' restores count anew
         self._warm_capture()
         self._next_group = int(next_group)
         self._free = sorted(int(g) for g in free)
@@ -647,11 +687,15 @@ class _Held:
 # Vector-op finalize kinds (vector_spec's last element): how the host
 # bookkeeping consumes the device result at the batched pump's finalize.
 VK_CAS, VK_GET_AND_SET, VK_SET = 1, 2, 3
+VK_MAP_PUT, VK_MAP_REMOVE, VK_MAP_PUT_IF_ABSENT, VK_MAP_REPLACE = 4, 5, 6, 7
 
 # Query-spec finalize kinds (query_spec's last element). Reads never
 # mutate host bookkeeping, so the only consumption modes are the raw
 # device int and its truthiness.
 QK_RAW, QK_BOOL = 1, 2
+# the map's: a telling reply as the value or None, or as the value or the
+# operation's default; a size as "is empty"
+QK_MAP_GET, QK_MAP_DEFAULT, QK_MAP_EMPTY = 3, 4, 5
 
 
 class DeviceBackedStateMachine(ResourceStateMachine):
@@ -1045,84 +1089,169 @@ class DeviceAtomicValueState(DeviceBackedStateMachine):
 # map
 # ---------------------------------------------------------------------------
 
+#: the commands ``DeviceMapState.vector_spec`` takes
+_MAP_VECTOR = frozenset({cc.MapPut, cc.MapRemove, cc.MapPutIfAbsent,
+                         cc.MapReplace})
+_MISSING = object()
+
+
 class DeviceMapState(DeviceBackedStateMachine):
     """Hashed map: int32 (key, value) entries live in the device probe
     table; overflow and non-int32 payloads take the host shadow — a put
-    into a full device pool SUCCEEDS transparently (SURVEY.md §7.3 #1
+    into a full bucket SUCCEEDS transparently (SURVEY.md §7.3 #1
     "eviction-to-host for overflow"; the reference ``MapState.java:32``
-    has no capacity bound, so neither may we)."""
+    has no capacity bound, so neither may we).
+
+    The device answers for what it holds: presence, value and size of
+    the table come from it (``MAP_TELL`` replies tell an absent key from
+    a stored 0), and the host keeps no record to answer from. What the
+    host keeps: ``_held``, a record for each entry with a value on the
+    host (shadow) or a TTL timer; and ``_commits``, the retained commit
+    of each plain device entry, which a superseding command cleans
+    (the log's compaction contract) and no answer reads."""
 
     def __init__(self, engine: DeviceEngine, group: int) -> None:
         super().__init__(engine, group)
-        # key -> _Held; on_device=True ⇒ value lives in the device table
+        # key -> _Held: host-shadowed values, and device entries under a
+        # TTL timer (on_device=True)
         self._held: dict[Any, _Held] = {}
+        # key -> retained commit of a device entry without a record
+        self._commits: dict[int, Commit] = {}
+        # the device table may hold entries (a host-only map asks it
+        # nothing)
+        self._device = False
+
+    def _wrap_handler(self, fn):
+        wrapped = super()._wrap_handler(fn)
+        if wrapped is fn:
+            return fn
+
+        def counted(commit):
+            self._eng.count_map_op(chain=True)
+            return wrapped(commit)
+
+        return counted
 
     # -- internals ---------------------------------------------------------
 
+    def _shadows(self) -> int:
+        return sum(1 for h in self._held.values() if not h.on_device)
+
+    def _record(self, key: Any, held: _Held | None) -> None:
+        """Put (or with ``None`` drop) the host record of ``key``,
+        keeping the engine's count of shadowed keys."""
+        previous = self._held.pop(key, None)
+        delta = int(held is not None and not held.on_device) \
+            - int(previous is not None and not previous.on_device)
+        if held is not None:
+            self._held[key] = held
+        if delta:
+            self._eng.count_shadow(delta)
+
+    def _retain(self, key: int, commit: Commit | None) -> None:
+        """``commit`` is now what holds device entry ``key`` (``None``:
+        nothing does); what held it before is superseded."""
+        previous = self._commits.pop(key, None)
+        if previous is not None:
+            previous.clean()
+        if commit is not None:
+            self._commits[key] = commit
+            self._device = True
+
+    def _on_device(self, key: Any) -> bool:
+        """May the device table hold ``key``? (Not where the host
+        shadows it: a key lives in one place.)"""
+        held = self._held.get(key)
+        return (self._device and _devint(key)
+                and (held is None or held.on_device))
+
+    @staticmethod
+    def _told(raw: int) -> Any:
+        o = ops()
+        return None if raw == o.ABSENT or raw == o.FULL else raw
+
     def _store(self, key: Any, value: Any, commit: Commit, ttl: float | None):
         """Insert/overwrite ``key``; returns the previous value."""
-        previous_held = self._held.get(key)
-        previous = self._read(key)
-        if previous_held is not None:
-            on_device = previous_held.on_device
-            previous_held.discard()
-        else:
-            on_device = False
-        if on_device:
-            if _devint(value):
-                yield from self._cmd(ops().OP_MAP_PUT, key, value)
-                held = _Held(commit, on_device=True)
-            else:
-                yield from self._cmd(ops().OP_MAP_REMOVE, key)
-                held = _Held(commit, value=value)
-        else:
-            if previous_held is None and _devint(key) and _devint(value):
-                placed = yield from self._cmd(ops().OP_MAP_PUT, key, value)
-            else:
-                placed = FAIL()
-            if placed != FAIL():
-                held = _Held(commit, on_device=True)
-            else:
-                held = _Held(commit, value=value)
-        self._held[key] = held
+        o = ops()
+        held = self._held.get(key)
+        shadowed = held is not None and not held.on_device
+        previous = held.value if shadowed else None
+        on_device = False
+        if shadowed or not _devint(key):
+            pass                      # a shadowed key stays on the host
+        elif _devint(value):
+            raw = yield from self._cmd(o.OP_MAP_PUT, key, value, o.MAP_TELL)
+            previous = self._told(raw)
+            on_device = raw != o.FULL
+        elif self._on_device(key):
+            previous = self._told((yield from self._cmd(
+                o.OP_MAP_REMOVE, key, 0, o.MAP_TELL)))
+        if held is not None:
+            held.discard()
+        new = None
+        if ttl or not on_device:
+            new = _Held(commit, value=None if on_device else value,
+                        on_device=on_device)
+        self._record(key, new)
+        if _devint(key):
+            self._retain(key, commit if on_device and new is None else None)
+        self._device = self._device or on_device
         if ttl:
             def expire() -> None:
                 def chain():
-                    if self._held.get(key) is held:
-                        yield from self._evict(key, held)
+                    if self._held.get(key) is new:
+                        yield from self._evict(key)
 
                 self._spawn(chain())
 
-            held.timer = self.executor.schedule(ttl, expire)
+            new.timer = self.executor.schedule(ttl, expire)
         return previous
 
-    def _read(self, key: Any) -> Any:
+    def _find(self, key: Any) -> Any:
+        """The value under ``key``, or ``_MISSING``."""
         held = self._held.get(key)
-        if held is None:
-            return None
-        if held.on_device:
-            return self._qry(ops().OP_MAP_GET, key)
-        return held.value
+        if held is not None and not held.on_device:
+            return held.value
+        if self._on_device(key):
+            o = ops()
+            raw = self._qry(o.OP_MAP_GET, key, 0, o.MAP_TELL)
+            if raw != o.ABSENT:
+                return raw
+        return _MISSING
 
-    def _evict(self, key: Any, held: _Held):
-        del self._held[key]
-        if held.on_device:
-            yield from self._cmd(ops().OP_MAP_REMOVE, key)
-        held.discard()
+    def _read(self, key: Any, default: Any = None) -> Any:
+        found = self._find(key)
+        return default if found is _MISSING else found
+
+    def _evict(self, key: Any):
+        """Remove ``key`` wherever it lives; returns the value it had."""
+        held = self._held.get(key)
+        previous = None
+        if held is not None and not held.on_device:
+            previous = held.value
+        elif self._on_device(key):
+            o = ops()
+            previous = self._told((yield from self._cmd(
+                o.OP_MAP_REMOVE, key, 0, o.MAP_TELL)))
+        if held is not None:
+            held.discard()
+            self._record(key, None)
+        if _devint(key):
+            self._retain(key, None)
+        return previous
 
     # -- queries -----------------------------------------------------------
 
     def contains_key(self, commit: Commit[cc.MapContainsKey]) -> bool:
         try:
-            return commit.operation.key in self._held
+            return self._find(commit.operation.key) is not _MISSING
         finally:
             commit.close()
 
     def contains_value(self, commit: Commit[cc.MapContainsValue]) -> bool:
         try:
             value = commit.operation.value
-            if _devint(value) and any(
-                    h.on_device for h in self._held.values()):
+            if _devint(value) and self._device:
                 if self._qry(ops().OP_MAP_CONTAINS_VALUE, value):
                     return True
             return any((not h.on_device) and h.value == value
@@ -1138,37 +1267,140 @@ class DeviceMapState(DeviceBackedStateMachine):
 
     def get_or_default(self, commit: Commit[cc.MapGetOrDefault]) -> Any:
         try:
-            if commit.operation.key in self._held:
-                return self._read(commit.operation.key)
-            return commit.operation.default
+            return self._read(commit.operation.key,
+                              commit.operation.default)
         finally:
             commit.close()
 
+    def _size(self) -> int:
+        device = self._qry(ops().OP_MAP_SIZE) if self._device else 0
+        return device + self._shadows()
+
     def is_empty(self, commit: Commit[cc.MapIsEmpty]) -> bool:
         try:
-            return not self._held
+            return self._size() == 0
         finally:
             commit.close()
 
     def size(self, commit: Commit[cc.MapSize]) -> int:
         try:
-            return len(self._held)
+            return self._size()
         finally:
             commit.close()
 
     # -- read pump (query vector lane) -------------------------------------
-    # A keyed read is one device query exactly when the key's value is
-    # held ON DEVICE; absent keys and host-shadowed values answer from
-    # host state and keep the handler path.
+    # A keyed read is one device query whenever the key is a device
+    # integer the host does not shadow: the device says whether it holds
+    # it. Size and emptiness are one query while nothing is shadowed.
 
     def query_spec(self, operation: Any
                    ) -> tuple[int, int, int, int, int] | None:
+        if not self._device:
+            return None
         t = type(operation)
+        o = ops()
         if t is cc.MapGet or t is cc.MapGetOrDefault:
-            held = self._held.get(operation.key)
-            if held is not None and held.on_device:
-                return (ops().OP_MAP_GET, operation.key, 0, 0, QK_RAW)
+            if self._on_device(operation.key):
+                return (o.OP_MAP_GET, operation.key, 0, o.MAP_TELL,
+                        QK_MAP_GET if t is cc.MapGet else QK_MAP_DEFAULT)
+        elif t is cc.MapContainsKey:
+            if self._on_device(operation.key) \
+                    and operation.key not in self._held:
+                return (o.OP_MAP_CONTAINS_KEY, operation.key, 0, 0, QK_BOOL)
+        elif t is cc.MapSize or t is cc.MapIsEmpty:
+            if not self._shadows():
+                return (o.OP_MAP_SIZE, 0, 0, 0,
+                        QK_RAW if t is cc.MapSize else QK_MAP_EMPTY)
         return None
+
+    def query_finalize(self, kind: int, operation: Any, raw: int) -> Any:
+        if kind == QK_MAP_GET:
+            return self._told(raw)
+        if kind == QK_MAP_DEFAULT:
+            return operation.default if raw == ops().ABSENT else raw
+        if kind == QK_MAP_EMPTY:
+            return raw == 0
+        return super().query_finalize(kind, operation, raw)
+
+    # -- vector lane (batched server-side pump) ---------------------------
+    # A keyed command on a device integer with no record on the host (no
+    # shadow, no timer), an int32 value and no TTL is ONE device op: the
+    # telling reply is the handler's answer, so nothing is read first.
+    # A put that finds its bucket full is shadowed at finalize; a later
+    # row of the same run on that key was classified before that, and
+    # ``vector_finalize`` answers it from the record (``_after_full``).
+
+    def vector_spec(self, operation: Any
+                    ) -> tuple[int, int, int, int, int] | None:
+        t = type(operation)
+        if t not in _MAP_VECTOR:
+            return None
+        key = operation.key
+        if not _devint(key) or key in self._held:
+            return None
+        o = ops()
+        if t is cc.MapRemove:
+            return (o.OP_MAP_REMOVE, key, 0, o.MAP_TELL, VK_MAP_REMOVE)
+        if operation.ttl or not _devint(operation.value):
+            return None
+        if t is cc.MapPut:
+            return (o.OP_MAP_PUT, key, operation.value, o.MAP_TELL,
+                    VK_MAP_PUT)
+        if t is cc.MapPutIfAbsent:
+            return (o.OP_MAP_PUT_IF_ABSENT, key, operation.value,
+                    o.MAP_TELL, VK_MAP_PUT_IF_ABSENT)
+        if t is cc.MapReplace:
+            return (o.OP_MAP_REPLACE, key, operation.value, o.MAP_TELL,
+                    VK_MAP_REPLACE)
+        return None
+
+    def vector_finalize(self, kind: int, operation: Any, raw: int,
+                        commit: Commit) -> Any:
+        self._eng.count_map_op(chain=False)
+        key = operation.key
+        if key in self._held:
+            return self._after_full(kind, operation, commit)
+        o = ops()
+        previous = self._told(raw)
+        if kind == VK_MAP_REMOVE:
+            commit.clean()
+            self._retain(key, None)
+            return previous
+        if raw == o.FULL:        # the reference's map has no bound
+            self._record(key, _Held(commit, value=operation.value))
+            return None
+        if kind == VK_MAP_PUT or raw == o.ABSENT \
+                and kind == VK_MAP_PUT_IF_ABSENT:
+            self._retain(key, commit)
+            return previous
+        if kind == VK_MAP_REPLACE and raw != o.ABSENT:
+            self._retain(key, commit)
+            return previous
+        commit.clean()           # found (put_if_absent), absent (replace)
+        return previous
+
+    def _after_full(self, kind: int, operation: Any, commit: Commit) -> Any:
+        """The row's key was shadowed since the run was staged (its
+        bucket was full): the record answers, and whatever the row's
+        own device op left in the table under the key goes."""
+        key = operation.key
+        held = self._held[key]
+        previous = held.value
+        if kind != VK_MAP_REMOVE:
+            def chain():
+                yield from self._cmd(ops().OP_MAP_REMOVE, key)
+
+            self._spawn(chain())
+        if kind == VK_MAP_PUT_IF_ABSENT:
+            commit.clean()
+            return previous
+        held.discard()
+        if kind == VK_MAP_REMOVE:
+            commit.clean()
+            self._record(key, None)
+        else:
+            self._record(key, _Held(commit, value=operation.value))
+        return previous
 
     # -- commands ----------------------------------------------------------
 
@@ -1178,108 +1410,100 @@ class DeviceMapState(DeviceBackedStateMachine):
 
     def put_if_absent(self, commit: Commit[cc.MapPutIfAbsent]) -> Any:
         op = commit.operation
-        if op.key in self._held:
-            value = self._read(op.key)
+        found = self._find(op.key)
+        if found is not _MISSING:
             commit.clean()
-            return value
+            return found
         yield from self._store(op.key, op.value, commit, op.ttl)
         return None
 
     def remove(self, commit: Commit[cc.MapRemove]) -> Any:
-        key = commit.operation.key
         commit.clean()
-        held = self._held.get(key)
-        if held is None:
-            return None
-        value = self._read(key)
-        yield from self._evict(key, held)
-        return value
+        return (yield from self._evict(commit.operation.key))
 
     def remove_if_present(self, commit: Commit[cc.MapRemoveIfPresent]) -> bool:
         op = commit.operation
         commit.clean()
-        held = self._held.get(op.key)
-        if held is None or self._read(op.key) != op.value:
+        if self._find(op.key) != op.value:
             return False
-        yield from self._evict(op.key, held)
+        yield from self._evict(op.key)
         return True
 
     def replace(self, commit: Commit[cc.MapReplace]) -> Any:
         op = commit.operation
-        if op.key not in self._held:
+        if self._find(op.key) is _MISSING:
             commit.clean()
             return None
         return (yield from self._store(op.key, op.value, commit, op.ttl))
 
     def replace_if_present(self, commit: Commit[cc.MapReplaceIfPresent]) -> bool:
         op = commit.operation
-        if op.key not in self._held or self._read(op.key) != op.expect:
+        if self._find(op.key) != op.expect:
             commit.clean()
             return False
         yield from self._store(op.key, op.value, commit, op.ttl)
         return True
 
-    def clear(self, commit: Commit[cc.MapClear]) -> None:
-        if any(h.on_device for h in self._held.values()):
+    def _reset(self):
+        """Empty the map on both sides (clear, delete)."""
+        if self._device:
             yield from self._cmd(ops().OP_MAP_CLEAR)
-        for held in self._held.values():
-            held.discard()
-        self._held.clear()
+            self._device = False
+        for key in list(self._held):
+            self._held[key].discard()
+            self._record(key, None)
+        for retained in self._commits.values():
+            retained.clean()
+        self._commits.clear()
+
+    def clear(self, commit: Commit[cc.MapClear]) -> None:
+        yield from self._reset()
         commit.clean()
 
     # -- snapshot hooks (crash-recovery plane, docs/DURABILITY.md) --------
-    # The device probe table rides the engine's checkpoint blob; the
-    # host bookkeeping is one record per key (device residency flag +
-    # the host-shadow value). Armed per-key TTL timers hold commit
-    # references that cannot round-trip — opt out (NotImplemented) and
-    # keep the whole manager on replay-only recovery, like the value
-    # machine.
+    # The device table rides the engine's checkpoint blob, and with it
+    # every key it holds: the host writes one record for each key it
+    # SHADOWS, and whether the table may hold any. Armed per-key TTL
+    # timers hold commit references that cannot round-trip — opt out
+    # (NotImplemented) and keep the whole manager on replay-only
+    # recovery, like the value machine.
 
     def snapshot_state(self) -> Any:
         if any(h.timer is not None for h in self._held.values()):
             return NotImplemented
-        return {"held": [(k, h.on_device,
-                          None if h.on_device else h.value)
-                         for k, h in self._held.items()]}
+        return {"held": [(k, False, h.value)
+                         for k, h in self._held.items()],
+                # (a machine built without __init__ holds nothing there)
+                **({"device": True} if getattr(self, "_device", False)
+                   else {})}
 
     def restore_state(self, data: Any, sessions: dict) -> None:
+        self._device = bool(data.get("device"))
         for key, on_device, value in data["held"]:
+            if on_device:    # an image from before the table answered
+                self._device = True
+                continue
             # creating commits are behind the snapshot boundary: log-less
             # stand-ins (clean() is a no-op) keep the retained-commit
             # discipline
-            self._held[key] = _Held(Commit(0, None, 0.0, None, None),
-                                    value=value, on_device=on_device)
+            self._record(key, _Held(Commit(0, None, 0.0, None, None),
+                                    value=value))
 
     # -- edge read tier (docs/EDGE_READS.md): full-state delta ------------
-    # Armed per-key TTLs opt out (timers fire outside the apply path —
-    # the value machine's rule); device-resident values gather through
-    # ONE batched query_step round, not a blocking round per key (this
-    # runs on the apply plane's event loop every delta flush).
+    # The keys of the device table are the device's to know, so a map
+    # that may hold any there serves no edge state (its subscribers
+    # retire, the snapshot_state rule); a map held on the host alone
+    # serves its records. Armed per-key TTLs opt out (timers fire
+    # outside the apply path — the value machine's rule).
 
     def edge_state(self) -> Any:
-        if any(h.timer is not None for h in self._held.values()):
+        if self._device or any(
+                h.timer is not None for h in self._held.values()):
             return NotImplemented
-        out = {k: h.value for k, h in self._held.items()
-               if not h.on_device}
-        dev_keys = [k for k, h in self._held.items() if h.on_device]
-        if dev_keys:
-            n = len(dev_keys)
-            raws = self._eng.run_query_vector(
-                [self._group] * n, [ops().OP_MAP_GET] * n, dev_keys,
-                [0] * n, [0] * n)
-            out.update(zip(dev_keys, raws))
-        return ("map", out)
+        return ("map", {k: h.value for k, h in self._held.items()})
 
     def delete(self) -> None:
-        def chain():
-            if any(h.on_device for h in self._held.values()):
-                # reset for group reuse
-                yield from self._cmd(ops().OP_MAP_CLEAR)
-            for held in self._held.values():
-                held.discard()
-            self._held.clear()
-
-        self._run_excl(chain())
+        self._run_excl(self._reset())   # reset for group reuse
         super().delete()
 
 

@@ -54,6 +54,22 @@ INT_MAX = jnp.iinfo(jnp.int32).max
 #: must avoid INT_MIN (the host facades enforce this).
 FAIL = int(INT_MIN)
 
+#: A keyed map op whose ``c`` is ``MAP_TELL`` tells an absent key from a
+#: stored 0: where the reply is "previous value | 0" it says ``ABSENT``
+#: for a key that was not there, and ``FULL`` where a put found no free
+#: slot (``FAIL`` is the engine's own refusal, which the served path
+#: reports as an error). The served path's values avoid all three.
+ABSENT = FAIL + 1
+FULL = FAIL + 2
+MAP_TELL = -1
+
+#: Slots of one map bucket. A bucket of the table is one tile of int32
+#: lanes, ``MAP_TILE``: two rows of keys, two of values, two of deadlines
+#: and two of live flags, which the chip keeps and moves as one piece
+#: (a bucket laid out over several tiles costs an index a piece).
+MAP_BUCKET = 256
+MAP_TILE = (8, 128)
+
 # --- opcodes (device-path operation catalog) -------------------------------
 # Mirrors the reference's serializer-id catalogs as a dense opcode space:
 # AtomicValueCommands ids 50-55, MapCommands ids 60-72, SetCommands 100-105,
@@ -67,7 +83,9 @@ OP_VALUE_CAS = 3          # a=expect, b=update -> 1 if swapped else 0
 OP_VALUE_GET_AND_SET = 4  # a=update -> previous value
 OP_LONG_ADD = 5           # a=delta -> new value (addAndGet)
 
-# map (MapState.java:32; hashed fixed keyspace per SURVEY.md §7.1)
+# map (MapState.java:32; hashed fixed keyspace per SURVEY.md §7.1). With
+# c=MAP_TELL (no TTL) "| 0" reads "| ABSENT", a full table FULL, and
+# PUT_IF_ABSENT answers the value it found | ABSENT when it put.
 OP_MAP_PUT = 10           # a=key, b=value, c=ttl -> previous value | 0
 OP_MAP_GET = 11           # a=key -> value | 0
 OP_MAP_REMOVE = 12        # a=key -> previous value | 0
@@ -217,6 +235,15 @@ class ResourceState(NamedTuple):
     map_val: jnp.ndarray   # [G,P,K] i32
     map_live: jnp.ndarray  # [G,P,K] bool
     map_dl: jnp.ndarray    # [G,P,K] i32 (0 = no TTL)
+    # The same map where ``map_buckets(map_slots)`` is more than one: the
+    # four planes above are then zero-width and the table lives here, a
+    # bucket's keys, values, deadlines and live flags in one ``MAP_TILE``,
+    # so that one index fetches or stores a bucket whole. A key lives in
+    # the bucket ``map_bucket`` names. ``map_count``: live slots, and those
+    # of them with a deadline: what size/is_empty read. Both zero-width
+    # otherwise.
+    map_table: jnp.ndarray  # [G,P,NB,8,128] i32
+    map_count: jnp.ndarray  # [G,P,2] i32
 
     # set: probe table without values
     set_key: jnp.ndarray   # [G,P,Ks] i32
@@ -262,6 +289,24 @@ class ResourceState(NamedTuple):
     tp_live: jnp.ndarray    # [G,P,T] bool
 
 
+def map_buckets(map_slots: int) -> int:
+    """Buckets of a map table of ``map_slots``: ``MAP_BUCKET`` slots each
+    where the table is whole buckets and more than one, else the table
+    is its one bucket (and a keyed op passes over all of it)."""
+    if map_slots > MAP_BUCKET and map_slots % MAP_BUCKET == 0:
+        return map_slots // MAP_BUCKET
+    return 1
+
+
+def map_bucket(key: jnp.ndarray, buckets: int) -> jnp.ndarray:
+    """The bucket a key lives in: multiply-shift on the key's 32 bits
+    (Knuth's 2^32/phi), the high 16 bits modulo ``buckets``. Part of the
+    state's format (docs/DURABILITY.md): every member and every restore
+    has to find a key where the put left it."""
+    mixed = key.astype(jnp.uint32) * jnp.uint32(2654435761)
+    return ((mixed >> 16) % jnp.uint32(buckets)).astype(jnp.int32)
+
+
 def init_resources(num_groups: int, num_peers: int,
                    rc: ResourceConfig = ResourceConfig()) -> ResourceState:
     G, P = num_groups, num_peers
@@ -273,10 +318,15 @@ def init_resources(num_groups: int, num_peers: int,
     def zb(n):
         return jnp.zeros((G, P, n), bool)
 
+    buckets = map_buckets(rc.map_slots)
+    flat = rc.map_slots if buckets == 1 else 0
     return ResourceState(
         value=z2, val_dl=z2,
-        map_key=zi(rc.map_slots), map_val=zi(rc.map_slots),
-        map_live=zb(rc.map_slots), map_dl=zi(rc.map_slots),
+        map_key=zi(flat), map_val=zi(flat),
+        map_live=zb(flat), map_dl=zi(flat),
+        map_table=zi(0) if buckets == 1 else jnp.zeros(
+            (G, P, buckets, *MAP_TILE), jnp.int32),
+        map_count=zi(0 if buckets == 1 else 2),
         set_key=zi(rc.set_slots), set_live=zb(rc.set_slots),
         set_dl=zi(rc.set_slots),
         q_val=zi(rc.queue_slots), q_head=z2, q_size=z2,
@@ -446,21 +496,23 @@ def apply_value(value, val_dl, opcode, a, b, c, now, live):
     return (out_value, out_dl), result
 
 
-def apply_map(mk, mv, ml, mdl, opcode, a, b, c, now, live):
-    """Hashed probe-table map; returns ((mk, mv, ml, mdl), result)."""
+def _map_row(mk, mv, ml, mdl, opcode, a, b, c, now, live):
+    """One entry a lane against one row of map slots ``[..., N]``: the
+    whole table where it is one bucket, else the key's bucket. Returns
+    ((mk, mv, ml, mdl), result); size, is_empty, contains_value and clear
+    speak of the row alone."""
     def op(code):
         return live & (opcode == code)
 
     is_map = live & (opcode >= OP_MAP_PUT) & (opcode <= OP_MAP_CLEAR)
     result = jnp.zeros_like(opcode)
-    if mk.shape[-1] == 0:
-        return (mk, mv, ml, mdl), jnp.where(is_map, INT_MIN, result)
-
+    tell = c == MAP_TELL
     m_alive = ml & ((mdl == 0) | (mdl > now[..., None]))
     hit = m_alive & (mk == a[..., None])
     hit_idx, hit_any = _first_true(hit)
     free_idx, free_any = _first_true(~m_alive)
-    old = jnp.where(hit_any, _gather3(mv, hit_idx), 0)
+    old = jnp.where(hit_any, _gather3(mv, hit_idx),
+                    jnp.where(tell, ABSENT, 0))
 
     put = op(OP_MAP_PUT)
     pia = op(OP_MAP_PUT_IF_ABSENT)
@@ -482,24 +534,27 @@ def apply_map(mk, mv, ml, mdl, opcode, a, b, c, now, live):
     rm = op(OP_MAP_REMOVE) | (op(OP_MAP_REMOVE_IF) & (old == b))
     ml = _scatter3(ml, hit_idx, rm & hit_any, jnp.zeros_like(a, bool))
     ml = jnp.where(op(OP_MAP_CLEAR)[..., None], False, ml)
-    # drop expired slots whenever any map op touches the group (lazy
+    # drop expired slots whenever any map op touches the row (lazy
     # purge; just-written slots have dl == 0 or dl > now, so they
     # always survive)
     ml = jnp.where(is_map[..., None],
                    ml & ((mdl == 0) | (mdl > now[..., None])), ml)
 
     m_size = jnp.sum(m_alive, axis=-1).astype(jnp.int32)
+    full = jnp.where(tell, FULL, INT_MIN)
     result = jnp.where(put, old, result)
-    result = jnp.where(put & write_new & ~free_any, INT_MIN, result)
-    result = jnp.where(pia, jnp.where(hit_any, 0,
-                       jnp.where(free_any, 1, INT_MIN)), result)
+    result = jnp.where(put & write_new & ~free_any, full, result)
+    result = jnp.where(pia, jnp.where(
+        hit_any, jnp.where(tell, old, 0),
+        jnp.where(free_any, jnp.where(tell, ABSENT, 1), full)), result)
     result = jnp.where(op(OP_MAP_GET), old, result)
     result = jnp.where(op(OP_MAP_GET_OR_DEFAULT),
                        jnp.where(hit_any, old, b), result)
     result = jnp.where(op(OP_MAP_REMOVE), old, result)
     result = jnp.where(op(OP_MAP_REMOVE_IF),
                        (hit_any & (old == b)).astype(jnp.int32), result)
-    result = jnp.where(rep, jnp.where(hit_any, old, INT_MIN), result)
+    result = jnp.where(rep, jnp.where(
+        hit_any, old, jnp.where(tell, ABSENT, INT_MIN)), result)
     result = jnp.where(op(OP_MAP_REPLACE_IF), repif.astype(jnp.int32),
                        result)
     result = jnp.where(op(OP_MAP_CONTAINS_KEY),
@@ -511,6 +566,100 @@ def apply_map(mk, mv, ml, mdl, opcode, a, b, c, now, live):
     result = jnp.where(op(OP_MAP_IS_EMPTY),
                        (m_size == 0).astype(jnp.int32), result)
     return (mk, mv, ml, mdl), result
+
+
+def apply_map(mk, mv, ml, mdl, mt, mc, opcode, a, b, c, now, live,
+              peer=None):
+    """Hashed probe-table map; returns ((mk, mv, ml, mdl, mt, mc), result).
+
+    A table of one bucket is the four planes, and every op passes over
+    them. A table of more is ``mt``: an op fetches its key's bucket (one
+    gather index a lane moves the bucket's four rows), works on that and
+    stores it back (one scatter index); no term touches more unless the
+    op is about the whole map. ``size``/``is_empty`` read ``mc``;
+    ``contains_value`` and a size over slots with deadlines (an expired
+    slot counts until its bucket is touched) pass over the table in an
+    evaluation in which some lane holds one, ``clear`` writes it in a
+    round in which some lane holds one.
+
+    ``peer`` ([G,S], read-only callers): lane ``[g,s]`` reads replica
+    ``peer[g,s]`` of ``mt [G,P,...]``; ``mc`` is already the lanes'.
+    ``None``: the lanes are the replicas, and buckets are stored."""
+    is_map = live & (opcode >= OP_MAP_PUT) & (opcode <= OP_MAP_CLEAR)
+    if mt.ndim != 5:
+        if mk.shape[-1] == 0:
+            return (mk, mv, ml, mdl, mt, mc), jnp.where(
+                is_map, INT_MIN, jnp.zeros_like(opcode))
+        rows, result = _map_row(mk, mv, ml, mdl, opcode, a, b, c, now, live)
+        return (*rows, mt, mc), result
+
+    def op(code):
+        return live & (opcode == code)
+
+    G, P, buckets = mt.shape[:3]
+
+    def planes(t):          # tiles [..., 8, 128] -> key, value, deadline, live
+        k, v, dl, lv = jnp.moveaxis(
+            t.reshape(*t.shape[:-2], 4, MAP_BUCKET), -2, 0)
+        return k, v, dl, lv != 0
+
+    at = (jnp.arange(G)[:, None],
+          jnp.arange(P)[None, :] if peer is None else peer,
+          map_bucket(a, buckets))
+    with jax.named_scope("map_lookup"):
+        ok, ov, odl, ol = planes(mt[at])                   # each [G,X,B]
+        (rk, rv, rl, rdl), result = _map_row(
+            ok, ov, ol, odl, opcode, a, b, c, now, live)
+        if peer is None:
+            mt = mt.at[at].set(
+                jnp.stack([rk, rv, rdl, rl.astype(jnp.int32)],
+                          axis=-2).reshape(*rk.shape[:-1], *MAP_TILE),
+                indices_are_sorted=True, unique_indices=True)
+            count = lambda live_, dl: jnp.stack(
+                [live_.sum(-1, dtype=jnp.int32),
+                 (live_ & (dl != 0)).sum(-1, dtype=jnp.int32)], axis=-1)
+            mc = mc + count(rl, rdl) - count(ol, odl)
+
+    has_value = op(OP_MAP_CONTAINS_VALUE)
+    sized = op(OP_MAP_SIZE) | op(OP_MAP_IS_EMPTY)
+    clear = op(OP_MAP_CLEAR)
+
+    def whole(t):
+        """What only a pass over a lane's whole table can say: does it
+        hold the value, and how many slots are alive now."""
+        # on the tiles as they lie (rows 2-3 values, 4-5 deadlines, 6-7
+        # live): a reshape of the whole table would be a copy of it
+        val, dl, lv = t[..., 2:4, :], t[..., 4:6, :], t[..., 6:8, :]
+        at_ = lambda x: x[..., None, None, None]
+        alive = (lv != 0) & ((dl == 0) | (dl > at_(now)))
+        return (jnp.any(alive & (val == at_(a)), axis=(-3, -2, -1)),
+                jnp.where(mc[..., 1] > 0,
+                          alive.sum((-3, -2, -1), dtype=jnp.int32),
+                          mc[..., 0]))
+
+    asked = jnp.any(has_value | (sized & (mc[..., 1] > 0)))
+    if peer is None:
+        # One loop of no or one turn for the reads above and for clear: a
+        # loop's carry is updated in place, where a conditional's
+        # pass-through branch would copy the table every entry.
+        _, mt, found, size = jax.lax.while_loop(
+            lambda carry: carry[0],
+            lambda carry: (False, jnp.where(
+                clear[..., None, None, None], 0, carry[1]),
+                *whole(carry[1])),
+            (asked | jnp.any(clear), mt, jnp.zeros_like(live), mc[..., 0]))
+        mc = jnp.where(clear[..., None], 0, mc)
+    else:
+        found, size = jax.lax.cond(
+            asked,
+            lambda: whole(jnp.take_along_axis(
+                mt, peer[:, :, None, None, None], axis=1)),
+            lambda: (jnp.zeros_like(live), mc[..., 0]))
+    result = jnp.where(has_value, found.astype(jnp.int32), result)
+    result = jnp.where(op(OP_MAP_SIZE), size, result)
+    result = jnp.where(op(OP_MAP_IS_EMPTY), (size == 0).astype(jnp.int32),
+                       result)
+    return (mk, mv, ml, mdl, mt, mc), result
 
 
 def apply_set(sk, sl, sdl, opcode, a, b, c, now, live):
@@ -883,6 +1032,7 @@ def apply_entry(
     index: jnp.ndarray,   # [G,P] i32 — absolute log index of this entry
     now: jnp.ndarray,     # [G,P] i32 — entry's logical timestamp
     live: jnp.ndarray,    # [G,P] bool — entry exists and is being applied
+    map_peer: jnp.ndarray | None = None,  # apply_map's ``peer``
 ) -> tuple[ResourceState, jnp.ndarray]:
     """Apply one committed entry per (group, replica) lane.
 
@@ -898,9 +1048,9 @@ def apply_entry(
     """
     (value, val_dl), r_val = apply_value(
         res.value, res.val_dl, opcode, a, b, c, now, live)
-    (mk, mv, ml, mdl), r_map = apply_map(
-        res.map_key, res.map_val, res.map_live, res.map_dl,
-        opcode, a, b, c, now, live)
+    (mk, mv, ml, mdl, mt, mc), r_map = apply_map(
+        res.map_key, res.map_val, res.map_live, res.map_dl, res.map_table,
+        res.map_count, opcode, a, b, c, now, live, map_peer)
     (sk, sl, sdl), r_set = apply_set(
         res.set_key, res.set_live, res.set_dl, opcode, a, b, c, now, live)
     (qv, qh, qs), r_q = apply_queue(
@@ -923,7 +1073,8 @@ def apply_entry(
 
     res = res._replace(
         value=value, val_dl=val_dl,
-        map_key=mk, map_val=mv, map_live=ml, map_dl=mdl,
+        map_key=mk, map_val=mv, map_live=ml, map_dl=mdl, map_table=mt,
+        map_count=mc,
         set_key=sk, set_live=sl, set_dl=sdl,
         q_val=qv, q_head=qh, q_size=qs,
         lk_holder=holder, lk_wait_id=wid, lk_wait_dl=wdl, lk_wait_live=wlv,
@@ -1051,16 +1202,30 @@ def apply_window(
             op_i, a_i, b_i, c_i, idx_i, now_i, live_i = x
             out = kernel(*st, op_i, a_i, b_i, c_i, idx_i, now_i, live_i)
             return out[0], out[1:]
-        # Full unroll: lax.scan blocks cross-iteration fusion, and with
-        # only ONE pool's arrays in the carry, XLA fuses the unrolled
-        # iterations into far fewer passes over that pool's HBM.
-        state, outs = jax.lax.scan(body, state_arrays, xs, unroll=True)
 
         def unpick(stacked):  # [B,G,P] -> [G,P,A] at window positions
             by_slot = jnp.moveaxis(stacked, 0, -1)            # [G,P,B]
             if oh is None:
                 return by_slot
             return jnp.where(oh, by_slot[..., None, :], 0).sum(axis=-1)
+        if k == POOL_MAP and res.map_table.ndim == 5:
+            # A bucketed map entry costs its lanes' gather and scatter
+            # indices whether the lanes are live or not: stop after the
+            # last position any lane holds, the carry updated in place.
+            def turn(i, carry):
+                st, out = carry
+                st, (r,) = body(st, jax.tree.map(lambda v: v[i], xs))
+                return st, out.at[i].set(r)
+
+            last = jnp.max(jnp.where(
+                jnp.any(live_b, axis=(0, 1)), 1 + jnp.arange(B), 0))
+            state, out = jax.lax.fori_loop(
+                0, last, turn, (state_arrays, jnp.zeros_like(xs[0])))
+            return state, unpick(out), None
+        # Full unroll: lax.scan blocks cross-iteration fusion, and with
+        # only ONE pool's arrays in the carry, XLA fuses the unrolled
+        # iterations into far fewer passes over that pool's HBM.
+        state, outs = jax.lax.scan(body, state_arrays, xs, unroll=True)
 
         contribution = unpick(outs[0])
         events = None
@@ -1071,8 +1236,8 @@ def apply_window(
     # adapters: uniform (state..., op, a, b, c, index, now, live) signature
     k_val = lambda v, dl, op_, a_, b_, c_, i_, n_, lv: \
         apply_value(v, dl, op_, a_, b_, c_, n_, lv)
-    k_map = lambda mk, mv, ml, mdl, op_, a_, b_, c_, i_, n_, lv: \
-        apply_map(mk, mv, ml, mdl, op_, a_, b_, c_, n_, lv)
+    k_map = lambda mk, mv, ml, mdl, mt, mc, op_, a_, b_, c_, i_, n_, lv: \
+        apply_map(mk, mv, ml, mdl, mt, mc, op_, a_, b_, c_, n_, lv)
     k_set = lambda sk, sl, sdl, op_, a_, b_, c_, i_, n_, lv: \
         apply_set(sk, sl, sdl, op_, a_, b_, c_, n_, lv)
     k_q = lambda qv, qh, qs, op_, a_, b_, c_, i_, n_, lv: \
@@ -1089,9 +1254,9 @@ def apply_window(
     (value, val_dl), r, _ = fold(
         k_val, (res.value, res.val_dl), POOL_VALUE, 1)
     result = result + r
-    (mk, mv, ml, mdl), r, _ = fold(
-        k_map, (res.map_key, res.map_val, res.map_live, res.map_dl),
-        POOL_MAP, 1)
+    (mk, mv, ml, mdl, mt, mc), r, _ = fold(
+        k_map, (res.map_key, res.map_val, res.map_live, res.map_dl,
+                res.map_table, res.map_count), POOL_MAP, 1)
     result = result + r
     (sk, sl, sdl), r, _ = fold(
         k_set, (res.set_key, res.set_live, res.set_dl), POOL_SET, 1)
@@ -1118,7 +1283,8 @@ def apply_window(
 
     res = res._replace(
         value=value, val_dl=val_dl,
-        map_key=mk, map_val=mv, map_live=ml, map_dl=mdl,
+        map_key=mk, map_val=mv, map_live=ml, map_dl=mdl, map_table=mt,
+        map_count=mc,
         set_key=sk, set_live=sl, set_dl=sdl,
         q_val=qv, q_head=qh, q_size=qs,
         lk_holder=holder, lk_wait_id=wid, lk_wait_dl=wdl, lk_wait_live=wlv,

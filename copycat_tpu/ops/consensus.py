@@ -527,17 +527,27 @@ def query_step(state: RaftState, queries: Submits,
     # Leader-lane view of every pool, broadcast over the S query slots so
     # the shape-generic apply kernel evaluates ALL slots in one fused pass
     # (the broadcast is a view — reads never materialize [G,S,...] pools).
+    # A map table of buckets is never viewed whole: the kernel fetches the
+    # key's bucket from the leader's replica of the table as it is.
+    res = state.resources
+    bucketed = res.map_table.ndim == 5
+    if bucketed:
+        res = res._replace(map_table=res.map_count[..., :0])
     lres = jax.tree.map(
         lambda x: jnp.broadcast_to(
             _peer_view(x, lead)[:, None], (G, S) + x.shape[2:]),
-        state.resources)
+        res)
+    map_peer = None
+    if bucketed:
+        lres = lres._replace(map_table=state.resources.map_table)
+        map_peer = jnp.broadcast_to(jnp.maximum(lead, 0)[:, None], (G, S))
     now = jnp.broadcast_to(_peer_view(state.clock, lead)[:, None], (G, S))
 
     # Read-only evaluation: the returned (possibly TTL-purged) state is
     # discarded, so the replicated pools are never perturbed.
     _, results = apply_entry(
         lres, queries.opcode, queries.a, queries.b, queries.c,
-        jnp.zeros_like(queries.opcode), now, served)
+        jnp.zeros_like(queries.opcode), now, served, map_peer)
     return jnp.where(served, results, 0), served
 
 
@@ -1013,7 +1023,23 @@ def step(state: RaftState, submits: Submits, deliver: jnp.ndarray,
             return apply_entry(resources, op_i, a_i, b_i, c_i, idx,
                                time_i, do)
 
-        resources, res_all = jax.lax.scan(_apply_one, state.resources, xs)
+        if state.resources.map_table.ndim == 5:
+            # A bucketed map entry costs its lanes' gather and scatter
+            # indices whether the lanes are live or not: take as many
+            # turns as the busiest lane applies (a lane's entries are a
+            # prefix of the window), the carry updated in place.
+            def _turn(i, carry):
+                resources, res_all = carry
+                resources, r = _apply_one(
+                    resources, jax.tree.map(lambda v: v[i], xs))
+                return resources, res_all.at[i].set(r)
+
+            resources, res_all = jax.lax.fori_loop(
+                0, jnp.max(do_all.sum(axis=-1, dtype=jnp.int32)), _turn,
+                (state.resources, jnp.zeros_like(xs[0])))
+        else:
+            resources, res_all = jax.lax.scan(
+                _apply_one, state.resources, xs)
         res_w = jnp.moveaxis(res_all, 0, 2)                   # [G,P,A]
         admitted = do_all
     applied = state.applied_index \

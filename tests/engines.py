@@ -12,6 +12,7 @@ line. Seeds stay per test: a seed is data and costs no compile.
 
 from copycat_tpu.manager.device_executor import DeviceEngineConfig
 from copycat_tpu.models import RaftGroups
+from copycat_tpu.ops.apply import ResourceConfig
 from copycat_tpu.ops.consensus import Config
 
 #: the deep drive's engines (``models/bulk.py``)
@@ -54,6 +55,17 @@ def short_ring(config: Config | None = None, seed: int = 0,
                       seed=seed, **kw)
 
 
+#: a map table of two buckets (``ops.apply.map_buckets``), the other pools
+#: as they are everywhere
+WIDE_MAP = Config(resource=ResourceConfig(map_slots=512))
+
+
+def wide_map(seed: int = 0, **kw) -> RaftGroups:
+    """The device plane with a map table of two buckets: a keyed map op
+    fetches its key's bucket, and 257 keys of one bucket fill it."""
+    return device_plane(WIDE_MAP, seed=seed, **kw)
+
+
 #: the served stacks' device executor (``AtomixServer(executor="tpu")``):
 #: the device plane's shape, so a served engine runs its programs
 SERVED = DeviceEngineConfig(capacity=G, num_peers=3, log_slots=32)
@@ -61,3 +73,6 @@ SERVED = DeviceEngineConfig(capacity=G, num_peers=3, log_slots=32)
 #: the same with room for 32 device resources, for the tests that hold
 #: more than eight at once
 SERVED_WIDE = SERVED._replace(capacity=32)
+
+#: the served shape over :data:`WIDE_MAP`'s pools
+SERVED_MAP = SERVED._replace(resource=WIDE_MAP.resource)

@@ -302,3 +302,25 @@ def test_group_sharded_step_has_zero_collectives(four_chips, pallas):
     compiled = compile_step(4096, 3, 64, 4, config, four_chips)
     assert collectives_in(compiled) == {}
     assert has_kernel(compiled) == pallas
+
+
+def test_a_bucketed_map_round_writes_its_table_in_place(one_chip):
+    """``map-1kx10k``'s engine (1,024 x 3, a map table of 16,384 slots =
+    64 buckets and no other pool): the round stores buckets into the
+    donated table, 805 MB, and holds no second copy of it (a relayout
+    between the scatter and the loop around ``clear`` once cost one a
+    turn); a query evaluation holds one replica's view only in the branch
+    that a ``contains_value`` takes."""
+    pools = dict.fromkeys(ResourceConfig._fields, 0) | {"map_slots": 16384}
+    config = Config(resource=ResourceConfig(**pools))
+    args = round_args(1024, 3, 64, 4, config, one_chip)
+    table = args[0].resources.map_table
+    assert table.shape == (1024, 3, 64, 8, 128)
+    table_bytes = table.size * table.dtype.itemsize
+    step_program, query_program, _ = _jitted_programs(config)
+    mem = step_program.lower(*args).compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= table_bytes
+    assert mem.temp_size_in_bytes < table_bytes // 8, mem
+    query = query_program.lower(
+        *round_args(1024, 3, 64, 1, config, one_chip, planes=7)).compile()
+    assert query.memory_analysis().temp_size_in_bytes <= table_bytes // 2
