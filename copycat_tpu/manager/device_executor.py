@@ -611,7 +611,7 @@ class DeviceEngine:
         return evs[-1][0] if evs else -1
 
     def run_vector(self, groups_idx, opcodes, a, b, c,
-                   max_rounds: int = 200) -> list[int]:
+                   max_rounds: int = 200, query: Any = None) -> list[int]:
         """The batched server-side pump's device leg: stage EVERY row in
         one vectorized pass (the ``_stage_direct`` fast lane scatters a
         fitting burst straight into the next round's Submits) and step
@@ -628,10 +628,15 @@ class DeviceEngine:
         when direct staging is refused (queued ops from generator
         chains, held groups) it degrades to the tracked submit_batch +
         results-dict walk, which interleaves correctly with the queue-
-        managed machinery."""
+        managed machinery.
+
+        ``query`` is a read window's staged rows
+        (:meth:`stage_query_vector`): the primary lane offers them to the
+        run's round, the tracked lane leaves them as they were, and
+        :meth:`finish_query_vector` answers them either way."""
         groups = self._ensure()
         res = groups.drive_vector(groups_idx, opcodes, a, b, c,
-                                  max_rounds=max_rounds)
+                                  max_rounds=max_rounds, query=query)
         if res is not None:
             return res.tolist()
         tags = groups.submit_batch(groups_idx, opcodes, a, b, c)
@@ -656,6 +661,19 @@ class DeviceEngine:
         groups = self._ensure()
         return groups.drive_query_vector(
             groups_idx, opcodes, a, b, c).tolist()
+
+    def stage_query_vector(self, groups_idx, opcodes, a, b, c) -> Any:
+        """The read pump's rows marshalled ahead of a parked vector run,
+        so that :meth:`run_vector` can take them along in the run's round
+        (``RaftGroups.stage_query_vector``)."""
+        return self._ensure().stage_query_vector(groups_idx, opcodes,
+                                                 a, b, c)
+
+    def finish_query_vector(self, query: Any) -> list[int]:
+        """Results of staged read rows, aligned with them: what the run's
+        round answered, and one evaluation of their own for what it did
+        not (``RaftGroups.finish_query_vector``)."""
+        return self._ensure().finish_query_vector(query).tolist()
 
 
 class _Held:
@@ -799,6 +817,16 @@ class DeviceBackedStateMachine(ResourceStateMachine):
         device query, or ``None`` when the read needs its handler (host
         shadow values, host-derived answers, mixed host/device state)."""
         return None
+
+    #: A read window that finds a vector run parked routes its reads
+    #: BEFORE the run's rows are finalized, so that they ride the run's
+    #: round. True where that cannot change a reply: ``vector_finalize``
+    #: writes nothing ``query_spec`` reads and leaves no device work
+    #: behind. Elsewhere a read of a machine with a parked row is routed
+    #: after the run has landed, as every read was
+    #: (``RaftGroup._route_ahead``; tests/test_read_joins_round.py holds
+    #: each class with a ``query_spec`` to its answer).
+    ROUTE_OUTLIVES_FINALIZE = False
 
     def query_finalize(self, kind: int, operation: Any, raw: int) -> Any:
         """Shape the raw device int like the plain handler's return."""
@@ -1036,6 +1064,10 @@ class DeviceAtomicValueState(DeviceBackedStateMachine):
                 and self._held.on_device):
             return (ops().OP_VALUE_GET, 0, 0, 0, QK_RAW)
         return None
+
+    #: every vector-eligible op leaves the value held on the device (see
+    #: the vector lane above), which is all ``query_spec`` reads
+    ROUTE_OUTLIVES_FINALIZE = True
 
     # -- change listeners (same protocol as the CPU machine) ---------------
     # listen/unlisten are host-state-only but still run as ordered jobs

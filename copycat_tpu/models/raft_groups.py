@@ -106,18 +106,36 @@ class PackedOutputs:
                            telemetry=self.telemetry)
 
 
+def _query_slab(state, packed, config: Config):
+    """Inside a program: ``query_step`` over :func:`_pack_host`'s seven
+    query planes (the six of ``Submits`` and ``atomic``); ``(results,
+    served)`` leave side by side as one int32 slab."""
+    queries, (atomic,) = _unpack_submits(packed, planes=7)
+    results, served = query_step(state, queries, atomic != 0, config=config)
+    return jnp.concatenate([results, served.astype(jnp.int32)], axis=1)
+
+
 @lru_cache(maxsize=None)
 def _jitted_programs(config: Config):
-    """(step, query, install) jit wrappers shared across all RaftGroups
-    instances with the same static Config (Config is a hashable NamedTuple,
-    so it keys the cache; shapes are handled inside each jit wrapper).
+    """(step, query, install, step-and-query) jit wrappers shared across
+    all RaftGroups instances with the same static Config (Config is a
+    hashable NamedTuple, so it keys the cache; shapes are handled inside
+    each jit wrapper).
 
     The signatures are cut to what a call costs the runtime, which is per
     buffer and not per byte: the state and the PRNG key are donated (their
     outputs alias them), the submits arrive as one buffer, the key is split
     inside (the same integers as an eager split) and the outputs leave as
     :class:`PackedOutputs`. The query program reads the state again and
-    donates nothing; its ``(results, served)`` leave as one int32 slab."""
+    donates nothing; its ``(results, served)`` leave as one int32 slab.
+
+    The fourth is both in one call, for a read window that finds a vector
+    run parked (:meth:`RaftGroups.step_round`): the round, then the query
+    planes that rode in behind the submits' (``slots`` wide each, static)
+    evaluated on the state the round wrote. Same ``step``, same
+    ``query_step``, one call and one fetch where the two programs cost two
+    of each. Its compiled name has to start ``jit_round_``: the benchmark
+    finds a served round's modules by that."""
 
     def round_(state, packed, deliver, key):
         key, k = jax.random.split(key)
@@ -126,15 +144,18 @@ def _jitted_programs(config: Config):
         return state, key, PackedOutputs.pack(out)
 
     def query(state, packed):
-        queries, (atomic,) = _unpack_submits(packed, planes=7)
-        results, served = query_step(state, queries, atomic != 0,
-                                     config=config)
-        return jnp.concatenate([results, served.astype(jnp.int32)], axis=1)
+        return _query_slab(state, packed, config)
+
+    def round_query(state, packed, deliver, key, slots):
+        cut = packed.shape[1] - 7 * slots
+        state, key, out = round_(state, packed[:, :cut], deliver, key)
+        return state, key, out, _query_slab(state, packed[:, cut:], config)
 
     return (jax.jit(round_, donate_argnums=(0, 3)),
             jax.jit(query),
             jax.jit(partial(install_snapshots, config=config),
-                    donate_argnums=0))
+                    donate_argnums=0),
+            jax.jit(round_query, donate_argnums=(0, 3), static_argnums=4))
 
 
 @lru_cache(maxsize=None)
@@ -164,6 +185,12 @@ def _fused_rounds_program(config: Config, n: int):
     return jax.jit(fused, donate_argnums=(0, 3))
 
 
+def _split_slab(slab: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """``(results, served)`` out of the query's one int32 slab."""
+    S = slab.shape[1] // 2
+    return slab[:, :S], slab[:, S:] != 0
+
+
 def _group_slot_pack(g: np.ndarray
                      ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Stable per-group slot assignment for ``[N]`` rows: returns
@@ -180,6 +207,55 @@ def _group_slot_pack(g: np.ndarray
     cnt = np.diff(np.append(starts, n))
     slots = np.arange(n) - np.repeat(starts, cnt)
     return order, gs, slots
+
+
+class QueryVector:
+    """``[N]`` read rows marshalled for ``query_step``: the rows in their
+    groups' slots (``planes``: the six of ``Submits`` and ``atomic``, each
+    ``[G, slots]``), where each row went (``order``, ``gs``, ``at``), and
+    what has come back (``out`` aligned with the input rows, ``done``).
+    :meth:`RaftGroups.stage_query_vector` makes one; a vector run's first
+    round takes it along (``rode``: the slab that round brought back, until
+    the run says whether it may be kept) or
+    :meth:`RaftGroups.finish_query_vector` evaluates it alone."""
+
+    __slots__ = ("n", "slots", "planes", "order", "gs", "at", "out", "done",
+                 "rode", "evaluations")
+
+    def __init__(self, g: np.ndarray, num_groups: int, op, a, b, c,
+                 atomic) -> None:
+        self.n = n = g.size
+        counts = np.bincount(g, minlength=num_groups)
+        width = int(counts.max(initial=1))
+        # pow2: burst-size jitter compiles at most log2 variants
+        self.slots = S = 1 << (width - 1).bit_length()
+        self.order, self.gs, self.at = order, gs, at = _group_slot_pack(g)
+        planes = [np.zeros((num_groups, S), np.int32) for _ in range(5)]
+        for plane, rows in zip(planes, (op, a, b, c)):
+            plane[gs, at] = rows[order]
+        valid = np.zeros((num_groups, S), bool)
+        valid[gs, at] = True
+        lease = np.zeros((num_groups, S), bool)
+        lease[gs, at] = atomic[order]
+        self.planes = (*planes, valid, lease)
+        self.out = np.zeros(n, np.int64)
+        self.done = np.zeros(n, bool)
+        self.rode: np.ndarray | None = None
+        self.evaluations = 0
+
+    def take(self, results: np.ndarray, served: np.ndarray) -> int:
+        """One evaluation's answers: rows served now are done and leave
+        the next evaluation; returns how many."""
+        self.evaluations += 1
+        gs, at, order = self.gs, self.at, self.order
+        hit = served[gs, at] & ~self.done[order]
+        if not hit.any():
+            return 0
+        rows = order[hit]
+        self.out[rows] = results[gs[hit], at[hit]]
+        self.done[rows] = True
+        self.planes[5][gs[hit], at[hit]] = False
+        return rows.size
 
 
 class RaftGroups:
@@ -264,8 +340,8 @@ class RaftGroups:
             # same Config (e.g. one device engine per server in a
             # multi-server test) share ONE compiled program instead of
             # recompiling per instance.
-            self._step, self._query, self._install = _jitted_programs(
-                self.config)
+            (self._step, self._query, self._install,
+             self._round_query) = _jitted_programs(self.config)
         else:
             # A subclass (parallel/multihost.py) supplies globally sharded
             # state/deliver and sharding-pinned jit wrappers itself —
@@ -274,6 +350,9 @@ class RaftGroups:
             self.state = None
             self.deliver = None
             self._step = self._query = self._install = None
+            # no round that takes a read window's rows along: a driver
+            # with round programs of its own evaluates them alone
+            self._round_query = None
         self._queues: dict[int, deque] = {}
         self._query_queues: dict[int, deque] = {}
         self._query_atomic: set[int] = set()  # tags needing the lease gate
@@ -317,6 +396,11 @@ class RaftGroups:
         self._m_fetches = self.metrics.counter("fetches")
         self._m_fetch_bytes = self.metrics.counter("fetch_bytes")
         self._m_settle_rounds = self.metrics.counter("query_settle_rounds")
+        # read windows evaluated (every one), and those of them whose rows
+        # all rode a vector run's round and cost no call of their own
+        self._m_queries_served = self.metrics.counter("queries_served")
+        self._m_query_drives = self.metrics.counter("query_vector_drives")
+        self._m_query_joined = self.metrics.counter("query_joined_drives")
         # buffers the runtime handles per call of the round's and the
         # query's programs (see _count_dispatch)
         self._m_dispatch_leaves = self.metrics.counter("dispatch_leaves")
@@ -586,8 +670,7 @@ class RaftGroups:
         raw = self._query(self.state, packed)
         slab = self._note_fetch(np.asarray(raw))
         self._count_dispatch(self._query, packed, raw)
-        S = slab.shape[1] // 2
-        return slab[:, :S], slab[:, S:] != 0
+        return _split_slab(slab)
 
     #: the round's programs donate the state and the key, so those
     #: outputs alias their inputs and cost the runtime no buffer; a
@@ -645,26 +728,39 @@ class RaftGroups:
 
     def step_round(self, submits: Submits | None = None,
                    deliver: Any | None = None,
-                   correlate: Any | None = None) -> StepOutputs:
+                   correlate: Any | None = None,
+                   query: QueryVector | None = None) -> StepOutputs:
         """Advance every group one round; harvests results into ``results``.
         ``correlate(out)`` is a caller's own pass over the round's outputs
         (``drive_vector``'s): it runs at the end of the round, inside its
-        harvest stage. One function, no inner helper: every Python frame
-        under the first call of the compiled step lengthens each of its
-        operations' source locations, and lowering pays for it."""
+        harvest stage. ``query`` is a read window's rows
+        (:meth:`stage_query_vector`): where this driver has the program
+        for it they ride the round, evaluated on the state it writes,
+        their planes in behind the submits' and their slab back in the
+        round's one fetch, and ``query.rode`` holds that slab; elsewhere
+        it stays ``None`` and the caller evaluates them alone. One
+        function, no inner helper: every Python frame under the first call
+        of the compiled step lengthens each of its operations' source
+        locations, and lowering pays for it."""
         stage = TRACER.open_span("engine.stage") if TRACER.enabled else None
         explicit = submits is not None
         if submits is None:
             submits = self._build_submits()
         dl = self.deliver if deliver is None else self._stage_deliver(deliver)
-        staged = self._stage_round(submits)
+        if query is None or self._round_query is None:
+            program, width = self._step, ()
+            staged = self._stage_round(submits)
+        else:
+            program, width = self._round_query, (query.slots,)
+            staged = _pack_host((*submits, *query.planes))
         if stage is not None:
             stage = stage.then("engine.wait")
             t0 = stage.start
         else:
             t0 = time.perf_counter()
-        self.state, self._key, raw = self._step(self.state, staged, dl,
-                                                self._key)
+        # (with a window's rows along, their slab comes back fourth)
+        self.state, self._key, raw, *rider = program(
+            self.state, staged, dl, self._key, *width)
         raw = jax.block_until_ready(raw)  # time compute, not dispatch
         if stage is not None:
             stage = stage.then("engine.fetch")
@@ -673,10 +769,16 @@ class RaftGroups:
             t1 = time.perf_counter()
         self._m_step_wall.record((t1 - t0) * 1e3)
         fetched = self._m_fetch_bytes.value
+        if rider:
+            rider[0].copy_to_host_async()  # in the one transfer below
         out = self._fetch_outputs(raw)
+        if rider:
+            query.rode = np.asarray(rider[0])
+            self._m_fetch_bytes.inc(query.rode.nbytes)
         self._count_dispatch(
-            self._step, staged,
-            raw if self.round_donates else (self.state, self._key, raw))
+            program, staged,
+            (raw, rider) if self.round_donates
+            else (self.state, self._key, raw))
         if stage is not None:
             stage = stage.then(
                 "engine.harvest", bytes=self._m_fetch_bytes.value - fetched)
@@ -887,68 +989,87 @@ class RaftGroups:
         a settling :meth:`step_round`, like :meth:`serve_query`; in the
         warm steady state every row serves on the first evaluation. The
         slot width pads to the next power of two so burst-size jitter
-        compiles at most log2 variants of the query program."""
+        compiles at most log2 variants of the query program.
+
+        The two halves, for a window whose rows may ride a vector run's
+        round between them: :meth:`stage_query_vector`,
+        :meth:`finish_query_vector`."""
+        if np.size(groups) == 0:
+            return np.zeros(0, np.int64)
+        span = TRACER.open_span("engine.query") if TRACER.enabled else None
+        query = self._marshal_query(groups, opcode, a, b, c, atomic)
+        return self.finish_query_vector(query, max_attempts, span)
+
+    def _marshal_query(self, groups, opcode, a, b, c,
+                       atomic) -> QueryVector:
         from ..ops.apply import QUERY_OPCODES
         g = np.asarray(groups, np.int64).ravel()
         n = g.size
-        out = np.zeros(n, np.int64)
-        if n == 0:
-            return out
-        span = TRACER.open_span("engine.query") if TRACER.enabled else None
         bc = lambda x: np.broadcast_to(
             np.asarray(x, np.int32).ravel(), (n,))
-        op_a, a_a, b_a, c_a = bc(opcode), bc(a), bc(b), bc(c)
+        op_a = bc(opcode)
         bad = ~np.isin(op_a, tuple(QUERY_OPCODES))
         if bad.any():
             raise ValueError(
                 f"opcode {int(op_a[bad][0])} is not read-only; submit it "
                 "as a command")
-        at_a = np.broadcast_to(np.asarray(atomic, bool).ravel(), (n,))
-        counts = np.bincount(g, minlength=self.num_groups)
-        width = int(counts.max(initial=1))
-        S = 1 << (width - 1).bit_length()  # pow2: bounded jit variants
-        G = self.num_groups
-        order, gs, slots = _group_slot_pack(g)
-        sub = Submits(opcode=np.zeros((G, S), np.int32),
-                      a=np.zeros((G, S), np.int32),
-                      b=np.zeros((G, S), np.int32),
-                      c=np.zeros((G, S), np.int32),
-                      tag=np.zeros((G, S), np.int32),
-                      valid=np.zeros((G, S), bool))
-        sub.opcode[gs, slots] = op_a[order]
-        sub.a[gs, slots] = a_a[order]
-        sub.b[gs, slots] = b_a[order]
-        sub.c[gs, slots] = c_a[order]
-        sub.valid[gs, slots] = True
-        at = np.zeros((G, S), bool)
-        at[gs, slots] = at_a[order]
-        done = np.zeros(n, bool)
-        served_ctr = self.metrics.counter("queries_served")
-        for attempt in range(max_attempts):
-            results, served = self._run_query(sub, at)
-            hit = served[gs, slots] & ~done[order]
-            if hit.any():
-                rows = order[hit]
-                out[rows] = results[gs[hit], slots[hit]]
-                done[rows] = True
-                served_ctr.inc(int(hit.sum()))
-                sub.valid[gs[hit], slots[hit]] = False
-            if self._agree(bool(done.all())):
-                self.metrics.counter("query_vector_drives").inc()
-                if span is not None:
-                    span.close(attempts=attempt + 1, width=S, n=n)
-                return out
-            # no leader yet / applied < commit: settle
-            self._m_settle_rounds.inc()
-            if span is not None:
-                with TRACER.scope(span.trace_id, "engine.query"):
-                    self.step_round()
-            else:
-                self.step_round()
+        return QueryVector(
+            g, self.num_groups, op_a, bc(a), bc(b), bc(c),
+            np.broadcast_to(np.asarray(atomic, bool).ravel(), (n,)))
+
+    def stage_query_vector(self, groups, opcode, a=0, b=0, c=0,
+                           atomic=False) -> QueryVector:
+        """:meth:`drive_query_vector`'s marshalling alone, for a read
+        window that finds a vector run parked: ``drive_vector(...,
+        query=)`` offers the rows to the run's first round, and
+        :meth:`finish_query_vector` returns their results, evaluating
+        what that round did not answer. ``engine.query`` is the marshal
+        here; the unpacking is part of the round's harvest."""
+        span = TRACER.open_span("engine.query") if TRACER.enabled else None
+        query = self._marshal_query(groups, opcode, a, b, c, atomic)
         if span is not None:
-            span.close(attempts=max_attempts, width=S, n=n, error="timeout")
+            span.close(attempts=0, width=query.slots, n=query.n)
+        return query
+
+    def finish_query_vector(self, query: QueryVector,
+                            max_attempts: int = 50,
+                            span: Any = None) -> np.ndarray:
+        """Results aligned with ``query``'s rows: one evaluation, then a
+        settling round and another for as long as a row is unserved. Rows
+        that rode a round and were all answered there cost nothing here
+        (``query_joined_drives``); every window counts one
+        ``query_vector_drives``."""
+        S, n = query.slots, query.n
+        planes = query.planes
+        rode = query.evaluations
+        while True:
+            if query.evaluations and self._agree(bool(query.done.all())):
+                self._m_query_drives.inc()
+                if rode and query.evaluations == rode:
+                    self._m_query_joined.inc()
+                if span is not None:
+                    span.close(attempts=query.evaluations, width=S, n=n)
+                return query.out
+            if query.evaluations - rode >= max_attempts:
+                break
+            if span is None and TRACER.enabled:
+                span = TRACER.open_span("engine.query")
+            if query.evaluations:
+                # no leader yet / applied < commit: settle
+                self._m_settle_rounds.inc()
+                if span is not None:
+                    with TRACER.scope(span.trace_id, "engine.query"):
+                        self.step_round()
+                else:
+                    self.step_round()
+            results, served = self._run_query(Submits(*planes[:6]),
+                                              planes[6])
+            self._m_queries_served.inc(query.take(results, served))
+        if span is not None:
+            span.close(attempts=query.evaluations, width=S, n=n,
+                       error="timeout")
         raise TimeoutError(
-            f"query vector: {int((~done).sum())}/{n} rows unservable "
+            f"query vector: {int((~query.done).sum())}/{n} rows unservable "
             f"after {max_attempts} attempts")
 
     def _record_assigned(self, submits: Submits, out: StepOutputs) -> None:
@@ -1184,7 +1305,8 @@ class RaftGroups:
         return tags
 
     def drive_vector(self, groups, opcode, a, b, c,
-                     max_rounds: int = 200) -> np.ndarray | None:
+                     max_rounds: int = 200,
+                     query: QueryVector | None = None) -> np.ndarray | None:
         """One-shot vectorized drive for full-delivery engines (the
         applying server's batched pump): stage every row straight into
         the next round's submit buffer, step shared rounds until all
@@ -1199,7 +1321,15 @@ class RaftGroups:
         sort preserves row order within a group and the engine applies
         accepted slots in log order; a rejected row (rare: group mid-
         election) is requeued by ``_requeue_rejected`` and caught by a
-        later round's correlation pass."""
+        later round's correlation pass.
+
+        ``query`` is a read window's staged rows, to be answered on the
+        state this run leaves. They ride the first round
+        (:meth:`step_round`); their answers are kept only if that round
+        resolved every row of the run, for a round that left one
+        unresolved evaluated them on a state the run had not finished
+        writing. Refused, or not kept, they are as they were staged and
+        :meth:`finish_query_vector` evaluates them alone."""
         g = np.asarray(groups, np.int64)
         n = g.size
         tags = np.arange(self._next_tag, self._next_tag + n)
@@ -1217,8 +1347,9 @@ class RaftGroups:
 
         def correlate(out: StepOutputs) -> None:
             """This block's rows among the round's reports, in one numpy
-            pass (part of the round's harvest)."""
-            nonlocal remaining
+            pass (part of the round's harvest); then, after the first
+            round, the answers of the reads that rode it."""
+            nonlocal remaining, query
             valid = np.asarray(out.out_valid)
             if valid.any():
                 gi, ii = np.nonzero(valid)
@@ -1244,9 +1375,15 @@ class RaftGroups:
                         res[k] = v
                         done[k] = True
                         remaining -= 1
+            if query is not None:
+                rode, query.rode = query.rode, None
+                if rode is not None and remaining == 0:
+                    self._m_queries_served.inc(
+                        query.take(*_split_slab(rode)))
+                query = None
 
         for _ in range(max_rounds):
-            self.step_round(correlate=correlate)
+            self.step_round(correlate=correlate, query=query)
             if remaining == 0:
                 self.metrics.counter("ops_committed").inc(n)
                 return res

@@ -1130,7 +1130,16 @@ class RaftServer(Managed):
             self._pump_batch = TRACER.new_trace()
         return self._pump_batch
 
-    def flush_fused(self, forced: str | None = None) -> None:
+    def parked_rows(self, engine: Any) -> list:
+        """The vector rows parked for ``engine``'s next round (empty when
+        none is): what a read window asks before it drains them, to know
+        whether its reads can ride that round and which machines the
+        round writes."""
+        return [row for grp, run in self._fused_runs
+                if grp.state_machine.device_engine is engine for row in run]
+
+    def flush_fused(self, forced: str | None = None, reader: Any = None,
+                    query: Any = None) -> None:
         """Dispatch every staged run as ONE mixed-rows engine round,
         then finalize per group in staging (= per-group log) order.
         Forced synchronously by dependency conflicts, gated reads,
@@ -1138,6 +1147,11 @@ class RaftServer(Managed):
         the trace); otherwise runs once per event-loop turn. An empty
         collector is a free no-op (every forced-flush site relies on
         that).
+
+        A read window that drains a run parked for its engine, ``reader``,
+        hands over its staged ``query`` rows (``DeviceEngine.
+        stage_query_vector``): that engine's round takes them along, and
+        the window reads their answers back after this returns.
 
         The documented architecture shares ONE engine across groups
         (``_manager_factory``), so the partition below is normally a
@@ -1166,15 +1180,20 @@ class RaftServer(Managed):
             bucket.append((grp, run))
         if not TRACER.enabled:
             for engine in engines:
-                self._flush_fused_engine(engine, per_engine[id(engine)])
+                self._flush_fused_engine(
+                    engine, per_engine[id(engine)],
+                    query if engine is reader else None)
             return
         # the turn's synchronous section: the engine records its stages
         # under the turn's id, as children of the requests' apply spans
         with TRACER.scope(batch or TRACER.new_trace(), "apply"):
             for engine in engines:
-                self._flush_fused_engine(engine, per_engine[id(engine)])
+                self._flush_fused_engine(
+                    engine, per_engine[id(engine)],
+                    query if engine is reader else None)
 
-    def _flush_fused_engine(self, engine, staged: list) -> None:
+    def _flush_fused_engine(self, engine, staged: list,
+                            query: Any = None) -> None:
         rows = [row for _, run in staged for row in run]
         self._m_apply_fused.inc()
         self._m_apply_fused_rows.record(len(rows))
@@ -1185,7 +1204,7 @@ class RaftServer(Managed):
         # dispatch helper, so each group's device-op order follows its
         # log
         raws, pump_error = dispatch_vector_rows(engine, engine.window,
-                                                rows)
+                                                rows, query)
         finalize = (TRACER.open_span("apply.finalize")
                     if TRACER.enabled else None)
         offset = 0

@@ -102,8 +102,8 @@ class _Capture:
         self.settled: asyncio.Future = loop.create_future()
 
 
-def dispatch_vector_rows(engine: Any, window: Any, rows: list
-                         ) -> tuple[list, str | None]:
+def dispatch_vector_rows(engine: Any, window: Any, rows: list,
+                         query: Any = None) -> tuple[list, str | None]:
     """ONE engine round for ``rows`` (staged vector-lane tuples, clock
     first): drain the window's in-flight generator chains so device-op
     order follows the log, marshal the rows into ``run_vector`` columns,
@@ -111,7 +111,8 @@ def dispatch_vector_rows(engine: Any, window: Any, rows: list
     yields empty ``raws`` with the error set, for the caller's explicit
     per-entry failure branch (:meth:`RaftGroup._finalize_vector_run`).
     Called by the server's fused cross-group dispatch
-    (``RaftServer._flush_fused_engine``)."""
+    (``RaftServer._flush_fused_engine``); ``query`` is a read window's
+    staged rows for ``run_vector`` to take along."""
     n = len(rows)
     marshal = TRACER.open_span("apply.marshal") if TRACER.enabled else None
     if window is not None and window.busy:
@@ -133,7 +134,8 @@ def dispatch_vector_rows(engine: Any, window: Any, rows: list
     if marshal is not None:
         marshal.close(rows=n)
     try:
-        return engine.run_vector(groups_idx, opc, av, bv, cv), None
+        return engine.run_vector(groups_idx, opc, av, bv, cv,
+                                 query=query), None
     except Exception as e:  # liveness failure: fail loudly, not hang
         logger.exception("vector pump failed; failing %d rows", n)
         return [], str(e)
@@ -2677,25 +2679,43 @@ class RaftGroup:
         does. ``gate`` is the window's open ``read.gate`` span when
         traced; it ends where this begins, and the engine records its
         query drive (and any settling round) under the window's id for
-        the length of this synchronous section."""
+        the length of this synchronous section.
+
+        ``last_applied`` may cover vector rows still parked in the
+        server's fused collector (their device/host effects land at the
+        turn's one engine round) — reads serve AT last_applied, so those
+        effects must land first. Where a run is parked for this window's
+        engine the reads are routed ahead of it and ride its round
+        (:meth:`_route_ahead`): one device round trip for the window's
+        writes and its reads. The replies keep their order: the run's
+        rows are finalized, then the reads."""
         evaluate = scope = None
         if gate is not None:
             evaluate = gate.then("read.eval")
             scope = TRACER.scope(evaluate.trace_id, "read.eval")
             scope.__enter__()
-            drain = TRACER.open_span("read.drain")
-        # ``last_applied`` may cover vector rows still parked in the
-        # server's fused collector (their device/host effects land at
-        # the turn's one engine round) — reads serve AT last_applied, so
-        # those effects must land first (free no-op when nothing staged)
-        self.server.flush_fused("read")
-        if evaluate is not None:
-            drain.close()
         applied = self.last_applied
-        clock = self.context.clock
         route = getattr(self.state_machine, "query_route", None)
-        rows: list = []  # (future, machine, instance, inner, spec)
+        engine = query = None
+        ahead: list = []  # (future, machine, instance, inner, spec)
+        rows: list = []   # the same, routed once nothing is parked
         try:
+            if route is not None:
+                engine = self.state_machine.device_engine
+                parked = self.server.parked_rows(engine)
+                if parked:
+                    ahead, items = self._route_ahead(
+                        items, route, parked, applied if check_index else 0)
+                    if ahead:
+                        query = engine.stage_query_vector(
+                            *self._query_columns(ahead))
+            drain = TRACER.open_span("read.drain") \
+                if evaluate is not None else None
+            # (free no-op when nothing is staged)
+            self.server.flush_fused("read", engine, query)
+            if drain is not None:
+                drain.close()
+            clock = self.context.clock
             for session_id, client_index, operation, fut in items:
                 if check_index and client_index and client_index > applied:
                     self._resolve_read(
@@ -2718,23 +2738,50 @@ class RaftGroup:
                     self._resolve_read(fut, (applied, result, None, None))
                 finally:
                     commit.close()
+            if ahead:
+                self._serve_query_rows(ahead, applied, query)
             if rows:
                 self._serve_query_rows(rows, applied)
         finally:
             if evaluate is not None:
                 scope.__exit__()
-                evaluate.close(device=len(rows),
+                evaluate.close(device=len(ahead) + len(rows),
                                per_op=len(items) - len(rows))
 
-    def _serve_query_rows(self, rows: list, applied: int) -> None:
-        """One query_step engine round for every device-eligible read in
-        the window (the read analog of the command pump's vector run):
-        stage [N] rows, evaluate from the leader lane's applied state,
-        correlate results in a single pass — no per-op Commit objects,
-        no per-op executor dispatch."""
+    @staticmethod
+    def _route_ahead(items: list, route: Any, parked: list,
+                     applied: int) -> tuple[list, list]:
+        """Route a window's reads while a vector run is still parked:
+        ``(ahead, later)``, the device reads whose reply cannot depend on
+        when they were routed, as ``_serve_query_rows`` takes them, and
+        the items left for the walk after the drain (no device read, a
+        read lagging ``applied`` (0: not asked), or a read of a machine
+        that a parked row writes and whose class does not promise
+        ``ROUTE_OUTLIVES_FINALIZE``: that one is routed when the row has
+        landed, as every read was)."""
+        ahead: list = []
+        later: list = []
+        written = None  # machines of the parked rows, built when asked
+        for item in items:
+            _session_id, client_index, operation, fut = item
+            rec = None
+            if not (applied and client_index and client_index > applied):
+                rec = route(operation)
+            if rec is not None and not rec[0].ROUTE_OUTLIVES_FINALIZE:
+                if written is None:
+                    written = {row[3] for row in parked}
+                if rec[0] in written:
+                    rec = None
+            if rec is None:
+                later.append(item)
+            else:
+                ahead.append((fut, *rec))
+        return ahead, later
+
+    @staticmethod
+    def _query_columns(rows: list) -> tuple[list, list, list, list, list]:
+        """``_serve_query_rows``' rows as the engine's query columns."""
         m = len(rows)
-        self._m_query_device.inc(m)
-        engine = self.state_machine.device_engine
         groups = [0] * m
         opc = [0] * m
         av = [0] * m
@@ -2743,8 +2790,25 @@ class RaftGroup:
         for i, (_fut, machine, _inst, _op, spec) in enumerate(rows):
             groups[i] = machine._group
             opc[i], av[i], bv[i], cv[i] = spec[0], spec[1], spec[2], spec[3]
+        return groups, opc, av, bv, cv
+
+    def _serve_query_rows(self, rows: list, applied: int,
+                          query: Any = None) -> None:
+        """One query_step engine round for every device-eligible read in
+        the window (the read analog of the command pump's vector run):
+        stage [N] rows, evaluate from the leader lane's applied state,
+        correlate results in a single pass — no per-op Commit objects,
+        no per-op executor dispatch. ``query`` is the rows as they were
+        staged ahead of a parked run: what its round answered is taken,
+        and only the rest evaluated now."""
+        m = len(rows)
+        self._m_query_device.inc(m)
+        engine = self.state_machine.device_engine
         try:
-            raws = engine.run_query_vector(groups, opc, av, bv, cv)
+            if query is None:
+                raws = engine.run_query_vector(*self._query_columns(rows))
+            else:
+                raws = engine.finish_query_vector(query)
         except Exception as e:  # noqa: BLE001 — fail loudly, never hang
             logger.exception("query vector failed; failing %d reads", m)
             for fut, *_rest in rows:

@@ -8,6 +8,7 @@ hard timeout so a hung cluster fails rather than wedging the suite.
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import functools
 from typing import Any, Awaitable, Callable
 
@@ -32,3 +33,28 @@ def async_test(fn: Callable[..., Awaitable[None]] | None = None, *, timeout: flo
     if fn is not None:
         return deco(fn)
     return deco
+
+
+@contextlib.contextmanager
+def the_tick_after_the_window():
+    """A loaded server's order of callbacks: the fused collector's tick
+    (``RaftServer._fused_tick``) comes round after the read window that
+    was staged in the same turn, so the window finds the run parked and
+    its reads ride the run's round. (A server with one burst in flight
+    runs the tick one callback ahead of the window, and nothing is parked
+    by the time the window is evaluated.)"""
+    from copycat_tpu.server.raft import RaftServer
+
+    real = RaftServer._fused_tick
+
+    def late(self, turns: int = 3) -> None:
+        if turns:
+            asyncio.get_running_loop().call_soon(late, self, turns - 1)
+        else:
+            real(self)
+
+    RaftServer._fused_tick = late
+    try:
+        yield
+    finally:
+        RaftServer._fused_tick = real

@@ -2,8 +2,10 @@
 "Batch-scope spans"): one span per batch and stage from the client's flush
 to the device fetch, the whole-window report with its counters, and the
 off switch. A tiny served deployment on the CPU (``executor="tpu"``,
-capacity 16) is driven once with bursts of writes and of ATOMIC reads; the
-tests read what that run recorded. No number from here is a device number.
+capacity 16) is driven once with bursts of writes and of ATOMIC reads, a
+burst of each at once, so that every read window finds the writes' run
+parked and rides its round (``tests/test_read_joins_round.py``); the tests
+read what that run recorded. No number from here is a device number.
 """
 
 import asyncio
@@ -27,7 +29,7 @@ from copycat_tpu.resource.consistency import Consistency  # noqa: E402
 from copycat_tpu.utils import tracing  # noqa: E402
 from copycat_tpu.utils.tracing import TRACER, Tracer  # noqa: E402
 
-from helpers import arun  # noqa: E402
+from helpers import arun, the_tick_after_the_window  # noqa: E402
 from raft_fixtures import next_ports  # noqa: E402
 from test_trace_plane import GOLDEN, _golden_samples  # noqa: E402
 
@@ -87,14 +89,17 @@ def _registries(server, client) -> dict:
 
 @pytest.fixture(scope="module")
 def recorded():
-    """One traced run: BURSTS x (a burst of writes, a burst of ATOMIC
-    reads) after an untraced warm-up; what the tracer and the registries
-    held when it was turned off."""
+    """One traced run: BURSTS x (a burst of writes and a burst of ATOMIC
+    reads at once, the window evaluated with the writes' run still parked)
+    after an untraced warm-up; what the tracer and the registries held
+    when it was turned off."""
     async def drive():
         server, client, ctrs = await _deployment()
         try:
             await _burst(ctrs, 1)
             await _burst(ctrs)                   # compiles, untraced
+            with the_tick_after_the_window():    # and the joint program
+                await asyncio.gather(_burst(ctrs, 0), _burst(ctrs))
             regs = _registries(server, client)
             before = {p: r.counter_values() for p, r in regs.items()}
             queries = []
@@ -109,9 +114,10 @@ def recorded():
             tracing.enable()
             try:
                 replies = []
-                for k in range(BURSTS):
-                    replies.append(await _burst(ctrs, 2))
-                    replies.append(await _burst(ctrs))
+                with the_tick_after_the_window():
+                    for k in range(BURSTS):
+                        replies += await asyncio.gather(_burst(ctrs, 2),
+                                                        _burst(ctrs))
             finally:
                 tracing.disable()
                 RaftGroups._run_query = real
@@ -234,29 +240,57 @@ def test_counter_deltas_equal_the_registries_own_difference(recorded):
     assert counters["group.query_windows"] == BURSTS
     assert counters["engine.queries_served"] == reads
     assert counters["engine.query_vector_drives"] == BURSTS
+    assert counters["engine.query_joined_drives"] == BURSTS
     assert counters["server.apply.fused_dispatches"] == BURSTS
     assert counters["engine.query_settle_rounds"] == 0
 
 
-def test_fetches_are_the_fetch_spans_plus_the_query_evaluations(recorded):
+def test_fetches_are_the_fetch_spans_plus_the_evaluations_that_ran_alone(
+        recorded):
+    """Every window's reads rode its writes' round here, so the query
+    program never ran alone and a round's fetch is the window's only one;
+    the reads' slab is among the bytes its ``engine.fetch`` span names."""
     counters = recorded["report"]["counters"]
     fetch_spans = recorded["report"]["spans"]["engine.fetch"]["n"]
+    assert recorded["run_query_calls"] == 0
     assert counters["engine.fetches"] == \
         fetch_spans + recorded["run_query_calls"]
     assert counters["engine.rounds"] == fetch_spans
     in_spans = sum(s.meta["bytes"] for s in _spans(recorded, "engine.fetch"))
-    assert 0 < in_spans < counters["engine.fetch_bytes"]   # + the queries'
+    assert 0 < in_spans == counters["engine.fetch_bytes"]
+
+
+def test_a_joined_window_is_one_drain_around_the_round_and_a_marshal(
+        recorded):
+    """What the two spans hold where the reads ride: ``read.drain`` the
+    joint call (the round's four stages lie inside it), ``engine.query``
+    the marshalling of the rows before it, with no evaluation of its own
+    (``attempts`` 0)."""
+    drains = _spans(recorded, "read.drain")
+    assert len(drains) == BURSTS
+    for name in ("engine.stage", "engine.wait", "engine.fetch",
+                 "engine.harvest", "apply.finalize"):
+        inside = [s for s in _spans(recorded, name)
+                  if any(d.start <= s.start and s.end <= d.end
+                         for d in drains)]
+        assert len(inside) == BURSTS, name
+    queries = _spans(recorded, "engine.query")
+    assert len(queries) == BURSTS
+    for q in queries:
+        assert q.meta == {"attempts": 0, "width": 1, "n": COUNTERS}
+        drain = next(d for d in drains if d.trace_id == q.trace_id)
+        assert q.end <= drain.start
 
 
 def test_dispatch_leaves_count_the_buffers_of_each_program(recorded):
     """``engine.dispatch_leaves`` in the whole-window report: every round
-    here is one call of the step (1 buffer put, 2 fresh slabs, 2 fetched:
-    5), every read window one query evaluation (1 put, 1 slab out, 1
-    fetched: 3)."""
+    here took a read window along (1 buffer put, the round's 2 fresh slabs
+    and the reads' 1, the 3 fetched: 7, where a round alone is 5 and a
+    query evaluation alone 1 put, 1 slab out, 1 fetched: 3)."""
     counters = recorded["report"]["counters"]
     assert counters["engine.rounds"] == BURSTS
     assert counters["engine.dispatch_leaves"] == \
-        5 * BURSTS + 3 * recorded["run_query_calls"]
+        7 * BURSTS + 3 * recorded["run_query_calls"]
 
 
 def test_one_write_round_is_five_buffers_and_one_query_three():

@@ -6,6 +6,8 @@ as one buffer, split the key inside and return the outputs packed
 result: driven over seeded nemesis schedules, the engine's outputs and
 state equal, leaf for leaf, what the plain ``jax.jit(step)`` /
 ``query_step`` / ``install_snapshots`` give on the same inputs and keys.
+The round that takes a read window's rows along (the fourth program) is
+the plain step and then the plain ``query_step`` on the state it left.
 """
 
 from functools import lru_cache, partial
@@ -17,7 +19,8 @@ jax = pytest.importorskip("jax")
 
 from copycat_tpu.models import checkpoint  # noqa: E402
 from copycat_tpu.models.bulk import BulkDriver  # noqa: E402
-from copycat_tpu.models.raft_groups import RaftGroups  # noqa: E402
+from copycat_tpu.models.raft_groups import (  # noqa: E402
+    RaftGroups, _split_slab)
 from copycat_tpu.ops import apply as ap  # noqa: E402
 from copycat_tpu.ops.consensus import (  # noqa: E402
     Config,
@@ -117,11 +120,22 @@ def read_all(rg: RaftGroups, ref: Plain) -> None:
                 "query")
 
 
-def drive(rg: RaftGroups, ref: Plain, seed: int, rounds: int) -> None:
+def drive(rg: RaftGroups, ref: Plain, seed: int, rounds: int,
+          ride: bool = False) -> None:
+    """``ride``: every round takes a read of every group at ATOMIC along
+    (``step_round(query=)``); what it brings back is what the plain query
+    program reads from the state the plain step left, served or not."""
+    reads = one_read_per_group(rg)
     for r, (sub, deliver) in enumerate(schedule(seed, rounds)):
+        query = rg.stage_query_vector(
+            np.arange(G), ap.OP_VALUE_GET, atomic=True) if ride else None
         want = ref.round(sub, deliver)
-        assert_same(rg.step_round(submits=sub, deliver=deliver), want,
-                    f"round {r}")
+        assert_same(rg.step_round(submits=sub, deliver=deliver, query=query),
+                    want, f"round {r}")
+        if ride:
+            assert_same(_split_slab(query.rode),
+                        [x[:, :1] for x in ref.query(ref.state, *reads)],
+                        f"round {r}'s reads")
         ref.install_if_stale(want)
     assert_same(rg.state, ref.state, "final state")
     assert_same(rg._key, ref.key, "carried key")
@@ -151,12 +165,24 @@ def test_step_round_equals_the_plain_step(seed):
     assert_same(rg.state, ref.state, "state after the reads")
 
 
-def test_step_round_equals_the_plain_step_on_a_mesh():
+def test_a_round_with_reads_equals_the_plain_step_then_the_plain_query():
+    """Over the nemesis schedule, snapshot installs and unserved reads
+    included: one call, the two plain programs' results."""
+    rg, ref = short_ring(seed=36), Plain(36)
+    compiled = rg._round_query._cache_size()
+    drive(rg, ref, 36, rounds=48, ride=True)
+    assert ref.installs > 0
+    # one width of reads: one program (none, had another test met it)
+    assert rg._round_query._cache_size() - compiled <= 1
+
+
+@pytest.mark.parametrize("ride", [False, True], ids=["round", "with-reads"])
+def test_step_round_equals_the_plain_step_on_a_mesh(ride):
     if len(jax.devices()) < 8:
         pytest.skip("needs the 8 virtual CPU devices (conftest)")
     mesh = make_mesh(groups=8)
     rg, ref = short_ring(seed=5, mesh=mesh), Plain(5)
-    drive(rg, ref, 5, rounds=24)
+    drive(rg, ref, 5, rounds=24, ride=ride)
     assert len(rg.state.term.devices()) == 8
     assert "groups" in str(rg.state.log_term.sharding.spec)
 

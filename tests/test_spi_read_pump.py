@@ -1,7 +1,8 @@
 """Differential proof for the batched device-native read plane.
 
 The read pump (``RaftServer._run_read_window`` + ``RaftGroups.
-drive_query_vector``) coalesces reads arriving across sessions into
+drive_query_vector``, or its two halves around a parked vector run's
+round) coalesces reads arriving across sessions into
 per-consistency windows, pays each window's consistency gate ONCE, and
 evaluates device-eligible reads as tensors through one ``query_step``
 engine round. Its contract is BIT-IDENTICAL observable behavior to the
@@ -238,11 +239,44 @@ def test_drive_query_vector_matches_per_op_serve():
     assert (got_atomic == want).all()
 
 
+@pytest.mark.parametrize("atomic", [False, True],
+                         ids=["applied", "lease"])
+def test_the_two_halves_of_the_vector_read_match_per_op_serve(atomic):
+    """``stage_query_vector`` then ``finish_query_vector`` is
+    ``drive_query_vector``; and offered to a vector run between them
+    (``drive_vector(query=)``: the read window that finds a run parked,
+    tests/test_read_joins_round.py) the same rows answer from the state
+    that run left, in one device round trip with it, as per-op
+    ``serve_query`` answers after it."""
+    rg = device_plane(seed=3)
+    rg.wait_for_leaders()
+    rg.run(3)
+    groups = np.concatenate([np.full(g + 1, g) for g in range(8)])
+    query = rg.stage_query_vector(groups, ap.OP_VALUE_GET, atomic=atomic)
+    assert query.slots == 8 and query.n == groups.size
+    got = rg.finish_query_vector(query)
+    assert (got == 0).all() and query.evaluations == 1
+    fetches = rg.metrics.counter("fetches")
+    joined = rg.metrics.counter("query_joined_drives")
+    before = (fetches.value, joined.value)
+    query = rg.stage_query_vector(groups, ap.OP_VALUE_GET, atomic=atomic)
+    adds = np.arange(8, dtype=np.int64)
+    rg.drive_vector(adds, np.full(8, ap.OP_LONG_ADD, np.int64), adds + 1,
+                    adds * 0, adds * 0, query=query)
+    got = rg.finish_query_vector(query)
+    assert (fetches.value, joined.value) == (before[0] + 1, before[1] + 1)
+    want = np.array([rg.serve_query(int(g), ap.OP_VALUE_GET)
+                     for g in groups])
+    assert (got == want).all() and (got == groups + 1).all()
+
+
 def test_drive_query_vector_refuses_writes():
     rg = device_plane(seed=4)
     rg.wait_for_leaders()
     with pytest.raises(ValueError, match="not read-only"):
         rg.drive_query_vector([0], ap.OP_LONG_ADD, 1)
+    with pytest.raises(ValueError, match="not read-only"):
+        rg.stage_query_vector([0], ap.OP_LONG_ADD, 1)
 
 
 @async_test(timeout=300)

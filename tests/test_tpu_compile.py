@@ -144,6 +144,16 @@ def round_args(G, P_, L, S, config, sharding, planes=6):
     return (state, packed, deliver, key) if planes == 6 else (state, packed)
 
 
+def joint_args(G, P_, L, S, config, sharding, slots=1):
+    """Arguments of the round that takes a read window's rows along: the
+    query's seven planes, ``slots`` wide each, behind the submits' six in
+    the one buffer, and ``slots`` (static)."""
+    state, _, deliver, key = step_args(G, P_, L, S, config, sharding)
+    packed = _struct((G, 6 * S + 7 * slots), jnp.int32,
+                     group_placer(sharding)(2))
+    return state, packed, deliver, key, slots
+
+
 def deep_args(G, P_, L, S, B, config, sharding, windows=None):
     """Arguments of ``deep_step`` (``windows=None``) or ``deep_scan``."""
     place = group_placer(sharding)
@@ -242,7 +252,7 @@ def test_served_round_aliases_the_state_and_packs_the_rest(one_chip):
     slab per dtype."""
     config = Config()
     args = round_args(1024, 3, 64, 4, config, one_chip)
-    step_program, query_program, _ = _jitted_programs(config)
+    step_program, query_program, _, joint_program = _jitted_programs(config)
     compiled = step_program.lower(*args).compile()
     donated = len(jax.tree.leaves(args[0])) + 1
     outputs = len(jax.tree.leaves(compiled.output_shardings))
@@ -256,6 +266,15 @@ def test_served_round_aliases_the_state_and_packs_the_rest(one_chip):
         *round_args(1024, 3, 64, 4, config, one_chip, planes=7)).compile()
     assert aliased_outputs(query) == 0
     assert len(jax.tree.leaves(query.output_shardings)) == 1
+    # both in one call: the state and the key in place as in the round,
+    # the round's two slabs and the query's one to allocate and fetch,
+    # under a name the benchmark's map plane counts among the rounds'
+    joint = joint_program.lower(
+        *joint_args(1024, 3, 64, 4, config, one_chip)).compile()
+    assert aliased_outputs(joint) == donated
+    assert len(jax.tree.leaves(joint.output_shardings)) - donated == 3
+    assert joint.as_text().split("\n", 1)[0].split()[1].startswith(
+        "jit_round_")
 
 
 def test_group_sharded_served_round_has_zero_collectives(four_chips):
@@ -267,6 +286,12 @@ def test_group_sharded_served_round_has_zero_collectives(four_chips):
     compiled = _jitted_programs(config)[0].lower(*args).compile()
     assert collectives_in(compiled) == {}
     assert aliased_outputs(compiled) == len(jax.tree.leaves(args[0])) + 1
+    # and so does the round that takes a read window along: a mesh engine
+    # gets it for nothing
+    joint = _jitted_programs(config)[3].lower(
+        *joint_args(4096, 3, 64, 4, config, four_chips)).compile()
+    assert collectives_in(joint) == {}
+    assert aliased_outputs(joint) == len(jax.tree.leaves(args[0])) + 1
 
 
 @pytest.mark.parametrize("chips", [1, 4])
@@ -317,10 +342,17 @@ def test_a_bucketed_map_round_writes_its_table_in_place(one_chip):
     table = args[0].resources.map_table
     assert table.shape == (1024, 3, 64, 8, 128)
     table_bytes = table.size * table.dtype.itemsize
-    step_program, query_program, _ = _jitted_programs(config)
+    step_program, query_program, _, joint_program = _jitted_programs(config)
     mem = step_program.lower(*args).compile().memory_analysis()
     assert mem.alias_size_in_bytes >= table_bytes
     assert mem.temp_size_in_bytes < table_bytes // 8, mem
     query = query_program.lower(
         *round_args(1024, 3, 64, 1, config, one_chip, planes=7)).compile()
     assert query.memory_analysis().temp_size_in_bytes <= table_bytes // 2
+    # the round with a read window's gets behind its puts: the table still
+    # in place, and no more beside it than the query holds alone
+    mem = joint_program.lower(
+        *joint_args(1024, 3, 64, 4, config, one_chip)).compile(
+        ).memory_analysis()
+    assert mem.alias_size_in_bytes >= table_bytes
+    assert mem.temp_size_in_bytes <= table_bytes // 2, mem

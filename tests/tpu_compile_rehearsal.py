@@ -71,7 +71,7 @@ def one_chip(chip) -> None:
            lambda: t.compile_deep(*shape, windows=64 // S + 3))
     # served path: the engine's programs at DeviceEngineConfig defaults
     engine = Config()
-    step_program, query_program, _ = _jitted_programs(engine)
+    step_program, query_program, _, joint_program = _jitted_programs(engine)
     args = t.round_args(1024, 3, 64, 4, engine, chip)
     report("engine step 1024x3", lambda: step_program.lower(*args).compile())
     for n in (2, 3, 4):
@@ -81,6 +81,9 @@ def one_chip(chip) -> None:
         report(f"engine query_step S={width}",
                lambda: query_program.lower(*t.round_args(
                    1024, 3, 64, width, engine, chip, planes=7)).compile())
+    report("engine step and query_step S=1",
+           lambda: joint_program.lower(*t.joint_args(
+               1024, 3, 64, 4, engine, chip)).compile())
 
 
 def four_chips(mesh) -> None:
