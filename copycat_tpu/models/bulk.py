@@ -50,6 +50,7 @@ import jax
 import numpy as np
 
 from ..ops.consensus import Submits, deep_scan, deep_step
+from ..utils.tracing import TRACER
 from .raft_groups import RaftGroups, _pack_host
 
 
@@ -98,12 +99,22 @@ def _window_rank(mask: np.ndarray, starts: np.ndarray, counts: np.ndarray,
     return pos, rank[pos]
 
 
+def _named(fn, **static):
+    """``partial(fn, **static)`` under ``fn``'s own name, so that JAX
+    names the jitted module after it (``jit_deep_scan``, ``jit_deep_step``)
+    and a trace tells the two deep programs apart; a bare partial is
+    ``jit__unknown``. No Python frame of its own over the step."""
+    bound = partial(fn, **static)
+    bound.__name__ = fn.__name__
+    return bound
+
+
 @lru_cache(maxsize=None)
 def _deep_scan_program(config, onehot: bool = False, donate: bool = False):
     """Jitted :func:`deep_scan` (whole blind phase as one program; W
     specializes by shape). Donation hands the state + accumulators back
     for in-place reuse on accelerators."""
-    return jax.jit(partial(deep_scan, config=config, onehot=onehot),
+    return jax.jit(_named(deep_scan, config=config, onehot=onehot),
                    donate_argnums=(0, 1, 2, 3, 4) if donate else ())
 
 
@@ -119,7 +130,7 @@ def _deep_program(config, onehot: bool = False, donate: bool = False):
     state + accumulators back to XLA for in-place reuse — on for
     accelerators (saves a full state copy per round), off for CPU
     (donation is unimplemented there and only warns)."""
-    return jax.jit(partial(deep_step, config=config, onehot=onehot),
+    return jax.jit(_named(deep_step, config=config, onehot=onehot),
                    donate_argnums=(0, 1, 2, 3, 4) if donate else ())
 
 
@@ -204,6 +215,13 @@ class BulkDriver:
         rg = self._rg
         S = rg.submit_slots
         t0 = time.perf_counter()
+        # one span a drive and stage (utils/tracing.py "bulk.*"), all
+        # under the id the root mints; off, no object and no clock read
+        root = stage = None
+        if TRACER.enabled:
+            root = TRACER.open_span("bulk.drive", start=t0)
+            stage = TRACER.open_span("bulk.admit", root.trace_id,
+                                     "bulk.drive", start=t0)
 
         g_arr = np.asarray(groups, np.int64).ravel()
         n = g_arr.size
@@ -211,8 +229,18 @@ class BulkDriver:
             np.asarray(x, np.int32).ravel(), (n,)).copy()
         op_a, a_a, b_a, c_a = bc(opcode), bc(a), bc(b), bc(c)
         if getattr(rg.config, "monotone_tag_accept", False):
-            return self._drive_deep(g_arr, op_a, a_a, b_a, c_a,
-                                    max_rounds, t0, deliver_schedule)
+            res, stage, windows = self._drive_deep(
+                g_arr, op_a, a_a, b_a, c_a, max_rounds, t0,
+                deliver_schedule, stage)
+            # the deep drive's arrays, some 200 bytes an operation, were
+            # freed as it returned: the last stage's time, and the root's
+            if stage is not None:
+                stage.close()
+                root.close(n=n, rounds=res.rounds, windows=windows,
+                           scan=self._scan)
+            return res
+        if stage is not None:   # the classic drive: the root and this
+            stage.close()
         if deliver_schedule is not None:
             raise NotImplementedError(
                 "deliver_schedule is a deep-drive feature (fault "
@@ -327,10 +355,13 @@ class BulkDriver:
             raise TimeoutError(f"bulk drive: {missing} ops unresolved")
         rg.rounds += r
         rg.metrics.counter("ops_committed").inc(n)
-        return BulkResult(results=results, rounds=r,
-                          wall_s=time.perf_counter() - t0,
-                          dispatch_round=dispatch_round,
-                          resolve_round=resolve_round)
+        res = BulkResult(results=results, rounds=r,
+                         wall_s=time.perf_counter() - t0,
+                         dispatch_round=dispatch_round,
+                         resolve_round=resolve_round)
+        if root is not None:
+            root.close(n=n, rounds=r, scan=False)
+        return res
 
 
     def drive_queries(self, groups, opcode, a=0, b=0, c=0,
@@ -506,7 +537,7 @@ class BulkDriver:
 
     def _drive_deep(self, g_arr, op_a, a_a, b_a, c_a,
                     max_rounds: int, t0: float,
-                    deliver_schedule=None) -> BulkResult:
+                    deliver_schedule=None, stage=None) -> tuple:
         """Zero-sync pipelined drive for monotone-tag engines.
 
         The classic drive pays one BLOCKING ``accepted`` fetch per round
@@ -527,12 +558,21 @@ class BulkDriver:
 
         Liveness matches the classic bulk plane (fault-free delivery);
         safety is the gate's and holds under any fault.
+
+        ``stage`` is the drive's open span while the tracer is on
+        (``bulk.admit``): every stage from here on closes into the next,
+        and a straggler phase records its stages again with ``phase=2``.
+        Returns the result, the stage left open (``bulk.return``: the
+        caller closes it once this frame's arrays are freed) and the
+        blind phase's windows.
         """
         rg = self._rg
         S = rg.submit_slots
         G = rg.num_groups
         n = g_arr.size
         multi = getattr(rg, "process_count", 1) > 1
+        if stage is not None:
+            stage = stage.then("bulk.plan")
 
         order = np.argsort(g_arr, kind="stable")
         g_s = g_arr[order]
@@ -570,8 +610,10 @@ class BulkDriver:
         B = rg._global_max_int(int(counts.max(initial=0)))
         if B == 0:   # agreed: every process is idle this drive
             z = np.zeros(0, np.int64)
+            if stage is not None:
+                stage = stage.then("bulk.return", segments=0)
             return BulkResult(results=z, rounds=0, wall_s=0.0,
-                              dispatch_round=z, resolve_round=z)
+                              dispatch_round=z, resolve_round=z), stage, 0
         Bpad = 1 << max(0, B - 1).bit_length()
         # accumulators are [G, max-burst]: a skewed drive (one group with
         # a huge burst on a large-G engine) would allocate G*Bpad
@@ -584,6 +626,10 @@ class BulkDriver:
                 f"({G_total * Bpad / 1e6:.0f}M slots) for {n} ops — burst "
                 "sizes are too skewed; split the drive into bursts of "
                 "similar per-group size")
+        if stage is not None:
+            stage = stage.then("bulk.stage", segments=starts.size)
+            staged = rg._m_staged_bytes.value
+            phase: dict = {}    # a straggler phase's stages say phase=2
         resbuf = rg._stage_acc(np.zeros((G, Bpad), np.int32))
         valbuf = rg._stage_acc(np.zeros((G, Bpad), bool))
         rndbuf = rg._stage_acc(np.full((G, Bpad), 2**30, np.int32))
@@ -627,9 +673,9 @@ class BulkDriver:
 
         def dispatch(tagl, vnp, leaves) -> None:
             nonlocal r, resbuf, valbuf, rndbuf, evflag
-            sub = rg._stage_submits(
+            sub = rg._stage_submits(rg._note_stage(
                 Submits(opcode=leaves[0], a=leaves[1], b=leaves[2],
-                        c=leaves[3], tag=tagl, valid=vnp))
+                        c=leaves[3], tag=tagl, valid=vnp)))
             dl = deliver if deliver_schedule is None else deliver_schedule(r)
             rg._key, key = jax.random.split(rg._key)
             (rg.state, resbuf, valbuf, rndbuf, evflag, out) = _deep(
@@ -650,9 +696,19 @@ class BulkDriver:
         def harvest() -> None:
             """ONE fetch of the [G,B] accumulators (+ telemetry, + the
             rare event leaves)."""
-            nonlocal evflag, tel_ingested
+            nonlocal evflag, tel_ingested, stage
+            if stage is not None:
+                # while the tracer is on the device's share is the
+                # wait's, not the fetch's; off, the fetch waits
+                jax.block_until_ready((resbuf, valbuf, rndbuf, evflag))
+                stage = stage.then("bulk.fetch", **phase)
+                fetched = rg._m_fetch_bytes.value
             res_np, val_np, rnd_np, ev, tels = rg._fetch_acc(
                 (resbuf, valbuf, rndbuf, evflag, tel_stash))
+            if stage is not None:
+                stage = stage.then(
+                    "bulk.harvest",
+                    bytes=rg._m_fetch_bytes.value - fetched, **phase)
             for tel in tels:
                 if np.asarray(tel.elections_started).ndim == 2:
                     w = int(np.asarray(tel.elections_started).shape[0])
@@ -727,15 +783,22 @@ class BulkDriver:
             _scan = _deep_scan_program(
                 rg.config, onehot=rg.mesh is not None, donate=rg.donate)
             rg._key, key = jax.random.split(rg._key)
+            if stage is not None:
+                stage = stage.then(
+                    "bulk.dispatch", bytes=rg._m_staged_bytes.value - staged)
             (rg.state, resbuf, valbuf, rndbuf, evflag, evs, tels) = _scan(
                 rg.state, resbuf, valbuf, rndbuf, evflag, base_dev,
-                Submits(opcode=op_w, a=a_w, b=b_w, c=c_w, tag=tagl_w,
-                        valid=valid_w), deliver, key)
+                rg._note_stage(Submits(
+                    opcode=op_w, a=a_w, b=b_w, c=c_w, tag=tagl_w,
+                    valid=valid_w)), deliver, key)
             r = W_total
             ev_stash.append(evs)   # stacked [W, ...] leaves
             if rg.telemetry is not None and tels is not None:
                 tel_stash.append(tels)  # stacked [W, G] leaves
         else:
+            if stage is not None:
+                stage = stage.then(
+                    "bulk.dispatch", bytes=rg._m_staged_bytes.value - staged)
             for w in range(windows):
                 in_w = (rank >= w * S) & (rank < (w + 1) * S)
                 pos = np.flatnonzero(in_w)
@@ -748,6 +811,8 @@ class BulkDriver:
                          payload_leaves(pos, rank[pos] - w * S))
             for _ in range(3):  # settle: replicate + commit + report lag
                 dispatch(*_idle[:2], _idle[2])
+        if stage is not None:
+            stage = stage.then("bulk.wait", rounds=r)
         harvest()
 
         # phase 2: straggler suffixes (lease-cold leaders, backpressure).
@@ -759,6 +824,11 @@ class BulkDriver:
         # dispatching EMPTY windows until every process is done (each
         # iteration launches 3 collective rounds + a fetch on multihost).
         while not rg._agree(bool(resolved.all())):
+            if stage is not None:
+                stage = stage.then(
+                    "bulk.stage", resolved=np.count_nonzero(resolved),
+                    **phase)
+                phase = {"phase": 2}
             if r > max_rounds:
                 missing = int(n - resolved.sum())
                 # abandoning mid-stream: tags up to the device ring max
@@ -786,11 +856,19 @@ class BulkDriver:
                 .astype(np.int32)
             vnp = np.zeros((G, S), bool)
             vnp[seg_groups] = np.arange(S)[None, :] < want[:, None]
-            dispatch(tagl.copy(), vnp, payload_leaves(pos, offs))
+            leaves = payload_leaves(pos, offs)
+            if stage is not None:   # (a pass puts no accumulator)
+                stage = stage.then("bulk.dispatch", phase=2)
+            dispatch(tagl.copy(), vnp, leaves)
             dispatch(*_idle[:2], _idle[2])
             dispatch(*_idle[:2], _idle[2])
+            if stage is not None:
+                stage = stage.then("bulk.wait", rounds=3, phase=2)
             harvest()
 
+        if stage is not None:
+            stage = stage.then(
+                "bulk.return", resolved=np.count_nonzero(resolved), **phase)
         if n:
             rg._stream_count[seg_groups] += counts
         rg.rounds += r
@@ -803,7 +881,8 @@ class BulkDriver:
         out_rr[order] = resolve_round
         return BulkResult(results=out_res, rounds=r,
                           wall_s=time.perf_counter() - t0,
-                          dispatch_round=out_dr, resolve_round=out_rr)
+                          dispatch_round=out_dr,
+                          resolve_round=out_rr), stage, windows
 
 
 class _EventView:

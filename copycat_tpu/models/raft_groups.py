@@ -395,6 +395,8 @@ class RaftGroups:
         self._m_step_wall = self.metrics.histogram("step_wall_ms")
         self._m_fetches = self.metrics.counter("fetches")
         self._m_fetch_bytes = self.metrics.counter("fetch_bytes")
+        # bytes of the host arrays the deep drive hands the device
+        self._m_staged_bytes = self.metrics.counter("staged_bytes")
         self._m_settle_rounds = self.metrics.counter("query_settle_rounds")
         # read windows evaluated (every one), and those of them whose rows
         # all rode a vector run's round and cost no call of their own
@@ -655,6 +657,17 @@ class RaftGroups:
             sum(getattr(x, "nbytes", 0) for x in jax.tree.leaves(host)))
         return host
 
+    def _note_stage(self, host: Any) -> Any:
+        """Count the bytes of the host arrays the deep drive hands the
+        device: an accumulator (``_stage_acc``, the multi-host override
+        too, ends in this) or the payload leaves that travel as
+        arguments of the deep program (``models/bulk.py`` calls it where
+        it hands them over). A scalar leaf counts 0."""
+        self._m_staged_bytes.inc(sum(
+            x.nbytes for x in jax.tree.leaves(host)
+            if getattr(x, "ndim", 0)))
+        return host
+
     def _fetch_outputs(self, raw: PackedOutputs) -> StepOutputs:
         # ONE overlapped device->host transfer for both slabs; the harvest
         # reads numpy views of them under StepOutputs' names.
@@ -706,6 +719,7 @@ class RaftGroups:
         leading axis is groups. On a single-host mesh the group axis is
         sharded like the state (placement-only, so the deep_step scatter
         stays shard-local — parallel/mesh.py rule)."""
+        self._note_stage(arr)
         if self.mesh is None:
             return jax.device_put(arr)
         from jax.sharding import NamedSharding, PartitionSpec as P

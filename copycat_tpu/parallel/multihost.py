@@ -217,7 +217,8 @@ class MultiHostRaftGroups(RaftGroups):
     def _stage_acc(self, arr: np.ndarray):
         spec = P("groups", *([None] * (arr.ndim - 1)))
         return jax.make_array_from_process_local_data(
-            NamedSharding(self.mesh, spec), np.ascontiguousarray(arr))
+            NamedSharding(self.mesh, spec),
+            self._note_stage(np.ascontiguousarray(arr)))
 
     def _fetch_acc(self, arrays):
         for leaf in jax.tree.leaves(arrays):
@@ -227,6 +228,7 @@ class MultiHostRaftGroups(RaftGroups):
 
     def _deep_fn(self):
         if self._deep_jit is None:
+            from ..models.bulk import _named
             from ..ops.consensus import deep_step
             acc2 = NamedSharding(self.mesh, P("groups", None))
             acc1 = NamedSharding(self.mesh, P("groups"))
@@ -235,7 +237,7 @@ class MultiHostRaftGroups(RaftGroups):
             # (saves a full sharded-state copy per round)
             donate = (0, 1, 2, 3, 4) if self.donate else ()
             self._deep_jit = jax.jit(
-                partial(deep_step, config=self.config, onehot=True),
+                _named(deep_step, config=self.config, onehot=True),
                 donate_argnums=donate,
                 out_shardings=(self._state_sh, acc2, acc2, acc2, acc1,
                                self._out_sh))

@@ -10,8 +10,11 @@ Design constraints, in order:
    command handlers, the replication window stager, the apply loop) does
    ONE attribute read (``TRACER.enabled`` / ``request.trace is None`` /
    an empty-dict truthiness check) and branches away. No span objects,
-   no clock reads, no dict lookups. Verified by the spi + sharded bench
-   A/Bs in PERF.md (rounds 7 and 13).
+   no clock reads, no dict lookups. Tested at each family of sites
+   (``tests/test_pump_spans.py``, ``tests/test_bulk_spans.py``: the
+   recording entry points refuse to be called) and measured on the chip
+   by the builder's pairs with ``--trace 0`` and by the cost of tracing
+   on (PERF.md section 6, the PR that added the family).
 2. **Propagation rides the existing frames — invisibly when off.**
    ``CommandRequest`` / ``CommandBatchRequest`` carry ``trace`` as a
    regular field (PR 2); the cross-member hops added since ride
@@ -98,6 +101,37 @@ taken meanwhile shows them on the host lines beside the device ops.
 - ``read.drain`` [read.eval] — the forced fused flush at its head.
 - ``read.finalize`` [read.eval] — ``run_query_vector`` returned -> the
   last read future resolved.
+
+The bulk drive's spans (``models/bulk.py``; one per drive and stage,
+never per operation, group or round). A drive mints its own id with the
+root; every stage has ``parent="bulk.drive"`` and begins where the one
+before it ended, so the root's ``self_ms`` is what no stage covers. A
+straggler phase records its five stages again with ``phase=2``; a
+classic drive (an engine without ``monotone_tag_accept``) records the
+root and ``bulk.admit`` only.
+
+- ``bulk.drive`` — ``BulkDriver.drive()`` entered -> its ``BulkResult``
+  returned (``n``, ``rounds``, ``windows``, ``scan``).
+- ``bulk.admit`` [bulk.drive] — ``drive()`` entered -> ``_drive_deep``
+  entered (the arguments as arrays of the drive's length).
+- ``bulk.plan`` [bulk.drive] — -> the accumulators about to be staged
+  (sort by group, starts, counts, ranks; ``segments``).
+- ``bulk.stage`` [bulk.drive] — -> the program about to be called (the
+  accumulators put, the stacked payload built; ``bytes`` put by
+  ``_stage_acc``).
+- ``bulk.dispatch`` [bulk.drive] — -> the scan's one call returned, or
+  the whole loop of windows in dispatch mode (``rounds``).
+- ``bulk.wait`` [bulk.drive] — -> the accumulators ready (a
+  ``block_until_ready`` made only while the tracer is on).
+- ``bulk.fetch`` [bulk.drive] — -> ``_fetch_acc`` returned (``bytes``).
+- ``bulk.harvest`` [bulk.drive] — -> every operation known resolved or
+  not (``resolved``).
+- ``bulk.return`` [bulk.drive] — -> ``_drive_deep`` returned (back to
+  submission order, the ``BulkResult`` built, the drive's arrays freed).
+
+``engine.staged_bytes`` (``RaftGroups._note_stage``) counts the bytes of
+the host arrays such a drive hands the device, beside
+``engine.fetch_bytes`` for what it takes back.
 
 :meth:`Tracer.report` is the whole-window account (docs/OBSERVABILITY.md
 "The window report"): per-name aggregates that do not depend on what the
