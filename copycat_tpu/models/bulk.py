@@ -211,6 +211,27 @@ class BulkDriver:
         to drive that rule; the large result arrays are harvested one
         round behind (double buffer), so host staging and the bulk of
         the D2H transfer overlap device compute.
+
+        The deep drive (monotone-tag engines) plans from what one pass
+        over ``groups`` finds, in three tiers, each a special case of the
+        one before and equal to it in everything it returns and leaves:
+
+        - ``sorted``: ``groups`` in any order. A stable argsort by group,
+          sorted copies of the payload, per-operation index arrays for
+          the payload and the harvest, an unsort at return.
+        - ``grouped``: ``groups`` non-decreasing. The submission is its
+          own sorted form: no argsort, no copies, and ``results``,
+          ``dispatch_round`` and ``resolve_round`` are returned as the
+          drive filled them, without an unsort.
+        - ``dense``: grouped, and every group present sends the same
+          count. No index array an operation either: the payload is
+          slices of the operations reshaped ``[groups, count]``, the
+          harvest slices of the accumulators.
+
+        Nothing selects a tier but the submission's own order and counts
+        (``RaftGroups.metrics`` ``bulk_grouped_drives`` and
+        ``bulk_dense_drives`` count them, the ``bulk.plan`` span names
+        the ``plan``); on a multihost engine each process reads its own.
         """
         rg = self._rg
         S = rg.submit_slots
@@ -574,15 +595,30 @@ class BulkDriver:
         if stage is not None:
             stage = stage.then("bulk.plan")
 
-        order = np.argsort(g_arr, kind="stable")
-        g_s = g_arr[order]
-        op_s, a_s, b_s, c_s = (x[order] for x in (op_a, a_a, b_a, c_a))
+        # What the submission's own order and counts allow, read in one
+        # pass each (see drive()): "sorted" pays for a permutation,
+        # "grouped" plans over the admitted arrays as they lie, "dense"
+        # (grouped, every segment B long) also needs no index per element.
+        order = None
+        if n and not (g_arr[1:] >= g_arr[:-1]).all():
+            order = np.argsort(g_arr, kind="stable")
+            g_s = g_arr[order]
+            op_s, a_s, b_s, c_s = (x[order] for x in (op_a, a_a, b_a, c_a))
+        else:
+            g_s, op_s, a_s, b_s, c_s = g_arr, op_a, a_a, b_a, c_a
         firsts = np.ones(n, bool)
         firsts[1:] = g_s[1:] != g_s[:-1]
         starts = np.flatnonzero(firsts)
         counts = np.diff(np.append(starts, n))
         seg_groups = g_s[starts]
-        rank = np.arange(n) - np.repeat(starts, counts)
+        nseg = starts.size
+        dense = bool(order is None and n and counts.min() == counts.max())
+        plan = ("sorted" if order is not None
+                else "dense" if dense else "grouped")
+        if order is None:
+            rg._m_bulk_grouped.inc()
+            if dense:
+                rg._m_bulk_dense.inc()
         seg_base = rg._stream_count[seg_groups]            # [nseg]
         # tag-space check on an AGREED value: a per-process-local raise
         # before the collectives below would leave peer processes hung
@@ -593,11 +629,11 @@ class BulkDriver:
             raise OverflowError(
                 "per-group stream exceeds int32 tag space")
 
-        # all bookkeeping lives in SORTED space; unsorted at return.
-        # Every op's dispatch round is fixed by the blind phase-1 plan.
+        # all bookkeeping lives in SORTED space; unsorted at return, where
+        # a permutation was paid for. Every op's dispatch round is fixed
+        # by the blind phase-1 plan: its rank in its segment over S.
         resolved = np.zeros(n, bool)
         results = np.zeros(n, np.int64)
-        dispatch_round = (rank // S).astype(np.int64)
         resolve_round = np.zeros(n, np.int64)
 
         # On-device result accumulators, fetched ONCE per drive: [G, B]
@@ -611,7 +647,7 @@ class BulkDriver:
         if B == 0:   # agreed: every process is idle this drive
             z = np.zeros(0, np.int64)
             if stage is not None:
-                stage = stage.then("bulk.return", segments=0)
+                stage = stage.then("bulk.return", segments=0, plan=plan)
             return BulkResult(results=z, rounds=0, wall_s=0.0,
                               dispatch_round=z, resolve_round=z), stage, 0
         Bpad = 1 << max(0, B - 1).bit_length()
@@ -626,8 +662,19 @@ class BulkDriver:
                 f"({G_total * Bpad / 1e6:.0f}M slots) for {n} ops — burst "
                 "sizes are too skewed; split the drive into bursts of "
                 "similar per-group size")
+        if dense:
+            # every segment is `per` long (the agreed B may be another
+            # process's, and longer) and lies at seg_groups' rows, which
+            # are every row in order where every group sends
+            per = int(counts[0])
+            rows = slice(None) if nseg == G else seg_groups
+            dispatch_round = np.tile(
+                np.arange(per, dtype=np.int64) // S, nseg)
+        else:
+            rank = np.arange(n) - np.repeat(starts, counts)
+            dispatch_round = rank // S
         if stage is not None:
-            stage = stage.then("bulk.stage", segments=starts.size)
+            stage = stage.then("bulk.stage", segments=nseg, plan=plan)
             staged = rg._m_staged_bytes.value
             phase: dict = {}    # a straggler phase's stages say phase=2
         resbuf = rg._stage_acc(np.zeros((G, Bpad), np.int32))
@@ -670,6 +717,18 @@ class BulkDriver:
                 c if c is not None else _scatter(G, S, g_s[pos], slots,
                                                  v[pos])
                 for c, v in zip(consts, vals))
+
+        def window(done):
+            """Each segment's next <=S operations past its first
+            ``done``: how many a segment (``want``), the segments that
+            send any, and the operations' positions and slots."""
+            want = np.clip(counts - done, 0, S)
+            segs = np.flatnonzero(want > 0)
+            reps = want[segs]
+            slots = np.arange(reps.sum()) \
+                - np.repeat(np.cumsum(reps) - reps, reps)
+            pos = np.repeat((starts + done)[segs], reps) + slots
+            return want, segs, pos, slots
 
         def dispatch(tagl, vnp, leaves) -> None:
             nonlocal r, resbuf, valbuf, rndbuf, evflag
@@ -719,10 +778,15 @@ class BulkDriver:
                     rg.telemetry.ingest(tel, rounds0 + tel_ingested)
                     tel_ingested += 1
             tel_stash.clear()
-            colm = np.arange(Bpad)[None, :] < counts[:, None]
-            resolved[:] = val_np[seg_groups][colm]
-            results[:] = res_np[seg_groups][colm]
-            resolve_round[:] = rnd_np[seg_groups][colm]
+            if dense:
+                for out, acc in ((resolved, val_np), (results, res_np),
+                                 (resolve_round, rnd_np)):
+                    out.reshape(nseg, per)[:] = acc[rows, :per]
+            else:
+                colm = np.arange(Bpad)[None, :] < counts[:, None]
+                resolved[:] = val_np[seg_groups][colm]
+                results[:] = res_np[seg_groups][colm]
+                resolve_round[:] = rnd_np[seg_groups][colm]
             if ev.any():
                 # rare path (session-event ops in the burst): fetch the
                 # stashed per-round event leaves and ingest with seq
@@ -764,22 +828,29 @@ class BulkDriver:
                     arr[:windows] = c     # burst-uniform: one fill
                 return arr
 
-            op_w, a_w, b_w, c_w = (_payload_w(c) for c in consts)
-            win_of = rank // S
-            slot_of = rank - win_of * S
+            planes = tuple(_payload_w(c) for c in consts)
+            op_w, a_w, b_w, c_w = planes
             for w in range(windows):
                 tagl_w[w, seg_groups, 0] = (seg_base + w * S + 1) \
                     .astype(np.int32)
-                valid_w[w][seg_groups] = (w * S + np.arange(S))[None, :] \
-                    < counts[:, None]
-            if consts[0] is None:
-                op_w[win_of, g_s, slot_of] = op_s
-            if consts[1] is None:
-                a_w[win_of, g_s, slot_of] = a_s
-            if consts[2] is None:
-                b_w[win_of, g_s, slot_of] = b_s
-            if consts[3] is None:
-                c_w[win_of, g_s, slot_of] = c_s
+            if dense:
+                # window w is columns w*S.. of the operations as they
+                # lie, [nseg, per]: a strided copy a window and plane
+                varying = [(x_w, x_s.reshape(nseg, per)) for c, x_w, x_s
+                           in zip(consts, planes, vals) if c is None]
+                for w in range(-(-per // S)):
+                    k = min(S, per - w * S)
+                    valid_w[w, rows, :k] = True
+                    for x_w, x_s in varying:
+                        x_w[w, rows, :k] = x_s[:, w * S:w * S + k]
+            else:
+                for w in range(windows):
+                    valid_w[w][seg_groups] = \
+                        (w * S + np.arange(S))[None, :] < counts[:, None]
+                slot_of = rank - dispatch_round * S
+                for c, x_w, x_s in zip(consts, planes, vals):
+                    if c is None:
+                        x_w[dispatch_round, g_s, slot_of] = x_s
             _scan = _deep_scan_program(
                 rg.config, onehot=rg.mesh is not None, donate=rg.donate)
             rg._key, key = jax.random.split(rg._key)
@@ -800,15 +871,12 @@ class BulkDriver:
                 stage = stage.then(
                     "bulk.dispatch", bytes=rg._m_staged_bytes.value - staged)
             for w in range(windows):
-                in_w = (rank >= w * S) & (rank < (w + 1) * S)
-                pos = np.flatnonzero(in_w)
+                want, _, pos, slots = window(w * S)
                 tagl[seg_groups, 0] = (seg_base + w * S + 1) \
                     .astype(np.int32)
                 vnp = np.zeros((G, S), bool)
-                vnp[seg_groups] = (w * S + np.arange(S))[None, :] \
-                    < counts[:, None]
-                dispatch(tagl.copy(), vnp,
-                         payload_leaves(pos, rank[pos] - w * S))
+                vnp[seg_groups] = np.arange(S)[None, :] < want[:, None]
+                dispatch(tagl.copy(), vnp, payload_leaves(pos, slots))
             for _ in range(3):  # settle: replicate + commit + report lag
                 dispatch(*_idle[:2], _idle[2])
         if stage is not None:
@@ -845,18 +913,13 @@ class BulkDriver:
                     f"stream cursors resynced from the device")
             # reduceat on bool would logical-or, not count — cast first
             fu = np.add.reduceat(resolved.astype(np.int64), starts)
-            want = np.minimum(counts - fu, S)
-            segs = np.flatnonzero(want > 0)
-            reps = want[segs]
-            offs = np.arange(reps.sum()) \
-                - np.repeat(np.cumsum(reps) - reps, reps)
-            pos = np.repeat((starts + fu)[segs], reps) + offs
+            want, segs, pos, slots = window(fu)
             tagl[:, 0] = 0
             tagl[seg_groups[segs], 0] = (seg_base[segs] + fu[segs] + 1) \
                 .astype(np.int32)
             vnp = np.zeros((G, S), bool)
             vnp[seg_groups] = np.arange(S)[None, :] < want[:, None]
-            leaves = payload_leaves(pos, offs)
+            leaves = payload_leaves(pos, slots)
             if stage is not None:   # (a pass puts no accumulator)
                 stage = stage.then("bulk.dispatch", phase=2)
             dispatch(tagl.copy(), vnp, leaves)
@@ -873,16 +936,17 @@ class BulkDriver:
             rg._stream_count[seg_groups] += counts
         rg.rounds += r
         rg.metrics.counter("ops_committed").inc(n)
-        out_res = np.zeros(n, np.int64)
-        out_dr = np.zeros(n, np.int64)
-        out_rr = np.zeros(n, np.int64)
-        out_res[order] = results
-        out_dr[order] = dispatch_round
-        out_rr[order] = resolve_round
-        return BulkResult(results=out_res, rounds=r,
+        if order is not None:   # back to submission order
+            unsorted = []
+            for x in (results, dispatch_round, resolve_round):
+                out = np.zeros(n, np.int64)
+                out[order] = x
+                unsorted.append(out)
+            results, dispatch_round, resolve_round = unsorted
+        return BulkResult(results=results, rounds=r,
                           wall_s=time.perf_counter() - t0,
-                          dispatch_round=out_dr,
-                          resolve_round=out_rr), stage, windows
+                          dispatch_round=dispatch_round,
+                          resolve_round=resolve_round), stage, windows
 
 
 class _EventView:

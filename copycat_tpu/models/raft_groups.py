@@ -397,6 +397,10 @@ class RaftGroups:
         self._m_fetch_bytes = self.metrics.counter("fetch_bytes")
         # bytes of the host arrays the deep drive hands the device
         self._m_staged_bytes = self.metrics.counter("staged_bytes")
+        # deep drives whose submission arrived in group order, and those
+        # of them in which every group sent the same count (models/bulk.py)
+        self._m_bulk_grouped = self.metrics.counter("bulk_grouped_drives")
+        self._m_bulk_dense = self.metrics.counter("bulk_dense_drives")
         self._m_settle_rounds = self.metrics.counter("query_settle_rounds")
         # read windows evaluated (every one), and those of them whose rows
         # all rode a vector run's round and cost no call of their own

@@ -115,7 +115,10 @@ root and ``bulk.admit`` only.
 - ``bulk.admit`` [bulk.drive] — ``drive()`` entered -> ``_drive_deep``
   entered (the arguments as arrays of the drive's length).
 - ``bulk.plan`` [bulk.drive] — -> the accumulators about to be staged
-  (sort by group, starts, counts, ranks; ``segments``).
+  (starts, counts; ``segments``, and ``plan``: ``"sorted"`` where
+  ``groups`` was not in group order and the drive sorted, ``"grouped"``
+  where it was, ``"dense"`` where every group also sent the same count
+  and no index per operation was built).
 - ``bulk.stage`` [bulk.drive] — -> the program about to be called (the
   accumulators put, the stacked payload built; ``bytes`` put by
   ``_stage_acc``).
@@ -127,11 +130,14 @@ root and ``bulk.admit`` only.
 - ``bulk.harvest`` [bulk.drive] — -> every operation known resolved or
   not (``resolved``).
 - ``bulk.return`` [bulk.drive] — -> ``_drive_deep`` returned (back to
-  submission order, the ``BulkResult`` built, the drive's arrays freed).
+  submission order where the drive sorted, the ``BulkResult`` built, the
+  drive's arrays freed).
 
 ``engine.staged_bytes`` (``RaftGroups._note_stage``) counts the bytes of
 the host arrays such a drive hands the device, beside
-``engine.fetch_bytes`` for what it takes back.
+``engine.fetch_bytes`` for what it takes back;
+``engine.bulk_grouped_drives`` and ``engine.bulk_dense_drives`` count the
+drives whose ``plan`` was not ``"sorted"``, and was ``"dense"``.
 
 :meth:`Tracer.report` is the whole-window account (docs/OBSERVABILITY.md
 "The window report"): per-name aggregates that do not depend on what the

@@ -126,7 +126,7 @@ def test_the_stages_bytes_are_the_counters_and_the_shapes(recorded):
     scan, _, traces, report, delta = recorded
     for spans in traces.values():
         meta = {s.name: s.meta or {} for s in spans}
-        assert meta["bulk.plan"] == {"segments": G}
+        assert meta["bulk.plan"] == {"segments": G, "plan": "dense"}
         assert meta["bulk.stage"] == {"bytes": ACCUMULATORS}
         assert meta["bulk.dispatch"] == {"rounds": ROUNDS}
         assert meta["bulk.fetch"] == {"bytes": FETCHED}
@@ -175,6 +175,57 @@ def test_a_straggler_phase_records_its_stages_again_with_phase_2(tracer):
     assert 0 <= root["self_ms"] <= 0.03 * root["total_ms"]
 
 
+def ragged():
+    """One to eight adds a group, in group order."""
+    g = np.concatenate([np.full(i + 1, i) for i in range(G)])
+    return g, ap.OP_LONG_ADD, np.arange(1, g.size + 1)
+
+
+def shuffled():
+    g, op, a = burst()
+    perm = np.random.default_rng(39).permutation(g.size)
+    return g[perm], op, a[perm]
+
+
+@pytest.mark.parametrize("scan", [True, False], ids=["scan", "dispatch"])
+@pytest.mark.parametrize("submission,plan,segments", [
+    (shuffled, "sorted", G), (ragged, "grouped", G), (burst, "dense", G)],
+    ids=["sorted", "grouped", "dense"])
+def test_the_stages_share_their_boundaries_whatever_the_plan(
+        tracer, scan, submission, plan, segments):
+    """The plan a submission's order and counts select changes what the
+    stages do, not where they begin and end: nine spans, each stage from
+    the instant the one before it ended, and ``bulk.plan`` names the plan."""
+    rg = engine(mesh=False)
+    driver = BulkDriver(rg, deep_scan=scan)
+    driver.drive(*burst())
+    counters = [rg.metrics.counter(f"bulk_{t}_drives")
+                for t in ("grouped", "dense")]
+    before = [c.value for c in counters]
+    tracing.enable()
+    res = driver.drive(*submission())
+    tracing.disable()
+    (spans,) = tracer.traces().values()
+    assert [s.name for s in spans] == STAGES + ["bulk.drive"]
+    *stages, root = spans
+    assert stages[0].start == root.start
+    assert all(b.start == a.end for a, b in zip(stages, stages[1:]))
+    assert root.start <= stages[-1].end <= root.end
+    assert all(s.parent == "bulk.drive" for s in stages)
+    assert stages[1].meta == {"segments": segments, "plan": plan}
+    assert root.meta == {"n": res.results.size, "rounds": ROUNDS,
+                         "windows": WINDOWS, "scan": scan}
+    assert [c.value - b for c, b in zip(counters, before)] \
+        == [int(plan != "sorted"), int(plan == "dense")]
+    report = tracer.report()
+    assert report["counters"]["engine.bulk_grouped_drives"] \
+        == int(plan != "sorted")
+    assert report["counters"]["engine.bulk_dense_drives"] \
+        == int(plan == "dense")
+    root = report["spans"]["bulk.drive"]
+    assert 0 <= root["self_ms"] <= 0.03 * root["total_ms"]
+
+
 def test_a_classic_drive_records_the_root_and_the_admit(tracer):
     rg = device_plane(seed=38)
     rg.wait_for_leaders()
@@ -198,7 +249,7 @@ def test_an_idle_drive_closes_what_it_opened(tracer):
     (spans,) = tracer.traces().values()
     assert [s.name for s in spans] == ["bulk.admit", "bulk.plan",
                                        "bulk.return", "bulk.drive"]
-    assert spans[1].meta == {"segments": 0}
+    assert spans[1].meta == {"segments": 0, "plan": "grouped"}
     assert spans[3].meta == {"n": 0, "rounds": 0, "windows": 0, "scan": True}
 
 

@@ -49,6 +49,34 @@ def test_deep_drive_on_sharded_mesh_fifo_and_reads():
     assert (got == (np.arange(G) % 7) + 2).all()
 
 
+def test_a_dense_drive_on_a_mesh_returns_what_its_sorted_form_does():
+    """The plan is read from the submission alone (``models/bulk.py``): over
+    a sharded engine the reshaped payload and the sliced harvest give what
+    the stable sort and the index arrays give, and leave the same state."""
+    engines = [_mesh_engine(seed=39), _mesh_engine(seed=39)]
+    dense, sorted_ = (BulkDriver(rg, deep_scan=True) for rg in engines)
+    g = np.repeat(np.arange(G), 6)     # two windows, the second half full
+    amounts = np.arange(g.size) % 5 + 1
+    mixed = np.random.default_rng(39).permutation(g)
+    perm = np.empty(g.size, np.int64)
+    perm[np.argsort(mixed, kind="stable")] = np.arange(g.size)
+    for _ in range(2):
+        res = dense.drive(g, ap.OP_LONG_ADD, amounts)
+        res_sorted = sorted_.drive(mixed, ap.OP_LONG_ADD, amounts[perm])
+        assert res.rounds == res_sorted.rounds
+        for name in ("results", "dispatch_round", "resolve_round"):
+            assert np.array_equal(getattr(res, name)[perm],
+                                  getattr(res_sorted, name)), name
+    assert (res.results.reshape(G, 6)[:, -1] == 2 * amounts.reshape(G, 6)
+            .sum(axis=1)).all()
+    for x, y in zip(*(jax.tree.leaves(jax.device_get(rg.state))
+                      for rg in engines)):
+        assert np.array_equal(x, y)
+    counts = [[rg.metrics.counter(f"bulk_{t}_drives").value
+               for t in ("grouped", "dense")] for rg in engines]
+    assert counts == [[2, 2], [0, 0]]
+
+
 def test_deep_step_census_zero_collectives_on_mesh():
     devices = jax.devices("cpu")
     config = Config(append_window=8, applies_per_round=8,
