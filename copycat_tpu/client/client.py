@@ -154,6 +154,7 @@ class RaftClient(Managed):
         # add and, per flushed batch, one histogram record.
         self.metrics = MetricsRegistry()
         TRACER.register(self.metrics, "client.")
+        self._m_events_received = self.metrics.counter("events_received")
         # batch-scope tracing (utils/tracing.py): the open client.stage
         # span of the command micro-batch and of the read batch being
         # staged (first operation staged -> its flush began)
@@ -468,21 +469,33 @@ class RaftClient(Managed):
         if request.event_index is None:
             # delta-only push: the event channel's position is untouched
             return msg.PublishResponse(event_index=position)
-        if request.prev_event_index != position:
-            # Gap or replay: report our position; the server resends from there.
-            return msg.PublishResponse(event_index=position)
-        for event, message in request.events or []:
-            try:
-                session._dispatch(event, message)
-            except Exception:  # listener errors must not poison the channel
-                pass
-        session._event_indices[g] = request.event_index
-        if trace is not None:
+        # the batch in the request's own fields, then the session's further
+        # sealed batches in order (``more``), each under the same rule
+        batches = [(request.event_index, request.prev_event_index,
+                    request.events)]
+        more = getattr(request, "more", None)
+        if more:
+            batches += more
+        received = 0
+        for event_index, prev_event_index, events in batches:
+            if prev_event_index != position:
+                # Gap or replay: report our position; the server resends
+                # from there.
+                break
+            for event, message in events or ():
+                try:
+                    session._dispatch(event, message)
+                except Exception:  # listener errors must not poison the channel
+                    pass
+            received += len(events or ())
+            position = session._event_indices[g] = event_index
+        self._m_events_received.inc(received)
+        if trace is not None and received:
             # traced event delivery: receipt + listener dispatch on the
             # originating causal timeline (member tag "client")
             TRACER.span(trace, "client.event", t0, time.perf_counter(),
-                        group=g, n=len(request.events or ()))
-        return msg.PublishResponse(event_index=request.event_index)
+                        group=g, n=received)
+        return msg.PublishResponse(event_index=position)
 
     # -- operation submission ---------------------------------------------
 

@@ -53,6 +53,7 @@ from ..server.state_machine import Commit
 from ..atomic import commands as vc
 from ..collections import commands as cc
 from ..coordination import commands as oc
+from ..utils.tracing import TRACER
 
 logger = logging.getLogger(__name__)
 
@@ -397,6 +398,15 @@ class DeviceEngine:
         self._window: DeviceWindow | None = None
         self._map_shadow = 0         # map keys held on the host
         self._map_ops = None         # the registry's two map counters
+        self._lock_ops = None        # and its two lock counters
+        self._lock_overflow = 0      # lock waiters held on the host
+        self._wait_slots = None      # width of a lock's device wait ring
+        #: dispatches of the vector lane so far: what a machine that looks
+        #: ahead over its staged rows tells one run from the next by
+        self.vector_epoch = 0
+        #: events the staged rows of the next vector run will cause, which
+        #: their finalize reads: group -> [the machine's cursor, how many]
+        self._events_due: dict[int, list[int]] = {}
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -465,6 +475,33 @@ class DeviceEngine:
                                         metrics.counter("map_chain_ops"))
         counters[chain].inc()
 
+    def count_lock_op(self, chain: bool) -> None:
+        """One more lock command finalised on the vector lane, or
+        (``chain``) run as a generator chain: ``engine.lock_vector_ops``
+        and ``engine.lock_chain_ops`` in the tracer's report."""
+        counters = self._lock_ops
+        if counters is None:
+            metrics = self._groups.metrics
+            counters = self._lock_ops = (metrics.counter("lock_vector_ops"),
+                                         metrics.counter("lock_chain_ops"))
+        counters[chain].inc()
+
+    def lock_wait_slots(self) -> int:
+        """Slots of a lock's wait ring on the device."""
+        slots = self._wait_slots
+        if slots is None:
+            slots = self._wait_slots = \
+                self._groups.state.resources.lk_wait_id.shape[-1]
+        return slots
+
+    def count_lock_overflow(self, delta: int) -> None:
+        """Lock waiters the device's wait ring refused and the host holds
+        moved by ``delta``: the gauge ``lock.host_overflow_waiters``."""
+        self._lock_overflow += delta
+        if self._groups is not None:
+            self._groups.metrics.gauge("lock.host_overflow_waiters").set(
+                self._lock_overflow)
+
     def count_shadow(self, delta: int) -> None:
         """Map keys held on the host for want of room in their bucket or
         of an int32 shape moved by ``delta``."""
@@ -517,7 +554,9 @@ class DeviceEngine:
         from ..models import checkpoint
         self._groups = checkpoint.load_bytes(blob, mesh=self.config.mesh)
         self._map_ops = None         # they were the replaced registry's
+        self._lock_ops = None
         self._map_shadow = 0         # the machines' restores count anew
+        self._lock_overflow = 0
         self._warm_capture()
         self._next_group = int(next_group)
         self._free = sorted(int(g) for g in free)
@@ -592,16 +631,61 @@ class DeviceEngine:
         """
         return self._ensure().serve_query(group, opcode, a, b, c)
 
-    def take_events(self, group: int, cursor: int) -> tuple[list, int]:
-        """Events for ``group`` with seq > cursor; returns (events, cursor)."""
-        if self._groups is None:
+    def take_events(self, group: int, cursor: int,
+                    limit: int | None = None) -> tuple[list, int]:
+        """Events for ``group`` with seq > cursor, the oldest ``limit`` of
+        them where one is given; returns (events, cursor). The retained
+        list ascends by seq, so what is new is found from its end: a call
+        costs what it returns, not what the group has kept."""
+        evs = self._groups.events.get(group) if self._groups is not None \
+            else None
+        if not evs or evs[-1][0] <= cursor:
             return [], cursor
-        out = []
-        for ev in self._groups.events.get(group, []):
-            if ev[0] > cursor:
-                out.append(ev)
-                cursor = ev[0]
-        return out, cursor
+        at = len(evs) - 1
+        while at and evs[at - 1][0] > cursor:
+            at -= 1
+        out = evs[at:] if limit is None else evs[at:at + limit]
+        return out, out[-1][0]
+
+    def expect_event(self, group: int, cursor: int) -> None:
+        """A row being staged for the next vector run will cause one
+        session event on ``group``, which its finalize reads past
+        ``cursor``: :meth:`run_vector` returns only once it is in the
+        host's buffer."""
+        due = self._events_due.get(group)
+        if due is None:
+            self._events_due[group] = [cursor, 1]
+        else:
+            due[1] += 1
+
+    def _settle_events(self, groups: Any, due: dict[int, list[int]],
+                       max_rounds: int = 16) -> None:
+        """Step until every event the run's rows caused is in the host's
+        buffer. The leader lane drains its outbox in the round it applies
+        in, so a run's own round has brought them as a rule and nothing
+        is stepped; else the rounds are stepped once for the turn, fused
+        (``engine.settle``)."""
+        events = groups.events
+        stepped = 0
+        while True:
+            for group, (cursor, count) in due.items():
+                evs = events.get(group)
+                if not evs or len(evs) < count \
+                        or evs[-count][0] <= cursor:
+                    break
+            else:
+                return
+            if stepped >= max_rounds:
+                # the rows' finalize fails each row whose event is missing
+                logger.error("vector pump: events of %d groups not drained "
+                             "after %d settle rounds", len(due), stepped)
+                return
+            span = TRACER.open_span("engine.settle") if TRACER.enabled \
+                else None
+            groups.step_rounds(self.SETTLE_ROUNDS)
+            stepped += self.SETTLE_ROUNDS
+            if span is not None:
+                span.close(rounds=self.SETTLE_ROUNDS, groups=len(due))
 
     def event_cursor(self, group: int) -> int:
         """Current newest event seq for ``group`` (start-of-life cursor)."""
@@ -635,9 +719,13 @@ class DeviceEngine:
         run's round, the tracked lane leaves them as they were, and
         :meth:`finish_query_vector` answers them either way."""
         groups = self._ensure()
+        self.vector_epoch += 1
+        due, self._events_due = self._events_due, {}
         res = groups.drive_vector(groups_idx, opcodes, a, b, c,
                                   max_rounds=max_rounds, query=query)
         if res is not None:
+            if due:
+                self._settle_events(groups, due)
             return res.tolist()
         tags = groups.submit_batch(groups_idx, opcodes, a, b, c)
         tag_l = tags.tolist()
@@ -645,6 +733,8 @@ class DeviceEngine:
         for _ in range(max_rounds):
             groups.step_round()
             if all(t in results for t in tag_l):
+                if due:
+                    self._settle_events(groups, due)
                 return [results.pop(t) for t in tag_l]
         missing = sum(1 for t in tag_l if t not in results)
         raise TimeoutError(
@@ -706,6 +796,7 @@ class _Held:
 # bookkeeping consumes the device result at the batched pump's finalize.
 VK_CAS, VK_GET_AND_SET, VK_SET = 1, 2, 3
 VK_MAP_PUT, VK_MAP_REMOVE, VK_MAP_PUT_IF_ABSENT, VK_MAP_REPLACE = 4, 5, 6, 7
+VK_LOCK, VK_TRY_LOCK, VK_UNLOCK = 8, 9, 10
 
 # Query-spec finalize kinds (query_spec's last element). Reads never
 # mutate host bookkeeping, so the only consumption modes are the raw
@@ -752,10 +843,15 @@ class DeviceBackedStateMachine(ResourceStateMachine):
             return fn
 
         def wrapped(commit, _fn=fn):
+            self._note_chain()
             return DeviceJob(self._eng, self._group, type(self).SETTLES,
                              _fn(commit))
 
         return wrapped
+
+    def _note_chain(self) -> None:
+        """A command of this machine runs as a generator chain (a machine
+        with a vector lane counts them beside that lane's)."""
 
     def _cmd(self, opcode: int, a: int = 0, b: int = 0, c: int = 0):
         """Issue one device command from inside a chain:
@@ -790,11 +886,19 @@ class DeviceBackedStateMachine(ResourceStateMachine):
     # tests/test_spi_vector_pump.py proves it differentially against the
     # host state machines (``executor="cpu"``).
 
+    #: True where ``vector_spec`` also takes what the handler would read
+    #: as ``commit.index`` and ``commit.session``:
+    #: ``vector_spec(operation, index, session)`` (a lock's waiter id IS
+    #: its ``Lock`` commit's index)
+    VECTOR_BY_COMMIT = False
+
     def vector_spec(self, operation: Any
                     ) -> tuple[int, int, int, int, int] | None:
         """(opcode, a, b, c, finalize_kind) for a vector-eligible op, or
         ``None`` when the op needs its generator handler (host shadow,
-        TTLs, listeners, events, multi-op chains)."""
+        TTLs, listeners, multi-op chains). A row that is routed is
+        staged, so a machine may note it here. What ``vector_finalize``
+        publishes is sealed inside the row's own entry."""
         return None
 
     def vector_finalize(self, kind: int, operation: Any, raw: int,
@@ -1153,16 +1257,8 @@ class DeviceMapState(DeviceBackedStateMachine):
         # nothing)
         self._device = False
 
-    def _wrap_handler(self, fn):
-        wrapped = super()._wrap_handler(fn)
-        if wrapped is fn:
-            return fn
-
-        def counted(commit):
-            self._eng.count_map_op(chain=True)
-            return wrapped(commit)
-
-        return counted
+    def _note_chain(self) -> None:
+        self._eng.count_map_op(chain=True)
 
     # -- internals ---------------------------------------------------------
 
@@ -1963,6 +2059,21 @@ def _rebind(commits: Iterable[Commit], session: Any) -> None:
 # lock
 # ---------------------------------------------------------------------------
 
+class _LockAhead:
+    """A lock as the rows staged for one vector run (``epoch``) will leave
+    it: the holder's waiter id and its session's id, and the queue's
+    ``(waiter id, session id)`` in order."""
+
+    __slots__ = ("epoch", "holder", "session", "queue")
+
+    def __init__(self, epoch: int, holder: int | None, session: Any,
+                 queue: deque) -> None:
+        self.epoch = epoch
+        self.holder = holder
+        self.session = session
+        self.queue = queue
+
+
 class DeviceLockState(DeviceBackedStateMachine):
     """Mutex on the device lock kernel: waiter id = the Lock commit index
     (unique per acquire, same as the CPU machine), grants delivered as
@@ -1983,11 +2094,23 @@ class DeviceLockState(DeviceBackedStateMachine):
         self._holder_id: int | None = None
         self._timers: dict[int, Any] = {}
         self._overflow: deque[int] = deque()    # ids the device ring rejected
+        # the lock as the rows staged for the next vector run will leave
+        # it (``vector_spec``)
+        self._ahead: _LockAhead | None = None
+        engine.count_lock_overflow(0)           # the gauge, from the first
+
+    def _note_chain(self) -> None:
+        self._eng.count_lock_op(chain=True)
+
+    def _overflowed(self, wid: int) -> None:
+        self._overflow.append(wid)
+        self._eng.count_lock_overflow(1)
 
     # -- event pump --------------------------------------------------------
 
-    def _pump(self):
-        for _seq, code, target, _arg in self._events():
+    def _pump(self, events: list | None = None):
+        for _seq, code, target, _arg in (self._events() if events is None
+                                         else events):
             if code != ops().EV_LOCK_GRANT:
                 continue
             waiter = self._waiters.get(target)
@@ -2008,17 +2131,14 @@ class DeviceLockState(DeviceBackedStateMachine):
     def _flush_overflow(self):
         while self._overflow:
             wid = self._overflow[0]
-            if wid not in self._waiters:
-                self._overflow.popleft()
-                continue
-            result = yield from self._cmd(ops().OP_LOCK_ACQUIRE, wid, -1)
-            if result == 1:  # granted immediately
-                self._overflow.popleft()
-                self._on_grant(wid)
-            elif result == 2:  # queued on device
-                self._overflow.popleft()
-            else:  # ring still full
-                break
+            if wid in self._waiters:
+                result = yield from self._cmd(ops().OP_LOCK_ACQUIRE, wid, -1)
+                if result == 0:  # ring still full
+                    break
+                if result == 1:  # granted immediately (2: queued on device)
+                    self._on_grant(wid)
+            self._overflow.popleft()
+            self._eng.count_lock_overflow(-1)
 
     def _on_grant(self, wid: int) -> None:
         waiter = self._waiters.get(wid)
@@ -2048,13 +2168,13 @@ class DeviceLockState(DeviceBackedStateMachine):
             return wid
         self._waiters[wid] = commit
         if self._overflow:
-            self._overflow.append(wid)  # preserve FIFO behind overflow
+            self._overflowed(wid)  # preserve FIFO behind overflow
         else:
             result = yield from self._cmd(ops().OP_LOCK_ACQUIRE, wid, -1)
             if result == 1:
                 self._on_grant(wid)
             elif result == 0:  # device wait ring full — host absorbs
-                self._overflow.append(wid)
+                self._overflowed(wid)
         if timeout and timeout > 0 and self._holder_id != wid:
             def expire() -> None:
                 def chain():
@@ -2073,6 +2193,7 @@ class DeviceLockState(DeviceBackedStateMachine):
             return
         if wid in self._overflow:
             self._overflow.remove(wid)
+            self._eng.count_lock_overflow(-1)
             outcome = 1
         else:
             outcome = yield from self._cmd(ops().OP_LOCK_CANCEL, wid)
@@ -2108,6 +2229,118 @@ class DeviceLockState(DeviceBackedStateMachine):
         yield from self._cmd(ops().OP_LOCK_RELEASE, wid)
         yield from self._pump()
 
+    # -- vector lane (batched server-side pump) ---------------------------
+    # With no acquire timer armed and no waiter in the host overflow the
+    # host's record (``_holder_id``, ``_waiters`` in arrival order) IS the
+    # device's lock, so ``Lock(-1)``, ``Lock(0)`` and ``Unlock`` are ONE
+    # device op each: an acquire under the commit's index, or a release of
+    # the holder. Which waiter an ``Unlock`` releases depends on the rows
+    # staged before it and not yet finalized (a run may hold a lock's
+    # ``Unlock`` and the next holder's), so ``vector_spec`` looks ahead:
+    # ``_ahead`` is the holder and the queue as the staged rows will leave
+    # them, drawn from the record when a run's first row is staged. A row
+    # it cannot answer for one device op (a positive timeout, a timer
+    # armed, a waiter in overflow, a ring the staged rows could fill, an
+    # ``Unlock`` that is not the holder's) takes its generator handler,
+    # which the pump applies after the staged rows have landed. The grant
+    # a release causes comes from the device's event ring as ever:
+    # ``run_vector`` returns once it is in the host's buffer, and the
+    # ``Unlock`` row that caused it publishes it, one event a release.
+
+    VECTOR_BY_COMMIT = True   # the waiter id, and whose unlock it is
+
+    def vector_spec(self, operation: Any, index: int, session: Any
+                    ) -> tuple[int, int, int, int, int] | None:
+        t = type(operation)
+        if t is oc.Lock:
+            timeout = operation.timeout
+            if timeout and timeout > 0:
+                return None
+        elif t is not oc.Unlock:
+            return None
+        if self._timers or self._overflow:
+            return None
+        eng = self._eng
+        ahead = self._ahead
+        if ahead is None or ahead.epoch != eng.vector_epoch:
+            held = self._holder_id
+            ahead = self._ahead = _LockAhead(
+                eng.vector_epoch, held,
+                None if held is None else self._waiters[held].session.id,
+                deque((wid, c.session.id)
+                      for wid, c in self._waiters.items() if wid != held))
+        o = ops()
+        if t is oc.Unlock:
+            released = ahead.holder
+            if released is None or ahead.session != session.id:
+                return None       # nothing to release, or not the holder
+            if ahead.queue:
+                ahead.holder, ahead.session = ahead.queue.popleft()
+                eng.expect_event(self._group, self._ev_cursor)
+            else:
+                ahead.holder = ahead.session = None
+            return (o.OP_LOCK_RELEASE, released, 0, 0, VK_UNLOCK)
+        if ahead.holder is None:
+            ahead.holder, ahead.session = index, session.id
+        elif timeout == 0:
+            pass                  # a try-lock of a held lock is refused
+        elif len(ahead.queue) >= eng.lock_wait_slots():
+            return None           # the ring could refuse it: the overflow
+        else:
+            ahead.queue.append((index, session.id))
+        if timeout == 0:
+            return (o.OP_LOCK_ACQUIRE, index, 0, 0, VK_TRY_LOCK)
+        return (o.OP_LOCK_ACQUIRE, index, -1, 0, VK_LOCK)
+
+    def vector_finalize(self, kind: int, operation: Any, raw: int,
+                        commit: Commit) -> Any:
+        self._eng.count_lock_op(chain=False)
+        if kind == VK_UNLOCK:
+            # the generator's _release_holder and the pump of its one
+            # event: the record, then the grant the release caused
+            try:
+                holder = self._waiters.pop(self._holder_id, None)
+                self._holder_id = None
+                if holder is not None:
+                    holder.clean()
+                if self._waiters:
+                    self._take_grant()
+            finally:
+                commit.clean()
+            return None
+        wid = commit.index
+        if kind == VK_TRY_LOCK:
+            if raw == 1:
+                self._waiters[wid] = commit
+                self._on_grant(wid)
+            else:
+                commit.session.publish(
+                    "lock", {"id": wid, "acquired": False})
+                commit.clean()
+            return wid
+        self._waiters[wid] = commit
+        if raw == 1:
+            self._on_grant(wid)
+        elif raw == 0:            # not reached: vector_spec counts the ring
+            self._overflowed(wid)
+        return wid
+
+    def _take_grant(self) -> None:
+        """The one event the release just finalized caused: the grant to
+        the ring's first waiter, published inside the ``Unlock``'s entry."""
+        events, self._ev_cursor = self._eng.take_events(
+            self._group, self._ev_cursor, limit=1)
+        if not events:
+            raise RuntimeError("lock: the release's grant has not surfaced")
+        _seq, code, target, _arg = events[0]
+        waiter = self._waiters.get(target)
+        if code != ops().EV_LOCK_GRANT or waiter is None:
+            self._spawn(self._pump(events))   # a dead waiter: as the pump
+            return
+        self._holder_id = target
+        if waiter.session.is_open:
+            waiter.session.publish("lock", {"id": target, "acquired": True})
+
     # -- snapshot hooks (crash-recovery plane, docs/DURABILITY.md) --------
     # The device lock (holder, wait ring) rides the engine's checkpoint
     # blob; the host bookkeeping is the holder's id, the waiters in
@@ -2133,6 +2366,7 @@ class DeviceLockState(DeviceBackedStateMachine):
             self._waiters[wid] = Commit(wid, _UnboundSession(sid), 0.0,
                                         None, None)
         self._overflow = deque(data["overflow"])
+        self._eng.count_lock_overflow(len(self._overflow))
 
     def register(self, session: Any) -> None:
         super().register(session)
@@ -2170,6 +2404,7 @@ class DeviceLockState(DeviceBackedStateMachine):
             for waiter in self._waiters.values():
                 waiter.clean()
             self._waiters.clear()
+            self._eng.count_lock_overflow(-len(self._overflow))
             self._overflow.clear()
 
         self._run_excl(chain())

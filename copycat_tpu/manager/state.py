@@ -294,14 +294,18 @@ class ResourceManager(StateMachine):
 
     # -- batched server-side pump (vector lane) ---------------------------
 
-    def vector_route(self, operation: Any):
+    def vector_route(self, operation: Any, index: int):
         """Classify one committed operation for the applying server's
         vector lane: ``(machine, instance, inner_op, spec)`` when the op
         is a routed resource command whose device-backed machine can
         express it as ONE device op (``DeviceBackedStateMachine.
         vector_spec``), else ``None`` — the per-entry windowed apply
-        handles everything else. Exact-type checks keep subclasses (which
-        may override semantics) on the general path."""
+        handles everything else. ``index`` is the entry's log index: what
+        the handler would read as ``commit.index`` (a lock's waiter id),
+        handed with the instance's session to a machine that asks for
+        them (``VECTOR_BY_COMMIT``).
+        Exact-type checks keep subclasses (which may override semantics)
+        on the general path."""
         if type(operation) is not InstanceCommand:
             return None
         envelope = operation.operation
@@ -315,7 +319,8 @@ class ResourceManager(StateMachine):
         if spec_fn is None:
             return None
         inner = envelope.operation
-        spec = spec_fn(inner)
+        spec = spec_fn(inner, index, instance.session) \
+            if machine.VECTOR_BY_COMMIT else spec_fn(inner)
         if spec is None:
             return None
         return machine, instance, inner, spec

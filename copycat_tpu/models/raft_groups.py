@@ -402,6 +402,7 @@ class RaftGroups:
         self._m_bulk_grouped = self.metrics.counter("bulk_grouped_drives")
         self._m_bulk_dense = self.metrics.counter("bulk_dense_drives")
         self._m_settle_rounds = self.metrics.counter("query_settle_rounds")
+        self._m_events_ingested = self.metrics.counter("events_ingested")
         # read windows evaluated (every one), and those of them whose rows
         # all rode a vector run's round and cost no call of their own
         self._m_queries_served = self.metrics.counter("queries_served")
@@ -1236,23 +1237,30 @@ class RaftGroups:
         driver that skipped this would LOSE them."""
         ev_valid = np.asarray(out.ev_valid)
         if ev_valid.any():
-            seq = np.asarray(out.ev_seq)
-            code = np.asarray(out.ev_code)
-            target = np.asarray(out.ev_target)
-            arg = np.asarray(out.ev_arg)
-            for g, i in zip(*np.nonzero(ev_valid)):
-                g = int(g)
-                s = int(seq[g, i])
-                if s <= self._ev_seen.get(g, -1):
+            # one pass: the round's events as plain lists (row-major, so a
+            # group's ascend by seq), then an append each
+            gi, ii = np.nonzero(ev_valid)
+            seen, events = self._ev_seen, self.events
+            keep = self.MAX_EVENTS_PER_GROUP
+            fresh = 0
+            for g, event in zip(gi.tolist(), zip(
+                    np.asarray(out.ev_seq)[gi, ii].tolist(),
+                    np.asarray(out.ev_code)[gi, ii].tolist(),
+                    np.asarray(out.ev_target)[gi, ii].tolist(),
+                    np.asarray(out.ev_arg)[gi, ii].tolist())):
+                if event[0] <= seen.get(g, -1):
                     continue  # re-delivered after a leader change
-                self._ev_seen[g] = s
-                evs = self.events.setdefault(g, [])
-                evs.append(
-                    (s, int(code[g, i]), int(target[g, i]), int(arg[g, i])))
+                seen[g] = event[0]
+                evs = events.get(g)
+                if evs is None:
+                    evs = events[g] = []
+                evs.append(event)
+                fresh += 1
                 # bounded buffer: facades track absolute seqs, so trimming
                 # old events never invalidates a consumer cursor
-                if len(evs) > self.MAX_EVENTS_PER_GROUP:
-                    del evs[: len(evs) - self.MAX_EVENTS_PER_GROUP]
+                if len(evs) > keep:
+                    del evs[: len(evs) - keep]
+            self._m_events_ingested.inc(fresh)
 
     def run(self, rounds: int) -> None:
         for _ in range(rounds):

@@ -268,11 +268,18 @@ class PublishRequest(Message):
     push carries ``event_index=None`` and the client acks its current
     position untouched. ``state=None`` retires the replica entry (the
     resource was deleted or stopped being edge-servable).
+
+    ``more`` (optional trailing, omitted when None): the session's
+    further sealed batches, ``[(event_index, prev_event_index, events),
+    ...]`` in order, each sealed by one entry as the first was. The client
+    takes them one after another under the same gap rule and answers with
+    the position it reached, so one request and one response carry what a
+    request a batch did.
     """
 
     _fields = ("session_id", "event_index", "prev_event_index", "events",
-               "group", "trace", "deltas")
-    _optional = 2
+               "group", "trace", "deltas", "more")
+    _optional = 3
 
 
 @serialize_with(211)

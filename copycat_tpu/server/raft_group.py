@@ -141,6 +141,17 @@ def dispatch_vector_rows(engine: Any, window: Any, rows: list,
         return [], str(e)
 
 
+class _EventGate:
+    """Responses held until the session events their commands caused are
+    acknowledged: ``left`` (session, event_index) pairs outstanding."""
+
+    __slots__ = ("left", "fut")
+
+    def __init__(self, fut: asyncio.Future) -> None:
+        self.left = 0
+        self.fut = fut
+
+
 class _EntryCtx:
     """Per-entry execution context for windowed applies.
 
@@ -301,7 +312,7 @@ class RaftGroup:
 
         # apply-side bookkeeping
         self._commit_futures: dict[int, asyncio.Future] = {}
-        self._event_pushes: set[asyncio.Task] = set()
+        self._pushing: set[ServerSession] = set()   # a push loop is running
         self._touched_sessions: set[ServerSession] = set()
         self._applied_event = asyncio.Event()
         self._publish_buffer: list | None = None
@@ -361,6 +372,11 @@ class RaftGroup:
         self._m_general_lane = m.counter("commands_general_lane")
         self._m_keepalive_ms = m.histogram("keepalive_latency_ms")
         self._m_append_block = m.histogram("append_block_entries")
+        # the event plane: batches sealed (one an entry and session),
+        # events in them, and the PublishRequests that carried them
+        self._m_events_sealed = m.counter("events.sealed")
+        self._m_events_published = m.counter("events.published")
+        self._m_publish_requests = m.counter("events.publish_requests")
         self._m_vector_runs = m.counter("vector_runs")
         self._m_vector_ops = m.counter("vector_ops")
         self._m_run_length = m.histogram("apply_run_length")
@@ -2207,26 +2223,23 @@ class RaftGroup:
         if trace is not None:
             t2 = time.perf_counter()
             self._trace_apply(trace, t1, t2, index)
-        if self._event_pushes:
+        if self._pushing:
             # Events-before-response (reference Consistency.java:157-176):
             # the general path gates each LINEARIZABLE response on its
             # apply's event-push acks inside _complete_command; this lane
             # has no per-seq futures, so gate the block response on the
-            # pushes outstanding at commit — a superset of the ones this
-            # block's applies spawned — under the same 1 s cap. Empty in
-            # the listener-free steady state, so the fast path pays one
-            # set check.
-            t_push = time.perf_counter() if trace is not None else 0.0
-            try:
-                await asyncio.wait_for(
-                    asyncio.gather(*list(self._event_pushes),
-                                   return_exceptions=True), 1.0)
-            except asyncio.TimeoutError:
-                pass
-            if trace is not None:
-                self._trace_span(trace, "event.push", t_push,
-                                 time.perf_counter(),
-                                 self._m_lat_event_push)
+            # events sealed and unacknowledged at commit, of every session
+            # with a push running — a superset of what this block's
+            # applies sealed — under the same 1 s cap. Empty in the
+            # listener-free steady state, so the fast path pays one set
+            # check.
+            gate = self._event_gate([(s, s.event_index)
+                                     for s in self._pushing])
+            if gate is not None:
+                try:
+                    await asyncio.wait_for(gate, 1.0)
+                except asyncio.TimeoutError:
+                    pass
         responses = session.responses
         out = []
         for seq, _ in entries:
@@ -3030,11 +3043,13 @@ class RaftGroup:
         if seq and (seq <= session.command_high
                     or (entry.session_id, seq) in self._window_pending_seqs):
             return None
-        rec = route(entry.operation)
-        if rec is None:
-            return None
         if deadline is not None \
                 and deadline <= max(self.context.clock, entry.timestamp):
+            return None
+        # last: a machine may note the row it classifies (a lock looks
+        # ahead over its staged rows), so a row routed is a row staged
+        rec = route(entry.operation, entry.index)
+        if rec is None:
             return None
         return (entry, session, *rec)
 
@@ -3098,6 +3113,11 @@ class RaftGroup:
         log = self.log
         futures = self._commit_futures
         marks = self._trace_entry_marks
+        # what a row's finalize publishes (a lock's grant) is sealed and
+        # pushed inside that row's entry, as the per-entry walk seals it:
+        # event_index may not depend on how a member cut its batches
+        touched = self._touched_sessions = set()
+        seal_s = 0.0
         for k, (clock, entry, session, machine, instance, inner, spec) in \
                 enumerate(run):
             trace = marks.pop(entry.index, None) if marks else None
@@ -3130,6 +3150,12 @@ class RaftGroup:
                 except Exception as e:  # noqa: BLE001 — app errors cross
                     result, error = None, str(e)
                     log.clean(entry.index)
+            waits: Any = ()
+            if touched:
+                t0 = time.perf_counter()
+                waits = self._seal_and_push(touched, trace)
+                touched.clear()
+                seal_s += time.perf_counter() - t0
             seq = entry.seq
             if seq:
                 session.last_keepalive_time = clock
@@ -3138,7 +3164,11 @@ class RaftGroup:
             if fut is not None and not fut.done():
                 fut.set_result((entry.index, result, error))
             if seq and session.command_futures:
-                self._complete_command(entry, result, error, [])
+                self._complete_command(entry, result, error, waits)
+        if seal_s and TRACER.enabled:
+            # the run's seals as one span, ending where its finalize does
+            TRACER.open_span("event.seal",
+                             start=time.perf_counter() - seal_s).close(rows=n)
         # dependency bookkeeping: this run's rows are no longer staged.
         # The collector drains whole (never partially), so a zero count
         # retires the key/session sets.
@@ -3204,13 +3234,19 @@ class RaftGroup:
                 self._edge_note_apply(entry, trace)
             self._complete_command(entry, result, error, pushes)
 
-    def _seal_and_push(self, touched,
-                       trace: int | None = None) -> list[asyncio.Task]:
-        pushes: list[asyncio.Task] = []
+    def _seal_and_push(self, touched, trace: int | None = None
+                       ) -> list[tuple[ServerSession, int]]:
+        """Seal what the current entry published, a batch a session, and
+        see that each session's push is running; returns the ``(session,
+        event_index)`` pairs a LINEARIZABLE response has to wait for
+        (:meth:`_event_gate`)."""
+        pushes: list[tuple[ServerSession, int]] = []
         for session in touched:
             batch = session.commit_events()
             if batch is None:
                 continue
+            self._m_events_sealed.inc()
+            self._m_events_published.inc(len(batch.events))
             # Single-group: only the leader pushes (it owns the client
             # connection). Multi-group: the member HOLDING the session's
             # connection pushes — that is the ingress, which may be a
@@ -3218,12 +3254,9 @@ class RaftGroup:
             # group's leader has no connection and skips (docs/SHARDING.md
             # "event channels").
             if (self.role == LEADER if self.server.single
-                    else session.connection is not None):
-                task = self._push_events(session, trace)
-                if task is not None:
-                    pushes.append(task)
-                    self._event_pushes.add(task)
-                    task.add_done_callback(self._event_pushes.discard)
+                    else session.connection is not None) \
+                    and self._push_events(session, trace):
+                pushes.append((session, batch.event_index))
         return pushes
 
     # -- windowed apply (device executor) ------------------------------
@@ -3426,7 +3459,7 @@ class RaftGroup:
 
     def _complete_command(self, entry: CommandEntry, result: Any,
                           error: str | None,
-                          pushes: list[asyncio.Task]) -> None:
+                          pushes: list[tuple[ServerSession, int]]) -> None:
         session = self.sessions.get(entry.session_id)
         if session is None:
             return
@@ -3438,72 +3471,123 @@ class RaftGroup:
                        if isinstance(operation, Command)
                        else CommandConsistency.LINEARIZABLE)
         payload = (entry.index, result, error)
-        if pushes and consistency is CommandConsistency.LINEARIZABLE:
+        gate = self._event_gate(pushes) if pushes \
+            and consistency is CommandConsistency.LINEARIZABLE else None
+        if gate is None:
+            fut.set_result(payload)
+        else:
             # Events-before-response: the response releases only after event
             # pushes are acknowledged (reference Consistency.java:157-176).
-            async def complete_after_events() -> None:
-                try:
-                    await asyncio.wait_for(
-                        asyncio.gather(*pushes, return_exceptions=True), 1.0)
-                except asyncio.TimeoutError:
-                    pass
-                if not fut.done():
-                    fut.set_result(payload)
-
-            spawn(complete_after_events(), name="events-before-response")
-        else:
-            fut.set_result(payload)
+            gate.add_done_callback(
+                lambda _gate: fut.done() or fut.set_result(payload))
 
     # ------------------------------------------------------------------
     # event push (connection-holder only; leader == holder when single)
     # ------------------------------------------------------------------
 
+    #: sealed batches one PublishRequest carries at most
+    PUSH_BATCHES = 4096
+
     def _push_events(self, session: ServerSession,
-                     trace: int | None = None) -> asyncio.Task | None:
-        if session.connection is None or session.connection.closed:
-            return None
-        return spawn(self._flush_events_async(session, trace),
-                     name="event-push")
+                     trace: int | None = None) -> bool:
+        """See that ``session``'s push loop is running (one task a
+        session: it sends whatever is sealed and unacknowledged, so a
+        seal that finds it running has nothing to start). False where
+        the session has no live connection here."""
+        conn = session.connection
+        if conn is None or conn.closed:
+            return False
+        if trace is not None and session.push_trace is None:
+            session.push_trace = trace
+        if session.push_task is None:
+            session.push_task = spawn(self._push_loop(session),
+                                      name="event-push")
+            self._pushing.add(session)
+        return True
 
     def _flush_events(self, session: ServerSession) -> None:
         self._push_events(session)
 
-    async def _flush_events_async(self, session: ServerSession,
-                                  trace: int | None = None) -> None:
-        conn = session.connection
-        if conn is None or conn.closed:
-            return
-        t0 = time.perf_counter() if trace is not None else 0.0
-        pushed = False
+    async def _push_loop(self, session: ServerSession) -> None:
+        """Send ``session``'s sealed, unacknowledged batches until none is
+        left: every one of them in ONE PublishRequest a pass (the first in
+        the request's own fields, the rest in ``more``, each still the
+        batch its entry sealed), the next pass when the response is back.
+        A batch sealed meanwhile rides the next pass. Ends where a send
+        fails or times out, or the client reports a position short of
+        what was sent (caught up by the next seal or keep-alive, as
+        ever); the responses held for this session's events are released
+        as their events are acknowledged, and all of them when it ends."""
         try:
-            for batch in list(session.event_queue):
-                if batch.event_index <= session.event_ack_index:
-                    continue
+            while True:
+                conn = session.connection
+                queue = session.event_queue
+                if conn is None or conn.closed or not queue:
+                    return
+                batches = queue[:self.PUSH_BATCHES]
+                first, last = batches[0], batches[-1].event_index
+                trace, session.push_trace = session.push_trace, None
+                t0 = time.perf_counter() if trace is not None else 0.0
+                self._m_publish_requests.inc()
                 try:
                     response = await asyncio.wait_for(
                         conn.send(msg.PublishRequest(
                             session_id=session.id,
-                            event_index=batch.event_index,
-                            prev_event_index=batch.prev_event_index,
-                            events=batch.events,
-                            group=self.wire_group, trace=trace)),
+                            event_index=first.event_index,
+                            prev_event_index=first.prev_event_index,
+                            events=first.events,
+                            group=self.wire_group, trace=trace,
+                            more=[(b.event_index, b.prev_event_index,
+                                   b.events) for b in batches[1:]] or None)),
                         1.0)
                 except (TransportError, OSError, asyncio.TimeoutError):
-                    return
-                pushed = True
-                if response.event_index is not None:
-                    session.ack_events(response.event_index)
-                    if response.event_index < batch.event_index:
-                        # client is behind; caught up on the next pass
+                    if session.connection is conn:
                         return
+                    continue            # re-attached meanwhile: go on there
+                if trace is not None:
+                    # any completed send is timeline-worthy — a trace with
+                    # a client.event but no event.push reads as a hole
+                    self._trace_span(trace, "event.push", t0,
+                                     time.perf_counter(),
+                                     self._m_lat_event_push,
+                                     batches=len(batches))
+                acked = response.event_index
+                if acked is not None:
+                    session.ack_events(acked)
+                    self._release_gates(session, session.event_ack_index)
+                if acked is None or acked < last:
+                    return              # client is behind
         finally:
-            # any completed push (including one before a catching-up
-            # early return) is timeline-worthy — an asymmetric trace
-            # with a client.event but no event.push reads as a hole
-            if trace is not None and pushed:
-                self._trace_span(trace, "event.push", t0,
-                                 time.perf_counter(),
-                                 self._m_lat_event_push)
+            session.push_task = None
+            self._pushing.discard(session)
+            self._release_gates(session, None)
+
+    def _event_gate(self, waits: list[tuple[ServerSession, int]]
+                    ) -> asyncio.Future | None:
+        """A future that resolves once every ``(session, event_index)`` is
+        acknowledged or its session's push has ended; ``None`` where
+        nothing is left to wait for. The responses that wait for one push
+        share its loop: no task a response."""
+        gate = None
+        for session, index in waits:
+            if index <= session.event_ack_index or session.push_task is None:
+                continue
+            if gate is None:
+                gate = _EventGate(asyncio.get_running_loop().create_future())
+            gate.left += 1
+            session.push_gates.append((index, gate))
+        return None if gate is None else gate.fut
+
+    @staticmethod
+    def _release_gates(session: ServerSession, upto: int | None) -> None:
+        """Release the responses held for ``session``'s events up to
+        ``upto`` (all of them with ``None``)."""
+        gates = session.push_gates
+        while gates and (upto is None or gates[0][0] <= upto):
+            gate = gates.popleft()[1]
+            gate.left -= 1
+            if not gate.left and not gate.fut.done():
+                gate.fut.set_result(None)
 
     # ------------------------------------------------------------------
     # edge read tier: subscriber registry + delta publication

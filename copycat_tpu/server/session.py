@@ -14,6 +14,7 @@ fans out to state machines (``ResourceManager.java:238-266``).
 from __future__ import annotations
 
 import enum
+from collections import deque
 from typing import Any, Callable
 
 
@@ -65,6 +66,13 @@ class ServerSession:
         # group, so a resent sub-block racing its first attempt can ride
         # the pending commit instead of mis-reading "pruned".
         self.last_block_future: Any = None
+        # Event push (RaftGroup._push_loop): the one task that sends this
+        # session's sealed batches, the responses held until their events
+        # are acknowledged, ``(event_index, gate)`` in sealing order, and
+        # the trace id the next send carries.
+        self.push_task: Any = None
+        self.push_gates: deque = deque()
+        self.push_trace: int | None = None
 
         # --- apply-time scratch ---
         self._current_events: list[tuple[str, Any]] = []
@@ -91,7 +99,15 @@ class ServerSession:
     def ack_events(self, event_index: int) -> None:
         if event_index > self.event_ack_index:
             self.event_ack_index = event_index
-            self.event_queue = [b for b in self.event_queue if b.event_index > event_index]
+            # the queue ascends: what is acknowledged is a prefix
+            queue = self.event_queue
+            acked = 0
+            for batch in queue:
+                if batch.event_index > event_index:
+                    break
+                acked += 1
+            if acked:
+                del queue[:acked]
 
     # -- exactly-once bookkeeping -----------------------------------------
 

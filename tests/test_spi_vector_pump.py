@@ -278,3 +278,378 @@ def test_events_delivered_until_close(deep_rg):
     assert [e.arg for e in got] == [41], (
         "closing session missed events committed by its own flush")
     assert watcher.id not in client._sessions, "closed session must leave"
+
+
+# ---------------------------------------------------------------------------
+# DistributedLock on the vector lane: Lock(-1), Lock(0) and Unlock as one
+# device op each, the grant a release causes published inside its entry
+
+
+async def _lock_history(executor: str):
+    """Two sessions over three locks, contended and not, a try-lock that
+    is refused and one that is taken, and a bounded wait that arms a timer
+    (the lane falls back to the chain while it is armed). Every wave is
+    one session's, so the log's order is the script's on both executors.
+    Returns what the clients saw, with every log index (waiter ids,
+    instance ids) as its rank among them, and what the server kept."""
+    from copycat_tpu.coordination import DistributedLock
+    from copycat_tpu.server.log import CommandEntry
+
+    registry = LocalServerRegistry()
+    server, a = await _spi_cluster(registry, executor)
+    b = AtomixClient(a.client.members, LocalTransport(registry),
+                     session_timeout=20.0)
+    await b.open()
+    try:
+        la = [await a.get(f"l{i}", DistributedLock) for i in range(3)]
+        lb = [await b.get(f"l{i}", DistributedLock) for i in range(3)]
+        seen: dict[str, list] = {"a": [], "b": []}
+        for name, client in (("a", a), ("b", b)):
+            client.client.session().on_event(
+                "lock", lambda m, _n=name: seen[_n].append(
+                    (m.resource, m.message["id"], m.message["acquired"])))
+        replies: list = []
+
+        async def call(lock, what, *args):
+            got = await getattr(lock, what)(*args)
+            replies.append((what, got))
+            return got
+
+        wave = asyncio.gather
+        await wave(*(call(l, "lock") for l in la))           # free: granted
+        waits = [asyncio.ensure_future(call(l, "lock")) for l in lb[:2]]
+        assert await call(lb[2], "try_lock") is False         # held: refused
+        await asyncio.sleep(0.05)
+        await wave(*(call(l, "unlock") for l in la[:2]))      # grants cross
+        await wave(*waits)
+        assert await call(la[0], "try_lock") is False
+        # a holder's unlock and the next contender's lock in ONE batch
+        again = [asyncio.ensure_future(call(l, "lock")) for l in la[:2]]
+        await asyncio.sleep(0.05)
+        await wave(*(call(l, "unlock") for l in lb[:2]))
+        await wave(*again)
+        # a bounded wait arms a timer: this lock is on the chain until the
+        # waiter is granted
+        timed = asyncio.ensure_future(call(lb[2], "try_lock", 30.0))
+        await asyncio.sleep(0.05)
+        await call(la[2], "unlock")
+        assert await timed is True
+        await call(lb[2], "unlock")
+        assert await call(lb[2], "try_lock") is True          # free: taken
+        await call(lb[2], "unlock")
+        await wave(*(call(l, "unlock") for l in la[:2]))
+        await asyncio.sleep(0.05)  # drain in-flight publishes
+
+        raft = server.server
+        log = raft.log
+        commands = [e for e in (log.get(i) for i in range(
+            1, log.last_index + 1)) if type(e) is CommandEntry]
+        ids = {x for ev in seen.values() for r, i, _ in ev for x in (r, i)}
+        ordinal = {x: k for k, x in enumerate(sorted(ids))}
+        sessions = sorted(raft.sessions.values(), key=lambda s: s.id)
+        manager = raft.state_machine
+        engine = getattr(manager, "device_engine", None)
+        counters = (0, 0)
+        if executor == "tpu":
+            c = engine._groups.metrics.counter
+            counters = (c("lock_vector_ops").value, c("lock_chain_ops").value)
+        return {
+            "replies": replies,
+            "events": {n: [(ordinal[r], ordinal[i], ok) for r, i, ok in ev]
+                       for n, ev in seen.items()},
+            "event_index": [(s.event_index, s.event_ack_index)
+                            for s in sessions],
+            "client_index": [c.client.session().event_index for c in (a, b)],
+            # the commands still retained (a cleaned entry may be
+            # compacted away already): the six that opened the locks
+            "retained": [type(e.operation).__name__ for e in commands
+                         if not log.is_cleaned(e.index)],
+        }, counters
+    finally:
+        for node in (a, b, server):
+            await asyncio.wait_for(node.close(), 5)
+
+
+@async_test(timeout=300)
+async def test_lock_on_the_vector_lane_is_the_cpu_lock_state():
+    """The same script on the device executor and on the CPU ``LockState``:
+    every reply, each session's events in order and content, the sessions'
+    ``event_index`` (a batch an entry) and which retained commits were
+    cleaned are equal; the device run took the vector lane for all but
+    the lock with the armed timer."""
+    dev, (vector, chain) = await _lock_history("tpu")
+    cpu, _ = await _lock_history("cpu")
+    assert dev == cpu
+    assert len(dev["events"]["a"]) >= 5 and len(dev["events"]["b"]) >= 5
+    assert vector >= 18 and 1 <= chain <= 4
+
+
+@async_test(timeout=300)
+async def test_lock_vector_lane_resumes_from_a_snapshot_mid_queue():
+    """A holder and two waiters, the machine's host record taken as a
+    snapshot and restored over it: the look-ahead reads the stand-in
+    commits, and the queue moves on down the vector lane in order."""
+    from copycat_tpu.coordination import DistributedLock
+    from copycat_tpu.manager.device_executor import DeviceLockState
+
+    registry = LocalServerRegistry()
+    server, a = await _spi_cluster(registry)
+    others = []
+    try:
+        for _ in range(2):
+            c = AtomixClient(a.client.members, LocalTransport(registry),
+                             session_timeout=20.0)
+            await c.open()
+            others.append(c)
+        locks = [await c.get("lock", DistributedLock)
+                 for c in (a, *others)]
+        await locks[0].lock()
+        waits = [asyncio.ensure_future(l.lock()) for l in locks[1:]]
+        await asyncio.sleep(0.1)
+        manager = server.server.state_machine
+        (machine,) = [h.state_machine for h in manager.resources.values()
+                      if isinstance(h.state_machine, DeviceLockState)]
+        image = machine.snapshot_state()
+        assert image["holder"] is not None and len(image["waiters"]) == 3
+        sessions = {c.session.id: c.session
+                    for c in machine._waiters.values()}
+        machine._waiters.clear()
+        machine._holder_id = None
+        machine.restore_state(image, {})
+        for session in sessions.values():
+            machine.register(session)
+        counter = manager.device_engine._groups.metrics.counter
+        chain0 = counter("lock_chain_ops").value
+        order = []
+        for k, lock in enumerate(locks):
+            if k:
+                await asyncio.wait_for(waits[k - 1], 10)
+            order.append(k)
+            await lock.unlock()
+        assert order == [0, 1, 2]
+        assert counter("lock_chain_ops").value == chain0
+        assert machine._holder_id is None and not machine._waiters
+        assert await locks[0].try_lock()
+    finally:
+        for node in (a, *others, server):
+            await asyncio.wait_for(node.close(), 5)
+
+
+def test_plain_locks_and_the_cpu_lock_state_agree():
+    """``benchmarks/reference_lock.PlainLocks`` (the benchmark's plain
+    reference) against the CPU ``LockState`` on a seeded sequence of
+    10,000 acquires, try-locks and releases over 16 locks: who holds each
+    lock and who waits, after every step."""
+    import importlib.util
+    import os
+
+    from copycat_tpu.coordination import commands as oc
+    from copycat_tpu.coordination.state import LockState
+    from copycat_tpu.server.state_machine import Commit
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "reference_lock.py")
+    spec = importlib.util.spec_from_file_location("reference_lock", path)
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+
+    class Session:
+        is_open = True
+
+        def __init__(self, sid):
+            self.id, self.events = sid, []
+
+        def publish(self, event, message):
+            self.events.append((message["id"], message["acquired"]))
+
+    class Log:
+        def clean(self, index):
+            pass
+
+    n_locks, rng = 16, random.Random(40)
+    plain = ref.PlainLocks(n_locks)
+    machines = [LockState() for _ in range(n_locks)]
+    sessions = [Session(s) for s in range(6)]
+    owner: dict[int, int] = {}            # waiter id -> session
+    granted: list[tuple[int, int]] = []   # (lock, id) by the plain locks
+    for index in range(1, 10_001):
+        i = rng.randrange(n_locks)
+        holder = plain.holder(i)
+        if holder is not None and rng.random() < 0.45:
+            passed = plain.release(i, holder)
+            if passed is not None:
+                granted.append((i, passed))
+            machines[i].unlock(Commit(index, sessions[owner[holder]], 0.0,
+                                      oc.Unlock(), Log()))
+        else:
+            s = rng.randrange(len(sessions))
+            wait = rng.random() < 0.8
+            owner[index] = s
+            if plain.acquire(i, index, wait=wait):
+                granted.append((i, index))
+            machines[i].lock(Commit(index, sessions[s], 0.0,
+                                    oc.Lock(timeout=-1 if wait else 0),
+                                    Log()))
+        state = machines[i]
+        assert (state._holder.index if state._holder else None) \
+            == plain.holder(i)
+        assert [c.index for c in state._queue] == plain.waiting(i)
+    told = sorted((wid, ok) for s in sessions for wid, ok in s.events)
+    assert [wid for wid, ok in told if ok] == sorted(w for _, w in granted)
+    assert len(granted) > 3000
+
+
+# ---------------------------------------------------------------------------
+# The event plane: one push loop a session, coalesced sends, batches sealed
+# an entry
+
+
+@async_test(timeout=300)
+async def test_events_once_and_in_order_when_a_publish_response_is_lost():
+    """Grants cross from one session's unlocks to another's event stream
+    in coalesced PublishRequests. The response to one of them is lost
+    after the client has taken its events: the server sends them again
+    with what was sealed meanwhile, the client answers with its position,
+    and the listener still sees every grant exactly once and in order."""
+    from copycat_tpu.coordination import DistributedLock
+    from copycat_tpu.io.transport import TransportError
+    from copycat_tpu.protocol import messages as msg
+
+    registry = LocalServerRegistry()
+    server, a = await _spi_cluster(registry)
+    b = AtomixClient(a.client.members, LocalTransport(registry),
+                     session_timeout=20.0)
+    await b.open()
+    try:
+        n = 6
+        la = [await a.get(f"l{i}", DistributedLock) for i in range(n)]
+        lb = [await b.get(f"l{i}", DistributedLock) for i in range(n)]
+        seen: list = []
+        b.client.session().on_event(
+            "lock", lambda m: seen.append(m.message["id"]))
+        raft = server.server
+        session_b = raft.sessions[b.client.session().id]
+        conn = session_b.connection
+        real_send, sent, dropped = conn.send, [], []
+
+        async def lossy(message):
+            if type(message) is not msg.PublishRequest:
+                return await real_send(message)
+            batches = 1 + len(message.more or ())
+            sent.append(batches)
+            response = await real_send(message)
+            if batches > 1 and not dropped:
+                dropped.append(batches)
+                raise TransportError("PublishResponse lost")
+            return response
+
+        conn.send = lossy
+        for rounds in range(3):
+            await asyncio.gather(*(l.lock() for l in la))
+            waits = [asyncio.ensure_future(l.lock()) for l in lb]
+            await asyncio.sleep(0.05)
+            # one batch of unlocks: n grants to b, sealed an entry each,
+            # leave in coalesced sends
+            await asyncio.gather(*(l.unlock() for l in la))
+            await asyncio.wait_for(asyncio.gather(*waits), 30)
+            await asyncio.gather(*(l.unlock() for l in lb))
+        assert dropped, f"no coalesced send to drop: {sent}"
+        for _ in range(100):
+            if session_b.event_ack_index == session_b.event_index:
+                break
+            await asyncio.sleep(0.05)
+        assert len(seen) == 3 * n and seen == sorted(set(seen))
+        assert session_b.event_index == 3 * n       # a batch an entry
+        assert session_b.event_ack_index == 3 * n and \
+            not session_b.event_queue
+        assert b.client.session().event_index == 3 * n
+        assert sum(sent) > 3 * n > len(sent)        # resent, and coalesced
+    finally:
+        for node in (a, b, server):
+            await asyncio.wait_for(node.close(), 5)
+
+
+@async_test(timeout=300)
+async def test_members_that_cut_batches_differently_seal_equal_event_indexes(
+        tmp_path, monkeypatch):
+    """The live member applies the log a few entries a batch as the
+    clients call; a member reborn over the same log replays it in one
+    batch, many rows of a lock to a vector run. Each session's
+    ``event_index`` (a batch an entry that published, whatever the cut)
+    and every lock's record are equal."""
+    from copycat_tpu.coordination import DistributedLock
+    from copycat_tpu.server.log import Storage, StorageLevel
+    from copycat_tpu.manager.device_executor import DeviceLockState
+    from copycat_tpu.testing.nemesis import crash_server
+
+    monkeypatch.setenv("COPYCAT_SNAPSHOTS", "0")
+    registry = LocalServerRegistry()
+    (addr,) = next_ports(1)
+
+    def build():
+        return AtomixServer(
+            addr, [addr], LocalTransport(registry, local_address=addr),
+            storage=Storage(StorageLevel.DISK, str(tmp_path / "m0")),
+            election_timeout=0.2, heartbeat_interval=0.04,
+            session_timeout=60.0, executor="tpu", engine_config=SERVED)
+
+    def image(server):
+        raft = server.server
+        manager = raft.state_machine
+        locks = {h.key: (h.state_machine._holder_id,
+                         list(h.state_machine._waiters))
+                 for h in manager.resources.values()
+                 if isinstance(h.state_machine, DeviceLockState)}
+        return ({sid: s.event_index for sid, s in raft.sessions.items()},
+                locks)
+
+    server = build()
+    await server.open()
+    clients = []
+    reborn = None
+    try:
+        for _ in range(3):
+            c = AtomixClient([addr], LocalTransport(registry),
+                             session_timeout=60.0)
+            await c.open()
+            clients.append(c)
+        locks = [[await c.get(f"l{i}", DistributedLock) for i in range(4)]
+                 for c in clients]
+
+        async def contend(mine):
+            for _ in range(5):
+                for lock in mine:
+                    await lock.lock()
+                    await lock.unlock()
+
+        await asyncio.wait_for(asyncio.gather(
+            *(contend(mine) for mine in locks)), 120)
+        # leave a queue standing: a holder and two waiters on every lock
+        await asyncio.gather(*(lock.lock() for lock in locks[0]))
+        waits = [asyncio.ensure_future(lock.lock())
+                 for mine in locks[1:] for lock in mine]
+        await asyncio.sleep(0.2)
+        live = image(server)
+        assert all(h is not None and len(w) == 3
+                   for h, w in live[1].values())
+        assert sum(live[0].values()) >= 60
+        for w in waits:
+            w.cancel()
+        await crash_server(server.server)
+
+        reborn = build()
+        await reborn.open()
+        for _ in range(100):
+            if reborn.server.last_applied >= server.server.last_applied:
+                break
+            await asyncio.sleep(0.05)
+        counter = reborn.server.state_machine.device_engine._groups \
+            .metrics.counter
+        assert counter("lock_vector_ops").value >= 100
+        assert image(reborn) == live
+    finally:
+        for node in (*clients, reborn or server):
+            try:
+                await asyncio.wait_for(node.close(), 10)
+            except (Exception, asyncio.TimeoutError):  # noqa: BLE001
+                pass
