@@ -3,10 +3,13 @@ one ``AtomixClient`` session over ``LocalTransport``, its engine holding one map
 table a replica, driven by a closed loop of clients through
 ``DistributedMap.put`` and ``get``.
 
-The deployment, the warm-up, the window, the ``gc_tune`` pause and the result
-keys are ``planes/served.py``'s. Set-up loads every key of every map through
-the public API. The reference is ``reference_map.PlainMaps``: one call
-outstanding a map makes every reply exact.
+The deployment, the window, the ``gc_tune`` pause and the result keys are
+``planes/served.py``'s. Set-up loads every key of every map through the public
+API. The warm-up ends on work done as well as on quiet (``_warm_up``): a read
+window is evaluated by one of two programs, and a loop that the collector
+holds over the load's heap reads as seconds of quiet. The reference is
+``reference_map.PlainMaps``: one call outstanding a map makes every reply
+exact.
 """
 
 from __future__ import annotations
@@ -24,6 +27,11 @@ import numpy as np
 GRACE_S = 5.0
 #: warm-up ends when JAX's compile events have been quiet this long
 QUIET_S = 2.0
+#: ... and for this many acknowledged calls a client, where the traffic file
+#: has no ``warmup_quiet_calls_per_client``
+QUIET_CALLS_PER_CLIENT = 20
+#: a warm-up that has not gone quiet after this long is an error
+DEADLINE_S = 300.0
 #: between the collection that ends warm-up and the window's first instant
 SETTLE_S = 0.5
 #: seconds of the window the profiler covers in a traced run
@@ -45,6 +53,41 @@ def _reference():
 def _resident_mb() -> float:
     with open("/proc/self/statm") as f:
         return int(f.read().split()[1]) * os.sysconf("SC_PAGE_SIZE") / 1e6
+
+
+def quiet_calls(mix: dict) -> int:
+    """Calls to be acknowledged after the last program was compiled or loaded
+    before the warm-up may end."""
+    return mix["clients"] * mix.get("warmup_quiet_calls_per_client",
+                                    QUIET_CALLS_PER_CLIENT)
+
+
+async def _warm_up(ctx, acks: list) -> int:
+    """The cell's own traffic until nothing has compiled or loaded for
+    ``warmup_quiet_s`` AND for ``quiet_calls`` acknowledged calls; returns the
+    calls acknowledged since the last program.
+
+    Seconds alone do not say that the traffic ran: the collector's first
+    passes over what the load left hold the loop for longer than the quiet,
+    and a read window's second program (``jit_query`` alone, or
+    ``jit_round_query`` on a parked round, whichever a window finds) then
+    loads inside the measured window. A held loop acknowledges no call."""
+    perf, compiles, mix = time.perf_counter, ctx.compiles, ctx.traffic
+    quiet, floor = mix.get("warmup_quiet_s", QUIET_S), quiet_calls(mix)
+    t_warm, programs, mark = perf(), compiles.count, len(acks)
+    while True:
+        await asyncio.sleep(0.25)
+        if compiles.count != programs:
+            programs, mark = compiles.count, len(acks)
+        since = len(acks) - mark
+        if since >= floor and compiles.quiet_for() >= quiet \
+                and perf() - t_warm >= quiet:
+            return since
+        if perf() - t_warm > DEADLINE_S:
+            raise RuntimeError(
+                f"map plane: warm-up not over after {DEADLINE_S:.0f} s: "
+                f"{len(acks):,} calls, {since:,} of the {floor:,} it takes "
+                f"since the last program; {compiles.note()}")
 
 
 async def _drive(ctx) -> dict:
@@ -216,24 +259,31 @@ async def _drive(ctx) -> dict:
                     wrong(f"map {i} key {key}: {'get' if read else 'put'} "
                           f"answered {got}, the plain map {want}")
 
+        # everything the load left is young to a collector switched back on:
+        # its first passes would hold the loop through the warm-up's traffic.
+        # One pass here, and the load's heap is out of every later one
+        t_gc = perf()
+        gc.collect()
+        gc.freeze()
+        say(f"map plane: the load's heap collected and frozen in "
+            f"{perf() - t_gc:.1f}s")
+
         tasks = [asyncio.ensure_future(one(i)) for i in range(clients)]
 
-        # warm-up: the cell's own traffic until nothing has compiled for
-        # QUIET_S (the fused-rounds programs compile on demand)
-        t_warm, quiet = perf(), mix.get("warmup_quiet_s", QUIET_S)
-        while True:
-            await asyncio.sleep(0.25)
-            if ctx.compiles.quiet_for() >= quiet and perf() - t_warm >= quiet:
-                break
-            if perf() - t_warm > 300:
-                raise RuntimeError("map plane: still compiling after "
-                                   "300 s of warm-up")
+        t_warm = perf()
+        try:
+            since = await _warm_up(ctx, acks)
+        except RuntimeError:
+            # or a client whose session is closed under it spins on the error
+            state["stop"] = True
+            raise
         ctx.gc_tune()
         # the collection holds the loop: let the calls it delayed be answered
         # before the window opens, or they sit in its tail
         await asyncio.sleep(SETTLE_S)
-        say(f"map plane: warm-up {perf() - t_warm:.1f}s, "
-            f"{len(acks):,} calls; {ctx.compiles.note()}")
+        say(f"map plane: warm-up {perf() - t_warm:.1f}s, {len(acks):,} calls, "
+            f"{since:,} of them after the last program was loaded; "
+            f"{ctx.compiles.note()}")
 
         # -- the window ------------------------------------------------------
         counter = groups.metrics.counter
@@ -357,6 +407,13 @@ async def _drive(ctx) -> dict:
                 f"calls not in flight then; {traced_commands:,} puts and "
                 f"{traced_queries:,} gets acknowledged between its start "
                 "and its stop")
+        if compiled_inside:
+            print("map plane: compile events inside the window, seconds after "
+                  "its first instant: " + ", ".join(
+                      f"+{at - secs - t_start:.3f} {name} ({secs:.3f}s)"
+                      for at, secs, name in ctx.compiles.events[
+                          compiled_before:compiled_before + compiled_inside]),
+                  file=sys.stderr, flush=True)
         for what, value, limit in checks:
             print(f"map plane: check: {what}: {value} (limit {limit})",
                   file=sys.stderr, flush=True)

@@ -46,8 +46,8 @@ def say(msg: str) -> None:
 class Compiles:
     """XLA's compile activity, from JAX's own monitoring events
     (``chip_smoke.Compiles``): seconds compiling or loading from the
-    persistent cache, cache hits, new cache entries, and when the last one
-    ended."""
+    persistent cache, cache hits, new cache entries, when the last one ended,
+    and every one's end, seconds and function name in ``events``."""
 
     def __init__(self) -> None:
         import jax.monitoring
@@ -55,14 +55,16 @@ class Compiles:
         self.secs = 0.0
         self.count = self.hits = self.misses = 0
         self.last = time.perf_counter()
+        self.events: list[tuple[float, float, str]] = []
         jax.monitoring.register_event_duration_secs_listener(self._on_secs)
         jax.monitoring.register_event_listener(self._on_event)
 
-    def _on_secs(self, event: str, secs: float, **_: object) -> None:
+    def _on_secs(self, event: str, secs: float, **kw: object) -> None:
         if event == "/jax/core/compile/backend_compile_duration":
             self.secs += secs
             self.count += 1
             self.last = time.perf_counter()
+            self.events.append((self.last, secs, str(kw.get("fun_name", "?"))))
 
     def _on_event(self, event: str, **_: object) -> None:
         if event == "/jax/compilation_cache/cache_hits":

@@ -2,8 +2,10 @@
 engine of capacity 8 whose map table has two buckets): the contract's line with
 ``correct: true``, every per-layer metric a CPU run can read, each check (a) to
 (h) seen when what it guards is broken underneath, both faults ``correct:
-false``; the reference on a hand-written history; the roofline's reducer on a
-made-up trace; what the root ``BENCHMARK.json`` names for the plane resolves.
+false``; the warm-up that ends on calls as well as on quiet, with the rule it
+replaced as its control; the reference on a hand-written history; the
+roofline's reducer on a made-up trace; what the root ``BENCHMARK.json`` names
+for the plane resolves.
 Sizes come from ``tests/benchmark/data_map``, never from the cell's own files.
 No number from here is a device number.
 """
@@ -64,10 +66,18 @@ def bench():
     return json.load(open(os.path.join(REPO, "BENCHMARK.json")))
 
 
-def drive(harness, capsys, trace=False, fault=None, seed=2**31 + 35):
+def drive(harness, capsys, trace=False, fault=None, seed=2**31 + 35,
+          data_root=DATA):
+    line, checks, out, _ = drive_err(harness, capsys, trace, fault, seed,
+                                     data_root)
+    return line, checks, out
+
+
+def drive_err(harness, capsys, trace=False, fault=None, seed=2**31 + 35,
+              data_root=DATA):
     rc, line = harness.run_cell(
         TINY, seed, 1.0, trace, fault,
-        bench_file=os.path.join(DATA, "BENCHMARK.json"), data_root=DATA,
+        bench_file=os.path.join(DATA, "BENCHMARK.json"), data_root=data_root,
         require_tpu=False)
     assert rc == 0
     json.dumps(line)                       # the line is plain JSON
@@ -78,7 +88,7 @@ def drive(harness, capsys, trace=False, fault=None, seed=2**31 + 35):
               for text in err.splitlines() if "map plane: check:" in text}
     assert err.rstrip().splitlines()[-1].startswith("map plane: check: (h)")
     assert list(checks) == [f"({c})" for c in "abcdefgh"]
-    return line, checks, out
+    return line, checks, out, err
 
 
 def seen(checks):
@@ -254,6 +264,162 @@ def test_a_compilation_inside_the_window_is_seen(harness, capsys,
     monkeypatch.setattr(DistributedMap, "put", put)
     line, checks, _ = drive(harness, capsys)
     assert line["correct"] is False and seen(checks) == {"(h)"}
+
+
+# -- the warm-up: quiet in calls as well as in seconds ---------------------------
+
+def plane(harness):
+    return harness.load_module("planes", "map", DATA)
+
+
+def mix_with(tmp_path, **keys):
+    """A data root whose only file is the twin's traffic mix with ``keys``
+    set; the harness finds everything else where it did."""
+    mix = json.load(open(os.path.join(DATA, "traffic", "putget50-tiny.json")))
+    mix.update(keys)
+    os.makedirs(tmp_path / "traffic")
+    with open(tmp_path / "traffic" / "putget50-tiny.json", "w") as f:
+        json.dump(mix, f)
+    return str(tmp_path)
+
+
+#: the case's floor a client; with 0 the rule is the one it replaced, quiet in
+#: seconds alone, which is the control
+HELD = {"seconds-alone": 0, "calls-too": 600}
+
+
+@pytest.mark.parametrize("floor", HELD.values(), ids=HELD.keys())
+def test_a_held_loop_does_not_pass_for_a_quiet_one(harness, capsys, tmp_path,
+                                                   monkeypatch, floor):
+    """The cell's fault at the twin's size. A tenth of a second after the
+    first client task started, one call holds the event loop for longer than
+    ``warmup_quiet_s`` (on the chip: the collector over the load's heap); the
+    first call that comes 0.8 s after the hold, the k-th, runs a program that
+    nothing has compiled yet (on the chip: the read window's second program;
+    by the clock and not by a count, so that it falls into the window the
+    control opens, whatever the machine's pace). Quiet in seconds alone
+    ends the warm-up inside the hold, opens the window and prints (h); with
+    ``k`` under the floor in calls the warm-up goes on past that program and
+    every check holds."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from copycat_tpu.collections import DistributedMap
+
+    spies = []
+
+    class Spy(harness.Compiles):
+        def __init__(self):
+            super().__init__()
+            spies.append(self)
+
+    monkeypatch.setattr(harness, "Compiles", Spy)
+    config = json.load(open(os.path.join(DATA, "configs", "map-tiny.json")))
+    load = config["maps"] * config["preloaded_keys_per_map"]
+    state = {"puts": 0, "began": None, "held": None, "calls": 0, "k": None}
+
+    def before_a_call():
+        if state["puts"] <= load:           # the load's puts
+            return
+        now = time.perf_counter()
+        state["began"] = state["began"] or now
+        if state["held"] is None:
+            # a tenth of a second into the traffic, whatever this process
+            # still had to compile for it done: the quiet is not yet over
+            if now - state["began"] >= 0.1 and spies[0].quiet_for() >= 0.1:
+                time.sleep(0.5)
+                state["held"] = time.perf_counter()
+            return
+        state["calls"] += 1
+        if state["k"] is None and now - state["held"] >= 0.8:
+            state["k"] = state["calls"]
+            jax.jit(lambda x: x * 5 + 2)(jnp.arange(733)).block_until_ready()
+
+    real_put, real_get = DistributedMap.put, DistributedMap.get
+
+    async def put(self, key, value, ttl=None):
+        state["puts"] += 1
+        before_a_call()
+        return await real_put(self, key, value, ttl)
+
+    async def get(self, key):
+        before_a_call()
+        return await real_get(self, key)
+
+    monkeypatch.setattr(DistributedMap, "put", put)
+    monkeypatch.setattr(DistributedMap, "get", get)
+    root = mix_with(tmp_path, warmup_quiet_calls_per_client=floor)
+    line, checks, out, err = drive_err(harness, capsys, data_root=root)
+    clients = config["maps"]
+    assert state["k"] is not None, out
+    warm = next(t for t in out.splitlines() if "map plane: warm-up" in t)
+    since = int(warm.split(" calls, ")[1].split(" ")[0].replace(",", ""))
+    if not floor:
+        assert line["correct"] is False and seen(checks) == {"(h)"}, out
+        # the record of such a run: when, which program, then the checks
+        events = [t for t in err.splitlines() if "compile events inside" in t]
+        assert len(events) == 1 and " +" in events[0], err
+        assert "jit__lambda" in events[0] or "<lambda>" in events[0], err
+        assert err.index(events[0]) < err.index("map plane: check: (a)")
+    else:
+        assert state["k"] < floor * clients, (state, out)
+        assert line["correct"] is True and not seen(checks), out
+        assert since >= floor * clients, warm
+        assert "compile events inside" not in err
+
+
+def test_a_warm_up_that_never_goes_quiet_ends_in_the_deadlines_error(
+        harness, monkeypatch):
+    """A program compiled every tenth of a second: no quiet, and after the
+    deadline an error that says how far the calls got."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from copycat_tpu.collections import DistributedMap
+
+    monkeypatch.setattr(plane(harness), "DEADLINE_S", 1.5)
+    state = {"next": 0.0, "n": 0}
+    real_get = DistributedMap.get
+
+    async def get(self, key):
+        if time.perf_counter() >= state["next"]:
+            state["n"] += 1
+            n = state["n"]
+            jax.jit(lambda x: x + n)(jnp.arange(7)).block_until_ready()
+            state["next"] = time.perf_counter() + 0.1
+        return await real_get(self, key)
+
+    monkeypatch.setattr(DistributedMap, "get", get)
+    with pytest.raises(RuntimeError, match=(
+            r"warm-up not over after 2 s: [\d,]+ calls, [\d,]+ of the 80 it "
+            r"takes since the last program; so far \d+ programs")):
+        harness.run_cell(TINY, 3, 0.2, False,
+                         bench_file=os.path.join(DATA, "BENCHMARK.json"),
+                         data_root=DATA, require_tpu=False)
+
+
+@pytest.mark.parametrize("path, clients, per_client", [
+    (os.path.join(BENCH, "traffic", "putget50.json"), 1000, 20),
+    (os.path.join(DATA, "traffic", "putget50-tiny.json"), 8, 10),
+    (None, 1000, 20)], ids=["the-cell", "the-twin", "no-key"])
+def test_the_floor_in_calls_is_read_from_the_traffic_file(harness, path,
+                                                          clients, per_client):
+    """``warmup_quiet_calls_per_client`` times the clients; a mix without
+    the key takes the default, which is the cell's."""
+    mod = plane(harness)
+    if path is None:
+        mix = json.load(open(os.path.join(BENCH, "traffic", "putget50.json")))
+        del mix["warmup_quiet_calls_per_client"]
+        assert mod.QUIET_CALLS_PER_CLIENT == per_client
+    else:
+        mix = json.load(open(path))
+        assert mix["warmup_quiet_calls_per_client"] == per_client
+    assert mix["clients"] == clients
+    assert mod.quiet_calls(mix) == clients * per_client
 
 
 # -- the reference, the keys and the reducer ------------------------------------
