@@ -399,8 +399,11 @@ class DeviceEngine:
         self._map_shadow = 0         # map keys held on the host
         self._map_ops = None         # the registry's two map counters
         self._lock_ops = None        # and its two lock counters
+        self._elect_ops = None       # its two election counters
+        self._end_ops = None         # and the two of a session's end
         self._lock_overflow = 0      # lock waiters held on the host
         self._wait_slots = None      # width of a lock's device wait ring
+        self._listener_slots = None  # and of an election's listener ring
         #: dispatches of the vector lane so far: what a machine that looks
         #: ahead over its staged rows tells one run from the next by
         self.vector_epoch = 0
@@ -486,6 +489,39 @@ class DeviceEngine:
                                          metrics.counter("lock_chain_ops"))
         counters[chain].inc()
 
+    def count_elect_op(self, chain: bool) -> None:
+        """One more election command finalised on the vector lane, or
+        (``chain``) run as a generator chain: ``engine.elect_vector_ops``
+        and ``engine.elect_chain_ops`` in the tracer's report."""
+        counters = self._elect_ops
+        if counters is None:
+            metrics = self._groups.metrics
+            counters = self._elect_ops = (
+                metrics.counter("elect_vector_ops"),
+                metrics.counter("elect_chain_ops"))
+        counters[chain].inc()
+
+    def count_session_end(self, chain: bool, instances: int = 1) -> None:
+        """``instances`` more instances of an ended session closed in its
+        one vector turn, or (``chain``) each by a generator chain of its
+        own: ``engine.session_end_vector_instances`` and
+        ``engine.session_end_chain_instances``."""
+        counters = self._end_ops
+        if counters is None:
+            metrics = self._groups.metrics
+            counters = self._end_ops = (
+                metrics.counter("session_end_vector_instances"),
+                metrics.counter("session_end_chain_instances"))
+        counters[chain].inc(instances)
+
+    def listener_slots(self) -> int:
+        """Slots of an election's listener ring on the device."""
+        slots = self._listener_slots
+        if slots is None:
+            slots = self._listener_slots = \
+                self._groups.state.resources.el_id.shape[-1]
+        return slots
+
     def lock_wait_slots(self) -> int:
         """Slots of a lock's wait ring on the device."""
         slots = self._wait_slots
@@ -555,6 +591,8 @@ class DeviceEngine:
         self._groups = checkpoint.load_bytes(blob, mesh=self.config.mesh)
         self._map_ops = None         # they were the replaced registry's
         self._lock_ops = None
+        self._elect_ops = None
+        self._end_ops = None
         self._map_shadow = 0         # the machines' restores count anew
         self._lock_overflow = 0
         self._warm_capture()
@@ -741,6 +779,22 @@ class DeviceEngine:
             f"vector pump: {missing}/{len(tag_l)} rows uncommitted after "
             f"{max_rounds} rounds")
 
+    def run_close_block(self, rows: list) -> list[int]:
+        """A session's end as one vector turn: ``rows`` are ``(machine,
+        session, spec)`` of the instances the ended session owned whose
+        machines close them in one device op (``close_spec``). The open
+        window's chains are drained first, as :meth:`run_excl` drains
+        them, the rows ride ONE staged block, and the events they cause
+        are in the host's buffer when this returns. Raw results aligned
+        with ``rows``."""
+        if self._window is not None and self._window.busy:
+            self._window.barrier()
+        opcodes, a, b, c, _kinds = zip(*(spec for _, _, spec in rows))
+        raws = self.run_vector([machine._group for machine, _, _ in rows],
+                               opcodes, a, b, c)
+        self.count_session_end(chain=False, instances=len(rows))
+        return raws
+
     def run_query_vector(self, groups_idx, opcodes, a, b, c) -> list[int]:
         """The batched READ pump's device leg: evaluate every row through
         ONE :func:`~copycat_tpu.ops.consensus.query_step` engine round
@@ -797,6 +851,7 @@ class _Held:
 VK_CAS, VK_GET_AND_SET, VK_SET = 1, 2, 3
 VK_MAP_PUT, VK_MAP_REMOVE, VK_MAP_PUT_IF_ABSENT, VK_MAP_REPLACE = 4, 5, 6, 7
 VK_LOCK, VK_TRY_LOCK, VK_UNLOCK = 8, 9, 10
+VK_ELECT_LISTEN, VK_ELECT_RESIGN = 11, 12
 
 # Query-spec finalize kinds (query_spec's last element). Reads never
 # mutate host bookkeeping, so the only consumption modes are the raw
@@ -903,6 +958,30 @@ class DeviceBackedStateMachine(ResourceStateMachine):
 
     def vector_finalize(self, kind: int, operation: Any, raw: int,
                         commit: Commit) -> Any:
+        raise NotImplementedError  # pragma: no cover — spec implies finalize
+
+    # -- a session's end as one vector turn --------------------------------
+    #
+    # ``ResourceManager.close`` hands the instances an ended session owned
+    # to their machines together: a machine that can close an instance in
+    # ONE device op says which (``close_spec``), the manager stages those
+    # rows as one block (``DeviceEngine.run_close_block``) and hands each
+    # its result (``close_finalize``), in the fan-out's order. The pair
+    # must leave what ``close`` leaves, bit for bit.
+
+    #: ``close_spec``'s answer where the session's instance holds nothing
+    #: in this machine: closed, and no device op
+    CLOSED = ()
+
+    def close_spec(self, session: Any
+                   ) -> tuple[int, int, int, int, int] | tuple | None:
+        """(opcode, a, b, c, finalize_kind) where closing ``session``'s
+        instance is one device op, :attr:`CLOSED` where it is none, or
+        ``None``: :meth:`close` runs as it is, once the rows staged
+        before it have landed."""
+        return None
+
+    def close_finalize(self, session: Any, spec: tuple, raw: int) -> None:
         raise NotImplementedError  # pragma: no cover — spec implies finalize
 
     # -- batched read pump (query vector lane) -----------------------------
@@ -2375,6 +2454,8 @@ class DeviceLockState(DeviceBackedStateMachine):
     # -- session lifecycle -------------------------------------------------
 
     def close(self, session: Any) -> None:
+        self._eng.count_session_end(chain=True)
+
         def chain():
             yield from self._pump()
             for wid in [w for w, c in self._waiters.items()
@@ -2415,6 +2496,18 @@ class DeviceLockState(DeviceBackedStateMachine):
 # leader election
 # ---------------------------------------------------------------------------
 
+class _ElectAhead:
+    """An election as the rows staged for one vector run (``epoch``) will
+    leave it: the leader's candidate id and the line behind it, in order."""
+
+    __slots__ = ("epoch", "leader", "queue")
+
+    def __init__(self, epoch: int, leader: int | None, queue: deque) -> None:
+        self.epoch = epoch
+        self.leader = leader
+        self.queue = queue
+
+
 class DeviceLeaderElectionState(DeviceBackedStateMachine):
     """Leader election on the device election kernel: candidate id = the
     client session id (CPU machine keys listeners by session), epoch =
@@ -2430,9 +2523,16 @@ class DeviceLeaderElectionState(DeviceBackedStateMachine):
         self._leader: int | None = None         # session id
         self._epoch: int | None = None
         self._overflow: deque[int] = deque()
+        # the election as the rows staged for the next vector run will
+        # leave it (``vector_spec``, ``close_spec``)
+        self._ahead: _ElectAhead | None = None
 
-    def _pump(self):
-        for _seq, code, target, arg in self._events():
+    def _note_chain(self) -> None:
+        self._eng.count_elect_op(chain=True)
+
+    def _pump(self, events: list | None = None):
+        for _seq, code, target, arg in (self._events() if events is None
+                                        else events):
             if code != ops().EV_ELECT:
                 continue
             listen = self._listens.get(target)
@@ -2495,7 +2595,9 @@ class DeviceLeaderElectionState(DeviceBackedStateMachine):
         # issue device commands (overflow flush / dead-candidate resign)
         # that would fork that server's device log from its peers. The
         # mirror is always current as of the last command (every command
-        # settles its events before returning), which is exactly the
+        # settles its events before returning; a row parked on the vector
+        # lane has landed and been finalized before a read window walks
+        # its reads, ``RaftGroup._evaluate_reads``), which is exactly the
         # linearization point a query may observe.
         try:
             return self._epoch is not None \
@@ -2516,6 +2618,118 @@ class DeviceLeaderElectionState(DeviceBackedStateMachine):
         if self._leader == sid:
             self._leader = self._epoch = None
         yield from self._pump()
+
+    # -- vector lane (batched server-side pump) ---------------------------
+    # With no candidate in the host overflow the mirror (``_leader``,
+    # ``_listens`` in arrival order) IS the device's election, so an
+    # ``ElectionListen`` of a candidate not yet listed and an
+    # ``ElectionUnlisten`` of one that is are ONE device op each. Who a
+    # resign promotes depends on the rows staged before it and not yet
+    # finalized, so ``vector_spec`` looks ahead as the lock's does:
+    # ``_ahead`` is the leader and the line as the staged rows will leave
+    # them, drawn from the mirror when a run's first row is staged. A row
+    # it cannot answer for one device op (a listen again of a candidate
+    # already listed, a candidate in overflow, a ring the staged rows
+    # could fill, an unlisten of a candidate not listed) takes its
+    # generator handler, which the pump applies after the staged rows
+    # have landed. The "elect" a resign causes comes from the device's
+    # event ring as ever: ``run_vector`` returns once it is in the host's
+    # buffer, and the row that caused it publishes it, one event a
+    # hand-over, inside that row's entry. A session's end closes its
+    # candidacies through the same two functions (``close_spec``).
+
+    VECTOR_BY_COMMIT = True   # the candidate is the instance's session
+
+    def _look_ahead(self) -> _ElectAhead:
+        eng = self._eng
+        ahead = self._ahead
+        if ahead is None or ahead.epoch != eng.vector_epoch:
+            leader = self._leader
+            ahead = self._ahead = _ElectAhead(
+                eng.vector_epoch, leader,
+                deque(sid for sid in self._listens if sid != leader))
+        return ahead
+
+    def _resign_spec(self, sid: int
+                     ) -> tuple[int, int, int, int, int] | None:
+        """The one device op that takes candidate ``sid`` out, noted in
+        the look-ahead; ``None`` where ``sid`` is not listed there."""
+        ahead = self._look_ahead()
+        if ahead.leader == sid:
+            if ahead.queue:
+                ahead.leader = ahead.queue.popleft()
+                self._eng.expect_event(self._group, self._ev_cursor)
+            else:
+                ahead.leader = None
+        elif sid in ahead.queue:
+            ahead.queue.remove(sid)
+        else:
+            return None
+        return (ops().OP_ELECT_RESIGN, sid, 0, 0, VK_ELECT_RESIGN)
+
+    def vector_spec(self, operation: Any, index: int, session: Any
+                    ) -> tuple[int, int, int, int, int] | None:
+        t = type(operation)
+        if self._overflow or (t is not oc.ElectionListen
+                              and t is not oc.ElectionUnlisten):
+            return None
+        sid = session.id
+        if t is oc.ElectionUnlisten:
+            return self._resign_spec(sid)
+        ahead = self._look_ahead()
+        if ahead.leader is None:
+            ahead.leader = sid
+        elif ahead.leader == sid or sid in ahead.queue:
+            return None           # listed already: the handler's to replace
+        elif len(ahead.queue) >= self._eng.listener_slots():
+            return None           # the ring could refuse it: the overflow
+        else:
+            ahead.queue.append(sid)
+        return (ops().OP_ELECT_LISTEN, sid, 0, 0, VK_ELECT_LISTEN)
+
+    def vector_finalize(self, kind: int, operation: Any, raw: int,
+                        commit: Commit) -> Any:
+        self._eng.count_elect_op(chain=False)
+        sid = commit.session.id
+        if kind == VK_ELECT_RESIGN:
+            try:
+                self._resigned(sid)
+            finally:
+                commit.clean()
+            return None
+        self._listens[sid] = commit
+        if raw == FAIL():         # not reached: vector_spec counts the ring
+            self._overflow.append(sid)
+        elif raw > 0:
+            self._on_elected(sid, raw)
+        return None
+
+    def _resigned(self, sid: int) -> None:
+        """The mirror after ``sid``'s one ``OP_ELECT_RESIGN`` has landed:
+        the generator's ``_resign`` and the pump of its one event."""
+        self._listens.pop(sid).clean()
+        if self._leader == sid:
+            self._leader = self._epoch = None
+            if self._listens:
+                self._take_elect()
+
+    def _take_elect(self) -> None:
+        """The one event the resign just finalized caused: the promotion
+        of the ring's first candidate, published inside the entry that
+        caused it."""
+        events, self._ev_cursor = self._eng.take_events(
+            self._group, self._ev_cursor, limit=1)
+        if not events:
+            raise RuntimeError("election: the resign's elect has not "
+                               "surfaced")
+        _seq, code, target, arg = events[0]
+        listen = self._listens.get(target)
+        if code != ops().EV_ELECT or listen is None:
+            self._spawn(self._pump(events))   # a dead candidate: as the pump
+            return
+        self._leader, self._epoch = target, arg
+        if listen.session.is_open:
+            listen.session.publish("elect", arg)
 
     # -- snapshot hooks (crash-recovery plane, docs/DURABILITY.md) --------
     # The device election (leader, listener ring, epoch) rides the
@@ -2541,7 +2755,19 @@ class DeviceLeaderElectionState(DeviceBackedStateMachine):
         _rebind(self._listens.values(), session)
 
     def close(self, session: Any) -> None:
+        self._eng.count_session_end(chain=True)
         self._run_excl(self._resign(session.id))
+
+    def close_spec(self, session: Any
+                   ) -> tuple[int, int, int, int, int] | tuple | None:
+        if self._overflow:
+            return None
+        if session.id not in self._listens:
+            return self.CLOSED    # ``_resign`` finds nothing to take out
+        return self._resign_spec(session.id)
+
+    def close_finalize(self, session: Any, spec: tuple, raw: int) -> None:
+        self._resigned(session.id)
 
     def delete(self) -> None:
         def chain():

@@ -586,8 +586,43 @@ class ResourceManager(StateMachine):
             if instance.owner is session:
                 instance.resource.state_machine.expire(instance.session)
 
-    def close(self, session: ServerSession) -> None:
-        for iid, instance in list(self.instances.items()):
-            if instance.owner is session:
-                instance.resource.state_machine.close(instance.session)
-                del self.instances[iid]
+    def close(self, session: ServerSession) -> dict:
+        """A session's end, fanned out to every instance it owned in the
+        order they were created. Instances whose machines can close them
+        in ONE device op (``DeviceBackedStateMachine.close_spec``: a
+        waiting candidate unlisted, a leader resigned and its successor
+        told) ride one staged block and one settle; a machine that cannot
+        keeps ``close`` as it is, after the block before it has landed, so
+        the effects keep the fan-out's order. Returns what the server's
+        ``session.end`` span says of it."""
+        owned = [(iid, instance) for iid, instance in self.instances.items()
+                 if instance.owner is session]
+        engine = self._engine
+        groups = engine._groups if engine is not None else None
+        rounds = groups.rounds if groups is not None else 0
+        block: list = []   # (machine, the instance's session, spec)
+        staged = 0
+
+        def land() -> None:
+            raws = engine.run_close_block(block)
+            for (machine, closing, spec), raw in zip(block, raws):
+                machine.close_finalize(closing, spec, raw)
+            block.clear()
+
+        for iid, instance in owned:
+            machine = instance.resource.state_machine
+            spec_fn = getattr(machine, "close_spec", None)
+            spec = spec_fn(instance.session) if spec_fn is not None else None
+            if spec is None:
+                if block:
+                    land()
+                machine.close(instance.session)
+            elif spec:
+                block.append((machine, instance.session, spec))
+                staged += 1
+            del self.instances[iid]
+        if block:
+            land()
+        return {"instances": len(owned), "vector": staged,
+                "rounds": groups.rounds - rounds if groups is not None
+                else 0}

@@ -3365,15 +3365,21 @@ class RaftGroup:
         self.metrics.counter(
             "sessions_expired_total" if entry.expired
             else "sessions_closed_total").inc()
+        # the entry's apply on the record: the fan-out of close to every
+        # instance the session owned, their one staged block and its settle
+        span = TRACER.open_span("session.end") if TRACER.enabled else None
         if entry.expired:
             session.expire()
             self.state_machine.expire(session)
         else:
             session.close()
-        self.state_machine.close(session)
+        ended = self.state_machine.close(session)
         session.state = (SessionState.EXPIRED if entry.expired
                          else SessionState.CLOSED)
         self.log.clean(entry.index)
+        if span is not None:
+            span.close(group=self.group_id, expired=bool(entry.expired),
+                       **(ended if isinstance(ended, dict) else {}))
 
     def _apply_command(self, entry: CommandEntry,
                        window: Any = None) -> tuple[Any, str | None, Any]:

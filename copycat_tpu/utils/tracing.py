@@ -60,6 +60,9 @@ mapping in docs/OBSERVABILITY.md):
 - ``follower.append`` — a follower ingesting the replication window
   that carried the traced entry (fsync included).
 - ``event.push`` — session event delivery send -> ack.
+- ``session.end`` — an unregister entry's apply: the session marked
+  expired or closed -> every instance it owned closed (``instances``,
+  ``vector`` of them in one staged block, ``rounds``, ``expired``).
 - ``client.event`` — client-side receipt/dispatch of a traced publish.
 
 Batch-scope spans (one per batch and stage of the served path's two

@@ -653,3 +653,295 @@ async def test_members_that_cut_batches_differently_seal_equal_event_indexes(
                 await asyncio.wait_for(node.close(), 10)
             except (Exception, asyncio.TimeoutError):  # noqa: BLE001
                 pass
+
+
+# ---------------------------------------------------------------------------
+# DistributedLeaderElection on the vector lane: ElectionListen and
+# ElectionUnlisten as one device op each, the elect a resign causes published
+# inside its entry, and a session's end as one staged block
+
+
+async def _election_history(mode: str):
+    """Three sessions over five elections: the lines dealt in waves, a
+    listen again of a waiting candidate, a waiting candidate's resign, a
+    leader's resign and its next listen in ONE batch, a ring filled past
+    its eight slots (the overflow keeps the chain), and the end of a
+    session that leads one election, waits in others, holds the leader AND
+    the next in line of one, and stands in the election with the overflow.
+    ``mode``: ``"vector"`` (the device machine as it is), ``"chain"`` (the
+    same with ``vector_spec`` and ``close_spec`` answering ``None``: the
+    generator handlers alone) or ``"cpu"`` (``LeaderElectionState``).
+    Returns what the clients saw, every log index (instance ids) as its
+    rank and every epoch as its rank among its election's, what the server
+    kept, and the device machines' mirrors."""
+    from copycat_tpu.coordination import DistributedLeaderElection
+    from copycat_tpu.coordination import commands as oc
+    from copycat_tpu.manager.device_executor import DeviceLeaderElectionState
+    from copycat_tpu.server.log import CommandEntry
+
+    saved = {name: vars(DeviceLeaderElectionState).get(name)
+             for name in ("vector_spec", "close_spec")}
+    if mode == "chain":
+        DeviceLeaderElectionState.vector_spec = \
+            lambda self, operation, index, session: None
+        DeviceLeaderElectionState.close_spec = lambda self, session: None
+    registry = LocalServerRegistry()
+    server, a = await _spi_cluster(registry,
+                                   "cpu" if mode == "cpu" else "tpu")
+    clients = {"a": a}
+    try:
+        for name in "bc":
+            clients[name] = AtomixClient(a.client.members,
+                                         LocalTransport(registry),
+                                         session_timeout=20.0)
+            await clients[name].open()
+        el = {n: [await c.create(f"e{i}", DistributedLeaderElection)
+                  for i in range(4)] for n, c in clients.items()}
+        # a second candidacy of ``a`` in e3, right behind its first, and a
+        # line of eleven in e4: ``c`` ten times, then ``a``
+        a_twice = await a.create("e3", DistributedLeaderElection)
+        crowd = [await clients["c"].create("e4", DistributedLeaderElection)
+                 for _ in range(10)]
+        a_crowd = await a.create("e4", DistributedLeaderElection)
+        seen: dict[str, list] = {n: [] for n in clients}
+        for name, client in clients.items():
+            client.client.session().on_event(
+                "elect", lambda m, _n=name: seen[_n].append(
+                    (m.resource, m.message)))
+        replies: list = []
+        noop = lambda epoch: None  # noqa: E731
+
+        async def listen(instance):
+            replies.append(("listen", await instance.submit(
+                oc.ElectionListen())))
+
+        async def unlisten(instance):
+            replies.append(("unlisten", await instance.submit(
+                oc.ElectionUnlisten())))
+
+        async def leads(instance, epoch):
+            got = await instance.is_leader(epoch)
+            replies.append(("is_leader", got))
+            return got
+
+        wave = asyncio.gather
+        for name in "abc":                       # the lines: a, b, c
+            await wave(*(listen(i) for i in el[name]))
+        await listen(a_twice)                    # e3: a, b, c, a again
+        first = {m[0]: m[1] for m in seen["a"]}  # a leads all four
+        assert len(first) == 4
+        for inst in el["a"]:
+            assert await leads(inst, first[inst.client.instance_id]) is True
+            assert await leads(inst, first[inst.client.instance_id] + 1) \
+                is False
+        await listen(el["b"][0])                 # waiting: listed once
+        await unlisten(el["c"][1])               # waiting: out of the line
+        # the leader's resign and its next listen in ONE batch: e0 passes
+        # to b, a stands behind c
+        await wave(unlisten(el["a"][0]), listen(el["a"][0]))
+        told_b = [m for m in seen["b"]
+                  if m[0] == el["b"][0].client.instance_id]
+        assert len(told_b) == 1
+        assert await leads(el["b"][0], told_b[0][1]) is True
+        assert await leads(el["a"][0], first[
+            el["a"][0].client.instance_id]) is False    # a stale epoch
+        # e4: a leader and ten behind it, two past the ring's eight slots
+        await wave(*(listen(i) for i in crowd))
+        await listen(a_crowd)
+        await unlisten(crowd[0])                 # the line moves up
+        await asyncio.sleep(0.05)
+        # the end of session a: it leads e1, e2 and e3 (with its second
+        # candidacy next in line there), waits in e0 and in e4's overflow
+        await asyncio.wait_for(a.close(), 10)
+        await asyncio.sleep(0.1)
+        # the elections go on: every leader resigns, its successor is told
+        for name in "bc":
+            for inst in el[name][:3]:
+                await unlisten(inst)
+        for inst in crowd[1:4]:
+            await unlisten(inst)
+        await asyncio.sleep(0.1)  # drain in-flight publishes
+
+        raft = server.server
+        log = raft.log
+        commands = [e for e in (log.get(i) for i in range(
+            1, log.last_index + 1)) if type(e) is CommandEntry]
+        ids = sorted({m[0] for ev in seen.values() for m in ev})
+        ordinal = {x: k for k, x in enumerate(ids)}
+        epochs: dict[int, list] = {}
+        for ev in seen.values():
+            for resource, epoch in ev:
+                epochs.setdefault(resource, []).append(epoch)
+        # an election's epochs, over all its candidates, in rising order
+        manager = raft.state_machine
+        by_instance = {iid: inst.resource.resource_id
+                       for iid, inst in manager.instances.items()}
+        sessions = sorted(raft.sessions.values(), key=lambda s: s.id)
+        engine = getattr(manager, "device_engine", None)
+        counters: dict = {}
+        mirrors: list = []
+        if mode != "cpu":
+            c = engine._groups.metrics.counter
+            counters = {n: c(n).value for n in (
+                "elect_vector_ops", "elect_chain_ops",
+                "session_end_vector_instances",
+                "session_end_chain_instances")}
+            mirrors = [(h.key, m._leader, m._epoch, list(m._listens),
+                        list(m._overflow))
+                       for h in manager.resources.values()
+                       for m in [h.state_machine]]
+        return {
+            "replies": replies,
+            "events": {n: [ordinal[r] for r, _ in ev]
+                       for n, ev in seen.items()},
+            "rising": all(e == sorted(set(e)) for e in epochs.values()),
+            "event_index": [(s.event_index, s.event_ack_index)
+                            for s in sessions],
+            "client_index": [clients[n].client.session().event_index
+                             for n in "bc"],
+            "retained": [type(e.operation.operation.operation).__name__
+                         for e in commands if not log.is_cleaned(e.index)
+                         and hasattr(e.operation, "resource")
+                         and hasattr(e.operation.operation, "operation")],
+            "instances": len(by_instance),
+        }, counters, mirrors, {n: list(ev) for n, ev in seen.items()}
+    finally:
+        for name, fn in saved.items():
+            if fn is None:
+                if name in vars(DeviceLeaderElectionState):
+                    delattr(DeviceLeaderElectionState, name)
+            else:
+                setattr(DeviceLeaderElectionState, name, fn)
+        for node in (*clients.values(), server):
+            try:
+                await asyncio.wait_for(node.close(), 5)
+            except (Exception, asyncio.TimeoutError):  # noqa: BLE001
+                pass
+
+
+@async_test(timeout=300)
+async def test_election_on_the_vector_lane_is_the_chain_and_the_cpu_state():
+    """The same script on the device machine's vector lane, on its
+    generator handlers alone and on the CPU ``LeaderElectionState``: every
+    reply, each session's events in order, the sessions' ``event_index`` (a
+    batch an entry) and which retained commits were cleaned are equal on
+    all three; the two device runs agree bit for bit, epochs and mirrors
+    included (the same device ops in the same order); the vector run took
+    the lane for all but the listen again, the overflow and what stood
+    behind it, and ended the session in blocks around the one election it
+    could not."""
+    vec, counters, mirrors, raw = await _election_history("vector")
+    chain, chain_counters, chain_mirrors, chain_raw = \
+        await _election_history("chain")
+    cpu, _, _, _ = await _election_history("cpu")
+    assert vec == chain == cpu
+    assert vec["rising"] and len(vec["events"]["b"]) >= 4 \
+        and len(vec["events"]["c"]) >= 4
+    assert raw == chain_raw and mirrors == chain_mirrors
+    assert counters["elect_vector_ops"] >= 30
+    assert 1 <= counters["elect_chain_ops"] <= 8
+    # a's six candidacies in the five elections: the overflow's by a chain,
+    # the rest in the blocks before and after it
+    assert counters["session_end_vector_instances"] == 5
+    assert counters["session_end_chain_instances"] == 1
+    assert chain_counters["elect_vector_ops"] == 0
+    assert chain_counters["session_end_vector_instances"] == 0
+    assert chain_counters["session_end_chain_instances"] == 6
+
+
+@async_test(timeout=300)
+async def test_election_vector_lane_resumes_from_a_snapshot_mid_line():
+    """A leader and two waiting candidates, the machine's host mirror taken
+    as a snapshot and restored over it: the look-ahead reads the stand-in
+    commits, the line moves on down the vector lane in order, and each
+    successor's ``is_leader(epoch)`` is true as soon as it is told."""
+    from copycat_tpu.coordination import DistributedLeaderElection
+    from copycat_tpu.manager.device_executor import DeviceLeaderElectionState
+
+    registry = LocalServerRegistry()
+    server, a = await _spi_cluster(registry)
+    others = []
+    try:
+        for _ in range(2):
+            c = AtomixClient(a.client.members, LocalTransport(registry),
+                             session_timeout=20.0)
+            await c.open()
+            others.append(c)
+        elections = [await c.get("election", DistributedLeaderElection)
+                     for c in (a, *others)]
+        told: list = []
+        checked: list = []
+
+        def on_elect(k):
+            def cb(epoch):
+                told.append((k, epoch))
+                # the token is checked from inside the event's dispatch
+                checked.append(asyncio.ensure_future(
+                    elections[k].is_leader(epoch)))
+            return cb
+
+        for k, election in enumerate(elections):
+            await election.on_election(on_elect(k))
+        assert [k for k, _ in told] == [0]
+        manager = server.server.state_machine
+        (machine,) = [h.state_machine for h in manager.resources.values()
+                      if isinstance(h.state_machine,
+                                    DeviceLeaderElectionState)]
+        image = machine.snapshot_state()
+        assert image["leader"] is not None and len(image["listens"]) == 3
+        sessions = {c.session.id: c.session
+                    for c in machine._listens.values()}
+        machine._listens.clear()
+        machine._leader = machine._epoch = None
+        machine.restore_state(image, {})
+        for session in sessions.values():
+            machine.register(session)
+        counter = manager.device_engine._groups.metrics.counter
+        chain0 = counter("elect_chain_ops").value
+        for k, election in enumerate(elections):
+            for _ in range(100):
+                if len(told) > k:
+                    break
+                await asyncio.sleep(0.02)
+            assert [j for j, _ in told] == list(range(k + 1))
+            await election.resign()
+        assert all(await asyncio.gather(*checked)) and len(checked) == 3
+        epochs = [epoch for _, epoch in told]
+        assert epochs == sorted(set(epochs))
+        assert counter("elect_chain_ops").value == chain0
+        assert counter("elect_vector_ops").value >= 6
+        assert machine._leader is None and not machine._listens
+        assert not await elections[0].is_leader(epochs[-1])
+    finally:
+        for node in (a, *others, server):
+            await asyncio.wait_for(node.close(), 5)
+
+
+def test_an_elect_reaches_its_instance_by_a_dictionary():
+    """300 candidacies on one client session hang ONE listener on it for
+    ``"elect"``: an event finds its instance by the id it names
+    (``manager/instance._EventRouter``), not by a walk of 300 listeners."""
+    from copycat_tpu.client.client import ClientSession
+    from copycat_tpu.coordination import DistributedLeaderElection
+    from copycat_tpu.manager.instance import InstanceClient, _ROUTERS
+    from copycat_tpu.manager.operations import InstanceEvent
+
+    class Raft:
+        def __init__(self):
+            self._session = ClientSession(self)
+
+        def session(self):
+            return self._session
+
+    raft = Raft()
+    got: list = []
+    elections = []
+    for iid in range(300):
+        election = DistributedLeaderElection(InstanceClient(iid, raft))
+        election._listeners.add(lambda epoch, _i=iid: got.append((_i, epoch)))
+        elections.append(election)
+    listeners = raft.session()._event_listeners["elect"]
+    assert len(listeners) == 1
+    assert len(_ROUTERS[raft.session()].routes["elect"]) == 300
+    raft.session()._dispatch("elect", InstanceEvent(217, 99))
+    assert got == [(217, 99)]
