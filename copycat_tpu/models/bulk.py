@@ -42,6 +42,7 @@ Two dispatch modes, chosen by the engine's Config:
 from __future__ import annotations
 
 import dataclasses
+import sys
 import time
 from functools import lru_cache, partial
 from typing import Any
@@ -134,8 +135,116 @@ def _deep_program(config, onehot: bool = False, donate: bool = False):
                    donate_argnums=(0, 1, 2, 3, 4) if donate else ())
 
 
+#: what ``resolve_round`` reads where no result came back: the seed of the
+#: deep drive's round accumulator, far above any round of a drive.
+#: ``tests/benchmark/test_benchmark_bulk_plane.py`` reads the mark from this
+#: module's text, which it knows as the statement
+#: ``rndbuf = rg._stage_acc(np.full((G, Bpad), 2**30, np.int32))``
+#: (the seed is a kept array now): keep that line and this value in step.
+_UNRESOLVED = 2**30
+
+
+class _KeptArrays:
+    """The deep drive's host arrays of an operation's or a group's size,
+    kept from drive to drive.
+
+    A drive of 12.8M operations uses 1.3 GB of them, each above the 32 MB
+    up to which glibc recycles a freed block: allocated a drive, each is
+    mapped afresh and faulted in page by page again, which was most of a
+    drive (PERF.md §5). So the driver owns one set. An array is taken by
+    ``name``; the one kept under it is handed back while its shape, dtype
+    and ``tag`` (what its constant contents depend on) are what is asked
+    for, and replaced otherwise; a name a drive did not take is released
+    at its end, and a drive that raises releases everything. What changes
+    from drive to drive is overwritten by the drive, in full over the
+    region the last one wrote; what does not is written once, by ``fill``.
+
+    ``RaftGroups.metrics`` ``bulk_host_bytes`` counts every array taken and
+    ``bulk_kept_bytes`` those that were there already.
+    """
+
+    __slots__ = ("_rg", "_arrays", "_taken", "_handed")
+
+    def __init__(self, rg) -> None:
+        self._rg = rg
+        self._arrays: dict[Any, tuple[np.ndarray, Any]] = {}
+        self._taken: set = set()
+        #: the arrays of the last two ``BulkResult``s, oldest first
+        self._handed: list[tuple[np.ndarray, ...]] = []
+
+    def take(self, name, shape: tuple, dtype, fill=None,
+             tag=None) -> np.ndarray:
+        """The array kept under ``name``. A new one is uninitialised
+        (``fill=None``), filled with the scalar ``fill``, or zeroed and
+        passed to the callable ``fill``: constant contents, never written
+        again while ``tag`` stays."""
+        self._taken.add(name)
+        arr, was = self._arrays.get(name, (None, None))
+        if (arr is not None and arr.shape == shape and arr.dtype == dtype
+                and was == tag):
+            self._rg._m_bulk_kept.inc(arr.nbytes)
+        else:
+            if fill is None:
+                arr = np.empty(shape, dtype)
+            elif callable(fill):
+                arr = np.zeros(shape, dtype)
+                fill(arr)
+            else:
+                arr = (np.full(shape, fill, dtype) if fill
+                       else np.zeros(shape, dtype))
+            self._arrays[name] = arr, tag
+        self._rg._m_bulk_host.inc(arr.nbytes)
+        return arr
+
+    def handout(self, n: int, count: int) -> tuple[np.ndarray, ...]:
+        """``count`` uninitialised ``int64[n]`` arrays for a ``BulkResult``:
+        those of one of the last two results if nothing outside this
+        object refers to any of them any more (a view keeps its base
+        alive, so the base's reference count speaks for its views too),
+        else fresh ones, remembered in place of the older of the two. A
+        caller that holds its last result while the next drive runs, as a
+        loop does, alternates two sets. (Whatever else holds a reference
+        counts as a holder, a sampling profiler's grip on the caller's
+        frame for its milliseconds too: the drive then makes its own.)"""
+        handed = self._handed
+        if handed and (len(handed[0]) != count or handed[0][0].size != n):
+            handed.clear()
+        self._rg._m_bulk_host.inc(8 * n * count)
+        for i in range(len(handed)):
+            # (this object's tuple, the loop's ``a`` and the call's
+            # argument: three references to an array no one else holds)
+            if all(sys.getrefcount(a) == 3 for a in handed[i]):
+                self._rg._m_bulk_kept.inc(8 * n * count)
+                handed.append(handed.pop(i))
+                return handed[-1]
+        handed.append(tuple(np.empty(n, np.int64) for _ in range(count)))
+        del handed[:-2]
+        return handed[-1]
+
+    def sweep(self) -> None:
+        """A drive's end: release what it did not take."""
+        for name in self._arrays.keys() - self._taken:
+            del self._arrays[name]
+        self._taken.clear()
+
+    def release(self) -> None:
+        self._arrays.clear()
+        self._taken.clear()
+        self._handed.clear()
+
+
 class BulkResult:
-    """Results + client-observed latency percentiles for one drive."""
+    """Results + client-observed latency percentiles for one drive.
+
+    ``results``, ``dispatch_round`` and ``resolve_round`` are ``int64``
+    arrays, one entry an operation in submission order. A caller that
+    holds the result, one of its arrays or a view of one owns them for
+    good: no later drive writes them. Arrays a caller has let go of may be
+    handed out again by a later drive of the same driver. A deep drive
+    whose groups each sent the same count hands out a ``dispatch_round``
+    that is read-only and shared with the other results of that shape (it
+    is the same for all of them).
+    """
 
     __slots__ = ("results", "rounds", "wall_s", "dispatch_round",
                  "resolve_round")
@@ -196,6 +305,7 @@ class BulkDriver:
                 "deep_scan needs a single-host monotone-tag engine")
         self._scan = deep_scan
         self._rg = rg
+        self._kept = _KeptArrays(rg)
 
     def drive(self, groups, opcode, a=0, b=0, c=0,
               max_rounds: int = 10_000,
@@ -232,6 +342,14 @@ class BulkDriver:
         (``RaftGroups.metrics`` ``bulk_grouped_drives`` and
         ``bulk_dense_drives`` count them, the ``bulk.plan`` span names
         the ``plan``); on a multihost engine each process reads its own.
+
+        The deep drive keeps its host arrays for the next drive
+        (:class:`_KeptArrays`): a burst of the shape of the last one
+        allocates nothing of an operation's or a group's size. What the
+        caller may rely on is :class:`BulkResult`'s: the arrays of a
+        result it holds are never written again; those it let go of may be
+        a later result's. The arguments are read during the call and never
+        written or kept.
         """
         rg = self._rg
         S = rg.submit_slots
@@ -246,20 +364,29 @@ class BulkDriver:
 
         g_arr = np.asarray(groups, np.int64).ravel()
         n = g_arr.size
-        bc = lambda x: np.broadcast_to(
-            np.asarray(x, np.int32).ravel(), (n,)).copy()
-        op_a, a_a, b_a, c_a = bc(opcode), bc(a), bc(b), bc(c)
         if getattr(rg.config, "monotone_tag_accept", False):
-            res, stage, windows = self._drive_deep(
-                g_arr, op_a, a_a, b_a, c_a, max_rounds, t0,
-                deliver_schedule, stage)
-            # the deep drive's arrays, some 200 bytes an operation, were
-            # freed as it returned: the last stage's time, and the root's
             if stage is not None:
-                stage.close()
+                host, kept = rg._m_bulk_host.value, rg._m_bulk_kept.value
+            try:
+                res, stage, windows = self._drive_deep(
+                    g_arr, (opcode, a, b, c), max_rounds, t0,
+                    deliver_schedule, stage)
+            except BaseException:
+                # transfers of the kept arrays may be in flight still, and
+                # a constant may be half written: the next drive sizes anew
+                self._kept.release()
+                raise
+            # nothing of the deep drive's is freed as it returns: the last
+            # stage says what it took, and what of that was there already
+            if stage is not None:
+                stage.close(host=rg._m_bulk_host.value - host,
+                            kept=rg._m_bulk_kept.value - kept)
                 root.close(n=n, rounds=res.rounds, windows=windows,
                            scan=self._scan)
             return res
+        bc = lambda x: np.broadcast_to(
+            np.asarray(x, np.int32).ravel(), (n,)).copy()
+        op_a, a_a, b_a, c_a = bc(opcode), bc(a), bc(b), bc(c)
         if stage is not None:   # the classic drive: the root and this
             stage.close()
         if deliver_schedule is not None:
@@ -556,8 +683,7 @@ class BulkDriver:
             rg._stream_count,
             stream_count_from_state(rg.state, fetch=rg._fetch_acc))
 
-    def _drive_deep(self, g_arr, op_a, a_a, b_a, c_a,
-                    max_rounds: int, t0: float,
+    def _drive_deep(self, g_arr, leaves, max_rounds: int, t0: float,
                     deliver_schedule=None, stage=None) -> tuple:
         """Zero-sync pipelined drive for monotone-tag engines.
 
@@ -580,18 +706,36 @@ class BulkDriver:
         Liveness matches the classic bulk plane (fault-free delivery);
         safety is the gate's and holds under any fault.
 
+        Every array of an operation's or a group's size that phase 1
+        names is taken from the driver's :class:`_KeptArrays` (``leaves``,
+        the payload as the caller passed it, included where it has to be
+        converted); a straggler pass allocates its own.
+
         ``stage`` is the drive's open span while the tracer is on
         (``bulk.admit``): every stage from here on closes into the next,
         and a straggler phase records its stages again with ``phase=2``.
         Returns the result, the stage left open (``bulk.return``: the
-        caller closes it once this frame's arrays are freed) and the
-        blind phase's windows.
+        caller closes it) and the blind phase's windows.
         """
         rg = self._rg
+        kept = self._kept
         S = rg.submit_slots
         G = rg.num_groups
         n = g_arr.size
         multi = getattr(rg, "process_count", 1) > 1
+
+        def admitted(i, x):
+            """Payload leaf ``i`` as ``int32[n]``: the caller's own array
+            where it is one (read, never written), else a kept one."""
+            x = x if isinstance(x, np.ndarray) else np.asarray(x, np.int32)
+            x = x.ravel()
+            if x.dtype == np.int32 and x.size == n:
+                return x
+            out = kept.take(("leaf", i), (n,), np.int32)
+            np.copyto(out, x, casting="unsafe")
+            return out
+
+        vals = tuple(admitted(i, x) for i, x in enumerate(leaves))
         if stage is not None:
             stage = stage.then("bulk.plan")
 
@@ -599,16 +743,22 @@ class BulkDriver:
         # pass each (see drive()): "sorted" pays for a permutation,
         # "grouped" plans over the admitted arrays as they lie, "dense"
         # (grouped, every segment B long) also needs no index per element.
+        # (one scratch mask: the order, the segments' firsts, uniformity)
+        mask = kept.take("mask", (n,), bool)
         order = None
-        if n and not (g_arr[1:] >= g_arr[:-1]).all():
+        if not np.greater_equal(g_arr[1:], g_arr[:-1], out=mask[1:]).all():
             order = np.argsort(g_arr, kind="stable")
-            g_s = g_arr[order]
-            op_s, a_s, b_s, c_s = (x[order] for x in (op_a, a_a, b_a, c_a))
+            g_s = np.take(g_arr, order, mode="clip",
+                          out=kept.take("sorted.groups", (n,), np.int64))
+            vals = tuple(
+                np.take(x, order, mode="clip",
+                        out=kept.take(("sorted.leaf", i), (n,), np.int32))
+                for i, x in enumerate(vals))
         else:
-            g_s, op_s, a_s, b_s, c_s = g_arr, op_a, a_a, b_a, c_a
-        firsts = np.ones(n, bool)
-        firsts[1:] = g_s[1:] != g_s[:-1]
-        starts = np.flatnonzero(firsts)
+            g_s = g_arr
+        mask[:1] = True
+        np.not_equal(g_s[1:], g_s[:-1], out=mask[1:])
+        starts = np.flatnonzero(mask)
         counts = np.diff(np.append(starts, n))
         seg_groups = g_s[starts]
         nseg = starts.size
@@ -628,13 +778,6 @@ class BulkDriver:
         if tag_end > np.iinfo(np.int32).max:
             raise OverflowError(
                 "per-group stream exceeds int32 tag space")
-
-        # all bookkeeping lives in SORTED space; unsorted at return, where
-        # a permutation was paid for. Every op's dispatch round is fixed
-        # by the blind phase-1 plan: its rank in its segment over S.
-        resolved = np.zeros(n, bool)
-        results = np.zeros(n, np.int64)
-        resolve_round = np.zeros(n, np.int64)
 
         # On-device result accumulators, fetched ONCE per drive: [G, B]
         # keyed by stream rank (ops/consensus.deep_step). B pads to a
@@ -662,27 +805,65 @@ class BulkDriver:
                 f"({G_total * Bpad / 1e6:.0f}M slots) for {n} ops — burst "
                 "sizes are too skewed; split the drive into bursts of "
                 "similar per-group size")
+
+        # all bookkeeping lives in SORTED space; unsorted at return, where
+        # a permutation was paid for. The harvest writes the three in
+        # full. Every op's dispatch round is fixed by the blind phase-1
+        # plan: its rank in its segment over S.
+        outs = kept.handout(n, 2 if dense else 3)
+        if order is None:
+            results, resolve_round = outs[:2]
+        else:
+            results, resolve_round = (
+                kept.take(name, (n,), np.int64)
+                for name in ("sorted.results", "sorted.resolve_round"))
+        resolved = kept.take("resolved", (n,), bool)
         if dense:
             # every segment is `per` long (the agreed B may be another
             # process's, and longer) and lies at seg_groups' rows, which
             # are every row in order where every group sends
             per = int(counts[0])
             rows = slice(None) if nseg == G else seg_groups
-            dispatch_round = np.tile(
-                np.arange(per, dtype=np.int64) // S, nseg)
+
+            def tiled(arr):
+                arr.reshape(nseg, per)[:] = np.arange(per) // S
+
+            # the same for every drive of `per` a group: shared, so
+            # read-only in the caller's hand
+            dispatch_round = kept.take("dispatch_round", (n,), np.int64,
+                                       fill=tiled, tag=per)
+            dispatch_round.flags.writeable = False
         else:
             rank = np.arange(n) - np.repeat(starts, counts)
-            dispatch_round = rank // S
+            dispatch_round = (
+                outs[2] if order is None else
+                kept.take("sorted.dispatch_round", (n,), np.int64))
+            np.floor_divide(rank, S, out=dispatch_round)
+            slot_of = rank - dispatch_round * S
+        # a rectangular burst over every group writes the same region of
+        # the payload every drive; any other clears the windows first
+        exact = dense and nseg == G
         if stage is not None:
             stage = stage.then("bulk.stage", segments=nseg, plan=plan)
             staged = rg._m_staged_bytes.value
             phase: dict = {}    # a straggler phase's stages say phase=2
-        resbuf = rg._stage_acc(np.zeros((G, Bpad), np.int32))
-        valbuf = rg._stage_acc(np.zeros((G, Bpad), bool))
-        rndbuf = rg._stage_acc(np.full((G, Bpad), 2**30, np.int32))
-        evflag = rg._stage_acc(np.zeros(G, bool))  # per-group: no
-        #                                            cross-shard reduce
-        base_dev = rg._stage_acc(rg._stream_count.astype(np.int32))
+        # the accumulators' seeds are kept constants; their device copies
+        # are donated to the program, so they are put again every drive
+        resbuf, valbuf, rndbuf = (
+            rg._stage_acc(kept.take(name, (G, Bpad), dtype, fill=seed))
+            for name, dtype, seed in (("seed.result", np.int32, 0),
+                                      ("seed.valid", bool, False),
+                                      ("seed.round", np.int32, _UNRESOLVED)))
+        no_event = kept.take("seed.event", (G,), bool, fill=False)
+        evflag = rg._stage_acc(no_event)  # per-group: no cross-shard reduce
+        base = kept.take("stream.base", (G,), np.int32)
+        np.copyto(base, rg._stream_count, casting="unsafe")
+        base_dev = rg._stage_acc(base)
+        fetched = tuple(
+            kept.take(name, (G, Bpad), dtype)
+            for name, dtype in (("fetched.result", np.int32),
+                                ("fetched.valid", bool),
+                                ("fetched.round", np.int32)))
         _deep = rg._deep_fn()
 
         # burst-uniform payload leaves travel as SCALARS (zero H2D bytes);
@@ -692,11 +873,10 @@ class BulkDriver:
         # scalar has no local block (payload uniformity is also a
         # per-process fact the other processes can't see).
         def _const(x):
-            return np.int32(x[0]) if (n and (x == x[0]).all()) else None
+            return (np.int32(x[0])
+                    if n and np.equal(x, x[0], out=mask).all() else None)
 
-        consts = ((None,) * 4 if multi
-                  else tuple(map(_const, (op_s, a_s, b_s, c_s))))
-        vals = (op_s, a_s, b_s, c_s)
+        consts = (None,) * 4 if multi else tuple(map(_const, vals))
         # telemetry stash: per-round [G] delta blocks kept ON DEVICE and
         # fetched with the accumulator harvest — the blind phase stays
         # one transfer per drive even with the flight recorder on
@@ -711,24 +891,6 @@ class BulkDriver:
         deliver = rg.deliver
         ev_stash: list[Any] = []
         r = 0
-
-        def payload_leaves(pos, slots):
-            return tuple(
-                c if c is not None else _scatter(G, S, g_s[pos], slots,
-                                                 v[pos])
-                for c, v in zip(consts, vals))
-
-        def window(done):
-            """Each segment's next <=S operations past its first
-            ``done``: how many a segment (``want``), the segments that
-            send any, and the operations' positions and slots."""
-            want = np.clip(counts - done, 0, S)
-            segs = np.flatnonzero(want > 0)
-            reps = want[segs]
-            slots = np.arange(reps.sum()) \
-                - np.repeat(np.cumsum(reps) - reps, reps)
-            pos = np.repeat((starts + done)[segs], reps) + slots
-            return want, segs, pos, slots
 
         def dispatch(tagl, vnp, leaves) -> None:
             nonlocal r, resbuf, valbuf, rndbuf, evflag
@@ -748,9 +910,17 @@ class BulkDriver:
                 tel_stash.append(out.telemetry)
             r += 1
 
-        _idle = (np.zeros((G, 1), np.int32), np.zeros((G, S), bool),
-                 (np.zeros((G, S), np.int32),) * 4 if multi
-                 else (np.int32(0),) * 4)
+        idle: tuple = ()
+
+        def settle() -> None:
+            """One round that submits nothing (kept constants)."""
+            nonlocal idle
+            idle = idle or (
+                kept.take("idle.tag", (G, 1), np.int32, fill=0),
+                kept.take("idle.valid", (G, S), bool, fill=False),
+                (kept.take("idle.leaf", (G, S), np.int32, fill=0),) * 4
+                if multi else (np.int32(0),) * 4)
+            dispatch(*idle)
 
         def harvest() -> None:
             """ONE fetch of the [G,B] accumulators (+ telemetry, + the
@@ -761,13 +931,13 @@ class BulkDriver:
                 # wait's, not the fetch's; off, the fetch waits
                 jax.block_until_ready((resbuf, valbuf, rndbuf, evflag))
                 stage = stage.then("bulk.fetch", **phase)
-                fetched = rg._m_fetch_bytes.value
+                fetched_bytes = rg._m_fetch_bytes.value
             res_np, val_np, rnd_np, ev, tels = rg._fetch_acc(
-                (resbuf, valbuf, rndbuf, evflag, tel_stash))
+                (resbuf, valbuf, rndbuf, evflag, tel_stash), into=fetched)
             if stage is not None:
                 stage = stage.then(
                     "bulk.harvest",
-                    bytes=rg._m_fetch_bytes.value - fetched, **phase)
+                    bytes=rg._m_fetch_bytes.value - fetched_bytes, **phase)
             for tel in tels:
                 if np.asarray(tel.elections_started).ndim == 2:
                     w = int(np.asarray(tel.elections_started).shape[0])
@@ -801,84 +971,92 @@ class BulkDriver:
                                 _EventView(*(x[w] for x in leaves)))
                     else:
                         rg._ingest_events(_EventView(*leaves))
-                evflag = rg._stage_acc(np.zeros(G, bool))
+                evflag = rg._stage_acc(no_event)
             ev_stash.clear()
 
         # phase 1: blind pipelined dispatch — NO device fetch at all. The
-        # device runs ~windows rounds deep while the host only stages
-        # tag bases [G,1] and valid masks [G,S]. Scan mode goes further:
-        # the whole phase (windows + settle) is ONE stacked payload and
-        # ONE compiled lax.scan dispatch.
+        # device runs ~windows rounds deep while the host only stages the
+        # payload: a tag base [G,1], a valid mask [G,S] and the leaves
+        # that vary [G,S] a window, stacked so that no window's arrays
+        # are written while another's still cross. Scan mode goes further:
+        # the stack holds the settle rounds' empty rows too and the whole
+        # phase is ONE compiled lax.scan dispatch.
         windows = int(np.ceil(B / S))
-        tagl = np.zeros((G, 1), np.int32)
         if self._scan and deliver_schedule is not None:
             raise NotImplementedError(
                 "deep_scan compiles the whole blind phase with ONE "
                 "deliver mask; per-round deliver_schedule fault "
                 "injection needs the dispatch mode (BulkDriver without "
                 "deep_scan)")
-        if self._scan:
-            W_total = windows + 3      # + replicate/commit/report settle
-            tagl_w = np.zeros((W_total, G, 1), np.int32)
-            valid_w = np.zeros((W_total, G, S), bool)
+        # (+ replicate/commit/report settle)
+        stack = windows + 3 if self._scan else windows
+        tagl_w = kept.take("payload.tag", (stack, G, 1), np.int32, fill=0)
+        if not exact:
+            tagl_w[:windows] = 0
+        for w in range(windows):
+            tagl_w[w, seg_groups, 0] = (seg_base + w * S + 1) \
+                .astype(np.int32)
 
-            def _payload_w(c):
-                arr = np.zeros((W_total, G, S), np.int32)
-                if c is not None:
-                    arr[:windows] = c     # burst-uniform: one fill
-                return arr
+        def mark_valid(arr):
+            if dense:
+                for w in range(-(-per // S)):
+                    arr[w, rows, :min(S, per - w * S)] = True
+            else:
+                for w in range(windows):
+                    arr[w][seg_groups] = \
+                        (w * S + np.arange(S))[None, :] < counts[:, None]
 
-            planes = tuple(_payload_w(c) for c in consts)
-            op_w, a_w, b_w, c_w = planes
-            for w in range(windows):
-                tagl_w[w, seg_groups, 0] = (seg_base + w * S + 1) \
-                    .astype(np.int32)
+        valid_w = kept.take("payload.valid", (stack, G, S), bool,
+                            fill=mark_valid if exact else False,
+                            tag=per if exact else None)
+        if not exact:
+            valid_w[:windows] = False
+            mark_valid(valid_w)
+        planes = []
+        for i, (c, x_s) in enumerate(zip(consts, vals)):
+            if c is not None:
+                # burst-uniform: a scalar a window; stacked, one fill
+                planes.append(kept.take(
+                    ("payload.leaf", i), (stack, G, S), np.int32,
+                    fill=lambda arr, c=c: arr[:windows].fill(c),
+                    tag=("uniform", int(c))) if self._scan else None)
+                continue
+            x_w = kept.take(("payload.leaf", i), (stack, G, S), np.int32,
+                            fill=0, tag=("varying", per if exact else None))
+            if not exact:
+                x_w[:windows] = 0
             if dense:
                 # window w is columns w*S.. of the operations as they
                 # lie, [nseg, per]: a strided copy a window and plane
-                varying = [(x_w, x_s.reshape(nseg, per)) for c, x_w, x_s
-                           in zip(consts, planes, vals) if c is None]
+                x_d = x_s.reshape(nseg, per)
                 for w in range(-(-per // S)):
                     k = min(S, per - w * S)
-                    valid_w[w, rows, :k] = True
-                    for x_w, x_s in varying:
-                        x_w[w, rows, :k] = x_s[:, w * S:w * S + k]
+                    x_w[w, rows, :k] = x_d[:, w * S:w * S + k]
             else:
-                for w in range(windows):
-                    valid_w[w][seg_groups] = \
-                        (w * S + np.arange(S))[None, :] < counts[:, None]
-                slot_of = rank - dispatch_round * S
-                for c, x_w, x_s in zip(consts, planes, vals):
-                    if c is None:
-                        x_w[dispatch_round, g_s, slot_of] = x_s
+                x_w[dispatch_round, g_s, slot_of] = x_s
+            planes.append(x_w)
+        if stage is not None:
+            stage = stage.then(
+                "bulk.dispatch", bytes=rg._m_staged_bytes.value - staged)
+        if self._scan:
             _scan = _deep_scan_program(
                 rg.config, onehot=rg.mesh is not None, donate=rg.donate)
             rg._key, key = jax.random.split(rg._key)
-            if stage is not None:
-                stage = stage.then(
-                    "bulk.dispatch", bytes=rg._m_staged_bytes.value - staged)
             (rg.state, resbuf, valbuf, rndbuf, evflag, evs, tels) = _scan(
                 rg.state, resbuf, valbuf, rndbuf, evflag, base_dev,
-                rg._note_stage(Submits(
-                    opcode=op_w, a=a_w, b=b_w, c=c_w, tag=tagl_w,
-                    valid=valid_w)), deliver, key)
-            r = W_total
+                rg._note_stage(Submits(*planes, tag=tagl_w, valid=valid_w)),
+                deliver, key)
+            r = stack
             ev_stash.append(evs)   # stacked [W, ...] leaves
             if rg.telemetry is not None and tels is not None:
                 tel_stash.append(tels)  # stacked [W, G] leaves
         else:
-            if stage is not None:
-                stage = stage.then(
-                    "bulk.dispatch", bytes=rg._m_staged_bytes.value - staged)
             for w in range(windows):
-                want, _, pos, slots = window(w * S)
-                tagl[seg_groups, 0] = (seg_base + w * S + 1) \
-                    .astype(np.int32)
-                vnp = np.zeros((G, S), bool)
-                vnp[seg_groups] = np.arange(S)[None, :] < want[:, None]
-                dispatch(tagl.copy(), vnp, payload_leaves(pos, slots))
+                dispatch(tagl_w[w], valid_w[w], tuple(
+                    c if c is not None else x_w[w]
+                    for c, x_w in zip(consts, planes)))
             for _ in range(3):  # settle: replicate + commit + report lag
-                dispatch(*_idle[:2], _idle[2])
+                settle()
         if stage is not None:
             stage = stage.then("bulk.wait", rounds=r)
         harvest()
@@ -911,20 +1089,29 @@ class BulkDriver:
                     f"{max_rounds} rounds (fault-free liveness assumption"
                     f" violated? use the queue-managed path under faults); "
                     f"stream cursors resynced from the device")
-            # reduceat on bool would logical-or, not count — cast first
+            # each segment's next <=S operations past its resolved prefix
+            # (reduceat on bool would logical-or, not count — cast first)
             fu = np.add.reduceat(resolved.astype(np.int64), starts)
-            want, segs, pos, slots = window(fu)
-            tagl[:, 0] = 0
+            want = np.clip(counts - fu, 0, S)
+            segs = np.flatnonzero(want > 0)
+            reps = want[segs]
+            slots = np.arange(reps.sum()) \
+                - np.repeat(np.cumsum(reps) - reps, reps)
+            pos = np.repeat((starts + fu)[segs], reps) + slots
+            tagl = np.zeros((G, 1), np.int32)
             tagl[seg_groups[segs], 0] = (seg_base[segs] + fu[segs] + 1) \
                 .astype(np.int32)
             vnp = np.zeros((G, S), bool)
             vnp[seg_groups] = np.arange(S)[None, :] < want[:, None]
-            leaves = payload_leaves(pos, slots)
+            leaves = tuple(
+                c if c is not None else _scatter(G, S, g_s[pos], slots,
+                                                 x_s[pos])
+                for c, x_s in zip(consts, vals))
             if stage is not None:   # (a pass puts no accumulator)
                 stage = stage.then("bulk.dispatch", phase=2)
-            dispatch(tagl.copy(), vnp, leaves)
-            dispatch(*_idle[:2], _idle[2])
-            dispatch(*_idle[:2], _idle[2])
+            dispatch(tagl, vnp, leaves)
+            settle()
+            settle()
             if stage is not None:
                 stage = stage.then("bulk.wait", rounds=3, phase=2)
             harvest()
@@ -937,12 +1124,11 @@ class BulkDriver:
         rg.rounds += r
         rg.metrics.counter("ops_committed").inc(n)
         if order is not None:   # back to submission order
-            unsorted = []
-            for x in (results, dispatch_round, resolve_round):
-                out = np.zeros(n, np.int64)
+            for out, x in zip(outs, (results, resolve_round,
+                                     dispatch_round)):
                 out[order] = x
-                unsorted.append(out)
-            results, dispatch_round, resolve_round = unsorted
+            results, resolve_round, dispatch_round = outs
+        kept.sweep()
         return BulkResult(results=results, rounds=r,
                           wall_s=time.perf_counter() - t0,
                           dispatch_round=dispatch_round,

@@ -401,6 +401,8 @@ class RaftGroups:
         # of them in which every group sent the same count (models/bulk.py)
         self._m_bulk_grouped = self.metrics.counter("bulk_grouped_drives")
         self._m_bulk_dense = self.metrics.counter("bulk_dense_drives")
+        self._m_bulk_host = self.metrics.counter("bulk_host_bytes")
+        self._m_bulk_kept = self.metrics.counter("bulk_kept_bytes")
         self._m_settle_rounds = self.metrics.counter("query_settle_rounds")
         self._m_events_ingested = self.metrics.counter("events_ingested")
         # read windows evaluated (every one), and those of them whose rows
@@ -733,10 +735,35 @@ class RaftGroups:
         # straight from host memory to each device's block
         return jax.device_put(arr, NamedSharding(self.mesh, spec))
 
-    def _fetch_acc(self, arrays: Any) -> Any:
+    @staticmethod
+    def _local_block(x: Any, out: np.ndarray | None = None) -> np.ndarray:
+        """This process's contiguous block of a group-sharded array, its
+        shards in the order of their group-axis offset: copied shard by
+        shard into ``out`` where the caller keeps an array of the block's
+        shape (the deep drive's kept accumulators), else into a fresh one."""
+        shards = sorted(x.addressable_shards,
+                        key=lambda s: s.index[0].start or 0)
+        if out is None:
+            return np.concatenate([np.asarray(s.data) for s in shards],
+                                  axis=0)
+        row = 0
+        for s in shards:
+            block = np.asarray(s.data)
+            out[row:row + len(block)] = block
+            row += len(block)
+        return out
+
+    def _fetch_acc(self, arrays: Any, into: tuple = ()) -> Any:
         """Fetch a pytree of group-leading device arrays to host numpy
-        (this process's local block on multihost)."""
-        return self._note_fetch(jax.device_get(arrays))
+        (this process's local block on multihost), in one transfer.
+        ``into``: host arrays that receive the first leaves of ``arrays``,
+        in their order, in place of arrays allocated for them."""
+        leaves, tree = jax.tree.flatten(arrays)
+        for leaf in leaves[:len(into)]:     # (device_get starts its own)
+            leaf.copy_to_host_async()
+        host = [self._local_block(x, out) for x, out in zip(leaves, into)]
+        return self._note_fetch(tree.unflatten(
+            host + jax.device_get(leaves[len(host):])))
 
     def _deep_fn(self) -> Any:
         """The jitted ``deep_step`` used by the deep drive. One-hot

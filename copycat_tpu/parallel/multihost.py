@@ -32,6 +32,7 @@ over a loopback coordinator on the CPU backend).
 
 from __future__ import annotations
 
+import itertools
 from typing import Any
 
 import jax
@@ -170,14 +171,6 @@ class MultiHostRaftGroups(RaftGroups):
         return jax.make_array_from_process_local_data(
             self._dl_sharding, np.ascontiguousarray(np.asarray(deliver)))
 
-    @staticmethod
-    def _local_block(x) -> np.ndarray:
-        """This process's contiguous block of a group-sharded global
-        array (shards ordered by their group-axis offset)."""
-        shards = sorted(x.addressable_shards,
-                        key=lambda s: s.index[0].start or 0)
-        return np.concatenate([np.asarray(s.data) for s in shards], axis=0)
-
     def _fetch_outputs(self, raw):
         # overlap the D2H transfers (same rationale as the base hook:
         # lazy per-array fetches each pay a full round-trip), then
@@ -220,11 +213,14 @@ class MultiHostRaftGroups(RaftGroups):
             NamedSharding(self.mesh, spec),
             self._note_stage(np.ascontiguousarray(arr)))
 
-    def _fetch_acc(self, arrays):
-        for leaf in jax.tree.leaves(arrays):
+    def _fetch_acc(self, arrays, into=()):
+        leaves, tree = jax.tree.flatten(arrays)
+        for leaf in leaves:
             for s in leaf.addressable_shards:
                 s.data.copy_to_host_async()
-        return self._note_fetch(jax.tree.map(self._local_block, arrays))
+        return self._note_fetch(tree.unflatten([
+            self._local_block(x, out)
+            for x, out in itertools.zip_longest(leaves, into)]))
 
     def _deep_fn(self):
         if self._deep_jit is None:

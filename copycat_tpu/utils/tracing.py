@@ -115,8 +115,8 @@ root and ``bulk.admit`` only.
 
 - ``bulk.drive`` — ``BulkDriver.drive()`` entered -> its ``BulkResult``
   returned (``n``, ``rounds``, ``windows``, ``scan``).
-- ``bulk.admit`` [bulk.drive] — ``drive()`` entered -> ``_drive_deep``
-  entered (the arguments as arrays of the drive's length).
+- ``bulk.admit`` [bulk.drive] — ``drive()`` entered -> the plan begins
+  (the arguments as arrays of the drive's length).
 - ``bulk.plan`` [bulk.drive] — -> the accumulators about to be staged
   (starts, counts; ``segments``, and ``plan``: ``"sorted"`` where
   ``groups`` was not in group order and the drive sorted, ``"grouped"``
@@ -133,14 +133,18 @@ root and ``bulk.admit`` only.
 - ``bulk.harvest`` [bulk.drive] — -> every operation known resolved or
   not (``resolved``).
 - ``bulk.return`` [bulk.drive] — -> ``_drive_deep`` returned (back to
-  submission order where the drive sorted, the ``BulkResult`` built, the
-  drive's arrays freed).
+  submission order where the drive sorted, the ``BulkResult`` built;
+  ``host``: bytes of the arrays the drive took from its kept set or
+  made, ``kept``: those of them that were there already).
 
 ``engine.staged_bytes`` (``RaftGroups._note_stage``) counts the bytes of
 the host arrays such a drive hands the device, beside
 ``engine.fetch_bytes`` for what it takes back;
 ``engine.bulk_grouped_drives`` and ``engine.bulk_dense_drives`` count the
-drives whose ``plan`` was not ``"sorted"``, and was ``"dense"``.
+drives whose ``plan`` was not ``"sorted"``, and was ``"dense"``;
+``engine.bulk_host_bytes`` and ``engine.bulk_kept_bytes`` the bytes of the
+host arrays deep drives took by name and those that were kept from an
+earlier drive (``models/bulk.py``, ``_KeptArrays``).
 
 :meth:`Tracer.report` is the whole-window account (docs/OBSERVABILITY.md
 "The window report"): per-name aggregates that do not depend on what the
