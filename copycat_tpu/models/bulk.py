@@ -148,7 +148,7 @@ class _KeptArrays:
     """The deep drive's host arrays of an operation's or a group's size,
     kept from drive to drive.
 
-    A drive of 12.8M operations uses 1.3 GB of them, each above the 32 MB
+    A drive of 12.8M operations uses 1.1 GB of them, each above the 32 MB
     up to which glibc recycles a freed block: allocated a drive, each is
     mapped afresh and faulted in page by page again, which was most of a
     drive (PERF.md §5). So the driver owns one set. An array is taken by
@@ -711,6 +711,17 @@ class BulkDriver:
         the payload as the caller passed it, included where it has to be
         converted); a straggler pass allocates its own.
 
+        The transfers run beside the host's passes. Out: an array starts
+        across the link the moment its bytes exist (``_stage_acc``: the
+        seeds first, then in scan mode each stacked plane as it is
+        written), and the runtime reads the host array after the put has
+        returned, so a kept array is not written again before the program
+        that takes its copy has run: the next drive's writes come after
+        this drive's harvest, which has waited for that program's outputs.
+        Back: the accumulators' copies are asked for with the last call
+        (``_ask_acc``) and harvested a chip's block at a time, each from
+        the runtime's own host copy; nothing is assembled.
+
         ``stage`` is the drive's open span while the tracer is on
         (``bulk.admit``): every stage from here on closes into the next,
         and a straggler phase records its stages again with ``phase=2``.
@@ -735,6 +746,8 @@ class BulkDriver:
             np.copyto(out, x, casting="unsafe")
             return out
 
+        # (a leaf broadcast from one value is uniform by construction)
+        scalar = tuple(np.size(x) == 1 for x in leaves)
         vals = tuple(admitted(i, x) for i, x in enumerate(leaves))
         if stage is not None:
             stage = stage.then("bulk.plan")
@@ -759,7 +772,8 @@ class BulkDriver:
         mask[:1] = True
         np.not_equal(g_s[1:], g_s[:-1], out=mask[1:])
         starts = np.flatnonzero(mask)
-        counts = np.diff(np.append(starts, n))
+        edges = np.append(starts, n)
+        counts = np.diff(edges)
         seg_groups = g_s[starts]
         nseg = starts.size
         dense = bool(order is None and n and counts.min() == counts.max())
@@ -859,11 +873,6 @@ class BulkDriver:
         base = kept.take("stream.base", (G,), np.int32)
         np.copyto(base, rg._stream_count, casting="unsafe")
         base_dev = rg._stage_acc(base)
-        fetched = tuple(
-            kept.take(name, (G, Bpad), dtype)
-            for name, dtype in (("fetched.result", np.int32),
-                                ("fetched.valid", bool),
-                                ("fetched.round", np.int32)))
         _deep = rg._deep_fn()
 
         # burst-uniform payload leaves travel as SCALARS (zero H2D bytes);
@@ -872,11 +881,15 @@ class BulkDriver:
         # global sharded array from each process's local block, and a
         # scalar has no local block (payload uniformity is also a
         # per-process fact the other processes can't see).
-        def _const(x):
-            return (np.int32(x[0])
-                    if n and np.equal(x, x[0], out=mask).all() else None)
+        # A leaf that varies says so in its head (the mixed pattern differs
+        # inside its first 16 entries): only a leaf that may be uniform
+        # pays the whole pass that proves it.
+        def _const(x, known):
+            uniform = n and (known or (x[:16] == x[0]).all()
+                             and np.equal(x, x[0], out=mask).all())
+            return np.int32(x[0]) if uniform else None
 
-        consts = (None,) * 4 if multi else tuple(map(_const, vals))
+        consts = (None,) * 4 if multi else tuple(map(_const, vals, scalar))
         # telemetry stash: per-round [G] delta blocks kept ON DEVICE and
         # fetched with the accumulator harvest — the blind phase stays
         # one transfer per drive even with the flight recorder on
@@ -924,20 +937,20 @@ class BulkDriver:
 
         def harvest() -> None:
             """ONE fetch of the [G,B] accumulators (+ telemetry, + the
-            rare event leaves)."""
+            rare event leaves), harvested a chip's block at a time: the
+            copies are asked for here, with the last call just made, so
+            each block leaves as its chip's program ends, and a block's
+            rows are written from its own host copy while the later
+            blocks still cross."""
             nonlocal evflag, tel_ingested, stage
+            asked = rg._ask_acc((resbuf, valbuf, rndbuf, evflag))
             if stage is not None:
                 # while the tracer is on the device's share is the
                 # wait's, not the fetch's; off, the fetch waits
                 jax.block_until_ready((resbuf, valbuf, rndbuf, evflag))
                 stage = stage.then("bulk.fetch", **phase)
                 fetched_bytes = rg._m_fetch_bytes.value
-            res_np, val_np, rnd_np, ev, tels = rg._fetch_acc(
-                (resbuf, valbuf, rndbuf, evflag, tel_stash), into=fetched)
-            if stage is not None:
-                stage = stage.then(
-                    "bulk.harvest",
-                    bytes=rg._m_fetch_bytes.value - fetched_bytes, **phase)
+            tels = rg._fetch_acc(tel_stash, asked=asked)
             for tel in tels:
                 if np.asarray(tel.elections_started).ndim == 2:
                     w = int(np.asarray(tel.elections_started).shape[0])
@@ -948,16 +961,33 @@ class BulkDriver:
                     rg.telemetry.ingest(tel, rounds0 + tel_ingested)
                     tel_ingested += 1
             tel_stash.clear()
-            if dense:
+            # a block holds the rows row.. of the groups, so the segments
+            # lo..hi of seg_groups (sorted) and the operations between
+            # their starts, whatever the plan
+            *accs, flags = asked
+            row = lo = 0
+            for blocks in zip(*accs):
+                res_np, val_np, rnd_np = map(np.asarray, blocks)
+                if not row and stage is not None:   # the first has arrived
+                    stage = stage.then(
+                        "bulk.harvest",
+                        bytes=rg._m_fetch_bytes.value - fetched_bytes,
+                        **phase)
+                height = len(val_np)
+                hi = lo + int(np.searchsorted(seg_groups[lo:], row + height))
+                ops = slice(edges[lo], edges[hi])
+                here = (slice(None) if hi - lo == height
+                        else seg_groups[lo:hi] - row)
+                if not dense:
+                    colm = np.arange(Bpad)[None, :] < counts[lo:hi, None]
                 for out, acc in ((resolved, val_np), (results, res_np),
                                  (resolve_round, rnd_np)):
-                    out.reshape(nseg, per)[:] = acc[rows, :per]
-            else:
-                colm = np.arange(Bpad)[None, :] < counts[:, None]
-                resolved[:] = val_np[seg_groups][colm]
-                results[:] = res_np[seg_groups][colm]
-                resolve_round[:] = rnd_np[seg_groups][colm]
-            if ev.any():
+                    if dense:
+                        out[ops].reshape(hi - lo, per)[:] = acc[here, :per]
+                    else:
+                        out[ops] = acc[here][colm]
+                row, lo = row + height, hi
+            if any(np.asarray(flag).any() for flag in flags):
                 # rare path (session-event ops in the burst): fetch the
                 # stashed per-round event leaves and ingest with seq
                 # dedup. Local-only decision — the fetch reads only this
@@ -990,12 +1020,19 @@ class BulkDriver:
                 "deep_scan)")
         # (+ replicate/commit/report settle)
         stack = windows + 3 if self._scan else windows
+        # The scan takes each stacked plane as a device array, put the
+        # moment its windows are written and cut by groups as the scan
+        # takes it, so that one plane crosses while the next is copied;
+        # dispatch mode hands a window's host rows to that window's call.
+        put = partial(rg._stage_acc, axis=1) if self._scan else (
+            lambda plane: plane)
         tagl_w = kept.take("payload.tag", (stack, G, 1), np.int32, fill=0)
         if not exact:
             tagl_w[:windows] = 0
         for w in range(windows):
             tagl_w[w, seg_groups, 0] = (seg_base + w * S + 1) \
                 .astype(np.int32)
+        tagl_w = put(tagl_w)
 
         def mark_valid(arr):
             if dense:
@@ -1012,14 +1049,19 @@ class BulkDriver:
         if not exact:
             valid_w[:windows] = False
             mark_valid(valid_w)
-        planes = []
-        for i, (c, x_s) in enumerate(zip(consts, vals)):
+        valid_w = put(valid_w)
+        planes: list = [None] * 4
+        # (a uniform leaf's plane is a kept constant with nothing to
+        # write: those cross first, under the copies of the others)
+        for i in sorted(range(4), key=lambda i: consts[i] is None):
+            c, x_s = consts[i], vals[i]
             if c is not None:
                 # burst-uniform: a scalar a window; stacked, one fill
-                planes.append(kept.take(
-                    ("payload.leaf", i), (stack, G, S), np.int32,
-                    fill=lambda arr, c=c: arr[:windows].fill(c),
-                    tag=("uniform", int(c))) if self._scan else None)
+                if self._scan:
+                    planes[i] = put(kept.take(
+                        ("payload.leaf", i), (stack, G, S), np.int32,
+                        fill=lambda arr, c=c: arr[:windows].fill(c),
+                        tag=("uniform", int(c))))
                 continue
             x_w = kept.take(("payload.leaf", i), (stack, G, S), np.int32,
                             fill=0, tag=("varying", per if exact else None))
@@ -1034,7 +1076,7 @@ class BulkDriver:
                     x_w[w, rows, :k] = x_d[:, w * S:w * S + k]
             else:
                 x_w[dispatch_round, g_s, slot_of] = x_s
-            planes.append(x_w)
+            planes[i] = put(x_w)
         if stage is not None:
             stage = stage.then(
                 "bulk.dispatch", bytes=rg._m_staged_bytes.value - staged)
@@ -1044,8 +1086,7 @@ class BulkDriver:
             rg._key, key = jax.random.split(rg._key)
             (rg.state, resbuf, valbuf, rndbuf, evflag, evs, tels) = _scan(
                 rg.state, resbuf, valbuf, rndbuf, evflag, base_dev,
-                rg._note_stage(Submits(*planes, tag=tagl_w, valid=valid_w)),
-                deliver, key)
+                Submits(*planes, tag=tagl_w, valid=valid_w), deliver, key)
             r = stack
             ev_stash.append(evs)   # stacked [W, ...] leaves
             if rg.telemetry is not None and tels is not None:

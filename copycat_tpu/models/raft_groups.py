@@ -403,6 +403,11 @@ class RaftGroups:
         self._m_bulk_dense = self.metrics.counter("bulk_dense_drives")
         self._m_bulk_host = self.metrics.counter("bulk_host_bytes")
         self._m_bulk_kept = self.metrics.counter("bulk_kept_bytes")
+        # bytes the deep drive's hooks hand the device or fetch from it,
+        # and those of them whose transfer began ahead of the program's
+        # call or of the fetch (_stage_acc, _ask_acc)
+        self._m_bulk_link = self.metrics.counter("bulk_link_bytes")
+        self._m_bulk_early = self.metrics.counter("bulk_early_bytes")
         self._m_settle_rounds = self.metrics.counter("query_settle_rounds")
         self._m_events_ingested = self.metrics.counter("events_ingested")
         # read windows evaluated (every one), and those of them whose rows
@@ -670,9 +675,10 @@ class RaftGroups:
         too, ends in this) or the payload leaves that travel as
         arguments of the deep program (``models/bulk.py`` calls it where
         it hands them over). A scalar leaf counts 0."""
-        self._m_staged_bytes.inc(sum(
-            x.nbytes for x in jax.tree.leaves(host)
-            if getattr(x, "ndim", 0)))
+        nbytes = sum(x.nbytes for x in jax.tree.leaves(host)
+                     if getattr(x, "ndim", 0))
+        self._m_staged_bytes.inc(nbytes)
+        self._m_bulk_link.inc(nbytes)
         return host
 
     def _fetch_outputs(self, raw: PackedOutputs) -> StepOutputs:
@@ -721,49 +727,70 @@ class RaftGroups:
         compiles/launches the same program."""
         return v
 
-    def _stage_acc(self, arr: np.ndarray) -> Any:
-        """Host numpy -> device array for a deep-drive accumulator whose
-        leading axis is groups. On a single-host mesh the group axis is
-        sharded like the state (placement-only, so the deep_step scatter
-        stays shard-local — parallel/mesh.py rule)."""
-        self._note_stage(arr)
+    def _stage_acc(self, arr: np.ndarray, axis: int = 0) -> Any:
+        """Host numpy -> device array for what a deep drive puts ahead of
+        its program's call: an accumulator, whose leading axis is groups,
+        or a stacked ``[stack, G, .]`` payload plane (``axis=1``). The
+        transfer starts here, so the host goes on while it crosses; the
+        caller does not write ``arr`` again before the program that takes
+        the copy has run. On a single-host mesh the group axis is sharded
+        like the state (placement-only, so the deep_step scatter stays
+        shard-local — parallel/mesh.py rule)."""
+        self._m_bulk_early.inc(self._note_stage(arr).nbytes)
         if self.mesh is None:
             return jax.device_put(arr)
         from jax.sharding import NamedSharding, PartitionSpec as P
         g_ax = "groups" if "groups" in self.mesh.axis_names else None
-        spec = P(g_ax, *([None] * (arr.ndim - 1)))
+        spec = [None] * arr.ndim
+        spec[axis] = g_ax
         # straight from host memory to each device's block
-        return jax.device_put(arr, NamedSharding(self.mesh, spec))
+        return jax.device_put(arr, NamedSharding(self.mesh, P(*spec)))
 
     @staticmethod
-    def _local_block(x: Any, out: np.ndarray | None = None) -> np.ndarray:
-        """This process's contiguous block of a group-sharded array, its
-        shards in the order of their group-axis offset: copied shard by
-        shard into ``out`` where the caller keeps an array of the block's
-        shape (the deep drive's kept accumulators), else into a fresh one."""
-        shards = sorted(x.addressable_shards,
-                        key=lambda s: s.index[0].start or 0)
-        if out is None:
-            return np.concatenate([np.asarray(s.data) for s in shards],
-                                  axis=0)
-        row = 0
-        for s in shards:
-            block = np.asarray(s.data)
-            out[row:row + len(block)] = block
-            row += len(block)
-        return out
+    def _blocks(x: Any) -> list:
+        """The single-device arrays of this process's shards of a
+        group-sharded array, in the order of their group-axis offset."""
+        return [s.data for s in sorted(
+            x.addressable_shards, key=lambda s: s.index[0].start or 0)]
 
-    def _fetch_acc(self, arrays: Any, into: tuple = ()) -> Any:
+    @classmethod
+    def _local_block(cls, x: Any) -> np.ndarray:
+        """This process's contiguous block of a group-sharded array."""
+        return np.concatenate([np.asarray(b) for b in cls._blocks(x)],
+                              axis=0)
+
+    def _ask_acc(self, arrays: Any) -> list[list]:
+        """Start the device->host copies of group-sharded device arrays
+        without waiting for any: each chip's block leaves as the program
+        that writes it ends. Returns each array's :meth:`_blocks`, which
+        the caller reads one at a time (``np.asarray`` of a block waits
+        for that block's copy alone and is the runtime's own host copy)
+        and hands to :meth:`_fetch_acc` as ``asked``."""
+        asked = [self._blocks(x) for x in arrays]
+        for blocks in asked:
+            for block in blocks:
+                block.copy_to_host_async()
+        return asked
+
+    def _to_host(self, leaves: list) -> list:
+        """Device arrays -> host numpy, whole (this process's local block
+        of each on multihost)."""
+        return jax.device_get(leaves)
+
+    def _fetch_acc(self, arrays: Any, asked: Any = ()) -> Any:
         """Fetch a pytree of group-leading device arrays to host numpy
         (this process's local block on multihost), in one transfer.
-        ``into``: host arrays that receive the first leaves of ``arrays``,
-        in their order, in place of arrays allocated for them."""
+        ``asked``: what :meth:`_ask_acc` returned for further arrays of
+        the same fetch, read block by block by the caller: their bytes
+        are counted here, with the one fetch they belong to."""
         leaves, tree = jax.tree.flatten(arrays)
-        for leaf in leaves[:len(into)]:     # (device_get starts its own)
-            leaf.copy_to_host_async()
-        host = [self._local_block(x, out) for x, out in zip(leaves, into)]
-        return self._note_fetch(tree.unflatten(
-            host + jax.device_get(leaves[len(host):])))
+        early = sum(x.nbytes for x in jax.tree.leaves(asked))
+        host = self._to_host(leaves)
+        self._note_fetch((host, asked))
+        self._m_bulk_early.inc(early)
+        self._m_bulk_link.inc(
+            early + sum(getattr(x, "nbytes", 0) for x in host))
+        return tree.unflatten(host)
 
     def _deep_fn(self) -> Any:
         """The jitted ``deep_step`` used by the deep drive. One-hot

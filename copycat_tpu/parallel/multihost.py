@@ -32,7 +32,6 @@ over a loopback coordinator on the CPU backend).
 
 from __future__ import annotations
 
-import itertools
 from typing import Any
 
 import jax
@@ -209,18 +208,14 @@ class MultiHostRaftGroups(RaftGroups):
 
     def _stage_acc(self, arr: np.ndarray):
         spec = P("groups", *([None] * (arr.ndim - 1)))
+        arr = self._note_stage(np.ascontiguousarray(arr))
+        self._m_bulk_early.inc(arr.nbytes)
         return jax.make_array_from_process_local_data(
-            NamedSharding(self.mesh, spec),
-            self._note_stage(np.ascontiguousarray(arr)))
+            NamedSharding(self.mesh, spec), arr)
 
-    def _fetch_acc(self, arrays, into=()):
-        leaves, tree = jax.tree.flatten(arrays)
-        for leaf in leaves:
-            for s in leaf.addressable_shards:
-                s.data.copy_to_host_async()
-        return self._note_fetch(tree.unflatten([
-            self._local_block(x, out)
-            for x, out in itertools.zip_longest(leaves, into)]))
+    def _to_host(self, leaves):
+        self._ask_acc(leaves)
+        return [self._local_block(x) for x in leaves]
 
     def _deep_fn(self):
         if self._deep_jit is None:

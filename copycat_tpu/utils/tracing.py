@@ -123,15 +123,19 @@ root and ``bulk.admit`` only.
   where it was, ``"dense"`` where every group also sent the same count
   and no index per operation was built).
 - ``bulk.stage`` [bulk.drive] — -> the program about to be called (the
-  accumulators put, the stacked payload built; ``bytes`` put by
-  ``_stage_acc``).
-- ``bulk.dispatch`` [bulk.drive] — -> the scan's one call returned, or
-  the whole loop of windows in dispatch mode (``rounds``).
-- ``bulk.wait`` [bulk.drive] — -> the accumulators ready (a
-  ``block_until_ready`` made only while the tracer is on).
-- ``bulk.fetch`` [bulk.drive] — -> ``_fetch_acc`` returned (``bytes``).
+  accumulators put, the stacked payload built and, in scan mode, each
+  plane put as it is written; ``bytes`` put by ``_stage_acc``).
+- ``bulk.dispatch`` [bulk.drive] — -> the scan's one call returned (over
+  device arrays: the call alone), or the whole loop of windows in
+  dispatch mode (``rounds``).
+- ``bulk.wait`` [bulk.drive] — -> the accumulators ready (their copies
+  to the host asked for at its head; a ``block_until_ready`` made only
+  while the tracer is on).
+- ``bulk.fetch`` [bulk.drive] — -> the first chip's block of the
+  accumulators has arrived (the drive's one counted fetch; ``bytes``).
 - ``bulk.harvest`` [bulk.drive] — -> every operation known resolved or
-  not (``resolved``).
+  not: the walk over the chips' blocks, each written from its own host
+  copy while the later ones cross (``resolved``).
 - ``bulk.return`` [bulk.drive] — -> ``_drive_deep`` returned (back to
   submission order where the drive sorted, the ``BulkResult`` built;
   ``host``: bytes of the arrays the drive took from its kept set or
@@ -144,7 +148,13 @@ the host arrays such a drive hands the device, beside
 drives whose ``plan`` was not ``"sorted"``, and was ``"dense"``;
 ``engine.bulk_host_bytes`` and ``engine.bulk_kept_bytes`` the bytes of the
 host arrays deep drives took by name and those that were kept from an
-earlier drive (``models/bulk.py``, ``_KeptArrays``).
+earlier drive (``models/bulk.py``, ``_KeptArrays``);
+``engine.bulk_link_bytes`` every byte a deep drive hands the device or
+fetches from it (``engine.staged_bytes`` and its ``_fetch_acc``s) and
+``engine.bulk_early_bytes`` those of them whose transfer began ahead of
+the stage that would otherwise start it (put by ``_stage_acc`` before the
+program's call; read block by block from copies ``_ask_acc`` started at
+dispatch).
 
 :meth:`Tracer.report` is the whole-window account (docs/OBSERVABILITY.md
 "The window report"): per-name aggregates that do not depend on what the

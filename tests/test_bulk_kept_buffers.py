@@ -6,6 +6,13 @@ with one driver and its twin of the same seed with a fresh driver a drive, and
 holds the two to each other bit for bit: what came back, what was handed to
 the device (every accumulator seed and payload leaf, slots no operation fills
 included) and the state left behind.
+
+Since PR 47 the arrays cross while the host works: the scan's planes are put
+as they are written and the accumulators harvested a chip's block at a time
+from the copies asked for at dispatch. The cases at the end hold that walk to
+a plain assembled fetch written here, the two counters of what crossed early
+to the bytes by shape, and the kept planes to the rule that none is written
+while its put may still read it.
 """
 
 import inspect
@@ -74,7 +81,7 @@ class Twins:
     """Two engines of one seed: ``drive`` drives the first with the one
     driver under test and the second with a driver made for the drive."""
 
-    def __init__(self, scan, seed=46, mesh=False):
+    def __init__(self, scan, seed=46, mesh=False, assembled=False):
         self.scan, self.engines, self.staged = scan, [], ([], [])
         for log in self.staged:
             rg = device_plane(MONOTONE, seed=seed, mesh=make_mesh(
@@ -84,6 +91,9 @@ class Twins:
             rg._note_stage = lambda host, log=log, note=note: (
                 log.append(jax.tree.map(np.array, host)), note(host))[1]
             self.engines.append(rg)
+        if assembled:   # the twin's harvest walks one block: the whole
+            self.engines[1]._ask_acc = lambda arrays: [
+                [np.asarray(jax.device_get(x))] for x in arrays]
         self.rg = self.engines[0]
         self.driver = BulkDriver(self.rg, deep_scan=scan)
         self._bytes = [self.rg.metrics.counter(f"bulk_{name}_bytes")
@@ -303,19 +313,15 @@ def test_the_mark_the_benchmarks_test_reads_is_the_one_staged():
     assert (seeds[1] == bulk._UNRESOLVED).all()
 
 
-def test_the_benchmarks_share_reads_the_two_counters():
-    """``bulk.kept_bytes_share`` is data alone: its file on the reducer
-    ``program_report`` and its entry in ``BENCHMARK.json``. Read here from
-    the window report of three traced drives: the first makes its arrays,
-    the next two keep all of them."""
+def share_metric(name):
+    """A share of two counters that is data alone: its file on the reducer
+    ``program_report`` and its entry in ``BENCHMARK.json``, wherever in the
+    list a later PR left it. Returns the file and the reducer's module."""
     import importlib.util
     import json
     import os
 
-    from copycat_tpu.utils import tracing
-
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    name = "bulk.kept_bytes_share"
     spec = json.load(open(os.path.join(
         repo, "benchmarks", "layer_metrics", name + ".json")))
     (entry,) = [m for m in json.load(open(os.path.join(
@@ -328,6 +334,17 @@ def test_the_benchmarks_share_reads_the_two_counters():
     loader = importlib.util.spec_from_file_location("program_report", path)
     reducer = importlib.util.module_from_spec(loader)
     loader.loader.exec_module(reducer)
+    return spec, reducer
+
+
+def test_the_benchmarks_share_reads_the_two_counters():
+    """``bulk.kept_bytes_share`` is data alone: its file on the reducer
+    ``program_report`` and its entry in ``BENCHMARK.json``. Read here from
+    the window report of three traced drives: the first makes its arrays,
+    the next two keep all of them."""
+    from copycat_tpu.utils import tracing
+
+    spec, reducer = share_metric("bulk.kept_bytes_share")
     assert reducer.reduce_report({"counters": {}}, {}, spec) is None
 
     rg = device_plane(MONOTONE, seed=46)
@@ -350,3 +367,140 @@ def test_the_benchmarks_share_reads_the_two_counters():
     assert [s.meta["kept"] for s in spans] == [0, host // 3, host // 3]
     assert [s.meta["host"] for s in spans] == [host // 3] * 3
     assert reducer.reduce_report(report, {}, spec) == kept / host == 2 / 3
+
+
+# -- PR 47: the transfers beside the host passes ---------------------------
+
+#: bytes by shape (``tests/test_bulk_spans.py`` derives them): what a drive
+#: puts for its accumulators, what it fetches back, and the payload, stacked
+#: over every round for the scan, a window's rows of the one varying leaf,
+#: the valid plane and the tags with that window's call in dispatch mode
+ACCUMULATORS = G * (B * (4 + 1 + 4) + 1 + 4)
+FETCHED = G * (B * (4 + 1 + 4) + 1)
+PAYLOAD = {True: ROUNDS * G * (S * (4 * 4 + 1) + 4),
+           False: B // S * G * (S * (4 + 1) + 4) + 3 * G * (S + 4)}
+
+
+def some(groups):
+    return lambda: dense(B - 2, groups)
+
+
+@MODES
+@pytest.mark.parametrize("tier,second", [
+    ("sorted", ragged), ("grouped", some([0, 1, 6])),
+    ("dense", some([1, 3, 4, 6]))], ids=list(TIERS))
+def test_blocks_harvested_as_they_arrive_equal_an_assembled_fetch(
+        tier, second, scan):
+    """Over four virtual devices a drive's accumulators come back as four
+    blocks of two groups, each written into the results from its own host
+    copy; the twin's come back whole, fetched and assembled here. The first
+    drive of a fresh engine runs straggler passes, so harvests again; the
+    second leaves blocks with one segment of two rows, and with none."""
+    twins = Twins(scan, mesh=True, assembled=True)
+    res, _, _ = twins.drive(TIERS[tier]())
+    assert res.rounds > ROUNDS
+    del res
+    twins.drive(second())
+    twins.same_state()
+
+
+def test_one_device_is_one_block():
+    twins = Twins(scan=True, assembled=True)
+    for sub in (ragged(), dense(B - 2, [0, 3, 5]), shuffled()):
+        twins.drive(sub)
+    twins.same_state()
+
+
+@MODES
+def test_what_crossed_early_by_shape_and_one_fetch_a_drive(scan):
+    """``bulk_link_bytes`` is every byte a drive stages and fetches;
+    ``bulk_early_bytes`` those put ahead of the program's call (the seeds
+    always, the scan's planes too) or read from copies asked for at
+    dispatch (the accumulators). One counted fetch a drive."""
+    rg = device_plane(MONOTONE, seed=46, mesh=make_mesh(
+        devices=jax.devices()[:4]))
+    rg.wait_for_leaders()
+    driver = BulkDriver(rg, deep_scan=scan)
+    driver.drive(*dense())                      # (the leases warm)
+    counters = [rg.metrics.counter(name) for name in (
+        "bulk_link_bytes", "bulk_early_bytes", "staged_bytes",
+        "fetch_bytes", "fetches")]
+    before = [c.value for c in counters]
+    notes = []
+    note = rg._note_fetch
+    rg._note_fetch = lambda host: (notes.append(host), note(host))[1]
+    res = driver.drive(*dense())
+    assert res.rounds == ROUNDS and len(notes) == 1
+    link, early, staged, fetched, fetches = (
+        c.value - b for c, b in zip(counters, before))
+    assert (staged, fetched, fetches) \
+        == (ACCUMULATORS + PAYLOAD[scan], FETCHED, 1)
+    assert link == staged + fetched
+    assert early == link - (0 if scan else PAYLOAD[False])
+
+
+def test_no_kept_plane_is_written_while_its_put_may_read_it(monkeypatch):
+    """The runtime reads a host array after its put has returned (on the
+    chip a write made right after the return showed up on the device; on
+    the CPU a put may alias the array outright). So every array a drive
+    takes from its kept set to write is taken after the outputs of the
+    last drive's program are ready, which has then read every array put
+    for it, and at a drive's end the device's copies are what the host
+    arrays held when they were put."""
+    rg = device_plane(MONOTONE, seed=46)
+    rg.wait_for_leaders()
+    driver = BulkDriver(rg, deep_scan=True)
+    driver.drive(*dense())                      # (the leases warm)
+    put, stage_acc, take = [], rg._stage_acc, bulk._KeptArrays.take
+
+    def staging(arr, axis=0):
+        put.append((arr.copy(), stage_acc(arr, axis)))
+        return put[-1][1]
+
+    def taking(kept, name, *args, **kw):
+        if "payload" in str(name) or name == "stream.base":
+            assert all(x.is_ready() for x in jax.tree.leaves(rg.state)), name
+        return take(kept, name, *args, **kw)
+
+    rg._stage_acc = staging
+    monkeypatch.setattr(bulk._KeptArrays, "take", taking)
+    for k in range(3):
+        driver.drive(*dense(a=np.arange(G * B) % 5 + k))
+        planes = [(was, dev) for was, dev in put if was.ndim == 3]
+        assert len(planes) == 6
+        for was, dev in planes:     # (the scan donates no plane)
+            assert np.array_equal(was, np.asarray(dev))
+        put.clear()
+
+
+def test_the_benchmarks_share_reads_what_crossed_early():
+    """``bulk.early_bytes_share`` is data alone, as
+    ``bulk.kept_bytes_share`` is: 1.0 over scanned drives, whose every
+    byte starts across ahead of its stage, less in dispatch mode."""
+    from copycat_tpu.utils import tracing
+
+    spec, reducer = share_metric("bulk.early_bytes_share")
+    assert (spec["key"], spec["over"]) == (
+        ["counters", "engine.bulk_early_bytes"],
+        ["counters", "engine.bulk_link_bytes"])
+    # (the parent of the PR that added the counters: left out of the line)
+    assert reducer.reduce_report(
+        {"counters": {"engine.bulk_host_bytes": 1}}, {}, spec) is None
+
+    rg = device_plane(MONOTONE, seed=46)
+    rg.wait_for_leaders()
+    shares = []
+    for scan in (True, False):
+        driver = BulkDriver(rg, deep_scan=scan)
+        driver.drive(*dense())
+        tracing.TRACER.clear()
+        tracing.enable()
+        try:
+            driver.drive(*dense())
+        finally:
+            tracing.disable()
+        shares.append(reducer.reduce_report(
+            tracing.TRACER.report(), {}, spec))
+        tracing.TRACER.clear()
+    whole = ACCUMULATORS + FETCHED + PAYLOAD[False]
+    assert shares == [1.0, (whole - PAYLOAD[False]) / whole]
