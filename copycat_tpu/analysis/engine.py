@@ -55,7 +55,7 @@ BASELINE_FILE = ".copycheck-baseline.json"
 
 #: Scanned by default (repo-root-relative). Tests are exercised by
 #: pytest, not linted — their fixtures *seed* violations on purpose.
-DEFAULT_ROOTS = ("copycat_tpu", "bench.py", "__graft_entry__.py", "examples")
+DEFAULT_ROOTS = ("copycat_tpu", "__graft_entry__.py", "examples")
 
 
 
@@ -187,7 +187,7 @@ RULE_GROUPS: tuple[RuleGroup, ...] = (
         modules=("rules_registries.py",),
         run=lambda path, src, tree, ctx: (
             check_knob_registry(tree, path, ctx.knob_names)
-            # metric-registry is package-scoped: benches/examples at
+            # metric-registry is package-scoped: examples at
             # the repo root stage env for servers they build, not
             # metric planes
             + (check_metric_registry(tree, path, ctx.metric_catalog)

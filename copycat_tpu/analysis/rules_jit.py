@@ -5,7 +5,7 @@ impure a traced function touches — wall clocks, Python RNG, env reads,
 host callbacks — either silently bakes a trace-time constant into every
 execution (``time.time()`` at trace time is *one* number forever) or
 drags a host round-trip into the hot loop. The op-definition census
-(PERF.md round 8, ``parallel/scaling.py``) checks the *compiled* program
+(``parallel/scaling.py``) checks the *compiled* program
 for stray collectives at runtime; this rule is its static complement —
 the impurity never lands on a branch CI didn't trace.
 

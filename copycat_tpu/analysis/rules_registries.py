@@ -8,7 +8,7 @@ hand-maintained.
 - any direct ``os.environ.get("COPYCAT_X")`` / ``os.getenv`` /
   ``os.environ["COPYCAT_X"]`` *read* outside ``utils/knobs.py`` is
   flagged — typed access goes through the registry (env *writes* are
-  fine: benches stage knobs for servers they build);
+  fine: tests and examples stage knobs for servers they build);
 - any ``knobs.get_*("COPYCAT_X")`` naming an unregistered knob is
   flagged. The registered set is parsed from ``utils/knobs.py``'s AST
   (the ``_knob("NAME", ...)`` declarations) — linting never imports the

@@ -36,8 +36,8 @@ class DistributedAtomicLong(DistributedAtomicValue):
         register value so the unset (None) register reads as 0 but still
         compare-and-sets correctly. Submits the CAS directly through the
         flattened facade lane (one coroutine frame fewer per op than
-        going through :meth:`compare_and_set` — this loop IS the spi
-        bench's hot path)."""
+        going through :meth:`compare_and_set` — this loop IS the served
+        path's hot loop)."""
         if self._raw is self._UNSET:
             await self.get()
         while True:

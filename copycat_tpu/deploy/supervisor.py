@@ -222,7 +222,7 @@ class Supervisor(Managed):
     def _child_env(self) -> dict:
         env = dict(os.environ)
         # the repo layout must be importable from the child no matter
-        # where the supervisor was launched from (tests, bench, a
+        # where the supervisor was launched from (tests, a
         # checked-out tree without `pip install -e .`)
         root = os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))))
@@ -335,7 +335,7 @@ class Supervisor(Managed):
 
     async def wait_healthy(self, timeout: float = 60.0) -> None:
         """Block until every child's ``/healthz`` answers (fresh probes,
-        not the watch cadence) — the launch gate benches and tests use
+        not the watch cadence) — the launch gate tests and operators use
         before opening client load. Raises ``TimeoutError`` with the
         stragglers named."""
         deadline = time.monotonic() + timeout

@@ -165,8 +165,8 @@ class TopologySpec:
 
     def stats_addrs(self) -> dict[str, str]:
         """``{child name: stats host:port}`` for the whole topology —
-        what per-tier attribution (``bench compartment``), ``copycat-tpu
-        doctor`` and the supervisor's health watch scrape."""
+        what ``copycat-tpu doctor`` and the supervisor's health watch
+        scrape."""
         return {c.name: f"{c.address.rsplit(':', 1)[0]}:{c.stats_port}"
                 for c in self.children()}
 

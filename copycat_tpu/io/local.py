@@ -232,8 +232,8 @@ class LocalConnection(Connection):
         if result is None:
             return None
         # response leg: the peer SENDS, we receive — counted like the
-        # request leg so cross-transport attribution (local vs tcp in
-        # the spi bench) compares like with like
+        # request leg so cross-transport attribution (local vs tcp)
+        # compares like with like
         wire = peer._serializer.write(result)
         peer._m_frames_out.inc()
         peer._m_bytes_out.inc(len(wire))

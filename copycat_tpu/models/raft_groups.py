@@ -1534,8 +1534,7 @@ class RaftGroups:
     def device_snapshot(self) -> dict:
         """The ``device.*`` telemetry family as a mergeable snapshot
         dict (empty when telemetry is off). This is what ``/stats``
-        embeds, ``bench.py --metrics-json`` records, and
-        ``merge_snapshots`` folds across shards/processes."""
+        embeds and ``merge_snapshots`` folds across shards/processes."""
         if self.telemetry is None:
             return {}
         return self.telemetry.snapshot()

@@ -41,9 +41,7 @@ Contract (reference parity — Copycat client runtime semantics):
 Throughput: all sessions' pending commands flush as ONE bulk drive
 (deep mode on monotone engines: zero blocking fetches per round, one
 result fetch per flush), with per-op bookkeeping held to numpy slicing
-+ one dict update per op. Measured by the ``session`` bench scenario
-(BENCH_SCENARIOS.md); the round-5 target is ≥100k client-visible
-committed ops/s on one chip through THIS sessioned surface.
++ one dict update per op.
 """
 
 from __future__ import annotations

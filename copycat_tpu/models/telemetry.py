@@ -10,10 +10,10 @@ surfaces:
 
 - :class:`DeviceTelemetryHub` — a dedicated ``MetricsRegistry`` holding
   the ``device.*`` metric family (exported via ``/stats``, ``/metrics``,
-  ``copycat-tpu stats`` and ``bench.py --metrics-json``), plus per-group
+  ``copycat-tpu stats``), plus per-group
   cumulative arrays so multichip runs can attribute elections /
-  commit-advance per shard (``parallel/scaling.py``,
-  ``MultiHostRaftGroups.merged_device_snapshot``).
+  commit-advance per shard
+  (``MultiHostRaftGroups.merged_device_snapshot``).
 - :class:`FlightRecorder` — a bounded ring of timestamped events: one
   per fetch that observed protocol activity, plus every nemesis fault
   installation (``testing/nemesis.py`` writes into the same ring) and
@@ -407,7 +407,7 @@ class DeviceTelemetryHub:
 
     def per_group_totals(self) -> dict:
         """Cumulative per-group arrays (copies) — the shard-attribution
-        feed for ``parallel/scaling.py`` and multihost roll-ups."""
+        feed for multihost roll-ups."""
         return {k: v.copy() for k, v in self.per_group.items()}
 
     def shard_snapshots(self, n_shards: int) -> list[dict]:

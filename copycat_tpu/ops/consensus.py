@@ -307,7 +307,7 @@ def pin_partitionable_rng() -> None:
     slices each shard's block, which on a group-sharded mesh compiles to
     collective-permutes + all-reduces per ``random.randint`` — the
     election-timer draws alone put 22 all-reduces into the step and
-    broke the zero-collective contract (MULTICHIP_SCALING.md) on jax
+    broke the zero-collective contract (``parallel/scaling.py``) on jax
     builds that default the flag off; the partitionable form derives
     every shard's bits locally from the key.
 

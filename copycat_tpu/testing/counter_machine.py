@@ -1,13 +1,13 @@
-"""The replicated counter machine shared by the cluster-shaped benches
+"""The replicated counter machine shared by the cluster-shaped tests
 and the deployment plane's child processes (docs/DEPLOYMENT.md).
 
-Lives OUTSIDE ``bench.py`` on purpose: a deployed member/ingress process
+Jax-free on purpose: a deployed member/ingress process
 (``python -m copycat_tpu.deploy.child``) imports this module by machine
 spec (``copycat_tpu.testing.counter_machine:counter_machine``) to host
-the workload the compartment bench drives — importing ``bench.py`` for
-the class would drag jax and the engine stack into every child, and the
-serialization ids (940/941) must bind to exactly ONE class each, so the
-bench and the children must share this definition.
+the workload its driver sends, without dragging jax and the engine
+stack into every child, and the serialization ids (940/941) must bind
+to exactly ONE class each, so drivers and children share this
+definition.
 
 Import of this module registers the op types with the serializer — any
 process that decodes ``ClusterAdd`` frames (members, ingress proxies,

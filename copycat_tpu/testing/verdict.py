@@ -1,9 +1,9 @@
-"""Linearizability verdict at bench scale (BASELINE.md's "Jepsen pass").
+"""Linearizability verdict at scale (BASELINE.md's "Jepsen pass").
 
 The reference's claim to fame is external Jepsen verification
 (``/root/reference/README.md:8``); the in-tree Wing & Gong checker
 (:mod:`linearize`) covers it on small histories in tests. This runner
-produces the VERDICT ARTIFACT at bench scale: a ``RaftGroups`` batch of
+produces the VERDICT ARTIFACT at scale: a ``RaftGroups`` batch of
 ≥10k groups runs under a randomized nemesis (partitions, isolation,
 message loss) with client load, histories are recorded on a sample of
 groups across three resource models (register/counter, map, try-lock),

@@ -12,7 +12,7 @@ Two gates keep that true:
   is byte-identical to :func:`render_markdown` (regenerate with
   ``python -m copycat_tpu.utils.knobs``).
 
-Getters read ``os.environ`` live (no caching): tests and benches
+Getters read ``os.environ`` live (no caching): tests
 monkeypatch knobs mid-process and expect the next server/client built
 to see the change — exactly what the raw reads they replace did.
 
@@ -66,8 +66,6 @@ SECTIONS = (
     ("durability", "Snapshots & durability"),
     ("observability", "Observability & invariants"),
     ("client", "Client"),
-    ("bench", "Bench scenarios (`bench.py`)"),
-    ("scaling", "Multichip scaling driver"),
     ("verdict", "Linearizability verdict runner"),
 )
 _SECTION_KEYS = tuple(key for key, _ in SECTIONS)
@@ -104,7 +102,7 @@ _knob("COPYCAT_REPL_MAX_INFLIGHT", "int", None, default_doc="window×depth",
 _knob("COPYCAT_INGRESS_TIER", "bool", True,
       "`0` removes the standalone ingress/proxy tier: members refuse "
       "ingress-kind proxy traffic (single-group servers register no "
-      "ProxyRequest handler), topologies/benches deploy no ingress "
+      "ProxyRequest handler), topologies deploy no ingress "
       "processes — the in-server ingress path bit-identically "
       "(docs/DEPLOYMENT.md)", section="deploy")
 _knob("COPYCAT_DEPLOY_HEALTH_INTERVAL_S", "float", 1.0,
@@ -263,218 +261,6 @@ _knob("COPYCAT_EDGE_FLUSH_MS", "float", 10.0,
       "(state-based merge makes coalescing free); `0` flushes every "
       "event-loop turn", section="client")
 
-# --- bench -----------------------------------------------------------------
-_knob("COPYCAT_BENCH_SCENARIO", "str", "counter",
-      "scenario: `counter`/`election`/`map`/`map_read`/`lock`/`mixed`/"
-      "`host`/`host_read`/`session`/`spi`/`readmix`/`cluster`/`sharded`/"
-      "`apply`/`recovery`/`compartment`/`fanout`",
-      section="bench")
-_knob("COPYCAT_BENCH_GROUPS", "int", None,
-      default_doc="10000 (election: 1000)",
-      doc="Raft groups in the engine tensor", section="bench")
-_knob("COPYCAT_BENCH_PEERS", "int", 3, "peer lanes per group",
-      section="bench")
-_knob("COPYCAT_BENCH_LOG_SLOTS", "int", None,
-      default_doc="64 (mixed: 32)",
-      doc="log-ring slots per group", section="bench")
-_knob("COPYCAT_BENCH_ROUNDS", "int", 200, "engine rounds per repetition",
-      section="bench")
-_knob("COPYCAT_BENCH_REPEATS", "int", 5,
-      "best-of-N repetitions recorded", section="bench")
-_knob("COPYCAT_BENCH_SUBMIT_SLOTS", "int", 16,
-      "submit slots per group (append window / applies-per-round floor)",
-      section="bench")
-_knob("COPYCAT_BENCH_PALLAS", "raw", None,
-      default_doc="auto (TPU: on, CPU: off)",
-      doc="`1` forces the Pallas quorum-tally kernel, any other set "
-          "value forces the jnp path", section="bench")
-_knob("COPYCAT_BENCH_POOL_BUDGETS", "str", None,
-      default_doc="per-scenario",
-      doc="comma-separated per-pool apply budgets "
-          "(value,map,set,queue,lock,election,multimap,topic); empty = "
-          "single sequential scan", section="bench")
-_knob("COPYCAT_BENCH_PROFILE", "str", "",
-      "directory for an XLA profiler trace of the first timed repetition",
-      section="bench")
-_knob("COPYCAT_BENCH_TELEMETRY", "bool", False,
-      "compile device telemetry into the measured step (the round-8 "
-      "on-cost A/B)", section="bench")
-_knob("COPYCAT_BENCH_TIMER_MIN", "int", None,
-      default_doc="4 (mixed: 2)",
-      doc="election timer lower bound, rounds", section="bench")
-_knob("COPYCAT_BENCH_TIMER_MAX", "int", None,
-      default_doc="9 (mixed: 4)",
-      doc="election timer upper bound, rounds", section="bench")
-_knob("COPYCAT_BENCH_HOST_MODE", "str", "deep",
-      choices=("deep", "deepscan", "bulk", "queued"),
-      doc="host-scenario driver lane", section="bench")
-_knob("COPYCAT_BENCH_HOST_BURST", "int", None,
-      default_doc="submit_slots×8 (queued: ×1)",
-      doc="ops per group per burst for the host/host_read scenarios",
-      section="bench")
-_knob("COPYCAT_BENCH_SESSIONS", "int", 16,
-      "sessions per group for the session scenario", section="bench")
-_knob("COPYCAT_BENCH_SESSION_SCAN", "bool", False,
-      "`1` drives the session scenario through the fused deep_scan",
-      section="bench")
-_knob("COPYCAT_BENCH_SPI_INSTANCES", "int", 1000,
-      "resource instances (sessions) for the spi/readmix scenarios",
-      section="bench")
-_knob("COPYCAT_BENCH_SPI_BURSTS", "int", 5,
-      "bursts per repetition for the spi/readmix scenarios",
-      section="bench")
-_knob("COPYCAT_BENCH_SPI_PAYLOAD", "str", "int", choices=("int", "str"),
-      doc="`int` = device-resident counters, `str` = host-shadow map "
-          "cliff", section="bench")
-_knob("COPYCAT_BENCH_SPI_POOLS", "str", None,
-      default_doc="counters (str payload: all)",
-      choices=("counters", "all"),
-      doc="engine pool provisioning for the spi scenario", section="bench")
-_knob("COPYCAT_BENCH_SPI_WAVES", "int", 1,
-      "client pipelining depth (commands in flight per instance)",
-      section="bench")
-_knob("COPYCAT_BENCH_SPI_TRANSPORT", "str", "local",
-      choices=("local", "tcp", "native"),
-      doc="transport under the spi scenario", section="bench")
-_knob("COPYCAT_BENCH_SPI_LOG_SLOTS", "int", 16,
-      "engine log-ring slots for the spi/readmix scenarios",
-      section="bench")
-_knob("COPYCAT_BENCH_READMIX_READS", "int", 9,
-      "reads per write in the readmix scenario", section="bench")
-_knob("COPYCAT_BENCH_READMIX_LEVEL", "str", "atomic",
-      choices=("atomic", "sequential", "none", "linearizable"),
-      doc="read consistency the readmix scenario requests", section="bench")
-_knob("COPYCAT_BENCH_READ_LEVEL", "str", "sequential",
-      choices=("sequential", "atomic"),
-      doc="read consistency for the map_read/host_read scenarios",
-      section="bench")
-_knob("COPYCAT_BENCH_CLUSTER_STORAGE", "str", "memory",
-      choices=("memory", "mapped", "disk"),
-      doc="log storage level for the cluster scenario (the durability "
-          "A/B; `bench.py --storage` sets it)", section="bench")
-_knob("COPYCAT_BENCH_CLUSTER_MEMBERS", "int", 3,
-      "cluster scenario member count", section="bench")
-_knob("COPYCAT_BENCH_CLUSTER_CLIENTS", "int", 4,
-      "concurrent clients in the cluster scenario", section="bench")
-_knob("COPYCAT_BENCH_CLUSTER_OPS", "int", 1500,
-      "ops per client per burst in the cluster scenario", section="bench")
-_knob("COPYCAT_BENCH_CLUSTER_BURSTS", "int", 5,
-      "bursts (best-of) in the cluster scenario", section="bench")
-_knob("COPYCAT_BENCH_CLUSTER_DELAY_MS", "float", 2.0,
-      "nemesis wire latency per leg, ms", section="bench")
-_knob("COPYCAT_BENCH_SHARDED_GROUPS", "int", 4,
-      "Raft groups in the sharded scenario (1 = the single-group A/B "
-      "baseline)", section="bench")
-_knob("COPYCAT_BENCH_SHARDED_CLIENTS", "int", 12,
-      "concurrent public-API clients in the sharded scenario",
-      section="bench")
-_knob("COPYCAT_BENCH_SHARDED_OPS", "int", 1200,
-      "commands per client per burst in the sharded scenario",
-      section="bench")
-_knob("COPYCAT_BENCH_SHARDED_BURSTS", "int", 5,
-      "measured bursts (best-of) in the sharded scenario",
-      section="bench")
-_knob("COPYCAT_BENCH_SHARDED_KEYS", "int", 1024,
-      "zipfian keyspace size in the sharded scenario", section="bench")
-_knob("COPYCAT_BENCH_SHARDED_ZIPF", "float", 0.9,
-      "zipf skew exponent for the sharded scenario's key draw",
-      section="bench")
-_knob("COPYCAT_BENCH_SHARDED_TRACE", "bool", False,
-      "`1` drives one traced client wave after the timed bursts and "
-      "embeds the assembled cross-member waterfall + `latency.*` phase "
-      "histograms in the `--metrics-json` artifact", section="bench")
-_knob("COPYCAT_BENCH_SHARDED_DELAY_MS", "float", 100.0,
-      "nemesis wire latency per leg, ms (cross-region shape: the "
-      "bounded replication window caps a single ordered log at "
-      "max-inflight/RTT — the cap sharding multiplies)",
-      section="bench")
-_knob("COPYCAT_BENCH_RECOVERY_OPS", "int", 6000,
-      "committed entries before the recovery scenario's catch-up",
-      section="bench")
-_knob("COPYCAT_BENCH_RECOVERY_STORAGE", "str", "disk",
-      choices=("memory", "mapped", "disk"),
-      doc="log storage level for the recovery scenario", section="bench")
-_knob("COPYCAT_BENCH_RECOVERY_SNAP_ENTRIES", "int", 512,
-      "snapshot cadence the recovery scenario pins", section="bench")
-_knob("COPYCAT_BENCH_RECOVERY_CLIENTS", "int", 4,
-      "concurrent clients in the recovery scenario", section="bench")
-_knob("COPYCAT_BENCH_APPLY_GROUPS", "int", 4,
-      "Raft groups in the apply scenario (`bench.py --groups` sets it; "
-      "1 = the single-group shape)", section="bench")
-_knob("COPYCAT_BENCH_APPLY_SESSIONS", "int", 24,
-      "client sessions in the apply scenario", section="bench")
-_knob("COPYCAT_BENCH_APPLY_OPS", "int", 48,
-      "commands per session per burst in the apply scenario",
-      section="bench")
-_knob("COPYCAT_BENCH_APPLY_BURSTS", "int", 5,
-      "measured bursts (best-of) in the apply scenario", section="bench")
-_knob("COPYCAT_BENCH_APPLY_KEYS", "int", 256,
-      "device counters in the apply scenario's hot/cold zipfian keyspace "
-      "(sized so the engine round dominates the apply path — the "
-      "apply-limited regime)", section="bench")
-_knob("COPYCAT_BENCH_APPLY_ZIPF", "float", 0.9,
-      "zipf skew exponent for the apply scenario's key draw",
-      section="bench")
-_knob("COPYCAT_BENCH_APPLY_INELIGIBLE", "float", 0.25,
-      "fraction of sessions streaming ineligible (host-shadow string) "
-      "ops — their log entries interleave with the device sessions' "
-      "rows, the shape that collapses the contiguous classifier toward "
-      "the per-entry path", section="bench")
-_knob("COPYCAT_BENCH_COMPARTMENT_MEMBERS", "int", 3,
-      "Raft member processes in the compartment scenario",
-      section="bench")
-_knob("COPYCAT_BENCH_COMPARTMENT_TIERS", "str", "1,2,4",
-      "comma-separated ingress-tier widths the compartment scenario "
-      "sweeps (processes per width)", section="bench")
-_knob("COPYCAT_BENCH_COMPARTMENT_GROUPS", "int", 4,
-      "Raft groups in the compartment scenario (`bench.py --groups` "
-      "sets it)", section="bench")
-_knob("COPYCAT_BENCH_COMPARTMENT_CLIENTS", "int", 8,
-      "concurrent TCP clients in the compartment scenario",
-      section="bench")
-_knob("COPYCAT_BENCH_COMPARTMENT_OPS", "int", 600,
-      "commands per client per burst in the compartment scenario",
-      section="bench")
-_knob("COPYCAT_BENCH_COMPARTMENT_BURSTS", "int", 3,
-      "measured bursts (best-of) per tier width", section="bench")
-_knob("COPYCAT_BENCH_COMPARTMENT_KEYS", "int", 1_000_000,
-      "zipfian keyspace size in the compartment scenario (the "
-      "million-key shape)", section="bench")
-_knob("COPYCAT_BENCH_COMPARTMENT_ZIPF", "float", 0.9,
-      "zipf skew exponent for the compartment scenario's key draw",
-      section="bench")
-_knob("COPYCAT_BENCH_COMPARTMENT_STORAGE", "str", "disk",
-      choices=("memory", "mapped", "disk"),
-      doc="member log storage level in the compartment scenario (real "
-          "fsync by default)", section="bench")
-_knob("COPYCAT_BENCH_COMPARTMENT_NEMESIS", "bool", True,
-      "`0` skips the process-level nemesis phase (kill -9 a member + "
-      "an ingress proxy mid-load, zero lost acknowledged writes)",
-      section="bench")
-_knob("COPYCAT_BENCH_FANOUT_READERS", "str", "8,32,128",
-      "comma-separated reader-session counts the fanout scenario "
-      "sweeps", section="bench")
-_knob("COPYCAT_BENCH_FANOUT_WRITERS", "int", 2,
-      "writer sessions in the fanout scenario", section="bench")
-_knob("COPYCAT_BENCH_FANOUT_KEYS", "int", 16,
-      "counter resources the fanout scenario reads/writes",
-      section="bench")
-_knob("COPYCAT_BENCH_FANOUT_READS", "int", 50,
-      "reads per reader session per burst in the fanout scenario",
-      section="bench")
-_knob("COPYCAT_BENCH_FANOUT_BURSTS", "int", 3,
-      "measured bursts (best-of) per reader count", section="bench")
-_knob("COPYCAT_BENCH_FANOUT_ZIPF", "float", 0.9,
-      "zipf skew exponent for the fanout scenario's key draw",
-      section="bench")
-
-# --- scaling ---------------------------------------------------------------
-_knob("COPYCAT_SCALING_GROUPS", "int", 4096,
-      "groups per bulk row in the multichip scaling driver",
-      section="scaling")
-_knob("COPYCAT_SCALING_ROUNDS", "int", 30,
-      "rounds per scaling measurement", section="scaling")
-
 # --- verdict ---------------------------------------------------------------
 _knob("COPYCAT_VERDICT_GROUPS", "int", 10000,
       "groups in the verdict engine", section="verdict")
@@ -519,8 +305,7 @@ def _lookup(name: str) -> Knob:
 
 def get_raw(name: str) -> str | None:
     """The raw env value, or ``None`` when unset. For tri-state knobs
-    where *set at all* is meaningful (``COPYCAT_INVARIANTS``,
-    ``COPYCAT_BENCH_PALLAS``)."""
+    where *set at all* is meaningful (``COPYCAT_INVARIANTS``)."""
     _lookup(name)
     return os.environ.get(name)
 
@@ -570,15 +355,6 @@ def get_bool(name: str, default: bool | None = None) -> bool:
                 f"{name} has no registered default; pass default=")
         return bool(knob.default)
     return value.strip().lower() not in _FALSY
-
-
-def overrides() -> dict[str, str]:
-    """Every registered knob explicitly set in the environment, with its
-    raw value — the scenario knob snapshot ``bench.py --metrics-json``
-    embeds so artifacts from different runs are comparable (an artifact
-    whose knobs differ is a different experiment, not a regression)."""
-    return {name: os.environ[name] for name in sorted(REGISTRY)
-            if name in os.environ}
 
 
 # --- README generation -----------------------------------------------------

@@ -404,24 +404,9 @@ class Profiler:
                        for s, n in stacks[:max(1, top)]],
         }
 
-    def top_summary(self, top: int = 10) -> dict:
-        """The compact top-frame summary ``bench --metrics-json``
-        embeds: the frame table over the retained window plus the
-        plane's own counters (so the artifact carries its cost)."""
-        payload = self.payload(top=max(top * 4, 40))
-        return {
-            "hz": self.hz,
-            "window_s": self.window_s,
-            "window_samples": payload["window_samples"],
-            "counters": payload["counters"],
-            "frames": frame_table(
-                [(r["stack"], r["count"]) for r in payload["stacks"]],
-                top=top),
-        }
-
 
 # ---------------------------------------------------------------------------
-# pure aggregation + cluster merge (the CLI/bench side; no profiler needed)
+# pure aggregation + cluster merge (the CLI side; no profiler needed)
 # ---------------------------------------------------------------------------
 
 
@@ -569,8 +554,8 @@ def render_profile(profile: dict, top: int = 20) -> str:
 _ACQUIRE_LOCK = threading.Lock()
 
 #: THE per-process profiler while any host holds a reference; ``None``
-#: when the plane is off or no host is alive (slow-trace stamping and
-#: bench read this directly)
+#: when the plane is off or no host is alive (slow-trace stamping reads
+#: this directly)
 PROFILER: Profiler | None = None
 
 

@@ -174,8 +174,8 @@ class SeriesStore:
 
     def ingest(self, snap: dict, t: float | None = None) -> None:
         """Delta-encode one registry snapshot into the ring (exposed
-        for tests and for bench, which samples at scenario boundaries
-        rather than on a timer)."""
+        for tests, which sample at their own instants rather than on a
+        timer)."""
         flat, gauge_keys = flatten_registry(snap)
         values: dict = {}
         prev = self._prev_raw

@@ -808,7 +808,8 @@ def test_exit_code_contract_flags_undocumented_codes():
     assert len(found) == 1
     assert "exit code 3" in found[0].message
     # scope: only the deploy-plane mains are under the contract
-    assert check_exit_contract(tree, "copycat_tpu/bench.py", codes) == []
+    assert check_exit_contract(
+        tree, "copycat_tpu/testing/verdict.py", codes) == []
 
 
 def test_exit_code_contract_sees_negative_literals():

@@ -32,6 +32,7 @@ from copycat_tpu.io.local import (  # noqa: E402
 from copycat_tpu.manager.atomix import AtomixClient, AtomixServer  # noqa: E402
 from copycat_tpu.resource.consistency import Consistency  # noqa: E402
 from copycat_tpu.server.raft import LEADER  # noqa: E402
+from copycat_tpu.utils import tracing  # noqa: E402
 
 from helpers import async_test  # noqa: E402
 from raft_fixtures import next_ports  # noqa: E402
@@ -99,6 +100,21 @@ async def test_warm_reads_never_touch_the_server():
         assert server_reads() == before, "warm reads must stay local"
         snap = _edge_snap(reader)
         assert snap["edge.local_serves"] >= 20, snap
+        assert snap["edge.seeds"] >= 1, snap
+        for key in ("edge.server_fallbacks", "edge.merges",
+                    "edge.evictions", "edge.stale_rejections"):
+            assert key in snap, (key, sorted(snap))
+        # a cache-served read's trace is client-side only: one
+        # client.edge_serve span, no proxy.hop, no quorum.wait
+        tracing.enable()
+        try:
+            assert await r.get() == 3
+            served = [spans for spans in tracing.TRACER.traces().values()
+                      if any(s.name == "client.edge_serve" for s in spans)]
+        finally:
+            tracing.disable()
+        assert served, "the local serve recorded no span"
+        assert {s.name for s in served[-1]} == {"client.edge_serve"}
 
         await w.add_and_get(4)
         # the delta flush rides the apply turn; give the push a beat
@@ -111,6 +127,7 @@ async def test_warm_reads_never_touch_the_server():
         ssnap = server.server.metrics.snapshot()
         assert ssnap["edge.subscribes"] >= 1
         assert ssnap["edge.deltas_sent"] >= 1
+        assert ssnap["edge.delta_flushes"] >= 1
         assert ssnap["edge.subscriptions"] >= 1
     finally:
         await _close_all([writer, reader], [server])

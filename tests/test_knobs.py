@@ -73,10 +73,10 @@ def test_registry_docs_complete():
 
 
 def test_typed_getters(monkeypatch):
-    monkeypatch.delenv("COPYCAT_BENCH_ROUNDS", raising=False)
-    assert knobs.get_int("COPYCAT_BENCH_ROUNDS") == 200
-    monkeypatch.setenv("COPYCAT_BENCH_ROUNDS", "7")
-    assert knobs.get_int("COPYCAT_BENCH_ROUNDS") == 7
+    monkeypatch.delenv("COPYCAT_REPL_WINDOW", raising=False)
+    assert knobs.get_int("COPYCAT_REPL_WINDOW") == 64
+    monkeypatch.setenv("COPYCAT_REPL_WINDOW", "7")
+    assert knobs.get_int("COPYCAT_REPL_WINDOW") == 7
 
     monkeypatch.delenv("COPYCAT_REPL_MAX_INFLIGHT", raising=False)
     # computed default: registry has none, the call site provides it

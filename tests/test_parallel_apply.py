@@ -200,6 +200,8 @@ async def test_fused_dispatch_merges_groups_per_turn():
         groups_hist = server._metrics.histogram("apply.fused_groups")
         assert fused > 0 and runs > 0
         assert fused <= runs, (fused, runs)
+        for g in server.groups:
+            assert g.metrics.histogram("apply.window_entries").count > 0
         assert groups_hist.max_value >= 2, (
             "no fused dispatch ever mixed rows from 2+ groups "
             f"(max {groups_hist.max_value})")

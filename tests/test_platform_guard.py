@@ -143,3 +143,23 @@ def test_a_test_engine_takes_a_shared_shape_or_says_why():
     assert not unexplained, (
         "RaftGroups(<literal sizes>) with no '# shape:' reason; take a "
         f"shape of tests/engines.py ({', '.join(shared)}): {unexplained}")
+
+
+def _project_scripts():
+    """The ``name = "module:callable"`` lines of pyproject.toml: only
+    ``[project.scripts]`` has them (read by hand: ``tomllib`` is not in
+    every Python the project allows)."""
+    import re
+
+    with open(os.path.join(REPO, "pyproject.toml")) as f:
+        return re.findall(r'^([\w-]+) = "([\w.]+:\w+)"$', f.read(), re.M)
+
+
+@pytest.mark.parametrize("name,target", _project_scripts())
+def test_a_console_script_points_at_a_callable(name, target):
+    # what a deleted module's forgotten entry breaks: the script installs
+    # and dies on its first line
+    import importlib
+
+    module, _, attr = target.partition(":")
+    assert callable(getattr(importlib.import_module(module), attr)), target

@@ -260,8 +260,13 @@ def test_install_streaming_catches_up_wiped_follower(tmp_path, monkeypatch,
             snap = leader.metrics.snapshot()
             assert snap["snap.installs_sent"] >= 1
             assert snap["snap.install_chunks_sent"] >= 2
+            assert snap["snap.snapshots_taken"] > 0
+            assert snap["snap.snapshot_bytes"] > 0
+            assert snap["snap.truncated_entries"] > 0
+            assert snap["snap.install_failures"] == 0
             rsnap = reborn.metrics.snapshot()
             assert rsnap["snap.installs_received"] >= 1
+            assert rsnap["snap.restores"] >= 1
             assert rsnap["snap.install_chunks_received"] >= 2
         finally:
             await cluster.close()

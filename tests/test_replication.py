@@ -237,6 +237,15 @@ def test_repl_window_knob_reaches_the_stream(monkeypatch):
             hist = leader.metrics.histogram("repl.window_entries")
             assert hist.count > 0
             assert hist.max_value <= 16, hist.max_value
+            # the whole repl.* family rides the leader's snapshot
+            snap = leader.metrics.snapshot()
+            assert snap["repl.windows_sent"] > 0, sorted(snap)
+            assert snap["repl.entries_sent"] >= 100
+            assert snap["repl.ack_ms"]["count"] > 0
+            for key in ("repl.rewinds", "repl.stalls",
+                        "repl.backpressure_waits", "repl.windows_inflight",
+                        "repl.entries_inflight"):
+                assert key in snap, (key, sorted(snap))
         finally:
             await cluster.close()
 

@@ -260,9 +260,9 @@ def test_classic_engine_compat():
 
 def test_throughput_smoke(client):
     """Mechanical throughput check (CPU): the sessioned surface commits
-    a 4k-op burst in one flush with per-op numpy cost only. The real
-    ≥100k/s target is measured by the ``session`` bench scenario on
-    TPU; this guards the mechanics (one drive per flush, vectorized
+    a 4k-op burst in one flush with per-op numpy cost only. No benchmark
+    cell drives this surface yet (its rate on the chip: not measured);
+    this guards the mechanics (one drive per flush, vectorized
     correlation)."""
     s = client.open_session()
     rounds_before = client._rg.rounds

@@ -211,6 +211,14 @@ async def test_leadership_spreads_across_members_at_boot():
         assert sum(led.values()) == 6
         # seed-spread: every member leads exactly G/N groups at boot
         assert sorted(led.values()) == [2, 2, 2], led
+        # the stats surface says the same: the shard family and one
+        # commit series a group ride every member's ``raft`` section
+        for s in servers:
+            raft = s.stats_snapshot()["raft"]
+            assert raft["shard.groups"] == 6, sorted(raft)
+            assert raft["shard.groups_led"] == led[str(s.address)]
+            for g in range(6):
+                assert f"raft_commit_index{{group={g}}}" in raft, g
         # and the preference is the deterministic one: group g's leader
         # is members[g % N] over the sorted member list
         ranked = sorted((s.address for s in servers),

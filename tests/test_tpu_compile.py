@@ -4,8 +4,8 @@ compiler for a v5e that is described and not attached.
 Nothing runs here — a compile says nothing of results or time — but what
 the chip's compiler refuses (a misaligned kernel block, a donated buffer
 it cannot reuse, a program that does not fit) fails in tier-1 instead of
-on the chip. ``tests/tpu_compile_rehearsal.py`` runs the same helpers at
-the deployment sizes ``chip_smoke.py`` uses (too slow for tier-1).
+on the chip. The deployment sizes themselves compile on the chip, in the
+benchmark's set-up (``benchmarks/run.py``): too slow for tier-1.
 
 The topology is described inside a fixture and never at import: one
 process at a time may load the TPU library, so only the worker that runs

@@ -306,8 +306,10 @@ async def test_proxied_write_produces_cross_member_waterfall():
         # phase histograms fed on the members that did the work
         leader0 = next(s for s in servers
                        if s.groups[0].role == LEADER)
-        lat = leader0.groups[0].metrics.histogram("latency.append_ms")
-        assert lat.count > 0
+        for phase in ("latency.append_ms", "latency.quorum_ms",
+                      "latency.apply_ms"):
+            lat = leader0.groups[0].metrics.histogram(phase)
+            assert lat.count > 0, phase
         assert ingress._metrics.histogram(
             "latency.ingress_queue_ms").count >= 2
         assert ingress._metrics.histogram(
