@@ -187,6 +187,15 @@ class RaftClient(Managed):
         # event-loop turn); a lone submit still rides CommandRequest.
         self._pending_batch: list = []
         self._batch_scheduled = False
+        # One session's failover is one event: every command batch sent
+        # and not yet answered stands here under its first seq, with the
+        # future its flush waits on once its own send has failed. The
+        # first flush to lose the connection (or to be told it no longer
+        # talks to the leader) starts ``_run_failover``; the others join.
+        self._unanswered: dict[int, tuple[list, asyncio.Future]] = {}
+        self._failover: asyncio.Task | None = None
+        self._dials = 0
+        self._m_resubmitted = self.metrics.counter("commands_resubmitted")
         # Query micro-batching: same-turn reads bucket by consistency
         # level (the server's gate differs per level) and ride one
         # QueryBatchRequest — the linearizable gate's quorum round is
@@ -262,6 +271,8 @@ class RaftClient(Managed):
         if self._keepalive is not None:
             self._keepalive.cancel()
             self._keepalive = None
+        if self._failover is not None:
+            self._failover.cancel()
         if self._session.is_open and self._session.id is not None:
             try:
                 response = await self._request(
@@ -292,11 +303,18 @@ class RaftClient(Managed):
         candidates += [a for a in order if a not in candidates]
         last_error: Exception | None = None
         for address in candidates:
+            self._dials += 1
             try:
                 conn = await self._client.connect(address)
             except (TransportError, OSError) as e:
                 last_error = e
                 continue
+            current = self._connection
+            if current is not None and not current.closed:
+                # another request's dial landed while this one was out:
+                # the session keeps one connection
+                spawn(conn.close(), name="drop-connection")
+                return current
             conn.handler(msg.PublishRequest, self._on_publish)
             self._connection = conn
             self._connected_to = address
@@ -561,9 +579,7 @@ class RaftClient(Managed):
         if len(batch) == 1:
             seq, operation, fut = batch[0]
             try:
-                response = await self._request(msg.CommandRequest(
-                    session_id=self._session.id, seq=seq,
-                    operation=operation, trace=trace))
+                response = await self._send_commands(batch, trace)
                 result = self._finish(response, seq)
             except BaseException as e:  # noqa: BLE001 — delivered via fut
                 if not fut.done():
@@ -576,9 +592,7 @@ class RaftClient(Managed):
                 resolve.close(n=1)
             return
         try:
-            response = await self._request(msg.CommandBatchRequest(
-                session_id=self._session.id,
-                entries=[(seq, op) for seq, op, _ in batch], trace=trace))
+            response = await self._send_commands(batch, trace)
             # batch-level fatal (UNKNOWN_SESSION etc.): _finish raises
             # the right exception type for every entry
             if getattr(response, "error", None):
@@ -633,6 +647,134 @@ class RaftClient(Managed):
         finally:
             if resolve is not None:
                 resolve.close(n=len(batch))
+
+    def _command_request(self, entries: list, trace: int | None) -> Any:
+        """The wire request for ``entries`` (``(seq, operation, ...)``):
+        a lone command rides ``CommandRequest``."""
+        if len(entries) == 1:
+            return msg.CommandRequest(
+                session_id=self._session.id, seq=entries[0][0],
+                operation=entries[0][1], trace=trace)
+        return msg.CommandBatchRequest(
+            session_id=self._session.id,
+            entries=[(e[0], e[1]) for e in entries], trace=trace)
+
+    async def _send_commands(self, batch: list, trace: int | None) -> Any:
+        """One batch's response: its own request on the session's
+        connection while that stands; once the connection is lost, or the
+        member at its end says it does not lead, what the session's one
+        failover brings back for it (:meth:`_run_failover`)."""
+        first = batch[0][0]
+        waiter: asyncio.Future = self._loop.create_future()
+        self._unanswered[first] = (batch, waiter)
+        try:
+            if self._failover is None:
+                try:
+                    # one try on the session's connection; a lost
+                    # connection or a member that does not lead has been
+                    # dropped, and the leader's hint taken, by the time
+                    # it gives up
+                    return await self._request(
+                        self._command_request(batch, trace), attempts=1)
+                except (msg.ProtocolError, TransportError, OSError):
+                    pass
+                if waiter.done():    # a failover already resubmitted it
+                    return waiter.result()
+                if self._failover is None:
+                    self._failover = spawn(self._run_failover(),
+                                           name="client-failover")
+            return await waiter
+        finally:
+            self._unanswered.pop(first, None)
+            if not waiter.done():
+                waiter.cancel()
+
+    async def _run_failover(self) -> None:
+        """The session's failover, once for everything in flight (the
+        connection it was on is dropped already): reach the leader with
+        ONE keep-alive through the routed loop (it dials, is told who
+        leads, dials again), then resubmit every unanswered command as
+        one block in sequence order.
+        The new leader answers what it already applied from the session's
+        replicated response cache and appends the rest (exactly once by
+        seq). Batches flushed meanwhile wait and ride the next block, so
+        nothing of the session overtakes what is unanswered."""
+        t0 = time.perf_counter()
+        dials = self._dials
+        inflight = sum(len(b) for b, _ in self._unanswered.values())
+        error: BaseException = msg.ProtocolError(
+            msg.INTERNAL, "failover ended with commands unanswered")
+        try:
+            session = self._session
+            response = await self._request(msg.KeepAliveRequest(
+                session_id=session.id, command_seq=self._acked_command_seq,
+                event_index=(session.event_index if self._num_groups == 1
+                             else dict(session._event_indices))),
+                per_try_timeout=max(1.0, session.timeout / 4.0))
+            if response.error == msg.UNKNOWN_SESSION:
+                session._expired()
+                raise SessionExpiredError("session expired")
+            response.raise_if_error()
+            if TRACER.enabled:
+                TRACER.span(TRACER.new_trace(), "client.failover", t0,
+                            time.perf_counter(), inflight=inflight,
+                            resubmitted=sum(
+                                len(b) for b, w in self._unanswered.values()
+                                if not w.done()),
+                            attempts=self._dials - dials)
+            while True:
+                waiting = [(batch, waiter) for _, (batch, waiter)
+                           in sorted(self._unanswered.items())
+                           if not waiter.done()]
+                if not waiting:
+                    break
+                entries = [e for batch, _ in waiting for e in batch]
+                self._m_resubmitted.inc(len(entries))
+                response = await self._request(
+                    self._command_request(entries, None))
+                self._hand_out(response, waiting)
+        except BaseException as e:  # noqa: BLE001 - handed to every waiter
+            error = e
+            if not isinstance(e, Exception):
+                raise
+        finally:
+            self._failover = None
+            for _, waiter in list(self._unanswered.values()):
+                if not waiter.done():
+                    waiter.set_exception(error)
+
+    @staticmethod
+    def _hand_out(response: Any, waiting: list) -> None:
+        """Give each waiting batch its part of a resubmitted block's
+        response, in the shape its own request would have been answered
+        in. A response-level error is every batch's."""
+        if getattr(response, "error", None):
+            for _, waiter in waiting:
+                if not waiter.done():
+                    waiter.set_result(response)
+            return
+        if isinstance(response, msg.CommandResponse):   # a block of one
+            (batch, waiter), = waiting
+            if not waiter.done():
+                waiter.set_result(response)
+            return
+        by_seq = {entry[0]: entry for entry in response.entries or ()}
+        for batch, waiter in waiting:
+            if waiter.done():
+                continue
+            if len(batch) > 1:
+                waiter.set_result(msg.CommandBatchResponse(
+                    event_index=response.event_index,
+                    entries=[by_seq[seq] for seq, _, _ in batch
+                             if seq in by_seq]))
+                continue
+            seq = batch[0][0]
+            _, index, result, code, detail = by_seq.get(seq) or (
+                seq, 0, None, msg.INTERNAL,
+                f"seq {seq} missing from batch response")
+            waiter.set_result(msg.CommandResponse(
+                index=index, result=result, error=code, error_detail=detail,
+                event_index=response.event_index))
 
     def _ack_seq(self, seq: int, index: int | None) -> None:
         """Per-command success bookkeeping (the _finish tail): advance the

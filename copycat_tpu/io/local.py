@@ -178,6 +178,10 @@ class LocalConnection(Connection):
         self.local_address = local_address
         self.remote_address = remote_address
         self.peer: "LocalConnection | None" = None
+        #: the tasks whose sends have not returned, and those of them a
+        #: close of the connection has interrupted
+        self._inflight: set[asyncio.Task] = set()
+        self._aborted: set[asyncio.Task] = set()
         m = metrics if metrics is not None else MetricsRegistry()
         self._m_bytes_out = m.counter("bytes_out")
         self._m_frames_out = m.counter("frames_out")
@@ -185,14 +189,47 @@ class LocalConnection(Connection):
         self._m_frames_in = m.counter("frames_in")
 
     async def send(self, message: Any) -> Any:
+        """Deliver ``message`` and return the peer handler's answer. The
+        handler runs on the sender's own task, as it always has (no hop of
+        the loop between them), and what interrupts it is told apart here:
+        a close of either end is ``ConnectionClosedError`` at once, as
+        ``TcpConnection._abort`` makes it; a handler cancelled on its own
+        side (a crashed server cancels what its handlers wait on) is a
+        ``TransportError``, never a cancellation of the sender; the
+        sender's own cancellation (a timeout around ``send``) stays one
+        and takes the handler with it."""
         peer = self.peer
         if self.closed or peer is None or peer.closed:
             raise ConnectionClosedError("connection closed")
+        task = asyncio.current_task()
+        self._inflight.add(task)
+        try:
+            return await self._exchange(message, peer)
+        except asyncio.CancelledError:
+            if task in self._aborted:
+                self._aborted.discard(task)
+                if task.uncancel() == 0:
+                    raise ConnectionClosedError("connection closed") from None
+                raise                   # and the sender was cancelled too
+            if task.cancelling():
+                raise
+            raise TransportError("CancelledError: the handler was "
+                                 "cancelled at the peer") from None
+        finally:
+            self._inflight.discard(task)
+
+    async def _exchange(self, message: Any, peer: "LocalConnection") -> Any:
         nem = self._registry.nemesis if self._registry is not None else None
         if nem is not None:
             d = nem.delay_s()
             if d:
                 await asyncio.sleep(d)
+                if self.closed or peer.closed:
+                    # the connection closed while the request was on the
+                    # wire (on a busy loop that is far longer than the
+                    # delay): it reaches nobody, and the sender is told
+                    # now, not when its own timeout runs out
+                    raise ConnectionClosedError("connection closed")
             if nem.drop_request(self.local_address, self.remote_address):
                 raise TransportError(
                     f"nemesis: request {self.local_address} -> "
@@ -241,11 +278,26 @@ class LocalConnection(Connection):
         self._m_bytes_in.inc(len(wire))
         return self._serializer.read(wire)
 
+    def _abort(self) -> None:
+        """Interrupt every send in flight on this end, which then raises
+        ``ConnectionClosedError`` (``TcpConnection._abort``), and mark the
+        end closed."""
+        closing = asyncio.current_task()
+        for task in self._inflight:
+            # a handler that closes its own connection runs on the
+            # sender's task: that send ends by itself, and a cancellation
+            # asked of it now would land in whatever it awaits next
+            if not task.done() and task is not closing:
+                self._aborted.add(task)
+                task.cancel()
+        self._inflight.clear()
+        self._fire_close()
+
     async def close(self) -> None:
         peer = self.peer
-        self._fire_close()
-        if peer is not None and not peer.closed:
-            peer._fire_close()
+        self._abort()
+        if peer is not None:
+            peer._abort()
 
 
 class LocalClient(Client):

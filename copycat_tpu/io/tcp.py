@@ -144,6 +144,14 @@ class TcpConnection(Connection):
             self._write_message(_RESPONSE, corr, result)
         except Exception as exc:  # marshal handler errors back to the caller
             self._write_error(corr, exc)
+        except asyncio.CancelledError:
+            # what the handler waited on was cancelled under it (a crashed
+            # server's commit futures): the caller is told, as by any
+            # failed handler, and does not wait out its own timeout
+            if asyncio.current_task().cancelling():
+                raise
+            self._write_error(corr, asyncio.CancelledError(
+                "the handler was cancelled at the peer"))
 
     def _write_error(self, corr: int, exc: Any) -> None:
         try:

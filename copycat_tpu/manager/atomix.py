@@ -13,6 +13,7 @@ API parity with the reference's ``builder()`` surface (SURVEY.md §5.6).
 
 from __future__ import annotations
 
+import time
 from typing import Any, Type, TypeVar
 
 from ..utils import knobs
@@ -361,7 +362,9 @@ class AtomixServer(Managed):
     async def _do_open(self) -> None:
         prewarm = getattr(self.server.state_machine, "prewarm", None)
         if callable(prewarm):
+            t0 = time.perf_counter()
             prewarm()
+            self.server.engine_s = time.perf_counter() - t0
         await self.server.open()
         if self._stats_port is not None:
             from ..server.stats import StatsListener

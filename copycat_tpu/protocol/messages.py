@@ -344,11 +344,15 @@ class InstallRequest(Message):
     that index (the follower's log restarts just past it), ``total`` the
     full payload length in bytes, ``offset`` this chunk's byte position,
     ``data`` the chunk, and ``done`` marks the final (empty) frame that
-    asks the follower to assemble + restore.
+    asks the follower to assemble + restore.  ``trace`` is optional
+    trailing (omitted when None, so the untraced wire is unchanged): the
+    id of the leader's ``snapshot.install`` span, carried on the final
+    frame so that the follower's ``snapshot.restore`` lands under it.
     """
 
     _fields = ("term", "leader", "index", "snap_term", "total", "offset",
-               "data", "done", "group")
+               "data", "done", "group", "trace")
+    _optional = 1
 
 
 @serialize_with(213)

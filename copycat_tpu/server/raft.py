@@ -47,6 +47,7 @@ from ..utils.health import BlackBox, HealthMonitor
 from ..utils.timeseries import SeriesStore
 from ..utils.managed import Managed
 from ..utils.metrics import MetricsRegistry
+from ..utils.scheduled import LoopWatch
 from ..utils.tracing import TRACER
 from .log import ConfigurationEntry, Storage, StorageLevel
 from .raft_group import (  # noqa: F401 - re-exported compat surface
@@ -85,6 +86,11 @@ class RaftServer(Managed):
         groups: int | None = None,
     ) -> None:
         super().__init__()
+        # the ``server.recover`` span: construction (the groups' boot
+        # recovery) to the end of open; ``engine_s`` is what an embedder
+        # spent bringing the state machine's engine up before that open
+        self._t_construct = time.perf_counter()
+        self.engine_s = 0.0
         self.address = address
         self.boot_members: list[Address] = list(members)
         if address not in self.boot_members:
@@ -234,6 +240,7 @@ class RaftServer(Managed):
         self._m_apply_fused_groups = self._metrics.histogram(
             "apply.fused_groups")
 
+        self._loop_watch: LoopWatch | None = None   # see loop_held
         self.groups: list[RaftGroup] = []
         for g in range(groups):
             reg = self._metrics if self.single else MetricsRegistry()
@@ -276,6 +283,10 @@ class RaftServer(Managed):
 
     async def _do_open(self) -> None:
         self._closing = False
+        # the groups' election timers read what this member's loop was
+        # held for since their leader's last message, which can come as
+        # soon as the member listens
+        self._loop_watch = LoopWatch(self.heartbeat_interval / 2)
         await self._server.listen(self.address, self._accept)
         if self._joining:
             await self._join_cluster()
@@ -283,6 +294,14 @@ class RaftServer(Managed):
             grp.start()
         if self.health is not None:
             self.health.start()
+        if TRACER.enabled:
+            TRACER.span(
+                TRACER.new_trace(), "server.recover", self._t_construct,
+                time.perf_counter(), member=str(self.address),
+                snapshot_index=self.groups[0]._snap_index,
+                replayed=sum(max(0, g._recovery_boot_last - g.last_applied)
+                             for g in self.groups),
+                engine_s=round(self.engine_s, 6))
         logger.info("%s listening at %s (members=%s, groups=%d)", self.name,
                     self.address, self.groups[0].members, self.num_groups)
 
@@ -302,6 +321,8 @@ class RaftServer(Managed):
             # its completion will find ``_closing`` and change nothing
             self._snap_worker.shutdown(wait=True)
             self._snap_worker = None
+        if self._loop_watch is not None:
+            self._loop_watch.cancel()
         for grp in self.groups:
             grp.shutdown()
         await self._server.close()
@@ -314,6 +335,15 @@ class RaftServer(Managed):
         # does NOT release — a crash doesn't run destructors either
         profiler.release(self.profiler, self._metrics)
         self.profiler = None
+
+    def loop_held(self, now: float) -> float:
+        """Seconds this member's event loop has stood still so far
+        (:class:`LoopWatch`; differences of two readings count). The
+        watch is begun at open, or here for a member whose handlers run
+        without one."""
+        if self._loop_watch is None:
+            self._loop_watch = LoopWatch(self.heartbeat_interval / 2)
+        return self._loop_watch.held(now)
 
     def snapshot_worker(self) -> ThreadPoolExecutor:
         """The thread the groups' captures finish on, off the loop."""
@@ -335,6 +365,8 @@ class RaftServer(Managed):
         # crash leaves whatever the last flush wrote, nothing more)
         if self.health is not None:
             self.health.stop()
+        if self._loop_watch is not None:
+            self._loop_watch.cancel()
         for grp in self.groups:
             grp._cancel_timers()
 

@@ -329,7 +329,7 @@ class RaftGroup:
 
         self._election_timer: Scheduled | None = None
         self._election_due = 0.0        # monotonic deadline of that timer
-        self._election_deferred = False
+        self._election_held0 = 0.0      # the loop watch's reading at arming
         self._leader_timer: Scheduled | None = None
 
         # read pump windows (per group: the gate is per-group leadership)
@@ -370,6 +370,9 @@ class RaftGroup:
         self._m_single_lane = m.counter("commands_single_lane")
         self._m_fast_lane = m.counter("commands_fast_lane")
         self._m_general_lane = m.counter("commands_general_lane")
+        #: commands answered from the session's response cache (a
+        #: resubmission of what was already applied: exactly once)
+        self._m_cached = m.counter("commands_cached")
         self._m_keepalive_ms = m.histogram("keepalive_latency_ms")
         self._m_append_block = m.histogram("append_block_entries")
         # the event plane: batches sealed (one an entry and session),
@@ -477,6 +480,12 @@ class RaftGroup:
         # assembly lays the turn's stages inside it
         self._trace_batch: dict[int, int] = {}
         self._member = str(self.address)
+        # the ``raft.election`` span: the instant this member's first
+        # election timer fired with no leader known since, the votes it
+        # won with and the index of the no-op its leadership began with
+        self._election_t0: float | None = None
+        self._election_votes = 1
+        self._election_noop = 0
         self._trace_slow_ms = knobs.get_float("COPYCAT_TRACE_SLOW_MS")
 
         # health-plane fsync accounting (utils/health.py): cheap EWMA +
@@ -1029,6 +1038,7 @@ class RaftGroup:
         self.role = FOLLOWER
         if leader is not None:
             self.leader_address = leader
+            self._election_t0 = None    # another member's election ended it
         if was_leader:
             self._stop_replication()
             self._fail_pending(msg.NOT_LEADER)
@@ -1036,10 +1046,11 @@ class RaftGroup:
         if reset_timer:
             self._reset_election_timer()
 
-    def _reset_election_timer(self) -> None:
+    def _reset_election_timer(self, listen: float | None = None) -> None:
+        """Arm the election timer with a fresh draw of the timeout, or,
+        for a deferral, with ``listen`` seconds if that is sooner."""
         if self._election_timer is not None:
             self._election_timer.cancel()
-        self._election_deferred = False
         base = self.election_timeout
         if self.server.single:
             timeout = random.uniform(base, base * 2)
@@ -1065,27 +1076,44 @@ class RaftGroup:
             else:
                 timeout = (random.uniform(base, base * 2)
                            + base * 0.3 * rank)
-        self._election_due = time.monotonic() + timeout
-        self._election_timer = Scheduled(timeout, None, self._start_election)
+        if listen is not None:
+            timeout = min(timeout, listen)
+        now = time.monotonic()
+        self._election_due = now + timeout
+        self._election_held0 = self.server.loop_held(now)
+        self._election_timer = timer = Scheduled(
+            timeout, None, lambda: self._start_election(timer))
 
-    async def _start_election(self) -> None:
+    async def _start_election(self, timer: Scheduled) -> None:
         if self._closing or self.role == LEADER:
             return
-        if (self.leader_address is not None and not self._election_deferred
-                and time.monotonic() - self._election_due
-                > self.heartbeat_interval):
-            # A follower's timer fired more than a heartbeat late: this
-            # member's own event loop stood still past the deadline (a
-            # snapshot capture, a collection), so it was not listening
-            # and its leader's silence is not shown; whatever the leader
-            # sent meanwhile is still queued behind this callback.
-            # Listen for one more timeout, once per silence: any contact
-            # resets the timer and the flag, a leader that is really
-            # gone is replaced one timeout later.
-            self._reset_election_timer()
-            self._election_deferred = True
-            self.metrics.counter("raft_elections_deferred").inc()
+        if timer is not self._election_timer:
+            # superseded between its firing and this callback: behind a
+            # held loop the timer that came due and the leader's message
+            # that re-arms it are queued in one turn, and a cancel never
+            # reaches a callback already spawned
             return
+        if self.leader_address is not None:
+            now = time.monotonic()
+            deaf = max(self.server.loop_held(now) - self._election_held0,
+                       now - self._election_due)
+            if deaf > self.heartbeat_interval:
+                # The timeout is counted in seconds this member LISTENED.
+                # Its own event loop stood still for more than a heartbeat
+                # of them (a snapshot capture, a collection, a neighbour's
+                # boot recovery or restore on a shared loop), in one hold
+                # past the deadline or in several before it: it was not
+                # listening for that long, so its leader's silence is not
+                # shown; whatever the leader sent meanwhile may still be
+                # queued behind this callback. Listen for as long again as
+                # it was deaf, one timeout at most: any contact resets the
+                # timer, a leader that is really gone is replaced once
+                # this member has listened a timeout through.
+                self._reset_election_timer(listen=deaf)
+                self.metrics.counter("raft_elections_deferred").inc()
+                return
+        if self._election_t0 is None:
+            self._election_t0 = time.perf_counter()
         self.role = CANDIDATE
         self.term += 1
         self.voted_for = self.address
@@ -1096,7 +1124,7 @@ class RaftGroup:
         logger.debug("%s starting election for term %d", self.name, term)
         self._reset_election_timer()  # re-elect if this round stalls
 
-        votes = 1  # self
+        votes = self._election_votes = 1  # self
         if votes >= self.quorum:
             self._become_leader()
             return
@@ -1129,6 +1157,7 @@ class RaftGroup:
             if granted:
                 votes += 1
                 if votes >= self.quorum:
+                    self._election_votes = votes
                     self._become_leader()
                     break
         for t in tasks:
@@ -1165,7 +1194,7 @@ class RaftGroup:
             session.last_contact = now
         # Commit an entry from this term immediately (Raft §5.4.2) and advance
         # the state machine clock.
-        self._append(NoOpEntry())
+        self._election_noop = self._append(NoOpEntry())
         self._leader_timer = Scheduled(self.heartbeat_interval,
                                        self.heartbeat_interval,
                                        self._leader_maintenance)
@@ -1319,6 +1348,13 @@ class RaftGroup:
                     # with the stream's depth + AIMD accounting), and
                     # resume appending where the snapshot ends
                     if ps.inflight_windows:
+                        # clear first: every append and every ack sets
+                        # the event, and a wait on a set event returns
+                        # without yielding, so that this loop span on
+                        # the one thread its windows need to come home
+                        # on (a rejoin under load: found on the chip,
+                        # PR 49, where three runs of six never ended)
+                        event.clear()
                         try:
                             await asyncio.wait_for(event.wait(),
                                                    self.heartbeat_interval)
@@ -1514,6 +1550,8 @@ class RaftGroup:
         chunk = self._snap_chunk
         sem = asyncio.Semaphore(self._repl_depth)
         failed = False
+        trace = TRACER.new_trace() if TRACER.enabled else None
+        t_install = time.perf_counter()
 
         async def send_chunk(offset: int) -> None:
             nonlocal failed
@@ -1553,7 +1591,8 @@ class RaftGroup:
                     conn.send(msg.InstallRequest(
                         term=term, leader=self.address, index=index,
                         snap_term=snap_term, total=total, offset=total,
-                        data=b"", done=True, group=self.wire_group)),
+                        data=b"", done=True, group=self.wire_group,
+                        trace=trace)),
                     self.election_timeout * 4)
             except (TransportError, OSError, asyncio.TimeoutError):
                 failed = True
@@ -1568,6 +1607,11 @@ class RaftGroup:
             ps.backoff = True
             return False
         self._m_snap_installs_sent.inc()
+        if trace is not None:
+            self._trace_span(trace, "snapshot.install", t_install,
+                             time.perf_counter(), bytes=total,
+                             chunks=-(-total // chunk), index=index,
+                             peer=str(peer))
         self._last_quorum_contact[peer] = time.monotonic()
         if index > self.match_index.get(peer, 0):
             self.match_index[peer] = index
@@ -1608,6 +1652,16 @@ class RaftGroup:
                         f"supported by {support}/{len(self.members)} "
                         f"(quorum {self.quorum}, last {self.log.last_index})")
             self.commit_index = candidate
+            if self._election_t0 is not None \
+                    and candidate >= self._election_noop:
+                # the election this member won is over: its first entry
+                # is committed and the cluster serves again
+                if TRACER.enabled:
+                    self._trace_span(TRACER.new_trace(), "raft.election",
+                                     self._election_t0, time.perf_counter(),
+                                     term=self.term,
+                                     votes=self._election_votes)
+                self._election_t0 = None
             hit: list[int] = []
             if self._trace_watch:
                 # traced entries the quorum just covered: close their
@@ -1903,12 +1957,23 @@ class RaftGroup:
                                        offset=pos)
         payload_bytes = b"".join(data for _, data in parts)
         self._installing = None
+        trace = getattr(request, "trace", None)
         try:
             payload = self._snap_serializer.read(payload_bytes)
             if self._snapshots is not None:
                 self._snapshots.save(request.index, payload_bytes)
                 self._snapshots.gc(keep=2)
+            t_restore = time.perf_counter()
             self._restore_snapshot(payload)
+            if trace is not None:
+                # image decoded and durable -> the state in place, the
+                # device engine's included
+                self._trace_span(trace, "snapshot.restore", t_restore,
+                                 time.perf_counter(),
+                                 resources=len(getattr(
+                                     self.state_machine, "resources", ())),
+                                 bytes=len(payload_bytes),
+                                 index=request.index)
         except Exception as e:  # noqa: BLE001 - refuse, don't die
             logger.exception("%s: snapshot install at %d failed",
                              self.name, request.index)
@@ -2056,6 +2121,7 @@ class RaftGroup:
         # Exactly-once: already applied -> cached response.
         cached = session.cached_response(seq)
         if cached is not None:
+            self._m_cached.inc()
             return "done", cached
         if seq <= session.command_high:
             return "err", (msg.INTERNAL,

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import time
 from typing import Awaitable, Callable
 
 from .tasks import spawn
@@ -78,6 +79,46 @@ class Scheduled:
     @property
     def is_done(self) -> bool:
         return self._task is None or self._task.done()
+
+
+class LoopWatch:
+    """Counts the seconds the running event loop stood still.
+
+    A tick twice every ``hold`` seconds notes how late it ran; a tick more
+    than ``hold`` late is a hold of the loop (a member's boot recovery, a
+    snapshot restore, a collection) and its lateness is added to
+    :meth:`held`, which also counts the hold the caller is running behind
+    right now.  An election timer reads it to tell its leader's silence from
+    its own deafness: while the loop stood still nothing was listened for.
+    A loop that is merely busy, its callbacks shorter than ``hold``, counts
+    nothing.
+    """
+
+    def __init__(self, hold: float) -> None:
+        self._hold = hold
+        self._held = 0.0
+        self._loop = asyncio.get_running_loop()
+        self._due = time.monotonic() + hold / 2
+        self._handle: asyncio.TimerHandle | None = self._loop.call_later(
+            hold / 2, self._tick)
+
+    def _tick(self) -> None:
+        now = time.monotonic()
+        late = now - self._due
+        if late > self._hold:
+            self._held += late
+        self._due = now + self._hold / 2
+        self._handle = self._loop.call_later(self._hold / 2, self._tick)
+
+    def held(self, now: float | None = None) -> float:
+        """Seconds of holds so far; differences of two readings count."""
+        late = (time.monotonic() if now is None else now) - self._due
+        return self._held + (late if late > self._hold else 0.0)
+
+    def cancel(self) -> None:
+        if self._handle is not None:
+            self._handle.cancel()
+            self._handle = None
 
 
 def schedule(delay: float, callback: Callable[[], Awaitable[None] | None]) -> Scheduled:
