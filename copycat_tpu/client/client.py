@@ -31,6 +31,14 @@ from ..utils.tracing import TRACER
 
 _client_counter = itertools.count()
 
+# A keep-alive is also sent once the session holds this many replies the
+# server has not been told of: the server caches a reply until a committed
+# keep-alive says the client has it, so its cache (copied, serialised,
+# installed and restored with every snapshot) holds this many and what is in
+# flight, whatever the session's timeout. The timer is the session's
+# liveness and stays.
+_KEEPALIVE_REPLIES = 8192
+
 
 class ApplicationError(Exception):
     """A state machine raised while applying the operation."""
@@ -174,6 +182,11 @@ class RaftClient(Managed):
         # completing first must not ack a lower seq still being retried.
         self._completed_seqs: set[int] = set()
         self._acked_command_seq = 0
+        # the early keep-alive (``_replies_resolved``): what the last
+        # answered keep-alive acknowledged, and the early one on its way
+        self._kept_alive_seq = 0
+        self._early_keepalive: asyncio.Task | None = None
+        self._m_keepalives_early = self.metrics.counter("keepalives_early")
         # High-water applied index seen, per Raft group (sequential
         # consistency). Single-group servers live entirely in key 0 —
         # the legacy scalar; a multi-group server (RegisterResponse
@@ -273,6 +286,8 @@ class RaftClient(Managed):
             self._keepalive = None
         if self._failover is not None:
             self._failover.cancel()
+        if self._early_keepalive is not None:
+            self._early_keepalive.cancel()
         if self._session.is_open and self._session.id is not None:
             try:
                 response = await self._request(
@@ -442,6 +457,7 @@ class RaftClient(Managed):
             return
         unsub = (self._edge.take_unsubscribes()
                  if self._edge is not None else None)
+        command_seq = self._acked_command_seq
         try:
             session = self._session
             event_index: Any = (session.event_index
@@ -450,7 +466,7 @@ class RaftClient(Managed):
             response = await self._request(
                 msg.KeepAliveRequest(
                     session_id=session.id,
-                    command_seq=self._acked_command_seq,
+                    command_seq=command_seq,
                     event_index=event_index,
                     unsubscribe=unsub),
                 # timeout/4 = the keep-alive interval: a stuck attempt
@@ -466,8 +482,24 @@ class RaftClient(Managed):
             return
         if response.error == msg.UNKNOWN_SESSION:
             self._session._expired()
-        elif response.ok and response.members:
-            self.members = list(response.members)
+        elif response.ok:
+            self._kept_alive_seq = max(self._kept_alive_seq, command_seq)
+            if response.members:
+                self.members = list(response.members)
+
+    def _replies_resolved(self) -> None:
+        """A reply frame's futures are resolved: acknowledge by count.
+        Starts a keep-alive once ``_KEEPALIVE_REPLIES`` replies have come
+        in since the last answered one acknowledged its prefix, unless
+        such a one is on its way (the next frame after its answer asks
+        again)."""
+        if (self._acked_command_seq - self._kept_alive_seq
+                >= _KEEPALIVE_REPLIES and self._session.is_open
+                and (self._early_keepalive is None
+                     or self._early_keepalive.done())):
+            self._m_keepalives_early.inc()
+            self._early_keepalive = spawn(self._send_keepalive(),
+                                          name="keepalive-early")
 
     async def _on_publish(self, request: msg.PublishRequest) -> msg.PublishResponse:
         session = self._session
@@ -590,6 +622,7 @@ class RaftClient(Managed):
                 fut.set_result(result)
             if resolve is not None:
                 resolve.close(n=1)
+            self._replies_resolved()
             return
         try:
             response = await self._send_commands(batch, trace)
@@ -647,6 +680,7 @@ class RaftClient(Managed):
         finally:
             if resolve is not None:
                 resolve.close(n=len(batch))
+        self._replies_resolved()
 
     def _command_request(self, entries: list, trace: int | None) -> Any:
         """The wire request for ``entries`` (``(seq, operation, ...)``):

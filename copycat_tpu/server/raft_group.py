@@ -436,6 +436,11 @@ class RaftGroup:
         # 1 on the snapshot lane (captures bound recovery and truncate
         # the log), 0 on replay-only recovery; set once the group knows
         self._m_snap_lane = m.gauge("snap.lane")
+        # replies in a session's response cache: the largest of the
+        # group's at a capture's cut (what the image carries), a
+        # session's own as its keep-alive applies, before the prune
+        # (its peak since the one before)
+        self._m_responses_cached = m.gauge("session.responses_cached")
         # Edge read tier (docs/EDGE_READS.md): subscription registry +
         # delta publication accounting. Pre-created so the family is
         # present (count 0) in every snapshot the CI asserts.
@@ -785,13 +790,16 @@ class RaftGroup:
                     "staying on the replay-only recovery path", self.name,
                     type(self.state_machine).__name__)
             return None
+        sessions = [s.snapshot_dict() for s in self.sessions.values()]
+        self._m_responses_cached.set(
+            max((len(s["responses"]) for s in sessions), default=0))
         return {
             "version": 1,
             "index": self.last_applied,
             "term": self.log.term_at(self.last_applied) or self.term,
             "clock": self.context.clock,
             "members": [str(m) for m in self.members],
-            "sessions": [s.snapshot_dict() for s in self.sessions.values()],
+            "sessions": sessions,
             "machine": machine_state,
         }
 
@@ -3411,6 +3419,7 @@ class RaftGroup:
         if session is None:
             return
         session.last_keepalive_time = self.context.clock
+        self._m_responses_cached.set(len(session.responses))
         session.ack_commands(entry.command_seq or 0)
         session.ack_events(entry.event_index or 0)
         self.log.clean(entry.index)
