@@ -410,6 +410,44 @@ def _term_at_own(log_term: jnp.ndarray, last: jnp.ndarray,
     return jnp.where(valid, t, 0)
 
 
+def _window_form(A: int, L: int, config: Config) -> str:
+    """Which form :func:`_window_gather` takes: ``rotate`` where A one-hot
+    compares a ring slot cost more than the ``ceil(log2(L))`` selects of a
+    barrel shifter and the shifter's kernel can run (``config.use_pallas``,
+    sizes it fits), else ``onehot``. Static sizes and the kernels' switch
+    decide, nothing else."""
+    from .pallas_kernels import ring_window_fits
+    kernel = config.use_pallas and ring_window_fits(A, L)
+    return "rotate" if kernel and A > (L - 1).bit_length() else "onehot"
+
+
+def _window_gather(slot_all: jnp.ndarray, L: int, config: Config):
+    """Reader of the committed window: ``ga(log)`` takes a ``[G,P,L]`` ring
+    plane to its ``[G,P,A]`` entries at the ring slots ``slot_all``
+    (``[G,P,A]``), which are a cyclic run: ``(slot_all[..., 0] + i) % L``.
+    It returns whatever the slots hold; the caller masks the positions
+    past the commit index.
+
+    Taking a cyclic run out of a ring is a rotation of the L axis: a
+    barrel shifter, one select for each bit of the first slot
+    (pallas_kernels.ring_window_pallas: the raw and bulk shapes, A = 16
+    of L = 32, kernels on). Where A is no more than the number of those
+    bits (the served engines' A = 4 at L = 64), or the kernel is off (in
+    jnp the shifter's static slices cost the TPU more than the one-hot
+    does), the ``[G,P,A,L]`` one-hot select-reduce is kept, op for op as
+    it was, its compare shared by the planes (:func:`_window_form`).
+    Neither is ``take_along_axis``, which lowers to an element-wise DMA
+    loop on TPU (700 ms for six planes of the raw cell)."""
+    A = slot_all.shape[-1]
+    if _window_form(A, L, config) == "onehot":
+        win_oh = slot_all[..., None] == jnp.arange(L, dtype=jnp.int32)  # [G,P,A,L]
+        return lambda log: jnp.where(win_oh, log[:, :, None, :], 0).sum(axis=-1)
+    from .pallas_kernels import ring_window_pallas
+    return partial(ring_window_pallas, s0=slot_all[..., 0], A=A,
+                   interpret=config.pallas_interpret,
+                   mesh=config.kernel_mesh)
+
+
 def _scatter_lane(x: jnp.ndarray, lead: jnp.ndarray, active: jnp.ndarray,
                   new: jnp.ndarray) -> jnp.ndarray:
     """Write new[G,...] into x[G,P,...] at lane (g, lead[g]) where active."""
@@ -981,17 +1019,20 @@ def step(state: RaftState, submits: Submits, deliver: jnp.ndarray,
 
     # ---- phase 5: apply committed entries (all replicas, A per round) ----
     # All A candidate entries (contiguous indices applied+1 .. applied+A,
-    # capped at commit) are gathered in ONE fused one-hot select-reduce
-    # per log array (take_along_axis lowers to an element-wise DMA loop on
-    # TPU; the masked sum is a vector pass), then applied by the
-    # conflict-partitioned window kernel: each resource pool folds only
-    # ITS entries, carrying only its own arrays (apply.py apply_window).
+    # capped at commit) are read out of each log array by _window_gather:
+    # the ring rotated to the window's first slot by a Pallas kernel,
+    # log2(L) selects a plane, where A > log2(L) and the kernels are on
+    # (the raw and bulk cells, A = 16 of L = 32); the [A, L] one-hot
+    # select-reduce where they are not (the served engines, A = 4 of
+    # L = 64; every engine with use_pallas off). They are then applied
+    # by the conflict-partitioned window kernel: each resource pool folds
+    # only ITS entries, carrying only its own arrays (apply.py
+    # apply_window).
     idx_all = state.applied_index[..., None] + 1 \
         + jnp.arange(A, dtype=jnp.int32)[None, None, :]       # [G,P,A]
     slot_all = (idx_all - 1) % L
     do_all = idx_all <= commit2[..., None]
-    win_oh = slot_all[..., None] == jnp.arange(L, dtype=jnp.int32)  # [G,P,A,L]
-    ga = lambda log: jnp.where(win_oh, log[:, :, None, :], 0).sum(axis=-1)
+    ga = _window_gather(slot_all, L, config)
     time_w = ga(log_time2)
     op_w = ga(log_op2)
     a_w = ga(log_a2)

@@ -12,7 +12,20 @@ blocked Pallas kernel instead of ``jnp.sort``:
 - the same closed-form selection is also provided as a pure-jnp reference
   (``kth_largest``), the default path and the differential-test oracle.
 
-The kernel compiles to Mosaic for the TPU; ``interpret=True`` runs it in
+The committed window of phase 5 — A contiguous log indexes, so a cyclic run
+of each replica's L-slot ring — is read here too (``ring_window_pallas``):
+a rotation of the ring by the run's first slot, as a barrel shifter of
+``ceil(log2(L))`` stages over ``[P, L, G]`` blocks (groups on the lanes,
+ring slots on the sublanes, which is how the log planes lie in HBM
+already), each stage one ``pltpu.roll`` along the sublanes and one select.
+It exists as a kernel only: spelled in jnp, a stage's rotation is static
+slices, which the TPU compiler turns into relayout copies where the shift
+is no multiple of the 8-slot sublane tile (six planes on a v5e: 6.1 ms,
+13.2 ms with ``jnp.roll``, against 1.37 ms for the ``[A, L]`` one-hot
+select-reduce and 0.95 ms for the kernel; PERF.md §6, PR 52), so where the
+kernel is off ``ops.consensus`` keeps the one-hot.
+
+The kernels compile to Mosaic for the TPU; ``interpret=True`` runs them in
 Pallas's interpreter (tests on the CPU). The caller says which — gate via
 ``Config.use_pallas`` / ``Config.pallas_interpret`` (``ops.consensus``).
 """
@@ -129,3 +142,77 @@ def kth_largest_pallas(x: jnp.ndarray, k: int, block: int = 512,
     g = "groups" if "groups" in mesh.axis_names else None
     return jax.shard_map(kernel, mesh=mesh, in_specs=P(g, None),
                          out_specs=P(g), check_vma=False)(x)
+
+
+def _ring_shifts(L: int) -> list[int]:
+    return [1 << b for b in range((L - 1).bit_length())]
+
+
+def ring_window_fits(A: int, L: int) -> bool:
+    """Whether :func:`ring_window_pallas` takes a window of A from a ring
+    of L: whole sublane tiles in and out, the window no longer than the
+    ring."""
+    return A <= L and A % 8 == 0 and L % 8 == 0
+
+
+def _ring_kernel(s_ref, log_ref, out_ref, *, A: int):
+    """Block kernel: s [P, BG], log [P, L, BG] -> out [P, A, BG]."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    peers, L, _ = log_ref.shape
+    for p in range(peers):
+        x = log_ref[p]
+        s = s_ref[p:p + 1, :]
+        for k in _ring_shifts(L):
+            x = jnp.where((s & k) != 0, pltpu.roll(x, L - k, 0), x)
+        out_ref[p] = x[:A]
+
+
+def _ring_blocks(log: jnp.ndarray, s0: jnp.ndarray, A: int, block: int,
+                 interpret: bool) -> jnp.ndarray:
+    from jax.experimental import pallas as pl
+
+    G, P, L = log.shape
+    block = min(block, (G + 127) // 128 * 128)
+    # [P, L, G] and [P, G]: the planes' own order in HBM (groups
+    # minor-most, then slots, then peers), so no copy is made of them.
+    # The fence keeps the transpose out of the fusion that wrote the
+    # plane: fused, the TPU compiler computes the plane twice, once in
+    # each logical shape (the raw scan's one pass over the log became
+    # three fusions, compiled for a described v5e, PR 52).
+    out = pl.pallas_call(
+        functools.partial(_ring_kernel, A=A),
+        grid=(pl.cdiv(G, block),),
+        in_specs=[pl.BlockSpec((P, block), lambda g: (0, g)),
+                  pl.BlockSpec((P, L, block), lambda g: (0, 0, g))],
+        out_specs=pl.BlockSpec((P, A, block), lambda g: (0, 0, g)),
+        out_shape=jax.ShapeDtypeStruct((P, A, G), log.dtype),
+        interpret=interpret,
+    )(jnp.transpose(s0), jnp.transpose(jax.lax.optimization_barrier(log),
+                                       (1, 2, 0)))
+    from jax.experimental.layout import Layout, with_layout_constraint
+    return with_layout_constraint(jnp.transpose(out, (2, 0, 1)),
+                                  Layout(major_to_minor=(1, 2, 0)))
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("A", "block", "interpret", "mesh"))
+def ring_window_pallas(log: jnp.ndarray, s0: jnp.ndarray, A: int,
+                       block: int = 1024, interpret: bool = False,
+                       mesh=None) -> jnp.ndarray:
+    """``log [G, P, L]`` to ``[G, P, A]``: the ring's slots ``(s0 + i) % L``
+    for ``i < A`` (``s0 [G, P]`` in ``[0, L)``), whatever they hold, via a
+    Pallas TPU kernel; ``A <= L``, both multiples of the 8-slot sublane
+    tile (:func:`ring_window_fits`). Under a ``mesh`` it runs
+    inside a ``shard_map`` over the group axis, as
+    :func:`kth_largest_pallas` does and for the same reason; a peer axis
+    the mesh shards is gathered whole first."""
+    kernel = functools.partial(_ring_blocks, A=A, block=block,
+                               interpret=interpret)
+    if mesh is None:
+        return kernel(log, s0)
+    g = "groups" if "groups" in mesh.axis_names else None
+    return jax.shard_map(kernel, mesh=mesh,
+                         in_specs=(P(g, None, None), P(g, None)),
+                         out_specs=P(g, None, None),
+                         check_vma=False)(log, s0)

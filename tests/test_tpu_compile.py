@@ -38,7 +38,10 @@ from copycat_tpu.ops.consensus import (  # noqa: E402
     query_step,
     step,
 )
-from copycat_tpu.ops.pallas_kernels import kth_largest_pallas  # noqa: E402
+from copycat_tpu.ops.pallas_kernels import (  # noqa: E402
+    kth_largest_pallas,
+    ring_window_pallas,
+)
 
 @pytest.fixture(scope="module")
 def topo():
@@ -207,6 +210,23 @@ def test_tally_kernel_compiles_for_the_chip(one_chip, P_):
     assert has_kernel(compiled)
 
 
+@pytest.mark.parametrize("G,L,A", [
+    (100_000, 32, 16),      # the raw and bulk cells' ring and window
+    (10_000, 64, 16),
+    (1_000, 32, 32),
+    (1_000, 24, 16),        # a ring that is no power of two
+])
+def test_ring_window_kernel_compiles_for_the_chip(one_chip, G, L, A):
+    compiled = ring_window_pallas.lower(
+        _struct((G, 5, L), jnp.int32, one_chip),
+        _struct((G, 5), jnp.int32, one_chip), A=A).compile()
+    assert has_kernel(compiled)
+    # the planes go in and the windows come out as they lie: a copy here
+    # is a pass over a log plane that the one-hot form did not make
+    entry = compiled.as_text().split("ENTRY", 1)[1]
+    assert " copy(" not in entry and " transpose(" not in entry, entry
+
+
 @pytest.mark.parametrize("G,P_,L,S,resource", [
     (1024, 3, 64, 4, ResourceConfig()),                 # engine default
     (10_000, 3, 64, 16, ResourceConfig.counters_only()),  # bench counter
@@ -318,15 +338,21 @@ def test_snapshot_cut_is_fresh_slabs_and_shard_local(one_chip, four_chips,
         >= state_bytes // chips
 
 
-@pytest.mark.parametrize("pallas", [False, True], ids=["jnp", "pallas"])
-def test_group_sharded_step_has_zero_collectives(four_chips, pallas):
+@pytest.mark.parametrize("pallas,L,S", [
+    (False, 64, 4), (True, 64, 4),
+    # the bulk cell's ring and window: the committed window's kernel too
+    (True, 32, 16),
+], ids=["jnp", "pallas", "pallas-window16"])
+def test_group_sharded_step_has_zero_collectives(four_chips, pallas, L, S):
     # RaftGroups(mesh=...) hands the kernel its mesh the same way: left
     # alone, the TPU compiler refuses a Mosaic kernel on sharded operands
-    config = Config(use_pallas=pallas,
+    config = Config(use_pallas=pallas, append_window=S,
+                    applies_per_round=S,
                     kernel_mesh=four_chips if pallas else None)
-    compiled = compile_step(4096, 3, 64, 4, config, four_chips)
+    compiled = compile_step(4096, 3, L, S, config, four_chips)
     assert collectives_in(compiled) == {}
     assert has_kernel(compiled) == pallas
+    assert ("ring_window_pallas" in compiled.as_text()) == (S == 16)
 
 
 def test_a_bucketed_map_round_writes_its_table_in_place(one_chip):
