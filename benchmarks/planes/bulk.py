@@ -62,6 +62,40 @@ def replay_drives(sampled: list[np.ndarray], groups: np.ndarray,
     return compared, wrong, first, counters
 
 
+def driven_scan(program, config, donate: bool) -> tuple:
+    """The scan program this process's drives called and the form of its
+    accumulators (``onehot``), found without stating the form. ``program``
+    is the program's own cached builder: asked for each form in turn it
+    hands back the very jitted function the drives called, and only a
+    function that was called holds compiled entries (one that this asking
+    built holds none, nor does the text lowered from it below). Raises
+    where not exactly one form was called.
+
+    The builder's cache is the process's and its key is the configuration,
+    so this sees every drive of the process under ``config``, not one
+    engine's: a process that drives both forms under one configuration (a
+    test that does, not a run of a cell, which builds one engine) gets the
+    error, which is why the tiny mesh cell has a configuration of its own.
+    The count of compiled entries is JAX's ``_cache_size``, which is not
+    public: where a later JAX drops it the plane says so and does not
+    guess."""
+    forms = [(program(config, onehot=onehot, donate=donate), onehot)
+             for onehot in (False, True)]
+    sizes = [getattr(scan, "_cache_size", None) for scan, _ in forms]
+    if not all(callable(size) for size in sizes):
+        raise RuntimeError(
+            "bulk plane: this JAX's jitted functions have no _cache_size(), "
+            "by which the plane tells the scan the drives called from the "
+            "one it asked for; planes/bulk.py: driven_scan needs another way")
+    called = [form for form, size in zip(forms, sizes) if size()]
+    if len(called) != 1:
+        raise RuntimeError(
+            "bulk plane: the drives of this process called "
+            f"{len(called)} of the scan program's two forms with this "
+            "configuration; it reads the text of exactly one")
+    return called[0]
+
+
 def run(ctx) -> dict:
     import jax
 
@@ -240,12 +274,8 @@ def run(ctx) -> dict:
         from copycat_tpu.ops.consensus import Submits
         from copycat_tpu.parallel.scaling import census_text
 
-        built = _deep_scan_program.cache_info().misses
-        scan = _deep_scan_program(rg.config, onehot=mesh is not None,
-                                  donate=rg.donate)
-        if _deep_scan_program.cache_info().misses != built:
-            raise RuntimeError("bulk plane: the drives built another scan "
-                               "program than the one read here")
+        scan, form = driven_scan(_deep_scan_program, rg.config, rg.donate)
+        say(f"bulk plane: the drives built the scan with onehot={form}")
         staged = lambda dtype, *tail: rg._stage_acc(
             np.zeros((G, *tail), dtype))
         stacked = lambda dtype, width: jax.ShapeDtypeStruct(

@@ -386,8 +386,10 @@ async def _drive(ctx, root: str, kind: str) -> dict:
         # served plane's. The members are then some 3 s into their first
         # traffic and their engines' 64-slot log rings are still filling:
         # every capture packs a fuller ring than the last until they have
-        # wrapped (48 s in), so the rate falls through the window and the
-        # steady state lies below it (PERF.md section 6 has both).
+        # wrapped (48 s in): the engine's part of an image climbs through
+        # the window (0.2 to 0.6 MB, PR 51). The rate no longer falls with
+        # it: what fell was the sessions' reply cache a capture walked,
+        # which a session now bounds by count (PERF.md section 6, PR 51).
         t_warm, quiet = perf(), mix.get("warmup_quiet_s", QUIET_S)
         while True:
             await asyncio.sleep(0.25)

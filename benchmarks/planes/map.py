@@ -431,8 +431,8 @@ async def _drive(ctx) -> dict:
                           cfg["capacity"] * cfg["peers"]
                           * map_buckets(cfg["map_slots"])),
                       "other_state_bytes": state_bytes - table_bytes,
-                      "programs": ["jit_round_", "jit_query", "jit_fused"],
-                      "round_program": "jit_round_",
+                      "programs": ["jit_round", "jit_query", "jit_fused"],
+                      "round_program": "jit_round",
                       "load_keys_per_s": n_maps * n_keys / load_s},
             "spans": spans,
             "counters": {"rounds": deltas["rounds"]},

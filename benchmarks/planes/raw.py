@@ -11,6 +11,7 @@ cell's configuration and traffic files alone.
 from __future__ import annotations
 
 import functools
+import sys
 import time
 
 import numpy as np
@@ -239,6 +240,10 @@ def run(ctx) -> dict:
     correct = (compared > 0 and committed > 0 and int(add_max.max()) > 0
                and all(v <= lim for _, v, lim in checks))
     say(f"raw plane: checks took {time.perf_counter() - t_check:.1f}s")
+    # each number compared beside its limit, the last lines of standard error
+    for what, value, limit in checks:
+        print(f"raw plane: check: {what}: {value} (limit {limit})",
+              file=sys.stderr, flush=True)
     return {
         "window_start": t_start,
         "correct": correct, "attempted": committed,

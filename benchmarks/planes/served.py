@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import asyncio
 import os
+import sys
 import time
 
 import numpy as np
@@ -240,6 +241,11 @@ async def _drive(ctx) -> dict:
                 + " and ".join(f"{h1 - h0:.1f}s" for h0, h1 in held)
                 + f"; ack p99 {p99_clear} ms over the {int(clear.sum()):,} "
                 f"calls not in flight then")
+        # each number compared beside its limit, the last lines of
+        # standard error
+        for what, value, limit in checks:
+            print(f"served plane: check: {what}: {value} (limit {limit})",
+                  file=sys.stderr, flush=True)
         out = {
             "window_start": t_start,
             "correct": correct, "attempted": issued, "failed": failed,

@@ -7,7 +7,8 @@ One process per run. It needs as many TPU chips as the cell asks for (exit 2
 otherwise, whatever ``JAX_PLATFORMS`` says), sets up and warms up from the
 seed, measures for ``--seconds``, checks the answers outside the window and
 prints ONE JSON object as the last line of stdout: ``correct``, ``attempted``,
-``failed``, ``metrics``, ``device`` (and ``breakdown`` with ``--trace 1``).
+``failed``, ``metrics``, ``device`` (and ``breakdown`` with ``--trace 1``),
+then ``checks``: each number compared beside its limit.
 ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` its
 per-layer metrics.
 
@@ -172,6 +173,11 @@ def metrics_of(bench: dict, group: str, workload: str) -> list[dict]:
             if workload in m.get("workloads", [workload])]
 
 
+def plain(number):
+    """A numpy scalar as the Python number ``json`` can write."""
+    return number.item() if hasattr(number, "item") else number
+
+
 def device_block(chips: int) -> dict:
     import jax
 
@@ -270,6 +276,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             device["window_s"] = reduced["window_s"]
             line["breakdown"] = {"device_ops": reduced["device_ops"],
                                  "idle_gaps": reduced["idle_gaps"]}
+    # each number compared beside its limit, the line's last key: what the
+    # driver's record keeps of a run that was not correct
+    line["checks"] = [[what[:160], plain(value), plain(limit)]
+                      for what, value, limit in res["checks"]]
     return 0, line
 
 

@@ -70,7 +70,8 @@ def holds_the_dense_share(bench, root):
     """The entry by its name, wherever in the list a later PR left it, and
     its file equal to it; nothing of what stands before or after."""
     (m,) = [m for m in bench["per_layer"] if m["name"] == NAME]
-    assert m == ENTRY
+    # the list as a prefix: a later cell joins by its name at its end
+    assert {**m, "workloads": m["workloads"][:1]} == ENTRY
     spec = metric_file(root)
     assert all(spec[k] == m[k] for k in (
         "name", "unit", "better", "layer", "source", "moves"))

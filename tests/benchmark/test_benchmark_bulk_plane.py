@@ -7,10 +7,12 @@ resolves. Sizes come from ``tests/benchmark/data_bulk``, never from the cell's
 own files. No number from here is a device number.
 """
 
+import copy
 import functools
 import importlib.util
 import json
 import os
+import shutil
 import sys
 import time
 
@@ -22,6 +24,10 @@ BENCH = os.path.join(REPO, "benchmarks")
 DATA = os.path.join(REPO, "tests", "benchmark", "data_bulk")
 ONE, FOUR = "mixed-tiny-bulk.bulk-tiny", "mixed-tiny-bulk.bulk-tiny4"
 CELL = "mixed-400kx5-4chip.bulk"
+#: the same traffic on one chip (PR 53): it joined the four-chip cell's lists
+ONE_CHIP = "mixed-100kx5.bulk"
+#: what the plane checks and reads over a mesh only
+MESH_ONLY = {"placement.collectives", "placement.peak_skew"}
 #: the cell's metrics that this file holds (PR 26), in the root file's order;
 #: a metric on the cell that is not named here is a later PR's and brings a
 #: test and a tiny data directory of its own
@@ -29,6 +35,20 @@ NINE = ["bulk.drive_ms", "bulk.drive_max_ms", "bulk.fetches_per_drive",
         "bulk.d2h_bytes_per_op", "bulk.rounds_per_drive",
         "step.deep_scan_roofline", "device.idle_share.bulk",
         "placement.collectives", "placement.peak_skew"]
+#: the lists the one-chip cell joined, by name: the four-chip cell's as they
+#: stood at PR 53 but the placement's two and the two shares that
+#: ``tests/test_bulk_kept_buffers.py``, which is not the benchmark's to edit,
+#: holds to the four-chip cell alone (``OWED``: a later PR that may edit that
+#: file appends the cell). This file holds these eighteen and nothing of what
+#: the cell joins or is given later: that is the later PR's to hold
+JOINED = [name for name in NINE if name not in MESH_ONLY] + [
+    "bulk.admit_ms", "bulk.plan_ms", "bulk.stage_ms", "bulk.dispatch_ms",
+    "bulk.wait_ms", "bulk.fetch_ms", "bulk.harvest_ms", "bulk.return_ms",
+    "bulk.unspanned_ms", "bulk.h2d_bytes_per_op", "bulk.dense_drives_share"]
+#: the two lists the one-chip cell is owed (PERF.md section 7), and a later
+#: entry that names it: what ``after_a_later_prs_joins`` rehearses
+OWED = ("bulk.kept_bytes_share", "bulk.early_bytes_share")
+LATER, LIKE = "rehearsed.bulk_drives", "bulk.rounds_per_drive"
 #: what a CPU run cannot read: its devices report no memory, and
 #: ``peaks.json`` holds no peak for them
 CHIP_ONLY = {"placement.peak_skew", "step.deep_scan_roofline"}
@@ -78,8 +98,15 @@ def drive(harness, cell, trace=False, fault=None, seed=2**31 + 26):
     (ONE, False), (ONE, True), (FOUR, False), (FOUR, True)],
     ids=["one-device", "one-device-traced", "mesh-of-4", "mesh-of-4-traced"])
 def test_cell_prints_the_contracts_line_and_is_correct(harness, tiny, cell,
-                                                       trace):
+                                                       trace, capsys):
     line = drive(harness, cell, trace)
+    # the plane states no form of the scan's accumulators: it asks the
+    # program's own builder for each and reads the text of the one the
+    # drives called. Today a single-device engine drives the scatter form
+    # and a mesh the one-hot form; a ``perf_opt`` that gives one chip the
+    # one-hot form edits no benchmark file
+    assert ("bulk plane: the drives built the scan with onehot="
+            f"{cell == FOUR}") in capsys.readouterr().out
     assert set(line) >= {"correct", "attempted", "failed", "metrics", "device"}
     assert line["correct"] is True and line["failed"] == 0
     # three drives at least, of 32 operations for each of 64 groups
@@ -108,6 +135,109 @@ def test_cell_prints_the_contracts_line_and_is_correct(harness, tiny, cell,
         assert got["placement.collectives"] == 0.0
     else:
         assert "placement.collectives" not in wanted
+
+
+def after_a_later_prs_joins(bench, data_root):
+    """A copy of the root file after the PRs that PERF.md section 7 names:
+    ``mixed-100kx5.bulk`` appended to the two lists it is owed, and one new
+    entry that lists it, whose file lies under ``data_root`` (a copy of the
+    tiny data directory) as a later PR's would lie under ``benchmarks/``."""
+    bench = copy.deepcopy(bench)
+    for m in bench["per_layer"]:
+        if m["name"] in OWED:
+            m["workloads"].append(ONE_CHIP)
+    spec = json.load(open(os.path.join(BENCH, "layer_metrics",
+                                       LIKE + ".json")))
+    os.makedirs(os.path.join(data_root, "layer_metrics"))
+    with open(os.path.join(data_root, "layer_metrics", LATER + ".json"),
+              "w") as f:
+        json.dump({**spec, "name": LATER, "key": "drives"}, f)
+    bench["per_layer"].append({
+        **{k: spec[k] for k in ("unit", "better", "source", "layer",
+                                "moves")},
+        "name": LATER, "workloads": [ONE_CHIP]})
+    return bench
+
+
+@pytest.mark.parametrize("later", [False, True],
+                         ids=["as-it-stands", "after-a-later-prs-joins"])
+def test_the_one_chip_cells_lists_print_on_one_device(harness, bench, tiny,
+                                                      tmp_path, later):
+    """``mixed-100kx5.bulk`` joined eighteen lists of the root file. The tiny
+    one-device cell under the very entries that list it prints every one of
+    the eighteen that a CPU run can read, nothing that was not asked for, and
+    nothing of the placement, which one chip checks and reads nothing of.
+    The run is held to what the root file asks, not to a list frozen here:
+    after a later PR has appended the cell to more lists and brought an entry
+    for it, the same assertions hold and the run prints those too."""
+    data_root = DATA
+    if later:
+        data_root = str(tmp_path / "data_bulk")
+        shutil.copytree(DATA, data_root)
+        bench = after_a_later_prs_joins(bench, data_root)
+        for rule in ROOT_FILE_RULES:
+            rule(bench, REPO)
+    asked = [{**m, "workloads": [ONE]}
+             for m in harness.metrics_of(bench, "per_layer", ONE_CHIP)]
+    names = [m["name"] for m in asked]
+    assert [name for name in names if name in JOINED] == JOINED
+    assert not MESH_ONLY & set(names)
+    rehearsal = tmp_path / "BENCHMARK.json"
+    rehearsal.write_text(json.dumps({**tiny, "per_layer": asked}))
+    rc, line = harness.run_cell(ONE, 2**31 + 53, 0.3, True, None,
+                                bench_file=str(rehearsal),
+                                data_root=data_root, require_tpu=False)
+    assert rc == 0 and line["correct"] is True and line["failed"] == 0
+    assert set(JOINED) - CHIP_ONLY <= set(line["metrics"]) <= set(names)
+    assert not [name for name in line["metrics"] if "placement" in name]
+    got = {k: v["value"] for k, v in line["metrics"].items()}
+    assert got["bulk.fetches_per_drive"] == 1.0
+    assert got["bulk.dense_drives_share"] == 1.0
+    if later:
+        assert set(names) == set(JOINED) | set(OWED) | {LATER}
+        assert set(line["metrics"]) == set(names) - CHIP_ONLY
+        assert got[LATER] >= 3 and got["bulk.kept_bytes_share"] > 0
+
+
+class FakeScan:
+    def __init__(self, entries):
+        self.entries = entries
+
+    def _cache_size(self):
+        return self.entries
+
+
+@pytest.mark.parametrize("called,found", [
+    ({False: 2, True: 0}, False), ({False: 0, True: 1}, True),
+    ({False: 0, True: 0}, None), ({False: 1, True: 1}, None)],
+    ids=["scatter", "onehot", "neither", "both"])
+def test_the_scan_is_the_one_form_that_was_called(harness, called, found):
+    """``driven_scan`` over a builder that hands back, for each form, a
+    jitted function with as many compiled entries as ``called`` says: the
+    form that was called, and an error where none or both were."""
+    plane = harness.load_module("planes", "bulk", DATA)
+    asked = []
+
+    def program(config, onehot, donate):
+        asked.append((config, onehot, donate))
+        return FakeScan(called[onehot])
+
+    if found is None:
+        with pytest.raises(RuntimeError, match="exactly one"):
+            plane.driven_scan(program, "config", True)
+    else:
+        scan, onehot = plane.driven_scan(program, "config", True)
+        assert onehot is found and scan.entries == called[found]
+    assert asked == [("config", False, True), ("config", True, True)]
+
+
+def test_a_jax_without_the_count_of_compiled_entries_is_said_so(harness):
+    """``_cache_size`` is not JAX's public surface: a jitted function
+    without it makes the plane say what it lacks, not guess a form."""
+    plane = harness.load_module("planes", "bulk", DATA)
+    with pytest.raises(RuntimeError, match="no _cache_size"):
+        plane.driven_scan(lambda config, onehot, donate: object(),
+                          "config", True)
 
 
 @pytest.mark.parametrize("fault", ["drop-ack", "flip-result"])
@@ -275,9 +405,9 @@ def holds_the_cells_metrics_to_the_tiny_cells(bench, root):
         root, "tests", "benchmark", "data_bulk", "BENCHMARK.json")))
     rate = next(m for m in bench["end_to_end"]
                 if m["name"] == "bulk_ops_per_s")
-    # a second cell under this rate would share its bound: a ``benchmark``
-    # issue's decision
-    assert rate["workloads"] == [CELL] and 0.01 <= rate["bound"] <= 0.25
+    # a cell under this rate shares its bound; the list is held as a prefix
+    assert rate["workloads"][:2] == [CELL, ONE_CHIP]
+    assert 0.01 <= rate["bound"] <= 0.25
     assert (rate["unit"], rate["better"], rate["source"]) == (
         "ops/s", "higher", "host_clock")
     assert {m["name"] for m in run_py().metrics_of(
@@ -293,8 +423,30 @@ def holds_the_cells_metrics_to_the_tiny_cells(bench, root):
         assert m["moves"] == "bulk_ops_per_s"
 
 
+def holds_the_one_chip_cell_to_the_four_chip_cells_lists(bench, root):
+    """``mixed-100kx5.bulk`` is data only: the raw cell's configuration, the
+    four-chip cell's traffic, one chip, and its name after the four-chip
+    cell's in every list of ``JOINED``."""
+    cell = next(w for w in bench["workloads"] if w["name"] == ONE_CHIP)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "mixed-100kx5", "bulk", 1)
+    entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
+    assert entry["reduced"] == []                       # the source's scale
+    assert {m["name"] for m in run_py().metrics_of(
+        bench, "end_to_end", ONE_CHIP)} == {"bulk_ops_per_s", "setup_s"}
+    joined = [m for m in bench["per_layer"] if m["name"] in JOINED]
+    assert [m["name"] for m in joined] == JOINED and len(JOINED) == 18
+    for m in joined:
+        assert m["workloads"][:2] == [CELL, ONE_CHIP], m["name"]
+        assert m["moves"] == "bulk_ops_per_s"
+    for m in bench["per_layer"]:
+        if m["name"] in MESH_ONLY:
+            assert ONE_CHIP not in m["workloads"], m["name"]
+
+
 ROOT_FILE_RULES = [holds_the_cell_its_configuration_and_its_traffic,
-                   holds_the_cells_metrics_to_the_tiny_cells]
+                   holds_the_cells_metrics_to_the_tiny_cells,
+                   holds_the_one_chip_cell_to_the_four_chip_cells_lists]
 
 
 def test_the_cell_its_configuration_and_its_traffic_resolve(bench, harness):
@@ -305,3 +457,12 @@ def test_the_cell_its_configuration_and_its_traffic_resolve(bench, harness):
 
 def test_the_cells_metrics_are_the_tiny_cells_metrics(bench):
     holds_the_cells_metrics_to_the_tiny_cells(bench, REPO)
+
+
+def test_the_one_chip_cell_is_in_the_four_chip_cells_lists(bench, harness):
+    holds_the_one_chip_cell_to_the_four_chip_cells_lists(bench, REPO)
+    # and a run of it loads the raw cell's deployment and the bulk mix
+    cell, config, traffic = harness.load_cell(bench, ONE_CHIP, BENCH)
+    assert (config["groups"], config["peers"], cell["chips"]) == (
+        100_000, 5, 1)
+    assert traffic == harness.load_cell(bench, CELL, BENCH)[2]

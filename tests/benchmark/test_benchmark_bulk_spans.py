@@ -91,7 +91,8 @@ def holds_a_bulk_span_metric(bench, root, name):
     its file equal to it; nothing of what stands before or after."""
     entry, reads = HELD[name]
     (m,) = [m for m in bench["per_layer"] if m["name"] == name]
-    assert m == {"name": name, **entry}
+    # the list as a prefix: a later cell joins by its name at its end
+    assert {**m, "workloads": m["workloads"][:1]} == {"name": name, **entry}
     spec = metric_file(name, root)
     assert all(spec[k] == m[k] for k in (
         "name", "unit", "better", "layer", "source", "moves"))

@@ -29,16 +29,25 @@ DATA = os.path.join(REPO, "tests", "benchmark", "data_crash")
 TINY, CELL = "crash-tiny.kill-rejoin-tiny", "cluster-3x1k-crash.kill-rejoin"
 CONFIG = "cluster-3x1k-crash"
 SECONDS = 4.6
-#: the cell's metrics, in the root file's order (fourteen: the contract
-#: allows 128 per-layer metrics and 114 stood, so the issue's
+#: the layers' readings the cell joined by its name in their lists, in the
+#: root file's order: the four PR 53 folded its copies into
+#: (``crash.ack_p99_ms``, ``crash.rounds_per_kop``, ``device.idle_share.crash``,
+#: ``crash.fsyncs_per_kop``) and the cluster's six counters that move
+#: ``served_ops_per_s``, whose keys the plane had handed the harness all along
+JOINED = ["client.ack_p99_ms", "engine.rounds_per_kop",
+          "device.idle_share.served", "cluster.fsyncs_per_kop",
+          "cluster.log_bytes_per_op", "cluster.repl_windows_per_kop",
+          "cluster.snapshots_per_kop", "cluster.captures_deferred_per_kop",
+          "cluster.codec_python_bodies_per_kop", "cluster.log_writes_per_kop"]
+#: the cell's own ten, in the root file's order (the issue's
 #: ``crash.ack_p50_ms``, the client count over the rate, was left out, and
 #: ``crash.elections_per_window``, which check (f) holds to 1, gave its place
 #: to the log's syncs, which nothing else says of a window with a member down)
-NEW = ["crash.leader_gap_ms", "crash.follower_gap_ms", "crash.ack_p99_ms", "crash.client_failover_ms",
-       "crash.resubmits_per_kill", "crash.election_ms", "crash.recover_ms",
-       "crash.install_ms", "crash.restore_ms", "crash.catchup_ms",
-       "crash.installs_per_kill", "crash.fsyncs_per_kop",
-       "crash.rounds_per_kop", "device.idle_share.crash"]
+OWN = ["crash.leader_gap_ms", "crash.follower_gap_ms",
+       "crash.client_failover_ms", "crash.resubmits_per_kill",
+       "crash.election_ms", "crash.recover_ms", "crash.install_ms",
+       "crash.restore_ms", "crash.catchup_ms", "crash.installs_per_kill"]
+NEW = JOINED + OWN
 #: the five spans this cell brought, with the attributes each carries
 SPANS = {"client.failover": {"inflight", "resubmitted", "attempts"},
          "raft.election": {"term", "votes"},
@@ -98,7 +107,28 @@ def often():
 def drive(harness, plane, trace=False, fault=None, seed=2**31 + 49):
     """One run of the tiny cell: the result line, the checks as printed on
     standard error, standard output, and what the plane handed the harness
-    (``facts`` for the checks, ``five`` for the failure's spans)."""
+    (``facts`` for the checks, ``five`` for the failure's spans).
+
+    Check (g) wants each rejoined member served by an install, and whether
+    the killed leader needs one is the timers' on this one CPU loop: where a
+    restart's boot recovery and restore hold the loop for 0.3 to 0.4 s each
+    (a process that loads its programs as it goes: a cold cache, many tests
+    before this one) the two members left elect no leader for 1.6 s of the
+    1.9 s between the kill and the restart, nothing is appended, and the
+    restarted leader catches up from its own log. Such a run gets a second
+    go, with the programs loaded; a sound run's answers, (a) to (e) and (h)
+    to (j), are held in the first go too."""
+    for attempt in (0, 1):
+        line, checks, out, handed = drive_once(harness, plane, trace, fault,
+                                               seed)
+        if not checks["(g)"] or attempt:
+            return line, checks, out, handed
+        if fault is None:
+            assert {c for c, v in checks.items() if v} <= {"(f)", "(g)"}, (
+                checks, out)
+
+
+def drive_once(harness, plane, trace, fault, seed):
     handed, real = {}, plane.run
 
     def run(ctx):
@@ -204,7 +234,7 @@ def test_the_traced_run_prints_the_cells_metrics(traced, harness):
         assert isinstance(got["value"], float) and got["value"] >= 0, name
     got = {k: v["value"] for k, v in line["metrics"].items()}
     assert got["crash.installs_per_kill"] >= 0.5
-    assert got["crash.fsyncs_per_kop"] > 0
+    assert got["cluster.fsyncs_per_kop"] > 0
     assert got["crash.resubmits_per_kill"] >= 0.5
     # an election timeout of 0.5 s: the gap is of its order, not a
     # session's timeout (10 s here)
@@ -250,6 +280,30 @@ def test_the_restore_lies_under_the_installs_trace_id(traced):
 
 
 # -- the faults, one run each -------------------------------------------------
+
+def test_only_a_run_without_an_install_gets_a_second_go(monkeypatch):
+    """``drive`` over runs that are given: (g) alone sends it round again,
+    once; a wrong answer beside it is not excused, and the second go's (g)
+    stands."""
+    here = sys.modules[__name__]
+    zero = {f"({c})": 0 for c in "abcdefghij"}
+
+    def given(*runs):
+        left = list(runs)
+        monkeypatch.setattr(here, "drive_once", lambda *_: (
+            {"n": len(runs) - len(left) + 1}, left.pop(0), "", {}))
+        return left
+
+    left = given(zero, zero)
+    assert drive(None, None)[0] == {"n": 1} and len(left) == 1
+    left = given({**zero, "(f)": 1, "(g)": 1}, zero)
+    assert drive(None, None)[0] == {"n": 2} and not left
+    left = given({**zero, "(g)": 1}, {**zero, "(g)": 1})
+    assert drive(None, None)[1]["(g)"] == 1 and not left
+    given({**zero, "(a)": 1, "(g)": 1}, zero)
+    with pytest.raises(AssertionError):
+        drive(None, None)
+
 
 @pytest.mark.parametrize("fault,seen,may", [
     ("drop-ack", {"(b)", "(c)"}, {"(a)", "(i)"}),
@@ -447,7 +501,12 @@ def holds_the_crash_cells_entries(bench, root):
     assert CELL not in tail["workloads"]
     mine = [m for m in bench["per_layer"] if m["name"] in NEW]
     assert [m["name"] for m in mine] == NEW
-    assert all(m["workloads"] == [CELL] for m in mine)
+    # a list is held as a prefix: the cell began its own ten, and joined
+    # the others after the cells their PRs had put there
+    assert all(m["workloads"][:1] == [CELL] for m in mine
+               if m["name"] in OWN)
+    assert all(CELL in m["workloads"][1:] for m in mine
+               if m["name"] in JOINED)
     assert all(m["moves"] == "served_ops_per_s" for m in mine)
     return cell, held, mix
 
@@ -481,7 +540,7 @@ def test_the_twin_reads_what_the_cell_reads(bench):
     holds_the_twins_entries_to_the_cells(bench, REPO)
 
 
-@pytest.mark.parametrize("name", NEW)
+@pytest.mark.parametrize("name", OWN)
 def test_a_metrics_file_is_there_and_says_what_it_reads(bench, name):
     spec = json.load(open(os.path.join(BENCH, "layer_metrics",
                                        name + ".json")))

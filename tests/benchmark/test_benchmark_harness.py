@@ -56,15 +56,34 @@ def drive(harness, cell, trace=False, fault=None, seed=2**31 + 77):
     return line
 
 
+def compared(line):
+    """The line's last key: each number compared beside its limit."""
+    assert list(line)[-1] == "checks" and line["checks"]
+    for what, value, limit in line["checks"]:
+        assert isinstance(what, str) and 0 < len(what) <= 160
+        assert type(value) in (int, float) and type(limit) in (int, float)
+    return line["checks"]
+
+
 @pytest.mark.parametrize("cell,trace", [
     (SERVED_W, False), (SERVED_R, True), (RAW, False), (RAW, True),
     (RAW + "4", False)],
     ids=["served-write", "served-read90-traced", "raw", "raw-traced",
          "raw-over-a-mesh-of-4"])
-def test_cell_prints_the_contracts_line_and_is_correct(harness, cell, trace):
+def test_cell_prints_the_contracts_line_and_is_correct(harness, cell, trace,
+                                                       capfd):
     line = drive(harness, cell, trace)
     assert set(line) >= {"correct", "attempted", "failed", "metrics", "device"}
     assert line["correct"] is True and line["failed"] == 0
+    # every number compared beside its limit: the line's last key, and the
+    # last lines of standard error (PERF.md section 7 item 12, PR 53)
+    assert all(value <= limit for _, value, limit in compared(line))
+    plane = "served" if cell.startswith("served") else "raw"
+    said = [text for text in capfd.readouterr().err.splitlines()
+            if text.startswith(plane + " plane: check: ")]
+    assert len(said) == len(line["checks"])
+    assert all(text.endswith(f": {value} (limit {limit})")
+               for text, (_, value, limit) in zip(said, line["checks"]))
     assert line["attempted"] > 0
     assert set(line["device"]) >= {"platform", "kind", "count",
                                    "memory_peak_bytes"}
@@ -92,7 +111,10 @@ def test_cell_prints_the_contracts_line_and_is_correct(harness, cell, trace):
 @pytest.mark.parametrize("cell", [SERVED_W, RAW], ids=["served", "raw"])
 @pytest.mark.parametrize("fault", ["drop-ack", "flip-result"])
 def test_a_fault_in_the_harness_gives_correct_false(harness, cell, fault):
-    assert drive(harness, cell, fault=fault)["correct"] is False
+    line = drive(harness, cell, fault=fault)
+    assert line["correct"] is False
+    # and the line says which number passed its limit
+    assert any(value > limit for _, value, limit in compared(line))
 
 
 def test_a_reply_altered_where_it_is_produced_gives_correct_false(
@@ -321,43 +343,62 @@ def every_files_rules():
 
 
 CLUSTER, BULK = "cluster-3x1k.write", "mixed-400kx5-4chip.bulk"
+#: the one-chip bulk cell, and the two lists PERF.md section 7 says it is owed
+BULK_ONE = "mixed-100kx5.bulk"
+OWED = ("bulk.kept_bytes_share", "bulk.early_bytes_share")
+
+
+def append_a_served_cell(root, bench, config, traffic, why):
+    """A later deployment and cell on the served plane: copies of
+    ``served-1k`` and the write mix under new names, an entry each, and the
+    cell's name at the end of ``served_ops_per_s``'s list."""
+    here, cell = root / "benchmarks", f"{config}.{traffic}"
+    shutil.copy(here / "configs" / "served-1k.json",
+                here / "configs" / (config + ".json"))
+    shutil.copy(here / "traffic" / "write.json",
+                here / "traffic" / (traffic + ".json"))
+    first = bench["configs"][0]
+    bench["configs"].append({
+        "name": config, "source": first["source"],
+        "file": f"benchmarks/configs/{config}.json",
+        "reduced": first["reduced"], "why": "a later deployment"})
+    bench["workloads"].append({"name": cell, "config": config,
+                               "traffic": traffic, "chips": 1, "why": why})
+    next(m for m in bench["end_to_end"]
+         if m["name"] == "served_ops_per_s")["workloads"].append(cell)
+    return cell
+
+
+def append_a_metric(root, bench, name, like, key, cells):
+    """A later entry for a reading no entry gives: ``like``'s file under
+    ``name`` with a ``key`` of its own (the same file under another name is
+    a copy, which test_benchmark_readings.py refuses)."""
+    metrics = root / "benchmarks" / "layer_metrics"
+    spec = json.load(open(metrics / (like + ".json")))
+    with open(metrics / (name + ".json"), "w") as f:
+        json.dump({**spec, "name": name, "key": key}, f)
+    bench["per_layer"].append({
+        **{k: spec[k] for k in ("unit", "better", "source", "layer",
+                                "moves")}, "name": name, "workloads": cells})
 
 
 def append_what_later_prs_add(root):
     """What a ``model_config`` PR adds: a configuration, a mix on a plane
     that is there, a one-chip cell under ``served_ops_per_s`` and two
     per-layer metrics at the end of the list, one of them read in the cluster
-    cell too. And what a ``tracing`` PR adds: a metric on the bulk cell.
+    cell too. And what a ``tracing`` PR adds: a metric on the two bulk cells.
     Files added, and no file that was there edited but ``BENCHMARK.json``."""
-    here = root / "benchmarks"
-    cell = "rehearsed-1k.rehearsed"
-    shutil.copy(here / "configs" / "served-1k.json",
-                here / "configs" / "rehearsed-1k.json")
-    shutil.copy(here / "traffic" / "write.json",
-                here / "traffic" / "rehearsed.json")
     bench = json.load(open(root / "BENCHMARK.json"))
-    first = bench["configs"][0]
-    bench["configs"].append({
-        "name": "rehearsed-1k", "source": first["source"],
-        "file": "benchmarks/configs/rehearsed-1k.json",
-        "reduced": first["reduced"], "why": "a later deployment"})
-    bench["workloads"].append({
-        "name": cell, "config": "rehearsed-1k", "traffic": "rehearsed",
-        "chips": 1, "why": "a later cell on the served plane"})
-    next(m for m in bench["end_to_end"]
-         if m["name"] == "served_ops_per_s")["workloads"].append(cell)
-    for name, like, cells in (
-            ("rehearsed.alone_per_kop", "runtime.fetches_per_kop", [cell]),
+    cell = append_a_served_cell(root, bench, "rehearsed-1k", "rehearsed",
+                                "a later cell on the served plane")
+    for name, like, key, cells in (
+            ("rehearsed.alone_per_kop", "runtime.fetches_per_kop",
+             ["counters", "rehearsed.alone"], [cell]),
             ("rehearsed.shared_per_kop", "runtime.fetches_per_kop",
-             [cell, CLUSTER]),
-            ("rehearsed.bulk_per_drive", "bulk.fetches_per_drive", [BULK])):
-        spec = json.load(open(here / "layer_metrics" / (like + ".json")))
-        with open(here / "layer_metrics" / (name + ".json"), "w") as f:
-            json.dump({**spec, "name": name}, f)
-        bench["per_layer"].append({
-            **{k: spec[k] for k in ("unit", "better", "source", "layer",
-                                    "moves")}, "name": name,
-            "workloads": cells})
+             ["counters", "rehearsed.shared"], [cell, CLUSTER]),
+            ("rehearsed.bulk_per_drive", "bulk.fetches_per_drive",
+             "rehearsed_fetches", [BULK, BULK_ONE])):
+        append_a_metric(root, bench, name, like, key, cells)
     with open(root / "BENCHMARK.json", "w") as f:
         json.dump(bench, f, indent=1)
     return bench
@@ -371,7 +412,8 @@ def test_a_later_prs_entries_pass_every_rule_of_every_test_file(tmp_path):
     rules = every_files_rules()
     assert {name for name, _ in rules} >= {
         "test_benchmark_harness", "test_benchmark_pump_metrics",
-        "test_benchmark_bulk_plane", "test_benchmark_cluster_plane"}
+        "test_benchmark_bulk_plane", "test_benchmark_cluster_plane",
+        "test_benchmark_readings"}
     # the layers' names and the spans' vocabulary, which a metric is held to
     copy_the_benchmark(tmp_path, "PERF.md",
                        os.path.join("docs", "OBSERVABILITY.md"))
@@ -381,7 +423,8 @@ def test_a_later_prs_entries_pass_every_rule_of_every_test_file(tmp_path):
         assert bench[group][:len(was[group])] == was[group]
         assert len(bench[group]) > len(was[group])
     for there, last in ((CLUSTER, "rehearsed.shared_per_kop"),
-                        (BULK, "rehearsed.bulk_per_drive")):
+                        (BULK, "rehearsed.bulk_per_drive"),
+                        (BULK_ONE, "rehearsed.bulk_per_drive")):
         assert run_py().metrics_of(bench, "per_layer", there)[-1][
             "name"] == last
     for _, rule in rules:
@@ -391,6 +434,71 @@ def test_a_later_prs_entries_pass_every_rule_of_every_test_file(tmp_path):
     os.remove(tmp_path / "benchmarks" / "traffic" / "rehearsed.json")
     with pytest.raises(FileNotFoundError, match="rehearsed.json"):
         holds_that_everything_named_resolves(bench, str(tmp_path))
+
+
+#: the layers' readings a later cell on the served plane reads as they are
+JOINS = ("client.ack_p50_ms", "engine.rounds_per_kop",
+         "device.idle_share.served")
+#: its one entry of its own, and the entry whose file it is written after
+OWN, LIKE = "joining.own_per_kop", "engine.settle_rounds_per_kop"
+
+
+def append_a_cell_that_joins(root):
+    """What a ``model_config`` PR adds since PR 53, where an entry already
+    gives the reading its cell wants: the cell, its name at the end of
+    ``served_ops_per_s``'s list and of three per-layer lists, and one entry
+    of its own for the reading no entry gives. And a cell that is there
+    joins two more lists: the one-chip bulk cell the two it is owed. Entries
+    and names appended; no file that was there edited but
+    ``BENCHMARK.json``."""
+    bench = json.load(open(root / "BENCHMARK.json"))
+    cell = append_a_served_cell(root, bench, "joining-1k", "joins",
+                                "a later cell that reads what the layers give")
+    for m in bench["per_layer"]:
+        if m["name"] in JOINS:
+            m["workloads"].append(cell)
+        if m["name"] in OWED and BULK_ONE not in m["workloads"]:
+            m["workloads"].append(BULK_ONE)
+    append_a_metric(root, bench, OWN, LIKE, ["counters", "joining.own"],
+                    [cell])
+    with open(root / "BENCHMARK.json", "w") as f:
+        json.dump(bench, f, indent=1)
+    return bench, cell
+
+
+def test_a_later_cell_that_joins_three_lists_passes_every_rule(tmp_path):
+    """A cell joins a metric by its list: appended to the lists of three
+    entries that give its readings, it passes every assertion of every test
+    file, and a traced run of it would be asked for those three and its
+    own. A test that holds a list whole, and not as a prefix, fails here."""
+    rules = every_files_rules()
+    assert "test_benchmark_readings" in {name for name, _ in rules}
+    copy_the_benchmark(tmp_path, "PERF.md",
+                       os.path.join("docs", "OBSERVABILITY.md"))
+    was = json.load(open(tmp_path / "BENCHMARK.json"))
+    bench, cell = append_a_cell_that_joins(tmp_path)
+    for group in ("end_to_end", "per_layer"):
+        for before, after in zip(was[group], bench[group]):
+            held = len(before.get("workloads", []))
+            assert {**after, "workloads": after.get("workloads", [])[:held]} \
+                == {"workloads": [], **before}
+    assert len(bench["per_layer"]) == len(was["per_layer"]) + 1
+    assert [m["name"] for m in run_py().metrics_of(
+        bench, "per_layer", cell)] == [
+            m["name"] for m in was["per_layer"] if m["name"] in JOINS
+        ] + [OWN]
+    assert set(OWED) <= {m["name"] for m in run_py().metrics_of(
+        bench, "per_layer", BULK_ONE)}
+    for _, rule in rules:
+        rule(bench, str(tmp_path))
+    # the same reading under the cell's own name is what the rules refuse
+    metrics = tmp_path / "benchmarks" / "layer_metrics"
+    with open(metrics / (OWN + ".json"), "w") as f:
+        json.dump({**json.load(open(metrics / (LIKE + ".json"))),
+                   "name": OWN}, f)
+    with pytest.raises(AssertionError):
+        for _, rule in rules:
+            rule(bench, str(tmp_path))
 
 
 # -- the yardstick's own arithmetic ------------------------------------------
